@@ -29,9 +29,6 @@ See the subpackage docstrings for the public surface of each plane.
 # NOTE: this module stays jax-free — the protocol plane (config, messages,
 # protocol/) runs in master/worker subprocesses that never touch a device,
 # and `import akka_allreduce_tpu` must not tax them with the jax import.
-# The 0.4.x compat shim (utils/compat.py) installs from the jax-facing
-# subpackage __init__s instead (ops, parallel, models, utils), which
-# Python runs before any of their submodules.
 
 from akka_allreduce_tpu.config import (
     ThresholdConfig,

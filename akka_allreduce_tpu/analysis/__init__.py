@@ -50,11 +50,7 @@ Layout:
                      jaxpr/StableHLO catalog provably misses.
 """
 
-from akka_allreduce_tpu.utils.compat import install as _install_jax_compat
-
-_install_jax_compat()  # graft current-JAX names onto 0.4.x (no-op on new)
-
-from akka_allreduce_tpu.analysis.core import (  # noqa: E402
+from akka_allreduce_tpu.analysis.core import (
     Finding,
     LintContext,
     LintPolicy,
@@ -63,20 +59,20 @@ from akka_allreduce_tpu.analysis.core import (  # noqa: E402
     run_passes,
     trace_entry,
 )
-from akka_allreduce_tpu.analysis.hlo import (  # noqa: E402
+from akka_allreduce_tpu.analysis.hlo import (
     HloModule,
     HloPolicy,
     parse_hlo_text,
     run_hlo_passes,
     run_with_hlo,
 )
-from akka_allreduce_tpu.analysis.host import (  # noqa: E402
+from akka_allreduce_tpu.analysis.host import (
     HostPolicy,
     analyze_source,
     build_host_catalog,
     run_host_passes,
 )
-from akka_allreduce_tpu.analysis.recompile import (  # noqa: E402
+from akka_allreduce_tpu.analysis.recompile import (
     CompileLog,
     RecompileError,
     assert_max_compiles,

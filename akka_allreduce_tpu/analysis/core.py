@@ -19,7 +19,9 @@ when the HLO passes are armed (``lint --hlo``).
 
 from __future__ import annotations
 
+import collections
 import dataclasses
+import math
 from typing import Any, Callable, Iterator, Optional
 
 import jax
@@ -42,9 +44,11 @@ REDUCE_PHASE_PRIMS = frozenset({"reduce_scatter", "all_to_all"})
 GATHER_PHASE_PRIMS = frozenset({"all_gather"})
 # Primitives that round-trip through the host: reachable from a hot loop
 # they serialize the device against Python.
+# (jax 0.9.0 lowers jax.debug.print to its own ``debug_print`` primitive;
+# jax.debug.callback stays ``debug_callback``.)
 HOST_SYNC_PRIMS = frozenset({
-    "pure_callback", "io_callback", "debug_callback", "outside_call",
-    "host_callback_call", "infeed", "outfeed",
+    "pure_callback", "io_callback", "debug_callback", "debug_print",
+    "infeed", "outfeed",
 })
 # Control-flow primitives whose body re-runs per trip — an eqn inside
 # them is "in a hot loop" for the host-sync pass.
@@ -218,13 +222,18 @@ def out_dtype(eqn):
 
 # -- the shared donation audit ------------------------------------------
 
-# the lowered markers jit emits for a donated input that survived
-# lowering: ``tf.aliasing_output`` pins the input to a specific output
-# at lowering time (simple un-sharded programs); ``jax.buffer_donor``
-# hands the buffer to XLA to alias during compilation (the sharded /
-# mesh path, where output layout is XLA's call). A donation that was
-# UNUSABLE (dtype/shape matched no output) gets neither marker — JAX
-# warns once at lowering and silently copies forever after.
+# the lowered markers jit (jax 0.9.0) emits for a donated input:
+# ``tf.aliasing_output`` pins the input to an output of the SAME shape
+# and dtype at lowering time; ``jax.buffer_donor`` hands the buffer to
+# XLA to place during compilation — the sharded / mesh path, where
+# output layout is XLA's call, AND every donor that matched no output's
+# shape+dtype but shares an element count with one (mlir._set_up_aliases).
+# So a marker is no longer proof of a usable donation: a donor whose
+# dtype matches no output is marked ``jax.buffer_donor`` without a
+# warning, and XLA — which only reuses a buffer for an output of the
+# same byte size — then copies silently forever after. Only a donor with
+# no output of even the same element count gets no marker (and JAX's
+# one warning).
 ALIAS_MARKER_ATTRS = ("tf.aliasing_output", "jax.buffer_donor")
 
 
@@ -236,6 +245,26 @@ def count_donation_markers(stablehlo: Optional[str]) -> Optional[int]:
     import re as _re
     return sum(len(_re.findall(_re.escape(attr), stablehlo))
                for attr in ALIAS_MARKER_ATTRS)
+
+
+def count_unplaceable_donors(ctx: "LintContext") -> int:
+    """Declared donations with no output of the same BYTE size left to
+    take them (each output serves one donor) — the buffers XLA cannot
+    reuse whatever marker the lowering left on them."""
+    def nbytes(aval) -> int:
+        return int(math.prod(aval.shape)) * aval.dtype.itemsize
+
+    free = collections.Counter(
+        nbytes(a) for a in ctx.jaxpr.out_avals if hasattr(a, "dtype"))
+    unplaceable = 0
+    for aval, donated in zip(ctx.in_avals, ctx.donated):
+        if not donated:
+            continue
+        if free[nbytes(aval)] > 0:
+            free[nbytes(aval)] -= 1
+        else:
+            unplaceable += 1
+    return unplaceable
 
 
 def donation_drop_findings(ctx: "LintContext",
@@ -284,17 +313,20 @@ def donation_drop_findings(ctx: "LintContext",
                 f"dispatch and the in-place-update HBM contract is "
                 f"fiction for it", name))
         return findings
-    if markers is not None and markers < len(declared):
-        dropped_n = len(declared) - markers
+    unmarked = max(0, len(declared) - markers) if markers is not None \
+        else 0
+    dropped_n = max(unmarked, count_unplaceable_donors(ctx))
+    if dropped_n:
         findings.append(Finding(
             pass_name, "error", ctx.name,
             f"{dropped_n} of {len(declared)} donated buffer(s) did "
             f"not survive lowering (no "
-            f"{' / '.join(ALIAS_MARKER_ATTRS)} attribute) — XLA will "
-            f"silently copy instead of reusing them; the usual causes "
-            f"are a dtype/shape mismatch between the donated input and "
-            f"every output, or an output that was already claimed by "
-            f"another donor"))
+            f"{' / '.join(ALIAS_MARKER_ATTRS)} attribute, or a "
+            f"jax.buffer_donor with no output of the same byte size "
+            f"for XLA to place it in) — XLA will silently copy instead "
+            f"of reusing them; the usual causes are a dtype/shape "
+            f"mismatch between the donated input and every output, or "
+            f"an output that was already claimed by another donor"))
     return findings
 
 
@@ -363,19 +395,10 @@ def trace_entry(name: str, fn, args: tuple, policy: LintPolicy,
     jitted = fn if hasattr(fn, "lower") else jax.jit(
         fn, donate_argnums=donate_argnums,
         static_argnums=static_argnums or None)
-    # one trace covers both artifacts when the AOT Traced stage exists
-    # (0.4.29+); otherwise pay a second trace for the lowering
-    text = None
-    try:
-        traced = jitted.trace(*args)
-        closed = traced.jaxpr
-        if lower:
-            text = traced.lower().as_text()
-    except AttributeError:
-        if lower:
-            text = jitted.lower(*args).as_text()
-        closed = jax.make_jaxpr(
-            fn, static_argnums=static_argnums)(*args)
+    # one trace covers both artifacts
+    traced = jitted.trace(*args)
+    closed = traced.jaxpr
+    text = traced.lower().as_text() if lower else None
     names, avals, donated = _flat_args(args, tuple(donate_argnums),
                                        tuple(static_argnums))
 

@@ -33,50 +33,32 @@ from typing import Optional
 
 import jax
 
-# the pxla module that owns the "Compiling <name> with global shapes and
-# types ..." record (stable across 0.4.x; pinned by tests/test_analysis)
+# the pxla module that owns the "Compiling jit(<name>) with global shapes
+# and types ..." record (jax 0.9.0; pinned by tests/test_analysis)
 _COMPILE_LOGGERS = ("jax._src.interpreters.pxla",)
 # loggers that get chatty at WARNING while jax_log_compiles is on; the
 # guard silences their propagation for its window so enabling the flag
 # does not spray compile timings over the program's stderr
 _QUIET_LOGGERS = ("jax._src.interpreters.pxla", "jax._src.dispatch",
                   "jax._src.compiler")
-# The record's name half has drifted across jax releases: bare function
-# names ("Compiling step with global shapes..."), module-suffixed names
-# ("Compiling jit_step.2 ..."), fingerprint-suffixed names ("Compiling
-# step (hash) for ..."). The guard's job is COUNTING — a format drift
-# that stopped the name regex matching must never zero the compile
-# count (that would green-light every recompile the count exists to
-# catch), so parsing is two-stage: any record whose message starts with
-# the "Compiling " prefix IS a compile (counted unconditionally, as
-# "<unparsed>" if the name can't be extracted), and the name regex +
-# suffix strip only decorate the entry for the diff message.
+# The guard's job is COUNTING: any record whose message starts with the
+# "Compiling " prefix IS a compile, counted unconditionally — as
+# "<unparsed>" if the jit(<name>) form ever stops matching, so a format
+# drift can blind the name-keyed contracts (loudly: "<unparsed>" matches
+# no hot name and shows in every diff) but never zero the count.
 _COMPILE_PREFIX = "Compiling "
-_COMPILE_RE = re.compile(r"^Compiling\s+(\S+)")
-# trailing decorations newer pxla variants append to the name token:
-# a ".N" disambiguation counter, trailing punctuation, a "(fingerprint)"
-# parenthetical glued to the name
-_NAME_SUFFIX_RE = re.compile(r"(?:\(.*\)|[.,;:]+|\.\d+)$")
+_COMPILE_RE = re.compile(r"^Compiling jit\((.+?)\) with ")
 
 
 def _compiled_name(message: str) -> Optional[str]:
-    """The program name a pxla compile record names, normalized across
-    log-format variants — or None when the record is not a compile
-    record at all. NEVER returns None for a "Compiling ..."-prefixed
-    message: an unparsable name degrades to "<unparsed>", not to an
-    uncounted compile."""
+    """The jitted function's name from a pxla compile record, or None
+    when the record is not a compile record at all. NEVER returns None
+    for a "Compiling ..."-prefixed message: an unparsable name degrades
+    to "<unparsed>", not to an uncounted compile."""
     if not message.startswith(_COMPILE_PREFIX):
         return None
     m = _COMPILE_RE.match(message)
-    if not m:
-        return "<unparsed>"
-    name = m.group(1)
-    while True:
-        stripped = _NAME_SUFFIX_RE.sub("", name)
-        if stripped == name or not stripped:
-            break
-        name = stripped
-    return name or "<unparsed>"
+    return m.group(1) if m else "<unparsed>"
 
 
 class RecompileError(AssertionError):

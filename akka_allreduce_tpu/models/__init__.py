@@ -10,17 +10,13 @@ collectives, tp-sharded projections, and ring-attention sequence parallelism
 (models/train.py).
 """
 
-from akka_allreduce_tpu.utils.compat import install as _install_jax_compat
-
-_install_jax_compat()  # graft current-JAX names onto 0.4.x (no-op on new)
-
-from akka_allreduce_tpu.models.mlp import init_mlp, mlp_apply  # noqa: E402
-from akka_allreduce_tpu.models.speculate import (  # noqa: E402
+from akka_allreduce_tpu.models.mlp import init_mlp, mlp_apply
+from akka_allreduce_tpu.models.speculate import (
     extend,
     speculative_generate,
     speculative_sample,
 )
-from akka_allreduce_tpu.models.transformer import (  # noqa: E402
+from akka_allreduce_tpu.models.transformer import (
     TransformerConfig,
     init_transformer,
     transformer_apply,

@@ -10,32 +10,44 @@ Makefile (g++; no pybind11 in this environment).
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
-_SO = os.path.join(_DIR, "_lib", "libaatpu.so")
-_SRCS = [os.path.join(_DIR, "src", f)
-         for f in ("transport.cpp", "cluster.cpp", "remote_worker.cpp",
-                   "remote_master.cpp", "ring.h", "wire_codec.h",
-                   "worker_core.h")]
+_LIB_DIR = os.path.join(_DIR, "_lib")
 
 _lib: ctypes.CDLL | None = None
 
 
+def _source_digest() -> str:
+    """Content hash of everything the library is built from: every file
+    under ``src/`` plus the Makefile. The digest is part of the library's
+    file name, so a tree that was copied with a ``_lib/`` from other
+    sources (``_lib/`` is git-ignored but travels with a directory copy,
+    and mtimes do not survive one meaningfully) never loads a library it
+    did not build."""
+    h = hashlib.sha256()
+    paths = sorted(glob.glob(os.path.join(_DIR, "src", "*")))
+    for path in paths + [os.path.join(_DIR, "Makefile")]:
+        h.update(os.path.basename(path).encode() + b"\0")
+        with open(path, "rb") as f:
+            h.update(f.read())
+        h.update(b"\0")
+    return h.hexdigest()[:16]
+
+
 def build_library(force: bool = False) -> str:
-    """Compile the shared library if missing or older than its source.
-    Concurrent-process safe: compiles to a per-pid temp file and atomically
-    renames, so simultaneous cold starts (the multi-process cluster) never
-    load a partially-written .so. Returns the .so path."""
-    makefile = os.path.join(_DIR, "Makefile")
-    src_mtime = max([os.path.getmtime(s) for s in _SRCS]
-                    + [os.path.getmtime(makefile)])
-    stale = (not os.path.exists(_SO)
-             or os.path.getmtime(_SO) < src_mtime)
-    if force or stale:
-        os.makedirs(os.path.dirname(_SO), exist_ok=True)
-        tmp = f"{_SO}.tmp.{os.getpid()}"
+    """Compile the shared library unless one built from exactly these
+    sources exists. Concurrent-process safe: compiles to a per-pid temp
+    file and atomically renames, so simultaneous cold starts (the
+    multi-process cluster) never load a partially-written .so. Returns
+    the .so path."""
+    so = os.path.join(_LIB_DIR, f"libaatpu-{_source_digest()}.so")
+    if force or not os.path.exists(so):
+        os.makedirs(_LIB_DIR, exist_ok=True)
+        tmp = f"{so}.tmp.{os.getpid()}"
         try:
             # Build through the in-tree Makefile so its CXX/CXXFLAGS
             # overrides apply on the automatic path too; OUT is redirected
@@ -45,11 +57,11 @@ def build_library(force: bool = False) -> str:
                 ["make", "-s", "-C", _DIR,
                  f"OUT={os.path.relpath(tmp, _DIR)}"],
                 check=True, capture_output=True, text=True)
-            os.replace(tmp, _SO)
+            os.replace(tmp, so)
         finally:
             if os.path.exists(tmp):
                 os.unlink(tmp)
-    return _SO
+    return so
 
 
 def load_library() -> ctypes.CDLL:

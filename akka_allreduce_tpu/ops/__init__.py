@@ -18,25 +18,21 @@ The reference's wire-level mechanisms map here as follows (SURVEY.md §7):
   pacer / DCN layer (runtime/pacer.py).
 """
 
-from akka_allreduce_tpu.utils.compat import install as _install_jax_compat
-
-_install_jax_compat()  # graft current-JAX names onto 0.4.x (no-op on new)
-
-from akka_allreduce_tpu.ops.bucketing import (  # noqa: E402
+from akka_allreduce_tpu.ops.bucketing import (
     BucketSpec,
     bucketize,
     debucketize,
     tree_to_vector,
     vector_to_tree,
 )
-from akka_allreduce_tpu.ops.collectives import (  # noqa: E402
+from akka_allreduce_tpu.ops.collectives import (
     exact_allreduce,
     pipelined_two_phase_allreduce,
     psum_allreduce,
     quantized_two_phase_allreduce,
     two_phase_allreduce,
 )
-from akka_allreduce_tpu.ops.masked import (  # noqa: E402
+from akka_allreduce_tpu.ops.masked import (
     masked_allreduce,
     expand_bucket_counts,
     rescale_by_count,

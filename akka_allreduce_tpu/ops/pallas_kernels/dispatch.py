@@ -2,9 +2,11 @@
 
 The production code paths (ops/collectives.py int8 transport,
 ops/masked.py staged reduce) choose between the Pallas kernel and the
-equivalent jnp/XLA formulation at trace time. The per-kernel defaults
-follow the measured A/B on this repo's real chip (scripts/bench_suite.py
-``ab_*`` lines, TPU v5e, 8 x 3.28M f32 inputs, round-2 measurements):
+equivalent jnp/XLA formulation at trace time, and say which
+(:func:`say`). The per-kernel defaults follow A/Bs taken on a v5e chip
+before this round, under another JAX (scripts/bench_suite.py ``ab_*``
+lines, 8 x 3.28M f32 inputs; ``git show b96eba3:PERF.md``) — none has
+been re-measured on the present stack (ROADMAP D5):
 
 * ``masked_reduce`` — Pallas WINS (738-779 GB/s vs 567-581 GB/s for the
   jnp form, ~+30%): the one-VMEM-pass kernel beats XLA's mask+sum+rescale
@@ -15,8 +17,8 @@ follow the measured A/B on this repo's real chip (scripts/bench_suite.py
   materialising its random-bits input tile-by-tile. Default: jnp.
 * ``int8_prng`` (quantize with IN-KERNEL hardware PRNG) — Pallas WINS
   end to end (164-182 vs ~109 GB/s round-trip INCLUDING bits generation,
-  +50-68% across captures; bench_suite.py ``ab_int8_e2e_*``, PERF.md
-  carries the canonical capture): production must generate rounding bits somewhere, and
+  +50-68% across captures; bench_suite.py ``ab_int8_e2e_*``):
+  production must generate rounding bits somewhere, and
   threefry outside the kernel costs more than the hardware PRNG inside
   it. Default on TPU: pallas (the production quantize path).
 
@@ -32,6 +34,7 @@ on TPU set ``AATPU_PALLAS_INT8_PRNG=0 AATPU_PALLAS_INT8=1``.
 from __future__ import annotations
 
 import os
+import sys
 
 import jax
 
@@ -59,10 +62,11 @@ _TPU_DEFAULTS = {
     # ring flash attention (ops/pallas_kernels/ring_flash.py) — the ring
     # INNER step is the same fused block computation the local A/B above
     # measures (the ring only adds ppermute rotation between steps), so
-    # the local 5x win carries; semantics are oracle-pinned on the CPU
-    # mesh (tests/test_ring_flash.py) and the kernels' Mosaic lowering is
-    # verified on this repo's real chip at sp=1. No multi-chip hardware
-    # exists here to A/B the rotated path itself. Default on TPU: pallas.
+    # the local 5x win should carry; semantics are oracle-pinned on the
+    # CPU mesh (tests/test_ring_flash.py), and the rotated path compiled
+    # and trained at sp=2 on a four-chip v5e host (`train --dp 2 --sp 2`,
+    # PERF.md) — correct, never timed against the pure-JAX ring.
+    # Default on TPU: pallas.
     "ring_flash": True,
 }
 
@@ -71,17 +75,53 @@ def _parse(env: str) -> bool:
     return env.strip().lower() not in ("0", "false", "no", "")
 
 
+_said: "set[str]" = set()
+
+
+def say(line: str) -> None:
+    """Trace-time notice on stderr of what a dispatch resolved to, once
+    per distinct line per process. Nothing on the hot path may hide the
+    device: a kernel that runs in interpreter mode, or gives way to its
+    jnp reference, says so here — ``chip_smoke.py`` reads these lines to
+    require the Mosaic kernels on the chip."""
+    if line not in _said:
+        _said.add(line)
+        print(line, file=sys.stderr, flush=True)
+
+
+def say_attention(where: str, impl: str, q, interpret=None,
+                  **detail) -> None:
+    """:func:`say` for an attention dispatch; ``q`` is the traced query.
+    A Pallas kernel passes its name and its ``interpret`` flag and is
+    announced as ``mosaic:<kernel>`` (compiled for the TPU) or
+    ``interpret:<kernel>`` (Pallas interpreter, the CPU tests); a pure-JAX
+    path passes ``reference:<function>``."""
+    if interpret is not None:
+        impl = ("interpret:" if interpret else "mosaic:") + impl
+    shape = "x".join(str(d) for d in q.shape)
+    extras = "".join(f" {k}={v}" for k, v in detail.items()
+                     if v is not None)
+    say(f"attention[{where}]: {impl} q={shape} dtype={q.dtype}{extras}")
+
+
 def use_pallas(kernel: str = "masked_reduce") -> bool:
     """True when the production path should call the Pallas kernel.
 
     Trace-time decision (plain Python): the default backend's platform is
     known before tracing starts, and a jitted function is traced per
-    backend anyway.
+    backend anyway. The verdict and its reason are announced
+    (:func:`say`).
     """
-    specific = os.environ.get(f"AATPU_PALLAS_{kernel.upper()}")
-    if specific is not None:
-        return _parse(specific)
+    specific_name = f"AATPU_PALLAS_{kernel.upper()}"
+    specific = os.environ.get(specific_name)
     blanket = os.environ.get("AATPU_PALLAS")
-    if blanket is not None:
-        return _parse(blanket)
-    return jax.default_backend() == "tpu" and _TPU_DEFAULTS[kernel]
+    if specific is not None:
+        choice, why = _parse(specific), f"{specific_name}={specific}"
+    elif blanket is not None:
+        choice, why = _parse(blanket), f"AATPU_PALLAS={blanket}"
+    else:
+        backend = jax.default_backend()
+        choice = backend == "tpu" and _TPU_DEFAULTS[kernel]
+        why = f"default on backend {backend}"
+    say(f"kernel[{kernel}]: {'pallas' if choice else 'reference'} ({why})")
+    return choice

@@ -180,7 +180,11 @@ def quantize_int8_stochastic(x: jnp.ndarray, seed,
 # f32 scale per block (block >= 128 keeps that under 1/32 of the int8
 # payload). The kernels make the scale block EQUAL to the VMEM column
 # tile: scale lookup is then one (rows, 1) operand per grid step —
-# no gather, no extra bandwidth over the per-row form.
+# no gather, no extra bandwidth over the per-row form. The scales reach
+# the kernel as (n_blocks, rows, 1): a (rows, 1) block of the
+# (rows, n_blocks) matrix is not a legal TPU block (its last dimension is
+# neither a multiple of 128 nor the whole axis — the Pallas TPU lowering
+# refuses it), while the trailing (rows, 1) of the column-major stack is.
 
 
 def _pad_cols_to(x: jnp.ndarray, mult: int) -> jnp.ndarray:
@@ -200,6 +204,16 @@ def block_scales(x: jnp.ndarray, block: int) -> jnp.ndarray:
     nb = xp.shape[1] // block
     abs_max = jnp.max(jnp.abs(xp).reshape(rows, nb, block), axis=2)
     return jnp.maximum(abs_max / 127.0, 1e-30)
+
+
+def _scale_columns(scales: jnp.ndarray) -> jnp.ndarray:
+    """(rows, n_blocks) -> (n_blocks, rows, 1), the kernel-side layout."""
+    return scales.T[:, :, None]
+
+
+def _scale_column_spec(rows: int) -> pl.BlockSpec:
+    return pl.BlockSpec((None, rows, 1), lambda j: (j, 0, 0),
+                        memory_space=pltpu.VMEM)
 
 
 def _quantize_block_kernel(x_ref, bits_ref, scales_ref, values_ref):
@@ -241,13 +255,12 @@ def quantize_int8_block(x: jnp.ndarray, bits: jnp.ndarray, block: int,
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((rows, block), lambda j: (0, j),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((rows, 1), lambda j: (0, j),
-                         memory_space=pltpu.VMEM),
+            _scale_column_spec(rows),
         ],
         out_specs=pl.BlockSpec((rows, block), lambda j: (0, j),
                                memory_space=pltpu.VMEM),
         interpret=interpret,
-    )(xp, bitsp, scales)
+    )(xp, bitsp, _scale_columns(scales))
     return values[:, :elems], scales
 
 
@@ -272,13 +285,12 @@ def quantize_int8_block_rtn(x: jnp.ndarray, block: int,
         in_specs=[
             pl.BlockSpec((rows, block), lambda j: (0, j),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((rows, 1), lambda j: (0, j),
-                         memory_space=pltpu.VMEM),
+            _scale_column_spec(rows),
         ],
         out_specs=pl.BlockSpec((rows, block), lambda j: (0, j),
                                memory_space=pltpu.VMEM),
         interpret=interpret,
-    )(xp, scales)
+    )(xp, _scale_columns(scales))
     return values[:, :elems], scales
 
 
@@ -303,11 +315,10 @@ def dequantize_int8_block(values: jnp.ndarray, scales: jnp.ndarray,
         in_specs=[
             pl.BlockSpec((rows, block), lambda j: (0, j),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((rows, 1), lambda j: (0, j),
-                         memory_space=pltpu.VMEM),
+            _scale_column_spec(rows),
         ],
         out_specs=pl.BlockSpec((rows, block), lambda j: (0, j),
                                memory_space=pltpu.VMEM),
         interpret=interpret,
-    )(vp, scales)
+    )(vp, _scale_columns(scales))
     return out[:, :elems]

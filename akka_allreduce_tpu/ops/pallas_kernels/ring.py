@@ -41,12 +41,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 LANE = 128
 
-# jax 0.4.x spells the compiler-params dataclass TPUCompilerParams;
-# 0.7+ renamed it CompilerParams. One alias so both ring and swing
-# kernels build on either.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
-
 
 def _ring_kernel(my_ref, x_ref, out_ref, carry_ref, comm_ref, send_sem,
                  recv_sem, free_sem, *, n: int, interpret: bool):
@@ -151,7 +145,7 @@ def _ring_call(blocks: jnp.ndarray, my: jnp.ndarray, n: int, rows: int,
             pltpu.SemaphoreType.DMA((2,)),               # recv sems
             pltpu.SemaphoreType.REGULAR((2,)),           # slot-free grants
         ],
-        compiler_params=_CompilerParams(collective_id=0),
+        compiler_params=pltpu.CompilerParams(collective_id=0),
         interpret=interpret,
     )(jnp.asarray([my], jnp.int32), blocks)
 
@@ -243,7 +237,7 @@ def _swing_call(blocks: jnp.ndarray, my: jnp.ndarray, n: int, rows: int,
         # distinct collective_id from the ring kernel: the barrier
         # semaphore is per-id, and a program composing both schedules
         # must not cross their barriers
-        compiler_params=_CompilerParams(collective_id=1),
+        compiler_params=pltpu.CompilerParams(collective_id=1),
         interpret=interpret,
     )(jnp.asarray([my], jnp.int32), blocks)
 

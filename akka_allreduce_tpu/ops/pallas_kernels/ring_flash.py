@@ -46,12 +46,6 @@ from akka_allreduce_tpu.ops.pallas_kernels.attention import (
 )
 from akka_allreduce_tpu.utils.vma import cast_varying
 
-# jax.__version_info__ itself only appeared mid-0.4.x — the exact
-# population the partitioner workaround below serves — so its absence
-# means "old", never an error (the compat layer's feature-detection
-# rule, utils/compat.py)
-_JAX_PRE_05 = getattr(jax, "__version_info__", (0, 4)) < (0, 5)
-
 
 def _tile_live(q_off, k_off, iq, ik, blk_q, blk_k):
     """Tile has at least one unmasked score (first key <= last query)."""
@@ -175,12 +169,8 @@ def _specs(b, h, h_kv, t, d, blk_q, blk_k):
 
 def _sds(shape, dtype, vma):
     """ShapeDtypeStruct that carries varying-axis info when inside a
-    vma-checked shard_map (pallas outputs need it declared explicitly).
-    Pre-vma JAX (0.4.x) has no such kwarg — and nothing to declare."""
-    try:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    except TypeError:
-        return jax.ShapeDtypeStruct(shape, dtype)
+    vma-checked shard_map (pallas outputs need it declared explicitly)."""
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
 def _ring_fwd_step(offs, q, k, v, m, l, acc, causal, blk_q, blk_k,
@@ -302,14 +292,6 @@ def _ring_fwd(q, k, v, axis_name, causal, block_q, block_k, interpret):
             # ranks strictly ahead contribute nothing: skip the whole call
             m, l, acc = lax.cond(src <= idx, fold, lambda mla: mla,
                                  (m, l, acc))
-        elif _JAX_PRE_05:
-            # 0.4.x only: the SPMD partitioner rejects this call when it
-            # is inlined unconditionally ("PartitionId instruction is not
-            # supported for SPMD partitioning"); an always-true cond
-            # keeps it in a subcomputation, which that partitioner
-            # handles — same program, admissible lowering
-            m, l, acc = lax.cond(src >= 0, fold, lambda mla: mla,
-                                 (m, l, acc))
         else:
             m, l, acc = fold((m, l, acc))
         kb = lax.ppermute(kb, axis_name, perm)
@@ -361,10 +343,6 @@ def _ring_bwd_rule(axis_name, causal, block_q, block_k, interpret, res,
 
         if causal:
             dq, dkb, dvb = lax.cond(src <= idx, contribute,
-                                    lambda a: a, (dq, dkb, dvb))
-        elif _JAX_PRE_05:
-            # same 0.4.x partitioner workaround as the forward step
-            dq, dkb, dvb = lax.cond(src >= 0, contribute,
                                     lambda a: a, (dq, dkb, dvb))
         else:
             dq, dkb, dvb = contribute((dq, dkb, dvb))

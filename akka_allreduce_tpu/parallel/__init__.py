@@ -9,11 +9,7 @@ parallelism (`tp.py`), sequence/context parallelism via ring attention
 (models/train.py).
 """
 
-from akka_allreduce_tpu.utils.compat import install as _install_jax_compat
-
-_install_jax_compat()  # graft current-JAX names onto 0.4.x (no-op on new)
-
-from akka_allreduce_tpu.parallel.mesh import (  # noqa: E402
+from akka_allreduce_tpu.parallel.mesh import (
     MeshSpec,
     make_device_mesh,
     local_axis_size,

@@ -23,12 +23,7 @@ from typing import Any, Optional, Sequence
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec
-
-try:  # JAX >= 0.5: meshes carry axis types (Explicit is the new default)
-    from jax.sharding import AxisType
-except ImportError:  # 0.4.x: every mesh is Auto-typed; nothing to pin
-    AxisType = None
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec
 
 
 @dataclasses.dataclass(frozen=True)
@@ -81,8 +76,6 @@ def make_device_mesh(spec: Optional[MeshSpec] = None,
         raise ValueError(
             f"mesh of {sizes} needs {total} devices, have {len(devices)}")
     dev_array = np.asarray(devices).reshape(sizes)
-    if AxisType is None:  # 0.4.x Mesh has no axis_types (all Auto)
-        return Mesh(dev_array, names)
     return Mesh(dev_array, names,
                 axis_types=(AxisType.Auto,) * len(names))
 
@@ -123,11 +116,10 @@ def place_tree(tree: Any, specs: Any, mesh: Mesh) -> Any:
                 getattr(x, "is_fully_addressable", True):
             # multi-process mesh, host-replicated value (every process
             # built the same tree — the deterministic-init contract):
-            # supply only this process's shards. jax.device_put would be
-            # equivalent on current JAX, but 0.4.x routes uncommitted
-            # host arrays through multihost_utils.assert_equal, whose
-            # broadcast psum the multi-process CPU backend (the dryrun /
-            # test topology) cannot run
+            # supply only this process's shards. jax.device_put would
+            # first run multihost_utils.assert_equal — one cross-process
+            # broadcast per leaf to check what that contract already
+            # guarantees
             x = np.asarray(x)
             return jax.make_array_from_callback(
                 x.shape, sharding, lambda idx: x[idx])

@@ -705,9 +705,15 @@ def _paged_decode_step(params: dict, kv: dict, token: jnp.ndarray,
             if impl == "pallas":
                 from akka_allreduce_tpu.ops.pallas_kernels.attention \
                     import paged_attention
+                from akka_allreduce_tpu.ops.pallas_kernels.dispatch \
+                    import say_attention
+                interpret = jax.default_backend() != "tpu"
+                say_attention("paged-decode", "paged_attention", q,
+                              interpret=interpret, page_size=P,
+                              pool_dtype=k_pool.dtype)
                 attn = paged_attention(
                     q, k_pool[i], v_pool[i], page_table, pos,
-                    interpret=jax.devices()[0].platform != "tpu")
+                    interpret=interpret)
             else:
                 k_all = paged_gather_kv(k_pool[i], page_table)
                 v_all = paged_gather_kv(v_pool[i], page_table)
@@ -1630,6 +1636,11 @@ class ServingEngine:
     def kv_cache_bytes(self) -> int:
         return sum(int(self._state[n].size * self._state[n].dtype.itemsize)
                    for n in _KV_KEYS if n in self._state)
+
+    def devices(self) -> "list[str]":
+        """The devices this engine's weights and cache occupy."""
+        leaves = jax.tree.leaves((self.params, self._state))
+        return sorted({str(d) for x in leaves for d in x.devices()})
 
     # -- admission (prefill) ------------------------------------------
 

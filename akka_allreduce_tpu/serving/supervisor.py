@@ -674,6 +674,22 @@ class RemoteEngine:
         return out
 
 
+def subprocess_replicas_refusal(platform: str) -> Optional[str]:
+    """Why a subprocess fleet cannot start from this process, or None.
+
+    A chip belongs to one process: the parent has initialised JAX (it
+    builds the spec, and usually the params) and holds the TPU, so a
+    worker told to use it would fail or hang until ``spawn_timeout_s``.
+    Until the parent stays off JAX and each child is given one visible
+    chip (ROADMAP R5), the fabric refuses a TPU backend at once."""
+    if platform == "tpu":
+        return ("subprocess replicas cannot run on a TPU backend: this "
+                "process holds the chip, so no worker process could "
+                "acquire it (one process per chip; use in-process "
+                "--replicas, or run the fabric on the CPU backend)")
+    return None
+
+
 class ReplicaSupervisor:
     """Spawn, watch, restart, and drain N replica worker processes.
 
@@ -704,6 +720,9 @@ class ReplicaSupervisor:
         if replicas < 1:
             raise ValueError(f"replicas must be >= 1, got {replicas}")
         self.spec = spec.captured()
+        refusal = subprocess_replicas_refusal(self.spec.platform)
+        if refusal:
+            raise RuntimeError(refusal)
         self.backoff = backoff
         self.budget = budget
         self.fleet = fleet

@@ -1,10 +1,6 @@
 """Shared utilities."""
 
-from akka_allreduce_tpu.utils.compat import install as _install_jax_compat
-
-_install_jax_compat()  # graft current-JAX names onto 0.4.x (no-op on new)
-
 from akka_allreduce_tpu.utils.vma import cast_varying, ensure_varying, \
-    psum_all  # noqa: E402
+    psum_all
 
 __all__ = ["cast_varying", "ensure_varying", "psum_all"]

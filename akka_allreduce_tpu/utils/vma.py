@@ -27,15 +27,8 @@ def _axis_tuple(axis_name: Axes) -> tuple[str, ...]:
 
 
 def cast_varying(x, axes: tuple[str, ...]):
-    """invariant -> varying cast, on whichever spelling this JAX has
-    (``lax.pvary`` is deprecated in favor of ``lax.pcast``). On pre-vma
-    JAX (0.4.x) there is no varying/invariant distinction to cast
-    between, so the cast is the identity."""
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, axes, to="varying")
-    if hasattr(lax, "pvary"):
-        return lax.pvary(x, axes)
-    return x
+    """invariant -> varying cast over ``axes``."""
+    return lax.pcast(x, axes, to="varying")
 
 
 def ensure_varying(x, axis_name: Axes):
@@ -58,5 +51,3 @@ def psum_all(x, axis_name: Axes):
     the invariant axes — which is precisely the intended sum."""
     return lax.psum(jax.tree.map(
         lambda leaf: ensure_varying(leaf, axis_name), x), axis_name)
-
-

@@ -1,218 +1,13 @@
-"""Driver benchmark entry: ALWAYS prints one JSON line to stdout.
+"""Device-plane goodput measurement: one process, the default backend.
 
-Round-1 postmortem (VERDICT.md weak #1): the benchmark initialized this
-environment's default TPU backend in-process with no watchdog; the backend
-hung for ~35 minutes before failing UNAVAILABLE, the driver timed out, and
-no number was captured. The reference's measurement contract is a sink that
-always prints (reference: AllreduceWorker.scala:329-343) — so this shim now
-guarantees a JSON line lands no matter what the backend does:
-
-  1. attempt the real measurement (akka_allreduce_tpu/bench.py) on the
-     default backend in a SUBPROCESS with a hard wall-clock timeout;
-  2. on timeout/crash, retry on a forced-CPU platform with a smaller,
-     CPU-sized config (still the full bucketize->psum->rescale path);
-  3. if every attempt fails, print a JSON line with an "error" field.
-
-Progress goes to stderr throughout; stdout carries single-line JSON rows
-with the HEADLINE metric last (the driver's parser takes the last line;
-extra rows — e.g. the fused-vs-windowed ``ab_overlap`` A/B under
-``AATPU_BENCH_AB_OVERLAP=1`` — ride ahead of it), and only successful
-attempts print to stdout.
-
-Env knobs: AATPU_BENCH_TIMEOUT_S (per-attempt wall clock, default 270),
-AATPU_BENCH_PLATFORMS (comma list, default "default,cpu"), plus the sizing
-knobs documented in akka_allreduce_tpu/bench.py (forwarded verbatim).
+Runs ``akka_allreduce_tpu.bench.main`` in this process. It measures the
+chip, so it fails (non-zero exit, no JSON row) when JAX finds no TPU; there
+is no probe, no second attempt and no CPU row. The per-cell benchmark that
+replaces it is ROADMAP S0; ``chip_smoke.py`` is the proof that the system
+starts on the chip.
 """
 
-import json
-import os
-import re
-import signal
-import subprocess
-import sys
-
-REPO_ROOT = os.path.dirname(os.path.abspath(__file__))
-
-# CPU-sized fallback: 2.5M floats (10 MB) x 40 rounds keeps the attempt in
-# tens of seconds on 8 virtual CPU devices while still exercising the full
-# device sync path (bucketize -> psum -> rescale -> debucketize).
-CPU_FALLBACK_ENV = {
-    "AATPU_BENCH_ELEMS": "2500000",
-    "AATPU_BENCH_BUCKET_ELEMS": "312500",
-    "AATPU_BENCH_R_HI": "40",
-    "AATPU_BENCH_R_LO": "10",
-    "AATPU_BENCH_REPS": "2",
-}
-
-
-def _ensure_host_device_count(env: dict, n: int) -> None:
-    """Merge the device-count flag into XLA_FLAGS: append when absent,
-    upgrade when an existing count is smaller (a pre-set '=1' would make
-    the 'allreduce' a 1-device no-op and the number meaningless)."""
-    flags = env.get("XLA_FLAGS", "")
-    m = re.search(r"--xla_force_host_platform_device_count=(\d+)", flags)
-    if m is None:
-        env["XLA_FLAGS"] = (
-            flags + f" --xla_force_host_platform_device_count={n}").strip()
-    elif int(m.group(1)) < n:
-        env["XLA_FLAGS"] = flags.replace(
-            m.group(0), f"--xla_force_host_platform_device_count={n}")
-
-
-def _log(msg: str) -> None:
-    print(f"[bench-driver] {msg}", file=sys.stderr, flush=True)
-
-
-def _attempt(platform: str, timeout_s: float
-             ) -> "tuple[dict, list] | None":
-    """Run one measurement subprocess; return (headline row, extra rows)
-    or None when it produced no parseable JSON."""
-    env = dict(os.environ)
-    env["AATPU_BENCH_PLATFORM"] = platform
-    if platform == "cpu":
-        for k, v in CPU_FALLBACK_ENV.items():
-            env.setdefault(k, v)
-        _ensure_host_device_count(env, 8)
-    cmd = [sys.executable, "-m", "akka_allreduce_tpu.bench"]
-    _log(f"attempt platform={platform} timeout={timeout_s:.0f}s: "
-         f"{' '.join(cmd)}")
-    # New session so a hung backend init (which ignores SIGTERM while
-    # blocked in C) can be killed as a whole process group.
-    proc = subprocess.Popen(cmd, cwd=REPO_ROOT, env=env,
-                            stdout=subprocess.PIPE, stderr=sys.stderr,
-                            text=True, start_new_session=True)
-    timed_out = False
-    try:
-        out, _ = proc.communicate(timeout=timeout_s)
-    except subprocess.TimeoutExpired:
-        _log(f"attempt platform={platform} timed out; killing process group")
-        try:
-            os.killpg(proc.pid, signal.SIGKILL)
-        except ProcessLookupError:
-            pass
-        # Recover whatever the child already printed: a measurement that
-        # emitted its JSON and then hung in backend teardown still counts.
-        out, _ = proc.communicate()
-        timed_out = True
-    if proc.returncode != 0 and not timed_out:
-        # still scan for JSON: a child that measured, printed, and then
-        # crashed in backend teardown produced a real number
-        _log(f"attempt platform={platform} exited rc={proc.returncode}")
-    rows = []
-    for line in (out or "").strip().splitlines():
-        try:
-            parsed = json.loads(line)
-        except json.JSONDecodeError:
-            continue
-        if isinstance(parsed, dict) and "metric" in parsed:
-            rows.append(parsed)
-    # the headline is the last NON-extra row (the measurement module
-    # prints it after the ab_overlap A/B rows under
-    # AATPU_BENCH_AB_OVERLAP=1); matching by prefix instead of position
-    # keeps a child that timed out mid-A/B — extras printed, headline
-    # never reached — from banking an ab_overlap row under the headline
-    # slot. Extras ride ahead of it so the harness parser, which takes
-    # the last line, still lands on the unchanged headline metric.
-    extras = [r for r in rows if r["metric"].startswith("ab_overlap")]
-    headline = [r for r in rows if not r["metric"].startswith("ab_overlap")]
-    if headline:
-        return headline[-1], extras
-    if extras:
-        # a child killed mid-A/B still banked real measurements (the
-        # module prints per-row for exactly this case): pass them
-        # through — safe because every caller of this path prints a
-        # later row (next platform's headline or the final error row)
-        # last, which is the slot the harness parser reads
-        for r in extras:
-            print(json.dumps(r), flush=True)
-    _log(f"attempt platform={platform} printed no headline JSON line"
-         + (f" ({len(extras)} ab_overlap extras banked without it)"
-            if extras else ""))
-    return None
-
-
-def _fast_probe(timeout_s: float = 90.0) -> bool:
-    """Small-matmul probe of the default backend in a budgeted subprocess.
-
-    Round-4 verdict #6: the default-platform attempt burns its full
-    watchdog budget (270-420 s) discovering the relay is dead before the
-    CPU fallback even starts. A 90 s probe answers the same question at a
-    fraction of the budget; an in-process call would hang for hours
-    (round-1 postmortem)."""
-    code = ("import jax, jax.numpy as jnp; x = jnp.ones((512, 512)); "
-            "print('PROBE_OK', float((x @ x).sum()))")
-    proc = subprocess.Popen([sys.executable, "-c", code], cwd=REPO_ROOT,
-                            stdout=subprocess.PIPE, stderr=sys.stderr,
-                            text=True, start_new_session=True)
-    try:
-        out, _ = proc.communicate(timeout=timeout_s)
-    except subprocess.TimeoutExpired:
-        try:
-            os.killpg(proc.pid, signal.SIGKILL)
-        except ProcessLookupError:
-            pass
-        proc.communicate()
-        return False
-    return "PROBE_OK" in (out or "")
-
-
-def _last_banked_note() -> str:
-    """Cite the last committed on-chip capture so a CPU-fallback round
-    still points the reader at real TPU evidence (round-4 verdict #6)."""
-    try:
-        with open(os.path.join(REPO_ROOT, "perf_tpu.json")) as f:
-            perf = json.load(f)
-        when = (perf.get("captured_at") or "?")[:19]
-        rows = perf.get("headline") or []
-        head = next((r for r in rows if "metric" in r), None)
-        if head is not None:
-            return (f"last banked on-chip capture {when}: "
-                    f"{head['metric']}={head.get('value')} "
-                    f"{head.get('unit', '')} (perf_tpu.json, committed)")
-        return f"last banked on-chip capture {when} (perf_tpu.json)"
-    except (OSError, json.JSONDecodeError, KeyError):
-        return "no banked on-chip capture found (perf_tpu.json missing)"
-
-
-def main() -> None:
-    # the ab_overlap A/B adds ~10 goodput measurements before the
-    # headline, so its default watchdog matches the capture harness's
-    # ab_overlap step budget instead of the single-measurement 270 s
-    # (an explicit AATPU_BENCH_TIMEOUT_S always wins)
-    default_timeout = ("1200" if os.environ.get(
-        "AATPU_BENCH_AB_OVERLAP") == "1" else "270")
-    timeout_s = float(os.environ.get("AATPU_BENCH_TIMEOUT_S",
-                                     default_timeout))
-    platforms = os.environ.get("AATPU_BENCH_PLATFORMS", "default,cpu")
-    errors = []
-    for platform in [p.strip() for p in platforms.split(",") if p.strip()]:
-        if platform != "cpu" and not _fast_probe():
-            _log(f"fast probe: default backend unreachable in 90s; "
-                 f"skipping platform={platform}")
-            errors.append(f"{platform}: fast-probe unreachable")
-            continue
-        attempt = _attempt(platform, timeout_s)
-        if attempt is not None:
-            result, extras = attempt
-            if platform == "cpu":
-                # a CPU number is a liveness proof, not the perf claim —
-                # point at the banked TPU rows
-                result["note"] = (result.get("note", "") +
-                                  "; " + _last_banked_note()).lstrip("; ")
-            for row in extras:
-                print(json.dumps(row), flush=True)
-            print(json.dumps(result), flush=True)
-            return
-        errors.append(f"{platform}: timeout/crash/no-json")
-    print(json.dumps({
-        "metric": "allreduce_goodput",
-        "value": 0.0,
-        "unit": "GB/s",
-        "vs_baseline": 0.0,
-        "error": "; ".join(errors) or "no platforms attempted",
-        "note": _last_banked_note(),
-    }), flush=True)
-
+from akka_allreduce_tpu.bench import main
 
 if __name__ == "__main__":
     main()

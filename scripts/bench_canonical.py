@@ -16,8 +16,7 @@ Memory honesty: the reference's buffer design (maxLag+1-row rings of
 each worker O(rows * dataSize) floats, so 64 workers x 25M f32 is a
 ~40 GB in-process footprint and 256 workers needs the bucket payload,
 not a whole model — this box has 125 GB. Runs are one-shot and emit
-PERF-style JSON rows; scripts/capture_tpu_numbers.py folds them into
-PERF.md under its own watchdog.
+PERF-style JSON rows.
 """
 
 import json

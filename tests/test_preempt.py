@@ -119,17 +119,27 @@ class TestWatcherThread:
                             prompt=tuple(int(x) for x in rng.integers(
                                 0, 61, size=4)),
                             max_new_tokens=24, submitted_at=0.0)
-                    for i in range(6)]
+                    for i in range(48)]
             for r in reqs:
                 sched.submit(r)
-            flip = threading.Timer(0.3,
-                                   lambda: setattr(state, "preempted",
-                                                   True))
+
+            # the notice is raised by PROGRESS, not by a timer: with the
+            # programs already compiled (a warm persistent cache) six
+            # requests finish in ~50 ms, before any fixed delay. 48
+            # requests outlast the watcher's poll by a wide margin, and
+            # whatever completes before the notice keeps its result.
+            def raise_notice_once_decoding():
+                while engine.decode_dispatches < 3:
+                    time.sleep(0.001)
+                state.preempted = True
+
+            flip = threading.Thread(target=raise_notice_once_decoding,
+                                    daemon=True)
             flip.start()
             with PreemptionWatcher(engine.request_drain, url=url,
                                    interval_s=0.03) as w:
-                serve_loop(engine, sched, max_dispatches=5000)
-            flip.cancel()
+                results = serve_loop(engine, sched, max_dispatches=5000)
+            flip.join(timeout=10)
             assert w.fired
             assert engine.drained, "notice did not drain in-flight work"
             assert engine.pool.pages_in_use == 0
@@ -137,7 +147,6 @@ class TestWatcherThread:
             # contract the notice now triggers for real
             fresh = PagedServingEngine(
                 params, cfg, PagedEngineConfig(num_slots=2, page_size=4))
-            results = {}
             while engine.drained or sched.unfinished:
                 for rr in engine.drained:
                     sched.bind(rr.req, fresh.restore(rr))

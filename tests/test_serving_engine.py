@@ -190,19 +190,22 @@ class TestEngineBookkeeping:
         params = init_transformer(jax.random.key(0), DENSE)
         reqs = make_requests(DENSE, 4, steps=6, seed=11)
         base, _ = run_engine(params, DENSE, reqs, slots=2)
-        # stop on each request's own second greedy token -> length 2
+        # stop on each request's own second greedy token: the request
+        # ends at that token's FIRST occurrence in its greedy stream
+        # (index 1, or index 0 when a random-init model repeats itself)
+        greedy = {r.rid: [int(t) for t in np.asarray(base[r.rid][0])]
+                  for r in reqs}
         stop_reqs = [
             Request(rid=r.rid, prompt=r.prompt, max_new_tokens=6,
-                    stop_tokens=(int(np.asarray(base[r.rid][0])[1]),),
-                    submitted_at=0.0)
+                    stop_tokens=(greedy[r.rid][1],), submitted_at=0.0)
             for r in reqs]
         results, _ = run_engine(params, DENSE, stop_reqs, slots=2)
         for r in stop_reqs:
             toks, reason = results[r.rid]
+            cut = greedy[r.rid].index(r.stop_tokens[0]) + 1
             assert reason == "stop"
-            assert len(toks) == 2
-            np.testing.assert_array_equal(
-                np.asarray(toks), np.asarray(base[r.rid][0])[:2])
+            assert [int(t) for t in np.asarray(toks)] == \
+                greedy[r.rid][:cut]
 
     def test_metrics_and_tracer_wiring(self):
         """TTFT/TPOT/occupancy/queue histograms fill and the tracer sees

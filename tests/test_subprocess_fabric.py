@@ -267,6 +267,12 @@ class TestFleetDrain:
             now = sched.clock()
             for r in make_requests():
                 r.deadline = now + 90.0
+                # ragged budgets (8..41 tokens): a router round ends
+                # when a wave's SHORT requests complete, so with equal
+                # budgets and warm workers every round boundary finds
+                # the fleet idle and the preempt below drains nothing;
+                # the long requests are what is mid-flight at round 3
+                r.max_new_tokens = 8 + 11 * (r.rid % 4)
                 fleet.on_submit(r.rid)
                 sched.submit(r)
             # remote rounds batch many worker dispatches, so the whole

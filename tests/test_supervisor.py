@@ -126,8 +126,7 @@ class FakeSupervisor:
 
 SPEC = ReplicaSpec(vocab_size=31, d_model=8, n_heads=1, n_layers=1,
                    d_ff=16, max_seq=16, num_slots=2, platform="cpu",
-                   disable_most_optimizations=False,
-                   compilation_cache_dir="")
+                   disable_most_optimizations=False)
 
 
 def req(rid, n=3, budget=4):
