@@ -47,6 +47,8 @@ import signal
 import sys
 import time
 
+from akka_allreduce_tpu.runtime.tracing import TRAIN_ROUND, span
+
 
 def _add_emulate(sub: argparse._SubParsersAction) -> None:
     p = sub.add_parser(
@@ -908,8 +910,7 @@ class _TrainTelemetry:
         sample (the hybrid run still exports counters/loss and the
         round spans)."""
         with contextlib.ExitStack() as s:
-            if tracer is not None:
-                s.enter_context(tracer.span("train_round", **fields))
+            s.enter_context(span(TRAIN_ROUND, tracer, **fields))
             ds = (s.enter_context(self.timer.span(**fields))
                   if device and self.timer is not None else None)
             yield ds

@@ -71,6 +71,11 @@ from akka_allreduce_tpu.parallel.ring_attention import (
     ring_attention,
     windowed_sp_attention,
 )
+from akka_allreduce_tpu.runtime.tracing import (
+    SCOPE_ATTENTION,
+    SCOPE_HEAD_LOSS,
+    SCOPE_OPTIMIZER,
+)
 from akka_allreduce_tpu.utils.vma import psum_all
 
 
@@ -508,7 +513,20 @@ def place_opt_state(opt: optax.GradientTransformation, opt_state: Any,
 
 
 def select_local_attention(cfg: TrainConfig):
-    """Rank-local attention per ``cfg.attn_impl`` (see TrainConfig).
+    """Rank-local attention per ``cfg.attn_impl`` (see TrainConfig),
+    under the ``attention`` named scope: in a profile the flash kernel's
+    custom calls, forward and backward, carry that name."""
+    attn = _local_attention_impl(cfg)
+
+    def attention(q, k, v):
+        with jax.named_scope(SCOPE_ATTENTION):
+            return attn(q, k, v)
+
+    return attention
+
+
+def _local_attention_impl(cfg: TrainConfig):
+    """The implementation :func:`select_local_attention` names.
 
     Trace-time decision like every kernel dispatch
     (ops/pallas_kernels/dispatch.py): on TPU "auto" runs the fused Pallas
@@ -1000,8 +1018,10 @@ def make_grad_step(cfg: TrainConfig, mesh: Mesh,
             xm = x.reshape(m, b_local // m, t_local, x.shape[-1])
             outs, aux = gpipe_apply(p["layers"], xm, stage, "pp")
             h = outs.reshape(b_local, t_local, outs.shape[-1])
-            logits = lm_logits(p, rmsnorm(h, p["out_norm"]), mcfg)
-            ce_sum, w_sum = weighted_ce(logits, targets, weights)
+            h = rmsnorm(h, p["out_norm"])
+            with jax.named_scope(SCOPE_HEAD_LOSS):
+                ce_sum, w_sum = weighted_ce(lm_logits(p, h, mcfg),
+                                            targets, weights)
             if "dispatch_fraction" in aux:
                 # scan_blocks summed over this stage's layers — make it the
                 # per-layer mean so metric reduction is uniform
@@ -1064,10 +1084,11 @@ def make_grad_step(cfg: TrainConfig, mesh: Mesh,
 
         def head_fn(p, h, mb):
             pc = cast_compute(p)
-            logits = lm_logits(pc, rmsnorm(h, pc["out_norm"]), mcfg)
+            h = rmsnorm(h, pc["out_norm"])
             tgt = lax.dynamic_index_in_dim(tgt_m, mb, 0, keepdims=False)
             w = lax.dynamic_index_in_dim(w_m, mb, 0, keepdims=False)
-            ce_sum, _ = weighted_ce(logits, tgt, w)
+            with jax.named_scope(SCOPE_HEAD_LOSS):
+                ce_sum, _ = weighted_ce(lm_logits(pc, h, mcfg), tgt, w)
             return ce_sum / total_count
 
         loss_sum, d_layers, d_other = one_f_one_b(
@@ -1307,14 +1328,18 @@ def make_train_step(cfg: TrainConfig, mesh: Mesh,
                 "optimizer with make_optimizer (or chain step_counter())")
         return state.count
 
+    def apply_optimizer(grads, opt_state, params):
+        with jax.named_scope(SCOPE_OPTIMIZER):
+            updates, opt_state = opt.update(grads, opt_state, params)
+            return optax.apply_updates(params, updates), opt_state
+
     @partial(jax.jit, donate_argnums=donate_args)
     def step(params, opt_state, tokens):
         # the optimizer's step counter seeds the int8 transport's rounding
         # noise, so every round draws fresh bits even on repeated batches
         count = step_count(opt_state)
         grads, metrics = grad_step(params, tokens, quant_seed=count)
-        updates, opt_state = opt.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        params, opt_state = apply_optimizer(grads, opt_state, params)
         return params, opt_state, metrics
 
     @partial(jax.jit, donate_argnums=donate_args)
@@ -1322,8 +1347,7 @@ def make_train_step(cfg: TrainConfig, mesh: Mesh,
         count = step_count(opt_state)
         grads, metrics = grad_step(params, tokens, quant_seed=count,
                                    valid=valid)
-        updates, opt_state = opt.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        params, opt_state = apply_optimizer(grads, opt_state, params)
         return params, opt_state, metrics
 
     # ef8 steps: the error-feedback residual is a fourth state item the
@@ -1335,8 +1359,7 @@ def make_train_step(cfg: TrainConfig, mesh: Mesh,
         grads, metrics, ef_state = grad_step(params, tokens,
                                              quant_seed=count,
                                              ef_state=ef_state)
-        updates, opt_state = opt.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        params, opt_state = apply_optimizer(grads, opt_state, params)
         return params, opt_state, metrics, ef_state
 
     @partial(jax.jit, donate_argnums=donate_args_ef)
@@ -1346,8 +1369,7 @@ def make_train_step(cfg: TrainConfig, mesh: Mesh,
                                              quant_seed=count,
                                              valid=valid,
                                              ef_state=ef_state)
-        updates, opt_state = opt.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        params, opt_state = apply_optimizer(grads, opt_state, params)
         return params, opt_state, metrics, ef_state
 
     if use_ef:

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 
 from akka_allreduce_tpu.parallel.ep import MoEConfig, init_moe_layer, moe_ffn
 from akka_allreduce_tpu.parallel.ring_attention import local_causal_attention
+from akka_allreduce_tpu.runtime.tracing import SCOPE_HEAD_LOSS
 from akka_allreduce_tpu.parallel.tp import column_parallel_dense, \
     row_parallel_dense, tp_grad_boundary
 
@@ -297,7 +298,23 @@ def transformer_apply_with_aux(params: dict, tokens: jnp.ndarray,
                                ep_axis: Optional[str] = None,
                                remat: bool = False
                                ) -> tuple[jnp.ndarray, dict]:
-    """tokens: (B, T_local) int32 → (logits (B, T_local, vocab), aux).
+    """:func:`transformer_hidden_with_aux` through the output head:
+    (logits (B, T_local, vocab), aux)."""
+    x, aux = transformer_hidden_with_aux(
+        params, tokens, cfg, positions, attn_fn, tp_axis, ep_axis, remat)
+    return lm_logits(params, x, cfg), aux
+
+
+def transformer_hidden_with_aux(params: dict, tokens: jnp.ndarray,
+                                cfg: TransformerConfig,
+                                positions: Optional[jnp.ndarray] = None,
+                                attn_fn: Optional[AttnFn] = None,
+                                tp_axis: Optional[str] = None,
+                                ep_axis: Optional[str] = None,
+                                remat: bool = False
+                                ) -> tuple[jnp.ndarray, dict]:
+    """tokens: (B, T_local) int32 → (the normed last hidden state (B,
+    T_local, d_model), what the output head reads; aux).
 
     ``positions``: global sequence positions of this rank's tokens (needed
     under sequence sharding; defaults to 0..T-1). When ``tp_axis`` is set,
@@ -331,8 +348,7 @@ def transformer_apply_with_aux(params: dict, tokens: jnp.ndarray,
         x, aux = block(layer, x)
         aux_total = _merge_aux(aux_total, aux)
 
-    x = rmsnorm(x, params["out_norm"])
-    return lm_logits(params, x, cfg), _finalize_aux(aux_total)
+    return rmsnorm(x, params["out_norm"]), _finalize_aux(aux_total)
 
 
 def transformer_apply(params: dict, tokens: jnp.ndarray,
@@ -368,15 +384,19 @@ def next_token_loss_and_aux(params: dict, tokens: jnp.ndarray,
     target and ``weights`` masks the positions that shouldn't count (the
     global final token).
     """
-    logits, aux = transformer_apply_with_aux(
+    x, aux = transformer_hidden_with_aux(
         params, tokens, cfg, positions, attn_fn, tp_axis, ep_axis,
         remat=remat)
-    if targets is None:
-        logits = logits[:, :-1]
-        tgt = tokens[:, 1:]
-    else:
-        tgt = targets
-    ce_sum, w_sum = weighted_ce(logits, tgt, weights)
+    # one name for the head's matmul and the loss over it, forward and
+    # backward (runtime/tracing.py SCOPES)
+    with jax.named_scope(SCOPE_HEAD_LOSS):
+        logits = lm_logits(params, x, cfg)
+        if targets is None:
+            logits = logits[:, :-1]
+            tgt = tokens[:, 1:]
+        else:
+            tgt = targets
+        ce_sum, w_sum = weighted_ce(logits, tgt, weights)
     loss_sum = ce_sum + aux["aux_loss"] * w_sum
     return loss_sum, w_sum, aux
 

@@ -42,6 +42,11 @@ from akka_allreduce_tpu.ops.collectives import (
 )
 from akka_allreduce_tpu.ops.masked import expand_bucket_counts, \
     masked_allreduce
+from akka_allreduce_tpu.runtime.tracing import (
+    SCOPE_SYNC_PACK,
+    SCOPE_SYNC_REDUCE,
+    SCOPE_SYNC_UNPACK,
+)
 from akka_allreduce_tpu.utils.vma import _axis_tuple, psum_all
 
 
@@ -166,7 +171,14 @@ def allreduce_gradients(grads: Any, config: GradSyncConfig = GradSyncConfig(),
     ``GradSyncResult.residual`` and MUST be threaded into the next round
     (dropping it silently degrades ef8 to plain block-int8).
     """
-    buckets, spec = bucketize(grads, config.bucket_elems)
+    # the three phases carry ``jax.named_scope`` names (runtime/tracing.py
+    # SCOPES) so a profile can follow the sync's staging and its wire
+    # apart, whatever the compiler numbers their instructions
+    pack = jax.named_scope(SCOPE_SYNC_PACK)
+    reduce = jax.named_scope(SCOPE_SYNC_REDUCE)
+    unpack = jax.named_scope(SCOPE_SYNC_UNPACK)
+    with pack:
+        buckets, spec = bucketize(grads, config.bucket_elems)
     # axes that actually move bytes: size-1 axes reduce to identity and
     # need no wire format — compressed transports bypass themselves there
     # (rounding gradients for zero wire savings would be pure loss)
@@ -328,6 +340,18 @@ def allreduce_gradients(grads: Any, config: GradSyncConfig = GradSyncConfig(),
             contrib, quant_key, ax,
             num_windows=n_windows if windowed else 1)
 
+    def scheduled_sum(mat: jnp.ndarray) -> jnp.ndarray:
+        """The uncompressed payload's collective on the selected
+        schedule."""
+        if windowed:
+            return windowed_sum(mat)
+        if swing:
+            return swing_allreduce(mat, win_axis)
+        return psum_all(mat, config.axis_name)
+
+    def count_psum() -> jnp.ndarray:
+        return psum_all(valid.astype(jnp.int32), config.axis_name)
+
     if valid is None:
         # Exact path (thresholds = 1.0): every rank contributes every
         # bucket, so the masking multiply and the count psum are pure
@@ -335,7 +359,8 @@ def allreduce_gradients(grads: Any, config: GradSyncConfig = GradSyncConfig(),
         # whole round at ~2 HBM passes (the reference's fast-path
         # degenerate case: the entire protocol is one sum).
         if quantized:
-            summed = quantized_sum(buckets, None)
+            with reduce:
+                summed = quantized_sum(buckets, None)
         elif use_bf16:
             # the collective's payload dtype IS its wire format: casting
             # the operand halves the bytes every hop moves; the f32
@@ -344,22 +369,22 @@ def allreduce_gradients(grads: Any, config: GradSyncConfig = GradSyncConfig(),
             # reduce_scatter geometry to satisfy, unlike int8's
             # two-phase; the windowed/swing forms trade that freedom for
             # their schedules (single axis, validated above)
-            wire = buckets.astype(jnp.bfloat16)
-            summed = (windowed_sum(wire) if windowed else
-                      swing_allreduce(wire, win_axis) if swing else
-                      psum_all(wire, config.axis_name)).astype(jnp.float32)
-        elif windowed:
-            summed = windowed_sum(buckets)
-        elif swing:
-            summed = swing_allreduce(buckets, win_axis)
+            with pack:
+                wire = buckets.astype(jnp.bfloat16)
+            with reduce:
+                summed = scheduled_sum(wire)
+            with unpack:
+                summed = summed.astype(jnp.float32)
         else:
-            summed = psum_all(buckets, config.axis_name)
+            with reduce:
+                summed = scheduled_sum(buckets)
         group = 1
         for a in _axis_tuple(config.axis_name):
             group *= lax.axis_size(a)
         bucket_counts = jnp.full((spec.num_buckets,), group, jnp.int32)
         if config.average:
-            summed = summed * (config.rescale_target / group)
+            with unpack:
+                summed = summed * (config.rescale_target / group)
     else:
         if quantized:
             # Lossy rounds keep the compressed wire: a masked rank's
@@ -371,21 +396,21 @@ def allreduce_gradients(grads: Any, config: GradSyncConfig = GradSyncConfig(),
             # separate exact int32 psum — tiny next to the payload, and
             # the honesty contract (reference: ReduceBlock.count,
             # AllreduceMessage.scala:20) tolerates no rounding.
-            summed = quantized_sum(buckets, valid)
-            bucket_counts = psum_all(valid.astype(jnp.int32),
-                                     config.axis_name)
+            with reduce:
+                summed = quantized_sum(buckets, valid)
+                bucket_counts = count_psum()
         elif use_bf16:
             # masked rows are exact zeros in bf16 too, so masking
             # commutes with the cast; counts stay on an exact int32 psum
             # (the honesty contract tolerates no rounding)
-            contrib = (buckets * valid.astype(buckets.dtype)[:, None]
-                       ).astype(jnp.bfloat16)
-            summed = (windowed_sum(contrib) if windowed else
-                      swing_allreduce(contrib, win_axis) if swing else
-                      psum_all(contrib,
-                               config.axis_name)).astype(jnp.float32)
-            bucket_counts = psum_all(valid.astype(jnp.int32),
-                                     config.axis_name)
+            with pack:
+                contrib = (buckets * valid.astype(buckets.dtype)[:, None]
+                           ).astype(jnp.bfloat16)
+            with reduce:
+                summed = scheduled_sum(contrib)
+                bucket_counts = count_psum()
+            with unpack:
+                summed = summed.astype(jnp.float32)
         elif windowed or swing:
             # lossy + windowed/swing: the masked payload rides the
             # selected schedule, but the per-bucket counts stay on ONE
@@ -393,34 +418,36 @@ def allreduce_gradients(grads: Any, config: GradSyncConfig = GradSyncConfig(),
             # the honesty contract would buy nothing (counts are tiny)
             # and fragment the one collective whose exactness is the
             # contract
-            contrib = buckets * valid.astype(buckets.dtype)[:, None]
-            summed = (windowed_sum(contrib) if windowed else
-                      swing_allreduce(contrib, win_axis))
-            bucket_counts = psum_all(valid.astype(jnp.int32),
-                                     config.axis_name)
+            with pack:
+                contrib = buckets * valid.astype(buckets.dtype)[:, None]
+            with reduce:
+                summed = scheduled_sum(contrib)
+                bucket_counts = count_psum()
         else:
-            summed, bucket_counts = masked_allreduce(buckets, valid,
-                                                     config.axis_name)
+            with reduce:
+                summed, bucket_counts = masked_allreduce(
+                    buckets, valid, config.axis_name)
         if config.average:
             # per-BUCKET rescale while still in bucket shape: the tiny
             # (num_buckets, 1) factor broadcasts into the same HBM pass,
             # instead of materialising + reading a full-size per-element
             # count tensor (rescale_by_count) — same math, ~3 fewer passes
-            c = bucket_counts.astype(summed.dtype)
-            factor = jnp.where(c > 0,
-                               config.rescale_target / jnp.maximum(c, 1.0),
-                               0.0)
-            summed = summed * factor[:, None]
-
-    vec = summed.reshape(-1)[:spec.total_size]
-    out_tree = vector_to_tree(vec, spec)
+            with unpack:
+                c = bucket_counts.astype(summed.dtype)
+                factor = jnp.where(
+                    c > 0, config.rescale_target / jnp.maximum(c, 1.0),
+                    0.0)
+                summed = summed * factor[:, None]
 
     counts_tree = None
-    if config.return_elem_counts:
-        per_elem = expand_bucket_counts(bucket_counts, spec)
-        counts_spec = dataclasses.replace(
-            spec, dtypes=tuple(jnp.int32 for _ in spec.dtypes))
-        counts_tree = vector_to_tree(per_elem, counts_spec)
+    with unpack:
+        vec = summed.reshape(-1)[:spec.total_size]
+        out_tree = vector_to_tree(vec, spec)
+        if config.return_elem_counts:
+            per_elem = expand_bucket_counts(bucket_counts, spec)
+            counts_spec = dataclasses.replace(
+                spec, dtypes=tuple(jnp.int32 for _ in spec.dtypes))
+            counts_tree = vector_to_tree(per_elem, counts_spec)
     return GradSyncResult(grads=out_tree, counts=counts_tree,
                           bucket_counts=bucket_counts, spec=spec,
                           transport=config.transport,

@@ -22,6 +22,15 @@ Usage::
 
 Every protocol engine (worker/master) takes an optional ``tracer``; the
 default ``None`` keeps the hot path free of any tracing cost.
+
+:func:`span` is the program's one span primitive: every span site opens
+it. It always opens a ``jax.profiler.TraceAnnotation`` of the span's name,
+so whenever a profiler session runs (``--xprof-dir``) the span sits in the
+same ``.xplane.pb`` as the device's timeline, on the profiler's clock; and
+where a :class:`Tracer` is attached it records the JSONL event
+``Tracer.span`` records. With neither it costs one annotation's
+construction and a flag check. ``SPANS`` and ``SCOPES`` below are the one
+table of the names.
 """
 
 from __future__ import annotations
@@ -112,6 +121,22 @@ class Tracer:
         against the model's guards."""
         return self.record("fleet_transition", t=t, **fields)
 
+    def _open_span(self) -> tuple:
+        """Push a new span on this thread's stack: (id, parent, start)."""
+        sid = self._next_span_id
+        self._next_span_id += 1
+        parent = self.current_span_id
+        self._span_stack.append(sid)
+        return sid, parent, self._clock()
+
+    def _close_span(self, opened: tuple, kind: str, fields: dict) -> None:
+        sid, parent, t0 = opened
+        t1 = self._clock()
+        self._span_stack.pop()
+        self._append(TraceEvent(ts=t0, kind=kind, fields=fields,
+                                duration_s=t1 - t0, span_id=sid,
+                                parent_id=parent))
+
     @contextmanager
     def span(self, kind: str, **fields: Any):
         """Time a block; records one event with ``duration_s`` on exit.
@@ -119,19 +144,11 @@ class Tracer:
         this span's id as their ``parent_id`` — nesting is structural,
         not inferred from timestamps. Yields the span id (useful as a
         correlation handle)."""
-        sid = self._next_span_id
-        self._next_span_id += 1
-        parent = self.current_span_id
-        self._span_stack.append(sid)
-        t0 = self._clock()
+        opened = self._open_span()
         try:
-            yield sid
+            yield opened[0]
         finally:
-            t1 = self._clock()
-            self._span_stack.pop()
-            self._append(TraceEvent(ts=t0, kind=kind, fields=fields,
-                                    duration_s=t1 - t0, span_id=sid,
-                                    parent_id=parent))
+            self._close_span(opened, kind, fields)
 
     def record_span(self, kind: str, ts: float, duration_s: float,
                     **fields: Any) -> TraceEvent:
@@ -238,3 +255,111 @@ def tracer_to_file(path: Optional[str]):
         yield tracer
     finally:
         tracer.write_jsonl(path)
+
+
+# -- the span primitive ---------------------------------------------------
+
+# The program's host spans: name -> (layer, the per-layer quantity that
+# reads it). The quantities are what ``benchmark/program_trace.py`` computes
+# from a profile; PERF.md section 3 copies this table.
+SERVE_STEP = "serve_step"
+SERVE_STEP_UPLOAD = "serve_step.upload"
+SERVE_STEP_DISPATCH = "serve_step.dispatch"
+SERVE_STEP_READBACK = "serve_step.readback"
+SERVE_STEP_COMMIT = "serve_step.commit"
+SERVE_ADMIT = "serve_admit"
+SERVE_PREFILL = "serve_prefill"
+SERVE_ADMIT_COMMIT = "serve_admit.commit"
+SCHED_POP_READY = "sched_pop_ready"
+TRAIN_ROUND = "train_round"
+
+_DECODE = "engine, decode step (serving/engine.py)"
+_PREFILL = "engine, prefill (serving/engine.py)"
+SPANS = {
+    SERVE_STEP: (_DECODE, "chat_step_idle_ms"),
+    SERVE_STEP_UPLOAD: (_DECODE, "flood_idle_launch_ms"),
+    SERVE_STEP_DISPATCH: (_DECODE, "flood_idle_launch_ms"),
+    SERVE_STEP_READBACK: (_DECODE, "flood_idle_readback_ms"),
+    SERVE_STEP_COMMIT: (_DECODE, "flood_idle_commit_ms"),
+    SERVE_ADMIT: (_PREFILL, "chat_admit_idle_ms"),
+    SERVE_PREFILL: (_PREFILL, "chat_admit_idle_ms"),
+    SERVE_ADMIT_COMMIT: (_PREFILL, "chat_admit_idle_ms"),
+    SCHED_POP_READY: ("scheduler (serving/scheduler.py)",
+                      "flood_idle_outside_ms"),
+    TRAIN_ROUND: ("train loop (cli._cmd_train shape)", "-"),
+}
+
+# ``jax.named_scope`` names inside the jitted train step (no host cost: they
+# are metadata of the HLO): name -> (layer, the quantities that read it).
+SCOPE_SYNC_PACK = "grad_sync/pack"
+SCOPE_SYNC_REDUCE = "grad_sync/reduce"
+SCOPE_SYNC_UNPACK = "grad_sync/unpack"
+SCOPE_HEAD_LOSS = "lm_head_loss"
+SCOPE_OPTIMIZER = "optimizer"
+SCOPE_ATTENTION = "attention"
+
+_SYNC = "gradient sync (parallel/dp.py, ops/collectives.py)"
+_STEP = "train step (models/train.py)"
+SCOPES = {
+    SCOPE_SYNC_PACK: (_SYNC, "sync_device_pct, sync_staging_ms"),
+    SCOPE_SYNC_REDUCE: (_SYNC, "sync_device_pct"),
+    SCOPE_SYNC_UNPACK: (_SYNC, "sync_device_pct, sync_staging_ms"),
+    SCOPE_HEAD_LOSS: (_STEP, "head_loss_device_pct"),
+    SCOPE_OPTIMIZER: (_STEP, "-"),
+    SCOPE_ATTENTION: ("attention kernels (ops/pallas_kernels/attention.py)",
+                      "-"),
+}
+
+_annotation = None   # the annotation-only span's class, made at first use
+
+
+def _annotation_only():
+    """``jax.profiler.TraceAnnotation`` with a ``set`` that drops its
+    fields: what :func:`span` hands out where no tracer is attached, so a
+    site with tracing off pays the annotation alone. Made at the first span,
+    so the protocol plane's processes that never trace never import jax."""
+    global _annotation
+    from jax.profiler import TraceAnnotation
+
+    class annotation(TraceAnnotation):
+        __slots__ = ()
+
+        def set(self, **fields: Any) -> None:
+            pass
+
+    _annotation = annotation
+    return annotation
+
+
+class _TracedSpan:
+    """The annotation and the tracer's JSONL event, one inside the other."""
+
+    __slots__ = ("_ann", "_tracer", "_kind", "_fields", "_opened")
+
+    def __init__(self, kind: str, tracer: Tracer, fields: dict):
+        self._ann = (_annotation or _annotation_only())(kind)
+        self._tracer = tracer
+        self._kind = kind
+        self._fields = fields
+
+    def set(self, **fields: Any) -> None:
+        self._fields.update(fields)
+
+    def __enter__(self) -> "_TracedSpan":
+        self._ann.__enter__()
+        self._opened = self._tracer._open_span()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._tracer._close_span(self._opened, self._kind, self._fields)
+        self._ann.__exit__(*exc)
+
+
+def span(kind: str, tracer: Optional[Tracer] = None, **fields: Any):
+    """``with span(SERVE_STEP, self.tracer, occupied=n) as sp:`` - the
+    program's one span primitive (see the module docstring).
+    ``sp.set(tokens=3)`` adds fields known only inside the block; fields
+    reach the JSONL event alone, so without a tracer they are dropped."""
+    if tracer is None:
+        return (_annotation or _annotation_only())(kind)
+    return _TracedSpan(kind, tracer, fields)

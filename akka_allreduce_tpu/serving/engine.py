@@ -126,6 +126,17 @@ from akka_allreduce_tpu.ops.pallas_kernels.attention import paged_gather_kv
 from akka_allreduce_tpu.parallel.ep import moe_ffn
 from akka_allreduce_tpu.parallel.ring_attention import NEG_INF
 from akka_allreduce_tpu.runtime.faults import InjectedFault, maybe_fail
+from akka_allreduce_tpu.runtime.tracing import (
+    SERVE_ADMIT,
+    SERVE_ADMIT_COMMIT,
+    SERVE_PREFILL,
+    SERVE_STEP,
+    SERVE_STEP_COMMIT,
+    SERVE_STEP_DISPATCH,
+    SERVE_STEP_READBACK,
+    SERVE_STEP_UPLOAD,
+    span,
+)
 from akka_allreduce_tpu.serving.scheduler import Request, RequestScheduler
 
 
@@ -1531,6 +1542,10 @@ class ServingEngine:
         # admit()/_free_slot() set the dirty flag to force re-upload.
         self._dev_vectors: Optional[dict] = None
         self._vectors_dirty = True
+        # (rid, prefill length) of every admission since the last step:
+        # the serve_step span's ``admitted`` field, which requests shared
+        # a step with whose prefill
+        self._admitted: list = []
         self.decode_dispatches = 0
         self.prefill_dispatches = 0
         # high-water mark of concurrently occupied slots/lanes — the
@@ -1568,15 +1583,11 @@ class ServingEngine:
     def _device_timer(self):
         if self._dtimer is None:
             from akka_allreduce_tpu.telemetry.device import DeviceTimer
-            # annotate_site="dispatch": profiler annotations are
-            # thread-local, and with the watchdog armed the dispatch
-            # runs on the executor thread — the annotation must open
-            # inside the dispatched callable (see _dispatch_single)
             self._dtimer = DeviceTimer(
                 "engine",
                 registry=(self.metrics.registry
                           if self.metrics is not None else None),
-                tracer=self.tracer, annotate_site="dispatch")
+                tracer=self.tracer)
         return self._dtimer
 
     def close(self) -> None:
@@ -1694,19 +1705,17 @@ class ServingEngine:
         (serve_loop / RequestScheduler.pop_ready)."""
         return True
 
-    def _prefill_into(self, slot: int, req: Request, full: tuple) -> None:
+    def _prefill_into(self, slot: int, req: Request, full: tuple) -> int:
         """Dispatch the prefill that fills ``slot``'s KV with ``full``
         (prompt + any restore-replayed tokens) — the slot engine's
         bucket-padded lane write; the paged engine overrides with page
-        allocation + pool scatter."""
+        allocation + pool scatter. Returns the length dispatched."""
         n_full = len(full)
         length = self._bucket_len(n_full)
         padded = np.zeros((1, length), np.int32)
         padded[0, :n_full] = full
-        span = (self.tracer.span("serve_prefill", rid=req.rid, slot=slot,
-                                 prompt_len=n_full, bucket=length)
-                if self.tracer is not None else _null_span())
-        with span:
+        with span(SERVE_PREFILL, self.tracer, rid=req.rid, slot=slot,
+                  prompt_len=n_full, bucket=length):
             self._state = _engine_prefill(
                 self.params, self._state, jnp.asarray(padded),
                 jnp.asarray(n_full, jnp.int32),
@@ -1714,6 +1723,7 @@ class ServingEngine:
                 self.cfg, gather=length != n_full)
         self.prefill_dispatches += 1
         self.prefill_shapes.add((length, length != n_full))
+        return length
 
     def admit(self, req: Request, emitted: tuple = ()) -> int:
         """Prefill ``req`` into a free slot; returns the slot index.
@@ -1725,15 +1735,24 @@ class ServingEngine:
         the drained engine's, so the continued stream is exact. The
         decode budget shrinks by ``len(emitted)``; the total sequence
         footprint (and the max_seq validation) is unchanged."""
-        stops = self._validate_admit(req, emitted)
-        try:
-            slot = self._slots.index(None)
-        except ValueError:
-            raise RuntimeError("no free slot (admit gated on "
-                               "free_slot_count)") from None
-        full = tuple(req.prompt) + tuple(emitted)
-        n_full = len(full)
-        self._prefill_into(slot, req, full)
+        with span(SERVE_ADMIT, self.tracer, rid=req.rid) as sp:
+            stops = self._validate_admit(req, emitted)
+            try:
+                slot = self._slots.index(None)
+            except ValueError:
+                raise RuntimeError("no free slot (admit gated on "
+                                   "free_slot_count)") from None
+            sp.set(slot=slot)
+            full = tuple(req.prompt) + tuple(emitted)
+            self._admitted.append(
+                (req.rid, self._prefill_into(slot, req, full)))
+            with span(SERVE_ADMIT_COMMIT, self.tracer):
+                self._commit_admit(slot, req, stops, emitted, len(full))
+            return slot
+
+    def _commit_admit(self, slot: int, req: Request, stops: tuple,
+                      emitted: tuple, n_full: int) -> None:
+        """The slot's host vectors after its prefill was dispatched."""
         self._pos[slot] = n_full
         self._eos[slot] = -1 if req.eos_token is None else req.eos_token
         self._stops[slot, :] = -1
@@ -1753,7 +1772,6 @@ class ServingEngine:
         self.peak_occupied = max(self.peak_occupied, self.occupied)
         if self.metrics is not None:
             self.metrics.on_admit(req.rid, slot, n_full)
-        return slot
 
     # -- decode ---------------------------------------------------------
 
@@ -1956,34 +1974,40 @@ class ServingEngine:
         ``evicted`` (deadline passed mid-flight; terminal). Completed
         and failed slots are freed before returning (the same dispatch
         that emitted the finishing token — a slot never idles
-        occupied)."""
+        occupied).
+
+        Every kind of step is one ``serve_step`` span over four phases,
+        ``upload``, ``dispatch``, ``readback`` and ``commit``
+        (runtime/tracing.py ``SPANS``)."""
         if self.ecfg.decode_steps > 1:
             return self._step_block()
-        self._maybe_poison()
-        span = (self.tracer.span("serve_step", occupied=self.occupied)
-                if self.tracer is not None else _null_span())
-        # snapshot the dispatch inputs NOW: a hung watchdog worker may
-        # wake after recovery has already rebuilt self._state, and it
-        # must donate the abandoned buffers it was given, never the
-        # live rebuilt ones
-        state_in, pos_in = self._state, jnp.asarray(self._pos)
-        try:
-            with span, self._device_timer().span(
-                    occupied=self.occupied) as dspan:
-                state, packed = self._guarded_dispatch(
-                    lambda: self._dispatch_single(state_in, pos_in,
-                                                  dspan))
-        except WatchdogTimeout:
-            self.watchdog_trips += 1
-            if self.metrics is not None:
-                self.metrics.on_watchdog_trip()
-            return self._recover("watchdog")
-        except InjectedFault:
-            return self._recover("fault")
-        self._state = state
+        with span(SERVE_STEP, self.tracer, occupied=self.occupied,
+                  admitted=self._take_admitted()):
+            with span(SERVE_STEP_UPLOAD, self.tracer):
+                self._maybe_poison()
+                self._prepare_writes()
+                # snapshot the dispatch inputs NOW: a hung watchdog
+                # worker may wake after recovery has already rebuilt
+                # self._state, and it must donate the abandoned buffers
+                # it was given, never the live rebuilt ones
+                state_in, pos_in = self._state, jnp.asarray(self._pos)
+                ops, tables = self._sample_operands(), self._step_tables()
+            out, failures = self._dispatch_guarded(
+                lambda: self._dispatch_single(state_in, pos_in, ops,
+                                              tables))
+            if out is None:
+                return failures
+            with span(SERVE_STEP_COMMIT, self.tracer) as commit:
+                finished, n_tokens = self._commit_single(out)
+                commit.set(tokens=n_tokens, finished=len(finished))
+            return finished
+
+    def _commit_single(self, out: tuple) -> tuple:
+        self._state, packed = out
         self.decode_dispatches += 1
         toks, finite = packed[0], packed[1]
         finished = []
+        n_tokens = 0
         for i, slot in enumerate(self._slots):
             if slot is None:
                 continue
@@ -1994,6 +2018,7 @@ class ServingEngine:
                 continue
             t = int(toks[i])
             slot.emitted.append(t)
+            n_tokens += 1
             self._pos[i] += 1
             self._remaining[i] -= 1
             self._step_idx[i] += 1
@@ -2008,7 +2033,52 @@ class ServingEngine:
                     self.metrics.on_complete(req.rid, len(slot.emitted),
                                              reason)
         self._evict_expired(finished)
-        return finished
+        return finished, n_tokens
+
+    def _take_admitted(self) -> list:
+        admitted, self._admitted = self._admitted, []
+        return admitted
+
+    def _prepare_writes(self) -> None:
+        """Host work on the cache's layout that has to precede a
+        dispatch. The slot engine has none; the paged engines resolve
+        sharing here."""
+
+    def _step_tables(self) -> tuple:
+        """The dispatch's device operands beyond the state and the slot
+        vectors, uploaded ahead of it: the paged engines' page tables."""
+        return ()
+
+    def _dispatch_guarded(self, launch, **fields):
+        """One decode dispatch and its one readback, under the
+        DeviceTimer's bracket and the watchdog: ``(out, None)``, or
+        ``(None, failures)`` after a recovery. ``launch()`` calls the
+        jitted program and returns its outputs, ``(state, packed,
+        ...)``. The two phases open where they run, which with the
+        watchdog armed is the executor's thread (a profiler annotation
+        and the tracer's span stack are both per thread)."""
+        def run():
+            with span(SERVE_STEP_DISPATCH, self.tracer):
+                out = launch()
+            # dispatch returned, readback not yet forced: everything
+            # after this mark is the block-until-ready wall delta — the
+            # device-time attribution (telemetry/device.py)
+            dspan.mark_dispatched()
+            with span(SERVE_STEP_READBACK, self.tracer):
+                packed = np.asarray(out[1])  # the one host readback
+            return (out[0], packed) + tuple(out[2:])
+
+        try:
+            with self._device_timer().span(occupied=self.occupied,
+                                           **fields) as dspan:
+                return self._guarded_dispatch(run), None
+        except WatchdogTimeout:
+            self.watchdog_trips += 1
+            if self.metrics is not None:
+                self.metrics.on_watchdog_trip()
+            return None, self._recover("watchdog")
+        except InjectedFault:
+            return None, self._recover("fault")
 
     def _refresh_dev_vectors(self, include_idx: bool) -> dict:
         """(Re)build the carried per-slot device vectors from host
@@ -2044,19 +2114,9 @@ class ServingEngine:
                 "key_data": jnp.asarray(self._key_data),
                 "step_idx": jnp.asarray(self._step_idx)}
 
-    def _dispatch_single(self, state_in: dict, pos_in, dspan=None):
-        with (dspan.annotation() if dspan is not None
-              else _null_span()):
-            state, packed = _engine_step(
-                self.params, state_in, pos_in, self.cfg,
-                **self._sample_operands())
-            if dspan is not None:
-                # dispatch returned, readback not yet forced:
-                # everything after this mark is the block-until-ready
-                # wall delta — the device-time attribution
-                # (telemetry/device.py)
-                dspan.mark_dispatched()
-            return state, np.asarray(packed)  # the one host readback
+    def _dispatch_single(self, state_in: dict, pos_in, ops: dict,
+                         tables: tuple):
+        return _engine_step(self.params, state_in, pos_in, self.cfg, **ops)
 
     def _step_block(self) -> list[tuple[int, Request, list, str]]:
         """The S>1 dispatch: one fused ``_engine_multi_step`` program,
@@ -2066,30 +2126,29 @@ class ServingEngine:
         (mirroring the device latch) and counting the trailing block
         steps as wasted."""
         s_steps = self.ecfg.decode_steps
-        self._maybe_poison()
         sampled = self.ecfg.sample is not None
-        d = self._refresh_dev_vectors(include_idx=sampled)
-        span = (self.tracer.span("serve_step", occupied=self.occupied,
-                                 decode_steps=s_steps)
-                if self.tracer is not None else _null_span())
-        # snapshot the state reference (see step(): a woken watchdog
-        # worker must donate the abandoned buffers, not the rebuilt
-        # live state)
-        state_in = self._state
-        try:
-            with span, self._device_timer().span(
-                    occupied=self.occupied,
-                    decode_steps=s_steps) as dspan:
-                out = self._guarded_dispatch(
-                    lambda: self._dispatch_block(state_in, d,
-                                                 s_steps, dspan))
-        except WatchdogTimeout:
-            self.watchdog_trips += 1
-            if self.metrics is not None:
-                self.metrics.on_watchdog_trip()
-            return self._recover("watchdog")
-        except InjectedFault:
-            return self._recover("fault")
+        with span(SERVE_STEP, self.tracer, occupied=self.occupied,
+                  decode_steps=s_steps, admitted=self._take_admitted()):
+            with span(SERVE_STEP_UPLOAD, self.tracer):
+                self._maybe_poison()
+                self._prepare_writes()
+                d = self._refresh_dev_vectors(include_idx=sampled)
+                # snapshot the state reference (see step(): a woken
+                # watchdog worker must donate the abandoned buffers,
+                # not the rebuilt live state)
+                state_in, tables = self._state, self._step_tables()
+            out, failures = self._dispatch_guarded(
+                lambda: self._dispatch_block(state_in, d, s_steps, tables),
+                decode_steps=s_steps)
+            if out is None:
+                return failures
+            with span(SERVE_STEP_COMMIT, self.tracer) as commit:
+                finished, n_tokens = self._commit_block(d, out, s_steps)
+                commit.set(tokens=n_tokens, finished=len(finished))
+            return finished
+
+    def _commit_block(self, d: dict, out: tuple, s_steps: int) -> tuple:
+        sampled = self.ecfg.sample is not None
         if sampled:
             state, block, pos_d, done_d, rem_d, idx_d = out
         else:
@@ -2105,6 +2164,7 @@ class ServingEngine:
         toks, dev_pos, bad = \
             block[:s_steps], block[s_steps], block[s_steps + 1]
         finished = []
+        n_tokens = 0
         for i, slot in enumerate(self._slots):
             if slot is None:
                 continue
@@ -2131,6 +2191,7 @@ class ServingEngine:
                 reason = self._finish_reason(req, t, len(slot.emitted))
                 if reason is not None:
                     break
+            n_tokens += consumed
             if self.metrics is not None:
                 self.metrics.on_block_tokens(req.rid, req.submitted_at,
                                              consumed)
@@ -2154,32 +2215,19 @@ class ServingEngine:
                     f"after a {s_steps}-step block — on-device finish "
                     f"latch and host completion logic diverged")
         self._evict_expired(finished)
-        return finished
+        return finished, n_tokens
 
     def _dispatch_block(self, state_in: dict, d: dict, s_steps: int,
-                        dspan=None):
+                        tables: tuple):
         sample = self.ecfg.sample
-        with (dspan.annotation() if dspan is not None
-              else _null_span()):
-            if sample is None:
-                state, packed, pos_d, done_d, rem_d = _engine_multi_step(
-                    self.params, state_in, d["pos"], d["done"],
-                    d["remaining"], d["eos"], d["stops"], self.cfg,
-                    s_steps)
-                if dspan is not None:
-                    dspan.mark_dispatched()  # see _dispatch_single
-                return (state, np.asarray(packed),  # ONE readback per S
-                        pos_d, done_d, rem_d)
-            state, packed, pos_d, done_d, rem_d, idx_d = \
-                _engine_multi_step(
-                    self.params, state_in, d["pos"], d["done"],
-                    d["remaining"], d["eos"], d["stops"], self.cfg,
-                    s_steps, sample=sample, key_data=d["key_data"],
-                    step_idx=d["step_idx"])
-            if dspan is not None:
-                dspan.mark_dispatched()
-            return (state, np.asarray(packed), pos_d, done_d, rem_d,
-                    idx_d)
+        if sample is None:
+            return _engine_multi_step(
+                self.params, state_in, d["pos"], d["done"],
+                d["remaining"], d["eos"], d["stops"], self.cfg, s_steps)
+        return _engine_multi_step(
+            self.params, state_in, d["pos"], d["done"], d["remaining"],
+            d["eos"], d["stops"], self.cfg, s_steps, sample=sample,
+            key_data=d["key_data"], step_idx=d["step_idx"])
 
 
 class _SpeculativeMixin:
@@ -2280,26 +2328,26 @@ class _SpeculativeMixin:
         host replays the device latch token for token, then settles
         the draft ledger from what actually entered the stream."""
         k = self.ecfg.draft_steps
-        self._maybe_poison()
-        d = self._refresh_dev_vectors(include_idx=True)
-        span = (self.tracer.span("serve_step", occupied=self.occupied,
-                                 draft_steps=k)
-                if self.tracer is not None else _null_span())
-        state_in = self._state  # see step(): donate the snapshot only
-        try:
-            with span, self._device_timer().span(
-                    occupied=self.occupied, draft_steps=k) as dspan:
-                state, block, pos_d, done_d, rem_d, idx_d = \
-                    self._guarded_dispatch(
-                        lambda: self._dispatch_spec(state_in, d, k,
-                                                    dspan))
-        except WatchdogTimeout:
-            self.watchdog_trips += 1
-            if self.metrics is not None:
-                self.metrics.on_watchdog_trip()
-            return self._recover("watchdog")
-        except InjectedFault:
-            return self._recover("fault")
+        with span(SERVE_STEP, self.tracer, occupied=self.occupied,
+                  draft_steps=k, admitted=self._take_admitted()):
+            with span(SERVE_STEP_UPLOAD, self.tracer):
+                self._maybe_poison()
+                self._prepare_writes()
+                d = self._refresh_dev_vectors(include_idx=True)
+                # see step(): donate the snapshot only
+                state_in, tables = self._state, self._step_tables()
+            out, failures = self._dispatch_guarded(
+                lambda: self._dispatch_spec(state_in, d, k, tables),
+                draft_steps=k)
+            if out is None:
+                return failures
+            with span(SERVE_STEP_COMMIT, self.tracer) as commit:
+                finished, n_tokens = self._commit_spec(d, out, k)
+                commit.set(tokens=n_tokens, finished=len(finished))
+            return finished
+
+    def _commit_spec(self, d: dict, out: tuple, k: int) -> tuple:
+        state, block, pos_d, done_d, rem_d, idx_d = out
         self._state = state
         self._dev_vectors = {**d, "pos": pos_d, "done": done_d,
                              "remaining": rem_d, "step_idx": idx_d}
@@ -2307,6 +2355,7 @@ class _SpeculativeMixin:
         toks, n_accs, dev_pos, bad = \
             block[:k + 1], block[k + 1], block[k + 2], block[k + 3]
         finished = []
+        n_tokens = 0
         for i, slot in enumerate(self._slots):
             if slot is None:
                 continue
@@ -2334,6 +2383,7 @@ class _SpeculativeMixin:
             # host consumed past the anchor) are accepted, the rest
             # rejected — computed-then-discarded verify work, charged
             # to the wasted-token account
+            n_tokens += consumed
             accepted = consumed - 1
             rejected = k - accepted
             self.draft_proposed += k
@@ -2363,7 +2413,7 @@ class _SpeculativeMixin:
                     f"after a draft_steps={k} speculative block — "
                     f"on-device accept latch and host replay diverged")
         self._evict_expired(finished)
-        return finished
+        return finished, n_tokens
 
 
 class SpeculativeEngine(_SpeculativeMixin, ServingEngine):
@@ -2423,35 +2473,26 @@ class SpeculativeEngine(_SpeculativeMixin, ServingEngine):
                 (self.ecfg.num_slots, self.cfg.vocab_size), jnp.float32)
         return state
 
-    def _prefill_into(self, slot: int, req: Request, full: tuple) -> None:
+    def _prefill_into(self, slot: int, req: Request, full: tuple) -> int:
         n_full = len(full)
         arr = np.asarray(full, np.int32)[None]
-        span = (self.tracer.span("serve_prefill", rid=req.rid,
-                                 slot=slot, prompt_len=n_full,
-                                 speculative=True)
-                if self.tracer is not None else _null_span())
-        with span:
+        with span(SERVE_PREFILL, self.tracer, rid=req.rid, slot=slot,
+                  prompt_len=n_full, speculative=True):
             self._state = _engine_spec_prefill(
                 self.params, self.draft_params, self._state,
                 jnp.asarray(arr), jnp.asarray(slot, jnp.int32),
                 self.cfg, self.draft_cfg)
         self.prefill_dispatches += 1
         self.prefill_shapes.add((n_full, False))
+        return n_full
 
     def _dispatch_spec(self, state_in: dict, d: dict, k: int,
-                       dspan=None):
-        with (dspan.annotation() if dspan is not None
-              else _null_span()):
-            state, packed, pos_d, done_d, rem_d, idx_d = \
-                _engine_speculative_step(
-                    self.params, self.draft_params, state_in,
-                    d["pos"], d["done"], d["remaining"], d["eos"],
-                    d["stops"], d["step_idx"], d.get("key_data"),
-                    self.cfg, self.draft_cfg, k, self.ecfg.sample)
-            if dspan is not None:
-                dspan.mark_dispatched()
-            return (state, np.asarray(packed), pos_d, done_d, rem_d,
-                    idx_d)
+                       tables: tuple):
+        return _engine_speculative_step(
+            self.params, self.draft_params, state_in,
+            d["pos"], d["done"], d["remaining"], d["eos"],
+            d["stops"], d["step_idx"], d.get("key_data"),
+            self.cfg, self.draft_cfg, k, self.ecfg.sample)
 
 
 class PagedServingEngine(ServingEngine):
@@ -2543,7 +2584,7 @@ class PagedServingEngine(ServingEngine):
         budget = req.max_new_tokens - len(emitted)
         return self.pool.can_admit(full, budget)
 
-    def _prefill_into(self, slot: int, req: Request, full: tuple) -> None:
+    def _prefill_into(self, slot: int, req: Request, full: tuple) -> int:
         from akka_allreduce_tpu.serving.paging import pages_for
         n_full = len(full)
         budget = req.max_new_tokens - (n_full - len(req.prompt))
@@ -2561,17 +2602,16 @@ class PagedServingEngine(ServingEngine):
                                        self._unshared_pages_now)
         arr = np.asarray(full, np.int32)[None]
         n_cov = pages_for(n_full, self.ecfg.page_size)
-        span = (self.tracer.span("serve_prefill", rid=req.rid, slot=slot,
-                                 prompt_len=n_full, pages=len(pages),
-                                 shared=sum(1 for w in _writes if not w))
-                if self.tracer is not None else _null_span())
-        with span:
+        with span(SERVE_PREFILL, self.tracer, rid=req.rid, slot=slot,
+                  prompt_len=n_full, pages=len(pages),
+                  shared=sum(1 for w in _writes if not w)):
             self._state = _engine_paged_prefill(
                 self.params, self._state, jnp.asarray(arr),
                 jnp.asarray(pages[:n_cov], jnp.int32),
                 jnp.asarray(slot, jnp.int32), self.cfg)
         self.prefill_dispatches += 1
         self.prefill_shapes.add((n_full, False))
+        return n_full
 
     def _free_slot(self, i: int) -> None:
         from akka_allreduce_tpu.serving.paging import pages_for
@@ -2629,10 +2669,6 @@ class PagedServingEngine(ServingEngine):
                                        rid=slot.req.rid,
                                        src=page, dst=new)
 
-    def step(self) -> list:
-        self._prepare_writes()
-        return super().step()
-
     # -- the dispatch paths (page-table operand) ------------------------
 
     def _page_table_device(self):
@@ -2641,43 +2677,28 @@ class PagedServingEngine(ServingEngine):
             self._pt_dirty = False
         return self._dev_pt
 
-    def _dispatch_single(self, state_in: dict, pos_in, dspan=None):
-        pt = self._page_table_device()
-        with (dspan.annotation() if dspan is not None
-              else _null_span()):
-            state, packed = _engine_paged_step(
-                self.params, state_in, pos_in, pt, self.cfg,
-                self.ecfg.attention_impl, **self._sample_operands())
-            if dspan is not None:
-                dspan.mark_dispatched()
-            return state, np.asarray(packed)
+    def _step_tables(self) -> tuple:
+        return (self._page_table_device(),)
+
+    def _dispatch_single(self, state_in: dict, pos_in, ops: dict,
+                         tables: tuple):
+        return _engine_paged_step(
+            self.params, state_in, pos_in, tables[0], self.cfg,
+            self.ecfg.attention_impl, **ops)
 
     def _dispatch_block(self, state_in: dict, d: dict, s_steps: int,
-                        dspan=None):
-        pt = self._page_table_device()
+                        tables: tuple):
         sample = self.ecfg.sample
-        with (dspan.annotation() if dspan is not None
-              else _null_span()):
-            if sample is None:
-                state, packed, pos_d, done_d, rem_d = \
-                    _engine_paged_multi_step(
-                        self.params, state_in, d["pos"], d["done"],
-                        d["remaining"], d["eos"], d["stops"], pt,
-                        self.cfg, s_steps, self.ecfg.attention_impl)
-                if dspan is not None:
-                    dspan.mark_dispatched()
-                return (state, np.asarray(packed), pos_d, done_d, rem_d)
-            state, packed, pos_d, done_d, rem_d, idx_d = \
-                _engine_paged_multi_step(
-                    self.params, state_in, d["pos"], d["done"],
-                    d["remaining"], d["eos"], d["stops"], pt,
-                    self.cfg, s_steps, self.ecfg.attention_impl,
-                    sample=sample, key_data=d["key_data"],
-                    step_idx=d["step_idx"])
-            if dspan is not None:
-                dspan.mark_dispatched()
-            return (state, np.asarray(packed), pos_d, done_d, rem_d,
-                    idx_d)
+        if sample is None:
+            return _engine_paged_multi_step(
+                self.params, state_in, d["pos"], d["done"],
+                d["remaining"], d["eos"], d["stops"], tables[0],
+                self.cfg, s_steps, self.ecfg.attention_impl)
+        return _engine_paged_multi_step(
+            self.params, state_in, d["pos"], d["done"], d["remaining"],
+            d["eos"], d["stops"], tables[0], self.cfg, s_steps,
+            self.ecfg.attention_impl, sample=sample,
+            key_data=d["key_data"], step_idx=d["step_idx"])
 
     # -- introspection / metrics ----------------------------------------
 
@@ -2798,7 +2819,7 @@ class PagedSpeculativeEngine(_SpeculativeMixin, PagedServingEngine):
                 and self.draft_pool.can_admit(full, budget,
                                               share=False))
 
-    def _prefill_into(self, slot: int, req: Request, full: tuple) -> None:
+    def _prefill_into(self, slot: int, req: Request, full: tuple) -> int:
         from akka_allreduce_tpu.serving.paging import pages_for
         n_full = len(full)
         budget = self._spec_budget(req, full[len(req.prompt):])
@@ -2822,12 +2843,9 @@ class PagedSpeculativeEngine(_SpeculativeMixin, PagedServingEngine):
                                        self._unshared_pages_now)
         arr = np.asarray(full, np.int32)[None]
         n_cov = pages_for(n_full, self.ecfg.page_size)
-        span = (self.tracer.span("serve_prefill", rid=req.rid,
-                                 slot=slot, prompt_len=n_full,
-                                 pages=len(pages), speculative=True,
-                                 shared=sum(1 for w in _writes if not w))
-                if self.tracer is not None else _null_span())
-        with span:
+        with span(SERVE_PREFILL, self.tracer, rid=req.rid, slot=slot,
+                  prompt_len=n_full, pages=len(pages), speculative=True,
+                  shared=sum(1 for w in _writes if not w)):
             self._state = _engine_paged_spec_prefill(
                 self.params, self.draft_params, self._state,
                 jnp.asarray(arr), jnp.asarray(pages[:n_cov], jnp.int32),
@@ -2835,6 +2853,7 @@ class PagedSpeculativeEngine(_SpeculativeMixin, PagedServingEngine):
                 jnp.asarray(slot, jnp.int32), self.cfg, self.draft_cfg)
         self.prefill_dispatches += 1
         self.prefill_shapes.add((n_full, False))
+        return n_full
 
     def _free_slot(self, i: int) -> None:
         if self._draft_lane_pages[i] is not None:
@@ -2846,7 +2865,7 @@ class PagedSpeculativeEngine(_SpeculativeMixin, PagedServingEngine):
 
     # -- dispatch ------------------------------------------------------
 
-    def step(self) -> list:
+    def _prepare_writes(self) -> None:
         # the verify writes draft_steps + 1 target-pool positions per
         # active lane whatever its remaining budget; resolve sharing
         # over that whole span (the draft pool never shares)
@@ -2854,7 +2873,6 @@ class PagedSpeculativeEngine(_SpeculativeMixin, PagedServingEngine):
             if slot is not None:
                 self._resolve_lane_writes(i, slot,
                                           self.ecfg.draft_steps + 1)
-        return self._step_spec()
 
     def _draft_table_device(self):
         if self._draft_pt_dirty or self._dev_draft_pt is None:
@@ -2862,31 +2880,17 @@ class PagedSpeculativeEngine(_SpeculativeMixin, PagedServingEngine):
             self._draft_pt_dirty = False
         return self._dev_draft_pt
 
+    def _step_tables(self) -> tuple:
+        return (self._page_table_device(), self._draft_table_device())
+
     def _dispatch_spec(self, state_in: dict, d: dict, k: int,
-                       dspan=None):
-        pt = self._page_table_device()
-        dpt = self._draft_table_device()
-        with (dspan.annotation() if dspan is not None
-              else _null_span()):
-            state, packed, pos_d, done_d, rem_d, idx_d = \
-                _engine_paged_speculative_step(
-                    self.params, self.draft_params, state_in,
-                    d["pos"], d["done"], d["remaining"], d["eos"],
-                    d["stops"], d["step_idx"], d.get("key_data"),
-                    pt, dpt, self.cfg, self.draft_cfg, k,
-                    self.ecfg.sample)
-            if dspan is not None:
-                dspan.mark_dispatched()
-            return (state, np.asarray(packed), pos_d, done_d, rem_d,
-                    idx_d)
-
-
-class _null_span:
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return None
+                       tables: tuple):
+        pt, dpt = tables
+        return _engine_paged_speculative_step(
+            self.params, self.draft_params, state_in,
+            d["pos"], d["done"], d["remaining"], d["eos"],
+            d["stops"], d["step_idx"], d.get("key_data"),
+            pt, dpt, self.cfg, self.draft_cfg, k, self.ecfg.sample)
 
 
 # -- drain persistence (ISSUE 6 / PR 5 loose end) -----------------------

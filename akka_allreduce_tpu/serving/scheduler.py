@@ -55,6 +55,8 @@ import random
 import time
 from typing import Optional
 
+from akka_allreduce_tpu.runtime.tracing import SCHED_POP_READY, span
+
 from .admission import price as _price
 
 
@@ -201,10 +203,11 @@ class RequestScheduler:
 
     def __init__(self, cfg: SchedulerConfig, num_slots: int,
                  clock=time.monotonic, sleep=time.sleep, on_reject=None,
-                 admission=None, admit_gate=None):
+                 admission=None, admit_gate=None, tracer=None):
         if num_slots < 1:
             raise ValueError(f"num_slots must be >= 1, got {num_slots}")
         self.cfg = cfg
+        self.tracer = tracer
         self.num_slots = num_slots
         self.clock = clock
         self._sleep = sleep
@@ -344,6 +347,13 @@ class RequestScheduler:
         admission waits for memory in policy order rather than
         reordering around it (counted in ``blocked_on_memory``, the
         page-pressure signal next to ``queue_depth``)."""
+        with span(SCHED_POP_READY, self.tracer) as sp:
+            req = self._pop_ready(now, can_admit)
+            sp.set(queue_depth=len(self._arrived))
+        return req
+
+    def _pop_ready(self, now: Optional[float],
+                   can_admit) -> Optional[Request]:
         if now is None:
             now = self.clock()
         self._drain_arrivals(now)

@@ -20,10 +20,10 @@ lint entry):
   .tracing.Tracer` event stream (now carrying nested span ids and
   per-request correlation) as Perfetto-loadable Chrome-trace JSON.
 * ``device`` — :class:`DeviceTimer` / ``device_span``: bracket every
-  engine dispatch and train step with ``jax.profiler``
-  StepTraceAnnotation when available plus block-until-ready wall
-  deltas, yielding host-vs-device time and the ``dispatch_gap_ms``
-  host-bubble series.
+  engine dispatch and train step with block-until-ready wall deltas,
+  yielding host-vs-device time and the ``dispatch_gap_ms`` host-bubble
+  series (what a profile shows of a dispatch comes from
+  ``runtime/tracing.py``'s ``span``, not from here).
 * ``regression`` — the perf-regression gate behind ``cli.py perfgate``:
   fresh A/B rows vs the banked ``perf_capture/`` medians within
   per-section tolerances, exit-nonzero on regression (ROADMAP item 5's

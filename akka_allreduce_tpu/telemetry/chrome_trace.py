@@ -99,7 +99,10 @@ def chrome_trace(events: Iterable[Any],
                         "s": "t", "pid": _PID, "tid": tid,
                         "args": args})
         rid = fields.get("rid")
-        if synthesize_requests and isinstance(rid, int):
+        # lifecycles are made of the metrics plane's instants alone: the
+        # engine's ``serve_admit`` SPAN (the whole of admit()) shares its
+        # kind with the instant recorded inside it, and is no second admit
+        if synthesize_requests and isinstance(rid, int) and dur is None:
             lifecycles.setdefault(rid, []).append((ts_us, kind))
     if synthesize_requests:
         out.extend(_request_slices(lifecycles, tids))

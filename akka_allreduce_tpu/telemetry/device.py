@@ -6,16 +6,14 @@ did a step take" but "how much of that was the DEVICE, and how much was
 the host sitting between dispatches" — the second number
 (``dispatch_gap_ms``) is what tells you whether overlap is actually
 overlapping and whether block decode's one-readback-per-S is paying
-off. This module brackets dispatches three ways at once, all host-side
-(nothing here enters jitted code — the graftlint host-sync pass stays
-clean by construction, pinned by the ``engine_step_telemetry`` catalog
-entry):
+off. This module brackets dispatches on the host's clock (nothing here
+enters jitted code — the graftlint host-sync pass stays clean by
+construction, pinned by the ``engine_step_telemetry`` catalog entry).
+What lands in a profile beside the device's timeline is not this
+module's: the dispatch site opens its phases through
+``runtime/tracing.py``'s ``span`` on the thread that does the work.
 
-* ``jax.profiler.StepTraceAnnotation`` when the profiler is available:
-  a live ``--xprof-dir`` trace then carries named step regions, so the
-  XProf timeline attributes per-op device time to engine dispatches and
-  train steps (the deep view);
-* block-until-ready wall deltas as the always-on fallback: the caller
+* block-until-ready wall deltas: the caller
   marks the instant its dispatch call returned (``mark_dispatched``);
   host time is start->mark (tracing + program launch), device time is
   mark->exit (the blocking readback — wall-clock truth on any backend);
@@ -39,17 +37,6 @@ from akka_allreduce_tpu.telemetry.registry import (Histogram,
                                                    MetricsRegistry)
 
 
-def _step_annotation(name: str, step: int):
-    """jax.profiler.StepTraceAnnotation when importable, else None.
-    Lazy and guarded: telemetry must work (and cost only clock reads)
-    in processes that never import jax."""
-    try:
-        from jax.profiler import StepTraceAnnotation
-    except Exception:  # pragma: no cover - jax is present repo-wide
-        return None
-    return StepTraceAnnotation(name, step_num=step)
-
-
 class DeviceSpan:
     """One bracketed dispatch (context manager; use via
     :meth:`DeviceTimer.span`). Call :meth:`mark_dispatched` the moment
@@ -62,45 +49,21 @@ class DeviceSpan:
     def __init__(self, timer: "DeviceTimer", fields: dict):
         self._timer = timer
         self._fields = fields
-        self._ann = None
         self._t0 = 0.0
         self._t_mark: Optional[float] = None
 
     def mark_dispatched(self) -> None:
         self._t_mark = self._timer._clock()
 
-    def annotation(self):
-        """The profiler annotation for a timer configured with
-        ``annotate_site="dispatch"``: jax profiler annotations are
-        THREAD-LOCAL, so when the dispatch runs on another thread (the
-        engine's watchdog executor) the annotation must open THERE,
-        inside the dispatched callable — an annotation opened by
-        ``__enter__`` on the calling thread would bracket no device
-        work. Returns a context manager (null when annotation is off
-        or owned by the span)."""
-        t = self._timer
-        if t.annotate and t.annotate_site == "dispatch":
-            ann = _step_annotation(t.name, t._step)
-            if ann is not None:
-                return ann
-        import contextlib
-        return contextlib.nullcontext()
-
     def __enter__(self) -> "DeviceSpan":
         t = self._timer
         self._t0 = t._clock()
         if t._last_end is not None:
             t.gap_ms.record((self._t0 - t._last_end) * 1e3)
-        if t.annotate and t.annotate_site == "span":
-            self._ann = _step_annotation(t.name, t._step)
-            if self._ann is not None:
-                self._ann.__enter__()
         return self
 
     def __exit__(self, *exc) -> None:
         t = self._timer
-        if self._ann is not None:
-            self._ann.__exit__(*exc)
         if exc and exc[0] is not None:
             # a failed dispatch (watchdog trip, injected fault) is
             # recovery territory, not a device-time sample: recording
@@ -112,7 +75,6 @@ class DeviceSpan:
             return
         end = t._clock()
         t._last_end = end
-        t._step += 1
         mark = self._t_mark
         host_s = (mark - self._t0) if mark is not None else end - self._t0
         device_s = (end - mark) if mark is not None else 0.0
@@ -136,24 +98,11 @@ class DeviceTimer:
 
     def __init__(self, name: str,
                  registry: Optional[MetricsRegistry] = None,
-                 tracer=None, annotate: bool = True,
-                 annotate_site: str = "span",
-                 clock=time.perf_counter):
-        if annotate_site not in ("span", "dispatch"):
-            raise ValueError(f"annotate_site must be 'span' or "
-                             f"'dispatch', got {annotate_site!r}")
+                 tracer=None, clock=time.perf_counter):
         self.name = name
         self.tracer = tracer
-        self.annotate = annotate
-        # "span": the annotation opens with the span on the calling
-        # thread (train loop — dispatch runs right there). "dispatch":
-        # the caller opens DeviceSpan.annotation() inside its dispatch
-        # callable, wherever that runs (the engine, whose watchdog
-        # moves dispatches onto an executor thread)
-        self.annotate_site = annotate_site
         self._clock = clock
         self._last_end: Optional[float] = None
-        self._step = 0
         if registry is not None:
             self.host_ms = registry.histogram(
                 f"{name}_dispatch_host_ms",

@@ -258,6 +258,49 @@ class TestChromeTrace:
         assert names.count("decode") == 2
         assert names.count("request") == 1
 
+    def test_engine_phases_nest_and_the_admit_span_is_no_second_admit(self):
+        """The engine's spans through the one primitive: the four phases
+        nest under ``serve_step`` by id, and ``serve_admit`` the SPAN
+        (the whole of admit(), carrying the rid) does not read as a
+        second admission beside the metrics plane's ``serve_admit``
+        instant — one queued/decode pair per residency, as before."""
+        from akka_allreduce_tpu.runtime import tracing as T
+        t = Tracer()
+        t.record("serve_submit", rid=3)
+        with T.span(T.SERVE_ADMIT, t, rid=3) as sp:
+            sp.set(slot=0)
+            with T.span(T.SERVE_PREFILL, t, rid=3, slot=0, prompt_len=5):
+                pass
+            with T.span(T.SERVE_ADMIT_COMMIT, t):
+                t.record("serve_admit", rid=3, slot=0)   # on_admit
+        with T.span(T.SERVE_STEP, t, occupied=1, admitted=[(3, 8)]):
+            for phase in (T.SERVE_STEP_UPLOAD, T.SERVE_STEP_DISPATCH,
+                          T.SERVE_STEP_READBACK, T.SERVE_STEP_COMMIT):
+                with T.span(phase, t):
+                    pass
+        t.record("serve_complete", rid=3, tokens=1)
+        doc = chrome_trace(t.events)
+        slices = [e for e in doc["traceEvents"] if e["ph"] == "X"]
+        by_name = {}
+        for e in slices:
+            by_name.setdefault(e["name"], []).append(e)
+        step = by_name[T.SERVE_STEP][0]
+        assert step["args"]["admitted"] == [(3, 8)]
+        for phase in (T.SERVE_STEP_UPLOAD, T.SERVE_STEP_DISPATCH,
+                      T.SERVE_STEP_READBACK, T.SERVE_STEP_COMMIT):
+            (e,) = by_name[phase]
+            assert e["args"]["parent_id"] == step["args"]["span_id"]
+            assert e["tid"] == step["tid"]
+        admit = by_name[T.SERVE_ADMIT][0]
+        assert by_name[T.SERVE_PREFILL][0]["args"]["parent_id"] \
+            == admit["args"]["span_id"]
+        for name in ("request", "queued", "decode"):
+            assert len(by_name[name]) == 1, name
+        # queued ends where the INSTANT sits, inside the admit span
+        queued = by_name["queued"][0]
+        assert admit["ts"] <= queued["ts"] + queued["dur"] \
+            <= admit["ts"] + admit["dur"]
+
     def test_span_ids_ride_args_and_tracks_split(self):
         t = Tracer()
         with t.span("outer"):
@@ -285,7 +328,7 @@ class TestDeviceTimer:
             11.3,   # exit
         ])
         reg = MetricsRegistry()
-        t = DeviceTimer("engine", registry=reg, annotate=False,
+        t = DeviceTimer("engine", registry=reg,
                         clock=lambda: next(clock))
         with t.span() as s:
             s.mark_dispatched()
@@ -300,7 +343,7 @@ class TestDeviceTimer:
 
     def test_unmarked_span_charges_host(self):
         clock = iter([1.0, 2.0])
-        t = DeviceTimer("x", annotate=False, clock=lambda: next(clock))
+        t = DeviceTimer("x", clock=lambda: next(clock))
         with t.span():
             pass
         assert t.host_ms._vals == [1000.0]
@@ -316,7 +359,7 @@ class TestDeviceTimer:
         # reads: span-1 enter; span-2 enter, mark, exit (the failed
         # span's exit path reads no clock — that is the point)
         clock = iter([1.0, 10.0, 10.1, 10.3])
-        t = DeviceTimer("engine", tracer=tracer, annotate=False,
+        t = DeviceTimer("engine", tracer=tracer,
                         clock=lambda: next(clock))
         with pytest.raises(RuntimeError):
             with t.span():
@@ -333,7 +376,7 @@ class TestDeviceTimer:
 
     def test_reset_gap_skips_recovery_interval(self):
         clock = iter([1.0, 2.0, 10.0, 11.0])
-        t = DeviceTimer("x", annotate=False, clock=lambda: next(clock))
+        t = DeviceTimer("x", clock=lambda: next(clock))
         with t.span():
             pass
         t.reset_gap()  # e.g. watchdog recovery in between
@@ -341,32 +384,50 @@ class TestDeviceTimer:
             pass
         assert t.gap_ms._vals == []
 
-    def test_dispatch_site_annotation(self):
-        """annotate_site='dispatch' (the engine's configuration): the
-        span itself opens no annotation; DeviceSpan.annotation() hands
-        the dispatch callable a context manager to open on WHATEVER
-        thread runs the dispatch (profiler annotations are
-        thread-local — the watchdog executor is the point)."""
-        with pytest.raises(ValueError, match="annotate_site"):
-            DeviceTimer("x", annotate_site="nope")
+    def test_phases_open_on_the_dispatching_thread(self):
+        """What ``annotate_site='dispatch'`` existed for, as a case of
+        the one span primitive: the DeviceSpan keeps its two brackets
+        on the caller's clock, and the phases are opened through
+        ``span`` on WHATEVER thread runs the dispatch (profiler
+        annotations and the tracer's span stack are both thread-local —
+        the watchdog executor is the point). On the other thread they
+        are roots: a parent there would be a lie about structure."""
+        import concurrent.futures
+        from akka_allreduce_tpu.runtime.tracing import (
+            SERVE_STEP, SERVE_STEP_DISPATCH, SERVE_STEP_READBACK, span)
+        tracer = Tracer()
         clock = iter([1.0, 1.2, 1.5])
-        t = DeviceTimer("x", annotate_site="dispatch",
-                        clock=lambda: next(clock))
-        with t.span() as s:
-            with s.annotation():  # the dispatch thread's bracket
-                s.mark_dispatched()
-        assert t.host_ms._vals == pytest.approx([200.0])
-        # annotation() is null when annotation is off entirely
-        t2 = DeviceTimer("y", annotate=False, annotate_site="dispatch",
-                         clock=iter([0.0, 0.1]).__next__)
-        with t2.span() as s2:
-            with s2.annotation():
+        t = DeviceTimer("x", clock=lambda: next(clock))
+
+        def dispatch(s):
+            with span(SERVE_STEP_DISPATCH, tracer):
                 pass
+            s.mark_dispatched()
+            with span(SERVE_STEP_READBACK, tracer):
+                pass
+
+        with concurrent.futures.ThreadPoolExecutor(1) as pool:
+            with span(SERVE_STEP, tracer), t.span() as s:
+                pool.submit(dispatch, s).result()
+        assert t.host_ms._vals == pytest.approx([200.0])
+        assert t.device_ms._vals == pytest.approx([300.0])
+        by_kind = {e.kind: e for e in tracer.events}
+        assert [e.kind for e in tracer.events] == [
+            SERVE_STEP_DISPATCH, SERVE_STEP_READBACK, SERVE_STEP]
+        assert by_kind[SERVE_STEP_DISPATCH].parent_id is None
+        assert by_kind[SERVE_STEP_READBACK].parent_id is None
+        # the same phases on the caller's thread nest under the step
+        with span(SERVE_STEP, tracer) as outer:
+            with span(SERVE_STEP_DISPATCH, tracer):
+                pass
+        inner, outer_ev = tracer.events[-2:]
+        assert inner.parent_id == outer_ev.span_id
+        assert outer._kind == SERVE_STEP
 
     def test_tracer_span_recorded(self):
         tracer = Tracer()
         clock = iter([1.0, 1.5])
-        t = DeviceTimer("engine", tracer=tracer, annotate=False,
+        t = DeviceTimer("engine", tracer=tracer,
                         clock=lambda: next(clock))
         with t.span(occupied=3):
             pass

@@ -128,3 +128,339 @@ class TestClusterTracing:
         dead = [e for e in tracer.events if e.kind == "worker_dead"]
         assert len(dead) == 1 and dead[0].fields["rank"] == 2
         assert tracer.counters["round_complete"] > 0
+
+
+# -- the span primitive (runtime/tracing.py ``span``) ----------------------
+
+import contextlib
+import re
+import statistics
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from akka_allreduce_tpu.runtime import tracing as T
+from akka_allreduce_tpu.runtime.tracing import span
+
+
+def _fake_clock(step=0.25):
+    ticks = iter(i * step for i in range(10_000))
+    return lambda: next(ticks)
+
+
+class TestSpanPrimitive:
+    def test_records_what_tracer_span_recorded(self):
+        """Same kind, fields, ids, parentage and timing as the method."""
+        a, b = Tracer(clock=_fake_clock()), Tracer(clock=_fake_clock())
+        with a.span("serve_step", occupied=3):
+            with a.span("serve_step.commit", tokens=3, finished=0):
+                a.record("point", x=1)
+        with span(T.SERVE_STEP, b, occupied=3):
+            with span(T.SERVE_STEP_COMMIT, b) as sp:
+                sp.set(tokens=3, finished=0)   # known only inside
+                b.record("point", x=1)
+        assert a.events == b.events
+        assert dict(a.counters) == dict(b.counters)
+        commit, step = b.events[1], b.events[2]
+        assert commit.parent_id == step.span_id and step.parent_id is None
+        assert b.events[0].parent_id == commit.span_id
+
+    def test_without_a_tracer_it_appends_nothing(self):
+        t = Tracer()
+        with span(T.SERVE_STEP, None, occupied=3) as sp:
+            sp.set(tokens=1)
+            assert t.current_span_id is None
+        assert t.events == [] and not t.counters
+
+    def test_records_on_exception_and_unwinds_the_stack(self):
+        t = Tracer()
+        with pytest.raises(ValueError):
+            with span(T.SERVE_ADMIT, t, rid=7):
+                raise ValueError("x")
+        assert [e.kind for e in t.events] == [T.SERVE_ADMIT]
+        assert t.current_span_id is None
+
+    def test_tables_name_every_constant(self):
+        spans = {v for k, v in vars(T).items()
+                 if k.startswith(("SERVE_", "SCHED_", "TRAIN_"))}
+        scopes = {v for k, v in vars(T).items() if k.startswith("SCOPE_")}
+        assert spans == set(T.SPANS) and scopes == set(T.SCOPES)
+        for layer, metric in list(T.SPANS.values()) + list(
+                T.SCOPES.values()):
+            assert layer and metric
+
+
+# -- the engine's phases ----------------------------------------------------
+
+_TOY = None
+
+
+def _toy():
+    global _TOY
+    if _TOY is None:
+        from akka_allreduce_tpu.models.transformer import (
+            TransformerConfig, init_transformer)
+        # wide enough that a step's work, not the tracer's own reads
+        # and records, is what a span times
+        cfg = TransformerConfig(vocab_size=1024, d_model=512, n_heads=4,
+                                n_kv_heads=2, n_layers=4, d_ff=2048,
+                                max_seq=32, rope=True, ffn="swiglu")
+        _TOY = (cfg, init_transformer(jax.random.key(0), cfg))
+    return _TOY
+
+
+def _toy_engine(kind, tracer):
+    from akka_allreduce_tpu.serving import EngineConfig, ServingEngine
+    from akka_allreduce_tpu.serving.engine import (PagedEngineConfig,
+                                                   PagedServingEngine)
+    cfg, params = _toy()
+    if kind == "slot":
+        return ServingEngine(params, cfg, EngineConfig(
+            num_slots=3, prefill_buckets=(4, 8)), tracer=tracer)
+    if kind == "block":
+        return ServingEngine(params, cfg, EngineConfig(
+            num_slots=3, decode_steps=2), tracer=tracer)
+    return PagedServingEngine(params, cfg, PagedEngineConfig(
+        num_slots=3, page_size=4), tracer=tracer)
+
+
+def _children(tracer, parent):
+    return [e for e in tracer.events if e.parent_id == parent.span_id
+            and e.duration_s is not None]
+
+
+@pytest.mark.parametrize("kind", ["slot", "paged", "block"])
+def test_engine_step_is_four_phases_and_admit_holds_its_prefill(kind):
+    from akka_allreduce_tpu.serving import (Request, RequestScheduler,
+                                            SchedulerConfig)
+    tracer = Tracer()
+    engine = _toy_engine(kind, tracer)
+    sched = RequestScheduler(SchedulerConfig(max_queue_depth=8),
+                             num_slots=3, tracer=tracer)
+    reqs = [Request(rid=r, prompt=tuple(range(1, 4 + r)),
+                    max_new_tokens=17, submitted_at=0.0) for r in range(3)]
+    for r in reqs:
+        sched.submit(r)
+    admitted_between = []
+    # two admitted before the first step, the third before the second
+    for upto in (2, 3, 3, 3, 3, 3, 3, 3):
+        now = []
+        while sum(map(len, admitted_between)) + len(now) < upto:
+            req = sched.pop_ready(can_admit=engine.can_admit)
+            sched.bind(req, engine.admit(req))
+            now.append(req.rid)
+        admitted_between.append(now)
+        for slot, _req, _toks, _why in engine.step():
+            sched.release(slot)
+    steps = [e for e in tracer.events if e.kind == T.SERVE_STEP]
+    assert len(steps) == 8
+    covered = []
+    phases = [T.SERVE_STEP_UPLOAD, T.SERVE_STEP_DISPATCH,
+              T.SERVE_STEP_READBACK, T.SERVE_STEP_COMMIT]
+    for i, step in enumerate(steps):
+        kids = sorted(_children(tracer, step), key=lambda e: e.ts)
+        # the DeviceTimer's own bracket (dispatch + readback on the
+        # caller's clock) keeps its kind and place
+        assert sum(k.kind == "engine_dispatch" for k in kids) == 1
+        kids = [k for k in kids if k.kind != "engine_dispatch"]
+        assert [k.kind for k in kids] == phases
+        for a, b in zip(kids, kids[1:]):
+            assert a.ts + a.duration_s <= b.ts
+        covered.append(sum(k.duration_s for k in kids) / step.duration_s)
+        assert [rid for rid, _n in step.fields["admitted"]] \
+            == admitted_between[i]
+        assert step.fields["occupied"] == (2 if i == 0 else 3)
+        commit = kids[-1]
+        assert commit.fields["tokens"] >= step.fields["occupied"]
+        assert commit.fields["finished"] == 0
+    # the four phases are the step but for the tracer's and the
+    # DeviceTimer's own reads and records (the median: a step that the
+    # machine preempted between two phases proves nothing)
+    assert statistics.median(covered[1:]) >= 0.95, covered
+    if kind == "slot":     # the bucket each prompt was padded to
+        assert [n for _r, n in steps[0].fields["admitted"]] == [4, 4]
+        assert [n for _r, n in steps[1].fields["admitted"]] == [8]
+    admits = [e for e in tracer.events if e.kind == T.SERVE_ADMIT]
+    assert [a.fields["rid"] for a in admits] == [0, 1, 2]
+    assert sorted(a.fields["slot"] for a in admits) == [0, 1, 2]
+    for a in admits:
+        assert [k.kind for k in sorted(_children(tracer, a),
+                                       key=lambda e: e.ts)] \
+            == [T.SERVE_PREFILL, T.SERVE_ADMIT_COMMIT]
+        assert a.parent_id is None
+    pops = [e for e in tracer.events if e.kind == T.SCHED_POP_READY]
+    assert [p.fields["queue_depth"] for p in pops] == [2, 1, 0]
+    engine.close()
+
+
+def test_engine_without_a_tracer_traces_nothing():
+    from akka_allreduce_tpu.serving import Request
+    engine = _toy_engine("slot", None)
+    engine.admit(Request(rid=1, prompt=(1, 2, 3), max_new_tokens=2,
+                         submitted_at=0.0))
+    engine.step()
+    assert engine._device_timer().tracer is None
+    engine.close()
+
+
+# -- the train step's named scopes -------------------------------------------
+
+_COLLECTIVE = re.compile(
+    r" (all-reduce|all-gather|reduce-scatter|collective-permute|"
+    r"all-to-all)(-start)?\(")
+
+
+@contextlib.contextmanager
+def _no_compile_cache():
+    """The persistent cache keys a program without its metadata, so a hit
+    hands back the first compile's text, names and all."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        cc.reset_cache()
+
+
+_TOY_BUCKETS = 23     # the toy's 23,488 parameters in 1024-element buckets
+
+
+def _toy_step_hlo(dp, masked=False):
+    """(optimized HLO text, HloModule name) of the toy train step on
+    ``dp`` (virtual) devices."""
+    from akka_allreduce_tpu.models.train import (TrainConfig,
+                                                 make_train_state,
+                                                 make_train_step)
+    from akka_allreduce_tpu.models.transformer import TransformerConfig
+    from akka_allreduce_tpu.parallel.mesh import MeshSpec, make_device_mesh
+    mcfg = TransformerConfig(vocab_size=64, d_model=32, n_heads=4,
+                             n_kv_heads=2, n_layers=2, d_ff=64, max_seq=16,
+                             rope=True, ffn="swiglu", tie_embeddings=False)
+    cfg = TrainConfig(model=mcfg, bucket_elems=1024, optimizer="adamw")
+    mesh = make_device_mesh(MeshSpec(dp=dp), devices=jax.devices()[:dp])
+    params, opt_state, opt = make_train_state(jax.random.key(0), cfg, mesh)
+    step = make_train_step(cfg, mesh, opt, dynamic_valid=masked)
+    args = [params, opt_state, jnp.zeros((dp * 2, 16), jnp.int32)]
+    if masked:
+        args.append(jnp.ones((dp, _TOY_BUCKETS), jnp.float32))
+    with _no_compile_cache():
+        return step.lower(*args).compile().as_text()
+
+
+def _op_names(hlo):
+    """instruction line -> its op_name with JAX's transform wrappers
+    (``jvp(...)``, ``transpose(...)``, ``jit(...)``) taken off."""
+    out = []
+    for line in hlo.splitlines():
+        m = re.search(r'op_name="([^"]*)"', line)
+        if m:
+            out.append((line, "/" + re.sub(r"[\w.-]+\(|\)", "",
+                                           m.group(1)) + "/"))
+    return out
+
+
+@pytest.mark.parametrize("dp", [1, 4])
+def test_train_step_scopes_are_in_the_hlo(dp):
+    named = _op_names(_toy_step_hlo(dp))
+    under = lambda path, sc: f"/{sc}/" in path  # noqa: E731
+    for sc in T.SCOPES:
+        assert any(under(p, sc) for _l, p in named), sc
+    # every collective that carries the bucket matrix is the sync's wire;
+    # the scalar sums of the loss and its metrics are not the sync
+    wires = [(l, p) for l, p in named if _COLLECTIVE.search(l)]
+    buckets = [(l, p) for l, p in wires
+               if re.search(rf"f32\[{_TOY_BUCKETS},1024\]",
+                            l.split(" all-", 1)[0])]
+    assert buckets, "no collective over the bucket matrix"
+    for line, path in buckets:
+        assert under(path, T.SCOPE_SYNC_REDUCE), line[:200]
+    for line, path in wires:
+        if (line, path) not in buckets:
+            assert not under(path, "grad_sync"), line[:200]
+            assert re.search(r"= \(?[fs]32\[\]", line), line[:200]
+    # the bucket matrix's staging: pad / reshape / dynamic-update-slice
+    # whose result or operand is the [buckets, 1024] matrix
+    staging = [(l, p) for l, p in named if re.search(
+        rf"= f32\[({_TOY_BUCKETS},1024|{_TOY_BUCKETS * 1024})\]\S* "
+        r"(pad|reshape|dynamic-update-slice|concatenate)\(", l)]
+    assert staging, "no staging op of the bucket matrix"
+    for line, path in staging:
+        assert under(path, T.SCOPE_SYNC_PACK) \
+            or under(path, T.SCOPE_SYNC_UNPACK) \
+            or under(path, T.SCOPE_SYNC_REDUCE), line[:200]
+
+
+def test_masked_sync_counts_ride_the_reduce_scope():
+    named = _op_names(_toy_step_hlo(4, masked=True))
+    counts = [p for l, p in named if _COLLECTIVE.search(l)
+              and re.search(rf"s32\[{_TOY_BUCKETS}\]", l)]
+    assert counts and all(f"/{T.SCOPE_SYNC_REDUCE}/" in p for p in counts)
+
+
+def _stripped(hlo):
+    hlo = re.sub(r",? ?metadata=\{[^}]*\}", "", hlo)
+    hlo = re.sub(r"(?m)^(FileNames|FunctionNames|FileLocations|"
+                 r"StackFrames)\n(?:.+\n)*\n?", "", hlo)
+    return hlo
+
+
+def test_scopes_add_no_operation(monkeypatch):
+    """The optimized HLO of the step is the same but for metadata as that
+    of the step lowered with ``jax.named_scope`` patched out."""
+    with_scopes = _toy_step_hlo(4)
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    without = _toy_step_hlo(4)
+    assert "grad_sync" in with_scopes and "grad_sync" not in without
+    assert _stripped(with_scopes) == _stripped(without)
+
+
+# -- the names the benchmark reads -----------------------------------------
+
+def test_names_the_benchmark_reads_are_pinned():
+    """``benchmark/metrics/*.json`` match programs by these names, and
+    ``benchmark/program_trace.py`` reads these scopes. A rename here nulls
+    those metrics on the ledger's next line."""
+    import glob
+    import json
+    import os
+    from akka_allreduce_tpu.serving import Request
+    from akka_allreduce_tpu.serving import engine as eng
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    cfg, params = _toy()
+    e = _toy_engine("slot", None)
+    step = eng._engine_step.lower(
+        params, e._state, jnp.asarray(e._pos), cfg).as_text("hlo")
+    prefill = eng._engine_prefill.lower(
+        params, e._state, jnp.zeros((1, 4), jnp.int32),
+        jnp.asarray(3, jnp.int32), jnp.asarray(0, jnp.int32), cfg,
+        gather=True).as_text("hlo")
+    e.close()
+    got = {
+        "jit__engine_step": step.split(",", 1)[0].split()[1],
+        "jit__engine_prefill": prefill.split(",", 1)[0].split()[1],
+        "jit_step": _toy_step_hlo(1).split(",", 1)[0].split()[1],
+    }
+    readers = {}
+    for path in glob.glob(os.path.join(root, "benchmark", "metrics",
+                                       "*.json")):
+        pattern = json.load(open(path)).get("args", {}).get("pattern", "")
+        for name in got:
+            if re.search(pattern, name) and pattern:
+                readers.setdefault(name, []).append(os.path.basename(path))
+    for name, module in got.items():
+        assert module == name, (
+            f"the program {name} is now {module}: "
+            f"benchmark/metrics/{sorted(readers.get(name, []))} match it "
+            f"by name; a rename goes with a `benchmark` PR that changes "
+            f"those files")
+        assert readers.get(name), f"no metric file matches {name}"
+    assert set(T.SCOPES) == {"grad_sync/pack", "grad_sync/reduce",
+                             "grad_sync/unpack", "lm_head_loss",
+                             "optimizer", "attention"}, (
+        "benchmark/program_trace.py (sync_device_pct, sync_staging_ms, "
+        "head_loss_device_pct) reads these scope names; a rename goes "
+        "with a `benchmark` PR")
