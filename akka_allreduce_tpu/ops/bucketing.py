@@ -20,6 +20,15 @@ relayout the (num_buckets, bucket_elems) view whenever per-bucket math
 (mask multiplies, count rescaling) materialises it — measured 10x round
 cost on a 25M-element sync with bucket_elems=3_125_000 vs an aligned
 size. Aligned rows keep the reshape free and the bucket ops fused.
+
+Building the matrix is itself a cost: concatenate, zero-pad and reshape
+are whole-payload HBM copies on the way in, and the slices back out are
+more on the way out (on a v5e, 242 ms round a 45 ms all-reduce of 2.5 GB
+at dp=4: PERF.md, PR 24). So only the syncs that use a row build it —
+``parallel/dp.py`` calls :func:`bucketize` for masked rounds, the int8 /
+ef8 wires and the windowed / swing / hierarchical schedules; its exact
+fused round on the f32 / bf16 wire takes :func:`tree_bucket_spec` alone
+(the geometry of the counts) and reduces the leaves where they lie.
 """
 
 from __future__ import annotations

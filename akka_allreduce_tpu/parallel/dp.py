@@ -9,14 +9,32 @@ pytree plus per-element contribution counts — the exact payload of the
 reference's ``AllReduceOutput(data, count, iteration)``.
 
 Rank-local: call inside the ``shard_map``/``pjit``-traced train step, where
-``axis_name`` is the mesh's data axis. The full pipeline per round is
+``axis_name`` is the mesh's data axis. A round takes one of two layouts,
+decided at trace time from what the call itself shows
+(``GradSyncResult.layout``):
+
+``"buckets"`` — a mask (``valid`` given), a quantized wire (``int8``,
+``ef8``) or the ``windowed`` / ``swing`` / ``hierarchical`` schedules need
+the bucket matrix (a row to mask and count, a residual of its shape, a
+reduce-scatter geometry):
 
     pytree --bucketize--> (B, E) buckets --masked psum--> (sums, counts)
            --rescale_by_count--> mean grads --debucketize--> pytree
 
-which lowers to one (or a few) XLA collectives over ICI — the whole
-scatter/reduce/broadcast protocol of the reference collapses into them
-(SURVEY.md §7).
+``"leaves"`` — the exact round (no mask) on the ``f32`` / ``bf16`` wire and
+the fused schedule uses none of that, so the matrix is never built and the
+leaves are reduced where they lie:
+
+    pytree leaves --a psum each--> summed leaves (--rescale--> mean)
+
+with the same static counts (``bucket_counts`` of ``B`` entries, the group
+size each). The copies into and out of the matrix were most of the sync's
+device time (PERF.md, PR 25); the sums are the same elements of the same
+ranks, to the order in which the collective adds them.
+
+Either way the round lowers to one (or a few) XLA collectives over ICI — the
+whole scatter/reduce/broadcast protocol of the reference collapses into
+them (SURVEY.md §7).
 """
 
 from __future__ import annotations
@@ -29,7 +47,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from akka_allreduce_tpu.ops.bucketing import BucketSpec, bucketize, \
-    debucketize, vector_to_tree
+    tree_bucket_spec, vector_to_tree
 from akka_allreduce_tpu.ops.autotune import resolve_schedule
 from akka_allreduce_tpu.ops.collectives import (
     DEFAULT_EF_BLOCK,
@@ -52,8 +70,13 @@ from akka_allreduce_tpu.utils.vma import _axis_tuple, psum_all
 
 @dataclasses.dataclass(frozen=True)
 class GradSyncConfig:
-    """``bucket_elems`` is the fusion granularity — the TPU meaning of the
-    reference's ``maxChunkSize`` (reference: AllreduceWorker.scala:31).
+    """``bucket_elems`` is the granularity of the contribution counts and
+    masks — the TPU meaning of the reference's ``maxChunkSize`` (reference:
+    AllreduceWorker.scala:31) — and, on every path that builds the bucket
+    matrix (masked, quantized, windowed, swing, hierarchical), the row
+    width of the collective's payload. The exact fused round on an
+    uncompressed wire reduces the leaves themselves and keeps the value
+    only as the geometry of ``bucket_counts`` and ``spec``.
     ``average=True`` divides by the per-element contribution count (honest
     mean even when stragglers were masked); ``False`` returns the raw sum,
     exactly what the reference's sink receives."""
@@ -139,7 +162,12 @@ class GradSyncResult:
     for every other transport). ``residual2`` is the phase-2
     (broadcast-leg) residual when the caller opted in (owner-rows-
     shaped; None otherwise). ``schedule`` is the schedule that actually
-    lowered — what "auto" resolved to, or the hand flag verbatim."""
+    lowered — what "auto" resolved to, or the hand flag verbatim.
+    ``layout`` says what carried the payload: ``"leaves"`` (the exact
+    fused round on the f32/bf16 wire: no bucket matrix was built) or
+    ``"buckets"`` (every other call). Static, like ``schedule`` and
+    ``transport``; ``spec`` and ``bucket_counts`` have the bucket geometry
+    under both."""
 
     grads: Any
     counts: Any
@@ -149,6 +177,43 @@ class GradSyncResult:
     residual: Any = None
     residual2: Any = None
     schedule: str = "fused"
+    layout: str = "buckets"
+
+
+def _allreduce_leaves(grads: Any, spec: BucketSpec, config: GradSyncConfig,
+                      group: int, use_bf16: bool) -> GradSyncResult:
+    """The exact fused round on an uncompressed wire, with no bucket
+    matrix: a ``psum`` of each leaf as it lies (the compiler groups them
+    as it schedules them; a ``psum`` of the whole tuple compiles to the
+    same program on the TPU, and a call a leaf stays one that a fault
+    test can intercept, as the benchmark's does). The same sums as
+    bucketize -> psum -> rescale -> debucketize (f32, or bf16 where the
+    wire says so; a leaf of another dtype is summed on the wire's dtype,
+    rescaled in f32 and cast back), the same static counts; only the
+    copies into and out of the ``(num_buckets, bucket_elems)`` matrix are
+    gone. On the f32 wire with a rescale factor of exactly 1.0 (what
+    ``make_train_step`` asks for) pack and unpack hold no operation."""
+    leaves = jax.tree.leaves(grads)
+    wire_dtype = jnp.bfloat16 if use_bf16 else jnp.float32
+    with jax.named_scope(SCOPE_SYNC_PACK):
+        wire = [leaf.astype(wire_dtype) for leaf in leaves]
+    with jax.named_scope(SCOPE_SYNC_REDUCE):
+        summed = [psum_all(w, config.axis_name) for w in wire]
+    factor = config.rescale_target / group if config.average else 1.0
+    with jax.named_scope(SCOPE_SYNC_UNPACK):
+        if factor != 1.0:
+            summed = [s.astype(jnp.float32) * factor for s in summed]
+        out = [s.astype(dtype) for s, dtype in zip(summed, spec.dtypes)]
+    counts = None
+    if config.return_elem_counts:
+        counts = jax.tree.unflatten(
+            spec.treedef,
+            [jnp.full(shape, group, jnp.int32) for shape in spec.shapes])
+    return GradSyncResult(
+        grads=jax.tree.unflatten(spec.treedef, out), counts=counts,
+        bucket_counts=jnp.full((spec.num_buckets,), group, jnp.int32),
+        spec=spec, transport=config.transport, schedule="fused",
+        layout="leaves")
 
 
 def allreduce_gradients(grads: Any, config: GradSyncConfig = GradSyncConfig(),
@@ -177,8 +242,9 @@ def allreduce_gradients(grads: Any, config: GradSyncConfig = GradSyncConfig(),
     pack = jax.named_scope(SCOPE_SYNC_PACK)
     reduce = jax.named_scope(SCOPE_SYNC_REDUCE)
     unpack = jax.named_scope(SCOPE_SYNC_UNPACK)
-    with pack:
-        buckets, spec = bucketize(grads, config.bucket_elems)
+    # geometry only: which layout carries the payload is decided below,
+    # from the mask, the wire and the schedule that lowers
+    spec = tree_bucket_spec(grads, config.bucket_elems)
     # axes that actually move bytes: size-1 axes reduce to identity and
     # need no wire format — compressed transports bypass themselves there
     # (rounding gradients for zero wire savings would be pure loss)
@@ -203,7 +269,7 @@ def allreduce_gradients(grads: Any, config: GradSyncConfig = GradSyncConfig(),
         # Infeasible/missing entries fall back to the fused default
         # inside resolve_schedule (auto is never worse than a flag).
         schedule, n_windows = resolve_schedule(
-            config.plan, buckets.shape[0], buckets.shape[1],
+            config.plan, spec.num_buckets, spec.bucket_elems,
             [lax.axis_size(a) for a in live_axes], config.transport,
             default_windows=config.num_windows)
     windowed = schedule == "windowed" and bool(live_axes)
@@ -236,6 +302,22 @@ def allreduce_gradients(grads: Any, config: GradSyncConfig = GradSyncConfig(),
                 f"a single (>1) data axis; got {live_axes} — fold the "
                 f"parallelism into one axis or use the fused schedule")
         win_axis = live_axes[0]
+    group = 1
+    for a in _axis_tuple(config.axis_name):
+        group *= lax.axis_size(a)
+    # The layout follows from what this call shows at trace time. An exact
+    # round (no mask) on an uncompressed wire and the fused schedule uses
+    # nothing of the bucket matrix: no per-bucket mask to multiply, no
+    # count to reduce, no reduce-scatter geometry to satisfy. There the
+    # bucket is only a fusion granularity, which the compiler's own
+    # grouping of all-reduces gives without a copy, so the leaves are
+    # reduced where they lie. Every other path builds the matrix.
+    layout = ("leaves" if valid is None
+              and config.transport in ("f32", "bf16")
+              and schedule == "fused" else "buckets")
+    if layout == "buckets":
+        with pack:
+            buckets, _ = bucketize(grads, config.bucket_elems)
 
     def windowed_sum(mat: jnp.ndarray) -> jnp.ndarray:
         """Pipelined two-phase sum of a bucket matrix, padding the bucket
@@ -285,6 +367,8 @@ def allreduce_gradients(grads: Any, config: GradSyncConfig = GradSyncConfig(),
             "on the fused two-phase schedule — the broadcast-leg "
             "residual is owner-rows-shaped, which only the fused carve "
             "keeps stable")
+    if layout == "leaves":
+        return _allreduce_leaves(grads, spec, config, group, use_bf16)
     # captured AFTER the fresh-start default so the size-1 identity
     # path still honors the residual contract (ef8 always returns the
     # buckets-shaped state, never the caller's None back)
@@ -353,11 +437,11 @@ def allreduce_gradients(grads: Any, config: GradSyncConfig = GradSyncConfig(),
         return psum_all(valid.astype(jnp.int32), config.axis_name)
 
     if valid is None:
-        # Exact path (thresholds = 1.0): every rank contributes every
-        # bucket, so the masking multiply and the count psum are pure
-        # overhead — counts are the static group size. This keeps the
-        # whole round at ~2 HBM passes (the reference's fast-path
-        # degenerate case: the entire protocol is one sum).
+        # Exact path (thresholds = 1.0) on a wire or schedule that needs
+        # the matrix: every rank contributes every bucket, so the masking
+        # multiply and the count psum are pure overhead — counts are the
+        # static group size (the reference's fast-path degenerate case:
+        # the entire protocol is one sum).
         if quantized:
             with reduce:
                 summed = quantized_sum(buckets, None)
@@ -378,9 +462,6 @@ def allreduce_gradients(grads: Any, config: GradSyncConfig = GradSyncConfig(),
         else:
             with reduce:
                 summed = scheduled_sum(buckets)
-        group = 1
-        for a in _axis_tuple(config.axis_name):
-            group *= lax.axis_size(a)
         bucket_counts = jnp.full((spec.num_buckets,), group, jnp.int32)
         if config.average:
             with unpack:
@@ -452,7 +533,7 @@ def allreduce_gradients(grads: Any, config: GradSyncConfig = GradSyncConfig(),
                           bucket_counts=bucket_counts, spec=spec,
                           transport=config.transport,
                           residual=new_residual,
-                          residual2=new_residual2,
+                          residual2=new_residual2, layout=layout,
                           # what actually lowered: a degraded
                           # hierarchical (< 2 live axes) ran fused
                           schedule=("fused" if schedule == "hierarchical"

@@ -362,31 +362,47 @@ def _op_names(hlo):
     return out
 
 
-@pytest.mark.parametrize("dp", [1, 4])
-def test_train_step_scopes_are_in_the_hlo(dp):
-    named = _op_names(_toy_step_hlo(dp))
+@pytest.mark.parametrize("dp,masked", [(1, False), (4, False),
+                                       (1, True), (4, True)])
+def test_train_step_scopes_are_in_the_hlo(dp, masked):
+    hlo = _toy_step_hlo(dp, masked=masked)
+    named = _op_names(hlo)
     under = lambda path, sc: f"/{sc}/" in path  # noqa: E731
+    # the exact step reduces the leaves where they lie (parallel/dp.py,
+    # layout "leaves"): f32 leaves on the f32 wire with a mean factor of
+    # exactly 1.0 leave nothing to pack or unpack
+    empty = () if masked else (T.SCOPE_SYNC_PACK, T.SCOPE_SYNC_UNPACK)
     for sc in T.SCOPES:
-        assert any(under(p, sc) for _l, p in named), sc
-    # every collective that carries the bucket matrix is the sync's wire;
-    # the scalar sums of the loss and its metrics are not the sync
+        assert any(under(p, sc) for _l, p in named) == (sc not in empty), sc
+    matrix = rf"f32\[({_TOY_BUCKETS},1024|{_TOY_BUCKETS * 1024})\]"
+    # every collective that carries gradients is the sync's wire; the
+    # scalar sums of the loss and its metrics are not the sync
     wires = [(l, p) for l, p in named if _COLLECTIVE.search(l)]
-    buckets = [(l, p) for l, p in wires
-               if re.search(rf"f32\[{_TOY_BUCKETS},1024\]",
-                            l.split(" all-", 1)[0])]
-    assert buckets, "no collective over the bucket matrix"
-    for line, path in buckets:
+    if masked:
+        # the matrix, and beside it the per-bucket counts' exact psum
+        carrying = [(l, p) for l, p in wires
+                    if re.search(rf"(f32\[{_TOY_BUCKETS},1024|"
+                                 rf"s32\[{_TOY_BUCKETS})\]",
+                                 l.split(" all-", 1)[0])]
+        assert any("f32" in l.split(" all-", 1)[0] for l, _p in carrying), \
+            "no collective over the bucket matrix"
+    else:
+        assert not re.search(matrix, hlo), "the exact step built the matrix"
+        carrying = [(l, p) for l, p in wires
+                    if not re.search(r"= \(?[fs]32\[\]", l)]
+        assert carrying, "no collective over the leaves"
+    for line, path in carrying:
         assert under(path, T.SCOPE_SYNC_REDUCE), line[:200]
     for line, path in wires:
-        if (line, path) not in buckets:
+        if (line, path) not in carrying:
             assert not under(path, "grad_sync"), line[:200]
             assert re.search(r"= \(?[fs]32\[\]", line), line[:200]
     # the bucket matrix's staging: pad / reshape / dynamic-update-slice
     # whose result or operand is the [buckets, 1024] matrix
     staging = [(l, p) for l, p in named if re.search(
-        rf"= f32\[({_TOY_BUCKETS},1024|{_TOY_BUCKETS * 1024})\]\S* "
-        r"(pad|reshape|dynamic-update-slice|concatenate)\(", l)]
-    assert staging, "no staging op of the bucket matrix"
+        rf"= {matrix}\S* (pad|reshape|dynamic-update-slice|concatenate)\(",
+        l)]
+    assert bool(staging) == masked, "staging ops of the bucket matrix"
     for line, path in staging:
         assert under(path, T.SCOPE_SYNC_PACK) \
             or under(path, T.SCOPE_SYNC_UNPACK) \

@@ -132,6 +132,39 @@ class TestTraining:
         assert float(jnp.abs(flat[:4]).max()) == 0.0
 
 
+class TestSyncLayoutsAgree:
+    def test_exact_step_equals_all_ones_masked_step(self):
+        """The exact path reduces the leaves where they lie; an all-ones
+        ``valid`` mask takes the bucket matrix through the masked psum.
+        Same elements, same ranks, same honest counts: the two steps may
+        differ by f32 summation order and nothing else, so the layouts
+        cannot drift apart unseen."""
+        from akka_allreduce_tpu.models.train import dense_bucket_count
+        mesh = make_device_mesh(MeshSpec(dp=4), devices=jax.devices()[:4])
+        cfg = TrainConfig(model=MCFG, bucket_elems=256)
+        params, opt_state, opt = make_train_state(jax.random.key(6), cfg,
+                                                  mesh)
+        ones = jnp.ones((dense_bucket_count(cfg, mesh, params),), jnp.int32)
+        tokens = make_tokens(8, 32, seed=9)
+        exact = make_train_step(cfg, mesh, opt)
+        masked = make_train_step(cfg, mesh, opt, valid_buckets=ones)
+        p_e, _, m_e = exact(params, opt_state, tokens)
+        p_m, _, m_m = masked(params, opt_state, tokens)
+        assert int(m_e["min_bucket_count"]) == 4
+        assert int(m_m["min_bucket_count"]) == 4
+        np.testing.assert_allclose(float(m_e["loss"]), float(m_m["loss"]),
+                                   rtol=1e-6)
+        moved = 0.0
+        for (path, a), b, start in zip(jax.tree.flatten_with_path(p_e)[0],
+                                       jax.tree.leaves(p_m),
+                                       jax.tree.leaves(params)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-5, atol=1e-7,
+                                       err_msg=str(path))
+            moved = max(moved, float(jnp.abs(a - start).max()))
+        assert moved > 1e-4   # the step did update what is compared
+
+
 class TestCompileStability:
     """ISSUE 3 satellite: the train step's compile-cache stability,
     asserted with the compile-counting guard (analysis/recompile.py).
