@@ -978,6 +978,23 @@ def _build_model_config(args: argparse.Namespace, max_seq: int):
         tie_embeddings=args.tie_embeddings)
 
 
+def _model_config_from_file(args: argparse.Namespace, max_seq: int):
+    """``serve --model-config FILE`` -> TransformerConfig; ``args.vocab``
+    follows the file, so the synthetic load draws its ids from it."""
+    import json
+
+    import jax.numpy as jnp
+
+    from akka_allreduce_tpu.models.transformer import config_from_hf
+    with open(args.model_config) as f:
+        hf = json.load(f)
+    dtype = jnp.bfloat16 if hf.get("torch_dtype") == "bfloat16" \
+        else jnp.float32
+    mcfg = config_from_hf(hf, max_seq, dtype)
+    args.vocab = mcfg.vocab_size
+    return mcfg
+
+
 def _restore_params(args: argparse.Namespace, mcfg) -> "tuple | int":
     """Build a 1-device params template and restore args.ckpt_dir's
     weights into it — params ONLY (CheckpointManager.restore_params), so
@@ -2121,6 +2138,15 @@ def _add_serve(sub: argparse._SubParsersAction) -> None:
                         "selfcheck mode — throughput and scheduling "
                         "behavior do not depend on trained values)")
     _add_model_args(p)
+    p.add_argument("--model-config", default=None, metavar="FILE",
+                   help="the model from a published config.json (HF-style "
+                        "keys; models/transformer.py config_from_hf) "
+                        "instead of the --d-model/... flags: with "
+                        "attention_method MLA the shortcut double layer "
+                        "with latent attention and a dropless expert share "
+                        "(experts_held: [offset, count] in the file says "
+                        "which experts this chip holds). torch_dtype "
+                        "bfloat16 serves in bf16")
     p.add_argument("--max-seq", type=int, default=128,
                    help="KV-cache length per slot; every request needs "
                         "prompt + max-new-tokens <= this")
@@ -4747,6 +4773,16 @@ def _cmd_serve(args: argparse.Namespace) -> int:
                                             ServingEngine,
                                             ServingMetrics, serve_loop)
 
+    mcfg_file = None
+    if args.model_config:
+        try:
+            # ahead of the checks below: they read args.vocab
+            mcfg_file = _model_config_from_file(args, args.max_seq)
+        except (OSError, KeyError, ValueError) as exc:
+            print(f"error: --model-config {args.model_config}: {exc!r}",
+                  file=sys.stderr)
+            return 2
+
     try:
         lo, _, hi = args.prompt_len.partition(":")
         p_lo, p_hi = int(lo), int(hi or lo)
@@ -4791,7 +4827,7 @@ def _cmd_serve(args: argparse.Namespace) -> int:
               f"than --prompt-len max {p_hi}", file=sys.stderr)
         return 2
 
-    mcfg = _build_model_config(args, args.max_seq)
+    mcfg = mcfg_file or _build_model_config(args, args.max_seq)
     if args.ckpt_dir:
         restored = _restore_params(args, mcfg)
         if isinstance(restored, int):

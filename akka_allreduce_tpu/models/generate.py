@@ -24,6 +24,7 @@ tokens at decode time would be strictly worse, not more faithful).
 
 from __future__ import annotations
 
+import dataclasses
 from functools import partial
 from typing import Optional
 
@@ -37,10 +38,15 @@ from akka_allreduce_tpu.models.transformer import (
     lm_logits,
     rmsnorm,
 )
-from akka_allreduce_tpu.parallel.ep import moe_ffn
+from akka_allreduce_tpu.parallel.ep import dropless_moe, moe_ffn
 from akka_allreduce_tpu.parallel.ring_attention import (
     NEG_INF,
     local_causal_attention,
+)
+from akka_allreduce_tpu.runtime.tracing import (
+    SCOPE_ATTENTION,
+    SCOPE_DENSE_FFN,
+    SCOPE_MLA_ATTENTION,
 )
 
 
@@ -62,6 +68,19 @@ def init_kv_cache(cfg: TransformerConfig, batch: int,
     Scales ride in ``k_scale``/``v_scale`` entries; every cache consumer
     (decode_step / prefill / extend / the serving engine) branches on
     their presence, so the pytree structure IS the format switch."""
+    if cfg.attention == "mla":
+        # the latent cache is a pytree key of its own: per attention (two
+        # a double layer) and position the normed, scaled latent and the
+        # rotary key all heads share - kv_lora_rank + qk_rope_head_dim
+        # numbers, whatever the number of heads
+        if kv_dtype is not None:
+            raise NotImplementedError(
+                f"kv_dtype={kv_dtype!r}: the latent cache has no "
+                f"quantized format (missing: a scale a cached latent and "
+                f"its dequantize-on-read in `_latent_attention`)")
+        return {"latent": jnp.zeros((2 * cfg.n_layers, batch, cfg.max_seq,
+                                     cfg.latent_dim), cfg.dtype),
+                "pos": jnp.zeros((), jnp.int32)}
     shape = (cfg.n_layers, batch, cfg.max_seq, cfg.kv_heads, cfg.head_dim)
     if kv_dtype is None:
         return {
@@ -103,6 +122,11 @@ def init_kv_pool(cfg: TransformerConfig, num_pages: int, page_size: int,
     if num_pages < 1 or page_size < 1:
         raise ValueError(f"num_pages/page_size must be >= 1, got "
                          f"{num_pages}/{page_size}")
+    if cfg.new_kind is not None:
+        raise NotImplementedError(
+            f"the paged pool cannot hold {cfg.new_kind} (missing: a latent "
+            f"page and cached-block functions that read it through the "
+            f"page table)")
     shape = (cfg.n_layers, num_pages, page_size, cfg.kv_heads,
              cfg.head_dim)
     if kv_dtype is None:
@@ -185,6 +209,298 @@ def _cached_attention(q: jnp.ndarray, k_all: jnp.ndarray,
     return out.reshape(b, one, h, d).astype(q.dtype)
 
 
+def _rope_slots(x: jnp.ndarray, positions: jnp.ndarray,
+                theta: float) -> jnp.ndarray:
+    """apply_rope (models/transformer.py) with a PER-ROW position:
+    x (slots, 1, heads, d), positions (slots,). Same formula, f32
+    phases, half-split pairing, cast points — the angle for row b here
+    is bitwise the angle decode_step computes for its whole batch at
+    scalar pos = positions[b], so per-slot rope output matches the
+    standalone decode exactly."""
+    d = x.shape[-1]
+    half = d // 2
+    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    angles = positions.astype(jnp.float32)[:, None] * freqs[None, :]
+    cos = jnp.cos(angles)[:, None, None, :]  # (slots, 1, 1, D/2)
+    sin = jnp.sin(angles)[:, None, None, :]
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., :half], xf[..., half:]
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x1 * sin + x2 * cos],
+        axis=-1).astype(x.dtype)
+
+
+def _slot_cached_attention(q: jnp.ndarray, k_all: jnp.ndarray,
+                           v_all: jnp.ndarray, pos: jnp.ndarray,
+                           window: "int | None" = None) -> jnp.ndarray:
+    """``_cached_attention`` with the scalar decode position generalized
+    to (slots,): row b masks by ITS ``pos[b]``.
+    Same einsum structure, f32 score/softmax, and cast points; the
+    contraction runs over the full static ``max_seq`` buffer for every
+    row (the mask is per-row data, the shape is not), which is exactly
+    the no-window standalone program — so per-row outputs are bitwise
+    equal to a batch-1 ``decode_step`` at that position. Sliding-window
+    decode keeps the mask-only form (positions outside the window mask
+    to NEG_INF; exp underflows to exactly 0.0): per-step cost stays
+    O(max_seq) rather than generate()'s O(window) slice, a trade for
+    per-row window offsets that only shows at long max_seq."""
+    b, one, h, d = q.shape
+    h_kv = k_all.shape[2]
+    g = h // h_kv
+    qg = q.reshape(b, one, h_kv, g, d)
+    scale = d ** -0.5
+    k_idx = jnp.arange(k_all.shape[1])
+    scores = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k_all,
+                        preferred_element_type=jnp.float32) * scale
+    valid = k_idx[None, :] <= pos[:, None]  # (slots, max_seq)
+    if window is not None:
+        valid &= k_idx[None, :] > pos[:, None] - window
+    scores = jnp.where(valid[:, None, None, None, :], scores, NEG_INF)
+    p = jax.nn.softmax(scores, axis=-1)
+    out = jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(v_all.dtype), v_all,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(b, one, h, d).astype(q.dtype)
+
+
+def _write_slot_rows(cache: jnp.ndarray, layer: int, vals: jnp.ndarray,
+                     pos: jnp.ndarray,
+                     mask: "jnp.ndarray | None" = None) -> jnp.ndarray:
+    """Write ``vals[s]`` at ``cache[layer, s, pos[s]]`` for every slot.
+    An unrolled loop of ``dynamic_update_slice`` (slots is small and
+    static) rather than one ``.at[layer, rows, pos].set`` scatter: with
+    the engine state donated, DUS updates the buffer in place, and the
+    XLA:CPU scatter lowering measured ~5x slower per write. Placement
+    only — the written values are identical either way. On the v5e at 128
+    lanes x 8 latent caches (1,024 unrolled writes) the step takes 36.2 ms
+    and with one scatter a cache 49.6 ms (chip runs, PR 26): kept.
+
+    ``mask`` (slots,) bool: a False lane keeps its old cache value at
+    ``pos[s]`` (the multi-step block's frozen lanes — the write becomes
+    a read-select-write of one tiny row, still a DUS the donation keeps
+    in place)."""
+    for s in range(vals.shape[0]):
+        val = vals[s][None, None, None]
+        idx = (layer, s, pos[s]) + (0,) * (vals.ndim - 1)
+        if mask is not None:
+            old = lax.dynamic_slice(cache, idx, val.shape)
+            val = jnp.where(mask[s], val, old)
+        cache = lax.dynamic_update_slice(cache, val, idx)
+    return cache
+
+
+# -- one cached-block function per block kind -----------------------------
+#
+# ``decode_step``, ``prefill`` and the serving engine's per-slot step
+# (serving/engine.py ``_slot_decode_step``) run the same block over the
+# same cache and differ only in WHERE: which positions the rotary phases
+# take, where the new keys land in the buffer, and what the queries attend.
+# :class:`CacheOps` is that difference; a block's mathematics is written
+# once a kind, in ``_dense_cached_block`` and ``_shortcut_cached_block``.
+
+@dataclasses.dataclass(frozen=True)
+class CacheOps:
+    """``pos``: None for a prefill of positions 0..t-1 (the queries attend
+    the block's fresh keys); a scalar for one decode position of the whole
+    batch; (b,) for a position a row (the slot engine). ``write_mask``
+    (b,) freezes rows' cache writes (per-row positions only). ``counted``
+    (b*t,) bool: the tokens an expert layer's counts see (None: all)."""
+    pos: Optional[jnp.ndarray] = None
+    write_mask: Optional[jnp.ndarray] = None
+    counted: Optional[jnp.ndarray] = None
+
+    def rope(self, x: jnp.ndarray, theta: float) -> jnp.ndarray:
+        if self.pos is None:
+            return apply_rope(x, jnp.arange(x.shape[1]), theta)
+        if self.pos.ndim == 0:
+            return apply_rope(x, self.pos[None], theta)
+        return _rope_slots(x, self.pos, theta)
+
+    def write(self, buf: jnp.ndarray, i: int,
+              vals: jnp.ndarray) -> jnp.ndarray:
+        """``vals`` (b, t, ...) into ``buf[i]`` (b, max_seq, ...)."""
+        if self.pos is not None and self.pos.ndim:
+            return _write_slot_rows(buf, i, vals[:, 0], self.pos,
+                                    self.write_mask)
+        at = 0 if self.pos is None else self.pos
+        return lax.dynamic_update_slice(
+            buf, vals[None], (i, 0, at) + (0,) * (vals.ndim - 2))
+
+
+def _dense_cached_block(layer: dict, x: jnp.ndarray, kv: dict, i: int,
+                        cfg: TransformerConfig, ops: CacheOps):
+    """The Llama-family block (transformer_block's math: same layer dict,
+    norms, residual order and cast points) with attention served through
+    the cache ``kv`` (``k``/``v`` [+ scales], layer ``i``). Returns
+    (x, kv, None)."""
+    b, t, _ = x.shape
+    kv = dict(kv)
+    quantized = "k_scale" in kv
+    with jax.named_scope(SCOPE_ATTENTION):
+        h = rmsnorm(x, layer["ln1"], cfg.norm_eps)
+        q = (h @ layer["wq"]).reshape(b, t, cfg.n_heads, cfg.head_dim)
+        k = (h @ layer["wk"]).reshape(b, t, cfg.kv_heads, cfg.head_dim)
+        v = (h @ layer["wv"]).reshape(b, t, cfg.kv_heads, cfg.head_dim)
+        if cfg.rope:
+            q = ops.rope(q, cfg.rope_theta)
+            k = ops.rope(k, cfg.rope_theta)
+        if quantized:
+            kq, ks = quantize_kv(k)
+            vq, vs = quantize_kv(v)
+            kv["k"] = ops.write(kv["k"], i, kq)
+            kv["v"] = ops.write(kv["v"], i, vq)
+            kv["k_scale"] = ops.write(kv["k_scale"], i, ks)
+            kv["v_scale"] = ops.write(kv["v_scale"], i, vs)
+        else:
+            kv["k"] = ops.write(kv["k"], i, k.astype(kv["k"].dtype))
+            kv["v"] = ops.write(kv["v"], i, v.astype(kv["v"].dtype))
+        if ops.pos is None:
+            # prompt positions attend the freshly-computed block K/V, not
+            # the cache, so prefill logits are identical under either
+            # cache format — quantization error enters at decode-time
+            # REREADS only
+            attn = local_causal_attention(q, k, v, window=cfg.attn_window)
+        else:
+            if quantized:
+                k_all = dequantize_kv(kv["k"][i], kv["k_scale"][i],
+                                      cfg.dtype)
+                v_all = dequantize_kv(kv["v"][i], kv["v_scale"][i],
+                                      cfg.dtype)
+            else:
+                k_all, v_all = kv["k"][i], kv["v"][i]
+            attend = (_slot_cached_attention if ops.pos.ndim
+                      else _cached_attention)
+            attn = attend(q, k_all, v_all, ops.pos, window=cfg.attn_window)
+        x = x + attn.reshape(b, t, -1) @ layer["wo"]
+
+    h = rmsnorm(x, layer["ln2"], cfg.norm_eps)
+    if "router" in layer:
+        y, _aux = moe_ffn(h, layer, cfg.moe, axis_name=None)
+        return x + y, kv, None
+    with jax.named_scope(SCOPE_DENSE_FFN):
+        if "w3" in layer:
+            x = x + (jax.nn.silu(h @ layer["w1"])
+                     * (h @ layer["w3"])) @ layer["w2"]
+        else:
+            x = x + jax.nn.gelu(h @ layer["w1"]) @ layer["w2"]
+    return x, kv, None
+
+
+def _latent_attention(q: jnp.ndarray, latent: jnp.ndarray,
+                      pos: jnp.ndarray, rank: int,
+                      scale: float) -> jnp.ndarray:
+    """Decode attention over the latent itself (absorbed projections).
+    q (b, 1, h, rank + rope): each head's query folded through the key
+    half of the up-projection, then its rotary part; latent (b, max_seq,
+    rank + rope): what the cache holds, positions <= pos valid (a scalar,
+    or one a row). Scores are q . latent over all rank + rope columns;
+    the value is the latent's first ``rank`` columns. Returns (b, 1, h,
+    rank). The weighted sum runs over all the columns and the rotary ones
+    are dropped after it: a slice of the buffer ahead of the matmul would
+    be a copy of the cache."""
+    b = q.shape[0]
+    pos = jnp.broadcast_to(pos, (b,))
+    # the one query position is squeezed out: a plain batched matmul
+    scores = jnp.einsum("bhc,bkc->bhk", q[:, 0], latent,
+                        preferred_element_type=jnp.float32) * scale
+    valid = jnp.arange(latent.shape[1])[None, :] <= pos[:, None]
+    scores = jnp.where(valid[:, None, :], scores, NEG_INF)
+    p = jax.nn.softmax(scores, axis=-1)
+    out = jnp.einsum("bhk,bkc->bhc", p.astype(latent.dtype), latent,
+                     preferred_element_type=jnp.float32)
+    return out[:, None, :, :rank].astype(q.dtype)
+
+
+def _mla_cached_attention(p: dict, x: jnp.ndarray, kv: dict, a: int,
+                          cfg: TransformerConfig, ops: CacheOps):
+    """Latent attention ``a`` over x (b, t, d) through ``kv["latent"]``:
+    the cache takes the normed, scaled latent and the rotary key (after
+    RoPE), ``latent_dim`` numbers a token. A prefill expands keys and
+    values from its fresh latents; a decode step folds the up-projection's
+    key half into the query and attends the cached latent directly. Both
+    are the same function of the same cache. Returns (the attention's
+    output through ``wo``, kv)."""
+    b, t, _ = x.shape
+    heads, rank = cfg.n_heads, cfg.kv_lora_rank
+    nope, vd = cfg.qk_nope_head_dim, cfg.v_head_dim
+    s_q, s_kv = cfg.mla_scales
+    kv = dict(kv)
+    h = rmsnorm(x, p["ln"], cfg.norm_eps)
+    c_q = rmsnorm(h @ p["wq_a"], p["q_norm"], cfg.norm_eps)
+    q = ((c_q @ p["wq_b"]) * s_q).reshape(b, t, heads, -1)
+    q_nope = q[..., :nope]
+    q_rope = ops.rope(q[..., nope:], cfg.rope_theta)
+    down = h @ p["wkv_a"]
+    c_kv = rmsnorm(down[..., :rank], p["kv_norm"], cfg.norm_eps) * s_kv
+    k_rope = ops.rope(down[:, :, None, rank:], cfg.rope_theta)
+    latent = jnp.concatenate([c_kv, k_rope[:, :, 0]], axis=-1)
+    kv["latent"] = ops.write(kv["latent"], a,
+                             latent.astype(kv["latent"].dtype))
+    up = p["wkv_b"].reshape(rank, heads, nope + vd)
+    if ops.pos is None:
+        kv_heads = jnp.einsum("btr,rhe->bthe", c_kv, up)
+        k = jnp.concatenate(
+            [kv_heads[..., :nope],
+             jnp.broadcast_to(k_rope, (b, t, heads, k_rope.shape[-1]))],
+            axis=-1)
+        out = local_causal_attention(
+            jnp.concatenate([q_nope, q_rope], axis=-1), k,
+            kv_heads[..., nope:])
+    else:
+        q_lat = jnp.einsum("bthn,rhn->bthr", q_nope, up[..., :nope])
+        out_lat = _latent_attention(
+            jnp.concatenate([q_lat, q_rope], axis=-1), kv["latent"][a],
+            ops.pos, rank, (nope + q_rope.shape[-1]) ** -0.5)
+        out = jnp.einsum("bthr,rhv->bthv", out_lat, up[..., nope:])
+    return out.reshape(b, t, heads * vd) @ p["wo"], kv
+
+
+def _shortcut_cached_block(layer: dict, x: jnp.ndarray, kv: dict, i: int,
+                           cfg: TransformerConfig, ops: CacheOps):
+    """The shortcut-connected double layer: two latent attentions
+    (cache entries 2i and 2i+1), two dense SwiGLU FFNs, and one expert
+    layer that reads the first half's post-attention norm and is added at
+    the end of the second half. Returns (x, kv, the expert layer's
+    counts: parallel/ep.py ``dropless_moe``)."""
+    b, t, d = x.shape
+
+    def ffn(p, h):
+        with jax.named_scope(SCOPE_DENSE_FFN):
+            return (jax.nn.silu(h @ p["w1"]) * (h @ p["w3"])) @ p["w2"]
+
+    def attention(j, x, kv):
+        with jax.named_scope(SCOPE_MLA_ATTENTION):
+            return _mla_cached_attention(layer["mla"][j], x, kv, 2 * i + j,
+                                         cfg, ops)
+
+    out, kv = attention(0, x, kv)
+    x = x + out
+    h = rmsnorm(x, layer["ffn"][0]["ln"], cfg.norm_eps)
+    m, counts = dropless_moe(h.reshape(b * t, d), layer["moe"],
+                             cfg.experts, ops.counted)
+    x = x + ffn(layer["ffn"][0], h)
+    out, kv = attention(1, x, kv)
+    x = x + out
+    h = rmsnorm(x, layer["ffn"][1]["ln"], cfg.norm_eps)
+    return x + ffn(layer["ffn"][1], h) + m.reshape(b, t, d), kv, counts
+
+
+def cached_blocks(params: dict, x: jnp.ndarray, kv: dict,
+                  cfg: TransformerConfig, ops: CacheOps):
+    """Every block of the model over x (b, t, d) through the cache:
+    (x, kv, counts). ``counts`` is None for the dense kind; for the
+    shortcut kind the expert layers' counts summed over the layers
+    (``held`` and ``identity`` a token, (b*t,); ``touched`` a scalar)."""
+    block = (_shortcut_cached_block if cfg.block == "shortcut"
+             else _dense_cached_block)
+    total = None
+    for i, layer in enumerate(params["layers"]):
+        x, kv, counts = block(layer, x, kv, i, cfg, ops)
+        if counts is not None:
+            total = counts if total is None else jax.tree.map(
+                jnp.add, total, counts)
+    return x, kv, total
+
+
 def decode_step(params: dict, cache: dict, token: jnp.ndarray,
                 cfg: TransformerConfig) -> tuple[dict, jnp.ndarray]:
     """One incremental step: consume ``token`` (b,) int32 at ``cache.pos``,
@@ -194,61 +510,41 @@ def decode_step(params: dict, cache: dict, token: jnp.ndarray,
     rmsnorm/residual order) with attention served from the cache; parity
     with the full forward is pinned by tests/test_generate.py.
     """
-    b = token.shape[0]
     pos = cache["pos"]
-    quantized = "k_scale" in cache
     x = params["embed"][token][:, None, :]
     if not cfg.rope:
         x = x + lax.dynamic_slice_in_dim(params["pos"], pos, 1,
                                          axis=0)[None]
-    k_cache, v_cache = cache["k"], cache["v"]
-    if quantized:
-        k_scales, v_scales = cache["k_scale"], cache["v_scale"]
-    for i, layer in enumerate(params["layers"]):
-        h = rmsnorm(x, layer["ln1"])
-        q = (h @ layer["wq"]).reshape(b, 1, cfg.n_heads, cfg.head_dim)
-        k = (h @ layer["wk"]).reshape(b, 1, cfg.kv_heads, cfg.head_dim)
-        v = (h @ layer["wv"]).reshape(b, 1, cfg.kv_heads, cfg.head_dim)
-        if cfg.rope:
-            q = apply_rope(q, pos[None], cfg.rope_theta)
-            k = apply_rope(k, pos[None], cfg.rope_theta)
-        if quantized:
-            kq, ks = quantize_kv(k)
-            vq, vs = quantize_kv(v)
-            k_cache = lax.dynamic_update_slice(
-                k_cache, kq[None], (i, 0, pos, 0, 0))
-            v_cache = lax.dynamic_update_slice(
-                v_cache, vq[None], (i, 0, pos, 0, 0))
-            k_scales = lax.dynamic_update_slice(
-                k_scales, ks[None], (i, 0, pos, 0))
-            v_scales = lax.dynamic_update_slice(
-                v_scales, vs[None], (i, 0, pos, 0))
-            k_all = dequantize_kv(k_cache[i], k_scales[i], cfg.dtype)
-            v_all = dequantize_kv(v_cache[i], v_scales[i], cfg.dtype)
-        else:
-            k_cache = lax.dynamic_update_slice(
-                k_cache, k[None].astype(k_cache.dtype), (i, 0, pos, 0, 0))
-            v_cache = lax.dynamic_update_slice(
-                v_cache, v[None].astype(v_cache.dtype), (i, 0, pos, 0, 0))
-            k_all, v_all = k_cache[i], v_cache[i]
-        attn = _cached_attention(q, k_all, v_all, pos,
-                                 window=cfg.attn_window)
-        x = x + attn.reshape(b, 1, -1) @ layer["wo"]
+    kv = {n: c for n, c in cache.items() if n != "pos"}
+    x, kv, _counts = cached_blocks(params, x, kv, cfg, CacheOps(pos=pos))
+    logits = lm_logits(
+        params, rmsnorm(x, params["out_norm"], cfg.norm_eps), cfg)
+    return {**kv, "pos": pos + 1}, logits[:, 0, :]
 
-        h = rmsnorm(x, layer["ln2"])
-        if "router" in layer:
-            y, _aux = moe_ffn(h, layer, cfg.moe, axis_name=None)
-            x = x + y
-        elif "w3" in layer:
-            x = x + (jax.nn.silu(h @ layer["w1"])
-                     * (h @ layer["w3"])) @ layer["w2"]
-        else:
-            x = x + jax.nn.gelu(h @ layer["w1"]) @ layer["w2"]
-    logits = lm_logits(params, rmsnorm(x, params["out_norm"]), cfg)
-    new_cache = {"k": k_cache, "v": v_cache, "pos": pos + 1}
-    if quantized:
-        new_cache["k_scale"], new_cache["v_scale"] = k_scales, v_scales
-    return new_cache, logits[:, 0, :]
+
+def prefill_counted(params: dict, cache: dict, prompt: jnp.ndarray,
+                    cfg: TransformerConfig,
+                    logit_pos: "jnp.ndarray | int | None" = None):
+    """:func:`prefill` with the expert layers' counts as a third result
+    (None for the dense kind). With ``logit_pos`` the positions after it
+    are padding and count nowhere."""
+    b, t = prompt.shape
+    x = params["embed"][prompt]
+    if not cfg.rope:
+        x = x + params["pos"][:t][None]
+    counted = None
+    if logit_pos is not None:
+        counted = jnp.broadcast_to(jnp.arange(t) <= logit_pos,
+                                   (b, t)).reshape(b * t)
+    kv = {n: c for n, c in cache.items() if n != "pos"}
+    x, kv, counts = cached_blocks(params, x, kv, cfg,
+                                  CacheOps(counted=counted))
+    x_last = (x[:, -1:] if logit_pos is None
+              else lax.dynamic_slice_in_dim(x, logit_pos, 1, axis=1))
+    logits = lm_logits(
+        params, rmsnorm(x_last, params["out_norm"], cfg.norm_eps), cfg)
+    return ({**kv, "pos": jnp.asarray(t, jnp.int32)}, logits[:, 0, :],
+            counts)
 
 
 def prefill(params: dict, cache: dict, prompt: jnp.ndarray,
@@ -268,61 +564,7 @@ def prefill(params: dict, cache: dict, prompt: jnp.ndarray,
     position mask never admits, and is overwritten as decode advances).
     The returned cache's ``pos`` is always t; bucketed callers own the
     true frontier."""
-    b, t = prompt.shape
-    quantized = "k_scale" in cache
-    x = params["embed"][prompt]
-    if not cfg.rope:
-        x = x + params["pos"][:t][None]
-    k_cache, v_cache = cache["k"], cache["v"]
-    if quantized:
-        k_scales, v_scales = cache["k_scale"], cache["v_scale"]
-    for i, layer in enumerate(params["layers"]):
-        h = rmsnorm(x, layer["ln1"])
-        q = (h @ layer["wq"]).reshape(b, t, cfg.n_heads, cfg.head_dim)
-        k = (h @ layer["wk"]).reshape(b, t, cfg.kv_heads, cfg.head_dim)
-        v = (h @ layer["wv"]).reshape(b, t, cfg.kv_heads, cfg.head_dim)
-        if cfg.rope:
-            q = apply_rope(q, jnp.arange(t), cfg.rope_theta)
-            k = apply_rope(k, jnp.arange(t), cfg.rope_theta)
-        if quantized:
-            kq, ks = quantize_kv(k)
-            vq, vs = quantize_kv(v)
-            k_cache = lax.dynamic_update_slice(
-                k_cache, kq[None], (i, 0, 0, 0, 0))
-            v_cache = lax.dynamic_update_slice(
-                v_cache, vq[None], (i, 0, 0, 0, 0))
-            k_scales = lax.dynamic_update_slice(
-                k_scales, ks[None], (i, 0, 0, 0))
-            v_scales = lax.dynamic_update_slice(
-                v_scales, vs[None], (i, 0, 0, 0))
-        else:
-            k_cache = lax.dynamic_update_slice(
-                k_cache, k[None].astype(k_cache.dtype), (i, 0, 0, 0, 0))
-            v_cache = lax.dynamic_update_slice(
-                v_cache, v[None].astype(v_cache.dtype), (i, 0, 0, 0, 0))
-        # prompt positions attend the freshly-computed block K/V, not the
-        # cache, so prefill logits are identical under either cache
-        # format — quantization error enters at decode-time REREADS only
-        attn = local_causal_attention(q, k, v, window=cfg.attn_window)
-        x = x + attn.reshape(b, t, -1) @ layer["wo"]
-
-        h = rmsnorm(x, layer["ln2"])
-        if "router" in layer:
-            y, _aux = moe_ffn(h, layer, cfg.moe, axis_name=None)
-            x = x + y
-        elif "w3" in layer:
-            x = x + (jax.nn.silu(h @ layer["w1"])
-                     * (h @ layer["w3"])) @ layer["w2"]
-        else:
-            x = x + jax.nn.gelu(h @ layer["w1"]) @ layer["w2"]
-    x_last = (x[:, -1:] if logit_pos is None
-              else lax.dynamic_slice_in_dim(x, logit_pos, 1, axis=1))
-    logits = lm_logits(params, rmsnorm(x_last, params["out_norm"]), cfg)
-    new_cache = {"k": k_cache, "v": v_cache,
-                 "pos": jnp.asarray(t, jnp.int32)}
-    if quantized:
-        new_cache["k_scale"], new_cache["v_scale"] = k_scales, v_scales
-    return new_cache, logits[:, 0, :]
+    return prefill_counted(params, cache, prompt, cfg, logit_pos)[:2]
 
 
 def multi_step_decode(params: dict, kv: dict, logits: jnp.ndarray,

@@ -104,7 +104,7 @@ def extend(params: dict, cache: dict, tokens: jnp.ndarray,
         k_scales, v_scales = cache["k_scale"], cache["v_scale"]
     positions = pos + jnp.arange(t)
     for i, layer in enumerate(params["layers"]):
-        h = rmsnorm(x, layer["ln1"])
+        h = rmsnorm(x, layer["ln1"], cfg.norm_eps)
         q = (h @ layer["wq"]).reshape(b, t, cfg.n_heads, cfg.head_dim)
         k = (h @ layer["wk"]).reshape(b, t, cfg.kv_heads, cfg.head_dim)
         v = (h @ layer["wv"]).reshape(b, t, cfg.kv_heads, cfg.head_dim)
@@ -134,7 +134,7 @@ def extend(params: dict, cache: dict, tokens: jnp.ndarray,
                                        window=cfg.attn_window)
         x = x + attn.reshape(b, t, -1) @ layer["wo"]
 
-        h = rmsnorm(x, layer["ln2"])
+        h = rmsnorm(x, layer["ln2"], cfg.norm_eps)
         if "router" in layer:
             y, _aux = moe_ffn(h, layer, cfg.moe, axis_name=None)
             x = x + y
@@ -143,7 +143,8 @@ def extend(params: dict, cache: dict, tokens: jnp.ndarray,
                      * (h @ layer["w3"])) @ layer["w2"]
         else:
             x = x + jax.nn.gelu(h @ layer["w1"]) @ layer["w2"]
-    logits = lm_logits(params, rmsnorm(x, params["out_norm"]), cfg)
+    logits = lm_logits(
+        params, rmsnorm(x, params["out_norm"], cfg.norm_eps), cfg)
     new_cache = {"k": k_cache, "v": v_cache, "pos": pos + t}
     if quantized:
         new_cache["k_scale"], new_cache["v_scale"] = k_scales, v_scales

@@ -1018,7 +1018,7 @@ def make_grad_step(cfg: TrainConfig, mesh: Mesh,
             xm = x.reshape(m, b_local // m, t_local, x.shape[-1])
             outs, aux = gpipe_apply(p["layers"], xm, stage, "pp")
             h = outs.reshape(b_local, t_local, outs.shape[-1])
-            h = rmsnorm(h, p["out_norm"])
+            h = rmsnorm(h, p["out_norm"], mcfg.norm_eps)
             with jax.named_scope(SCOPE_HEAD_LOSS):
                 ce_sum, w_sum = weighted_ce(lm_logits(p, h, mcfg),
                                             targets, weights)
@@ -1084,7 +1084,7 @@ def make_grad_step(cfg: TrainConfig, mesh: Mesh,
 
         def head_fn(p, h, mb):
             pc = cast_compute(p)
-            h = rmsnorm(h, pc["out_norm"])
+            h = rmsnorm(h, pc["out_norm"], mcfg.norm_eps)
             tgt = lax.dynamic_index_in_dim(tgt_m, mb, 0, keepdims=False)
             w = lax.dynamic_index_in_dim(w_m, mb, 0, keepdims=False)
             with jax.named_scope(SCOPE_HEAD_LOSS):

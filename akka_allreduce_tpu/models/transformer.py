@@ -23,7 +23,13 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 
-from akka_allreduce_tpu.parallel.ep import MoEConfig, init_moe_layer, moe_ffn
+from akka_allreduce_tpu.parallel.ep import (
+    ExpertShareConfig,
+    MoEConfig,
+    init_expert_share,
+    init_moe_layer,
+    moe_ffn,
+)
 from akka_allreduce_tpu.parallel.ring_attention import local_causal_attention
 from akka_allreduce_tpu.runtime.tracing import SCOPE_HEAD_LOSS
 from akka_allreduce_tpu.parallel.tp import column_parallel_dense, \
@@ -67,10 +73,47 @@ class TransformerConfig:
     # embedding transposed — no separate lm_head parameter, vocab x d
     # fewer weights, and both ends of the model train one matrix.
     tie_embeddings: bool = False
+    # The published epsilon of every RMSNorm (1e-6: what the program ran
+    # before it took the key).
+    norm_eps: float = 1e-6
+    # What a layer IS, beyond the Llama-family block above (the serving
+    # slot path runs both; training runs "standard" only):
+    # * attention = "mla": multi-head latent attention. Queries go through
+    #   a rank-``q_lora_rank`` bottleneck; keys and values are expanded
+    #   from ONE rank-``kv_lora_rank`` latent a token plus one rotary key
+    #   of ``qk_rope_head_dim`` that all heads share, so the cache holds
+    #   kv_lora_rank + qk_rope_head_dim numbers a token an attention
+    #   (models/generate.py ``init_kv_cache``). Head sizes are
+    #   qk_nope_head_dim + qk_rope_head_dim for q.k and v_head_dim for v.
+    # * block = "shortcut": the shortcut-connected double layer - two
+    #   attentions, two dense FFNs of ``d_ff`` and ONE expert layer
+    #   (``experts``, parallel/ep.py ``dropless_moe``) read from the first
+    #   half's post-attention norm and added at the end of the second
+    #   half. ``n_layers`` counts double layers.
+    attention: str = "gqa"
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    block: str = "standard"
+    experts: Optional[ExpertShareConfig] = None
 
     @property
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
+
+    @property
+    def latent_dim(self) -> int:
+        """What the cache holds a token an attention under ``mla``."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def mla_scales(self) -> tuple[float, float]:
+        """The two lora scales, (d_model / rank) ** 0.5 each: on the
+        query after its up-projection, on the latent after its norm."""
+        return ((self.d_model / self.q_lora_rank) ** 0.5,
+                (self.d_model / self.kv_lora_rank) ** 0.5)
 
     @property
     def kv_heads(self) -> int:
@@ -100,6 +143,45 @@ class TransformerConfig:
         if self.attn_window is not None and self.attn_window < 1:
             raise ValueError(
                 f"attn_window must be >= 1, got {self.attn_window}")
+        if self.attention not in ("gqa", "mla"):
+            raise ValueError(f"unknown attention {self.attention!r}")
+        if self.block not in ("standard", "shortcut"):
+            raise ValueError(f"unknown block {self.block!r}")
+        if self.attention == "mla":
+            sizes = (self.q_lora_rank, self.kv_lora_rank,
+                     self.qk_nope_head_dim, self.qk_rope_head_dim,
+                     self.v_head_dim)
+            if min(sizes) < 1 or self.qk_rope_head_dim % 2:
+                raise ValueError(
+                    f"mla needs its five sizes (q_lora_rank, kv_lora_rank, "
+                    f"qk_nope_head_dim, qk_rope_head_dim even, v_head_dim), "
+                    f"got {sizes}")
+            if not self.rope or self.attn_window is not None \
+                    or self.n_kv_heads is not None:
+                raise ValueError(
+                    "mla runs with rope, without a window and without "
+                    "n_kv_heads (its keys come from the latent)")
+        if (self.block == "shortcut") != (self.attention == "mla") \
+                or (self.block == "shortcut") != (self.experts is not None):
+            raise ValueError(
+                "latent attention and an `experts` share come with the "
+                "shortcut double layer and it with them: mla in a standard "
+                "block is not implemented")
+        if self.block == "shortcut" and (
+                self.ffn != "swiglu" or self.moe is not None
+                or self.tie_embeddings):
+            raise ValueError(
+                "the shortcut double layer has swiglu dense FFNs and an "
+                "untied head, and its expert layer is `experts`, not `moe`")
+
+    @property
+    def new_kind(self) -> Optional[str]:
+        """None for the Llama-family block every path runs; else what a
+        path that cannot run this configuration names in its refusal."""
+        if self.block == "shortcut":
+            return ("the shortcut double layer with latent attention "
+                    "(block='shortcut', attention='mla')")
+        return None
 
 
 def apply_rope(x: jnp.ndarray, positions: jnp.ndarray,
@@ -121,12 +203,14 @@ def apply_rope(x: jnp.ndarray, positions: jnp.ndarray,
         axis=-1).astype(x.dtype)
 
 
-def rmsnorm(x: jnp.ndarray, scale: jnp.ndarray) -> jnp.ndarray:
+def rmsnorm(x: jnp.ndarray, scale: jnp.ndarray,
+            eps: float = 1e-6) -> jnp.ndarray:
     """RMS statistics in f32 regardless of compute dtype (bf16 squares
-    lose ~5 bits where the variance needs them), result back in x's."""
+    lose ~5 bits where the variance needs them), result back in x's.
+    ``eps`` is the configuration's (``TransformerConfig.norm_eps``)."""
     xf = x.astype(jnp.float32)
     var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
-    return (xf * jax.lax.rsqrt(var + 1e-6)).astype(x.dtype) * scale
+    return (xf * jax.lax.rsqrt(var + eps)).astype(x.dtype) * scale
 
 
 def lm_logits(params: dict, x: jnp.ndarray,
@@ -147,6 +231,11 @@ def init_transformer(key: jax.Array, cfg: TransformerConfig,
         raise ValueError(
             f"tp={tp} must divide n_heads={cfg.n_heads}, "
             f"n_kv_heads={cfg.kv_heads}, and d_ff={cfg.d_ff}")
+    if cfg.new_kind is not None:
+        if tp != 1:
+            raise ValueError(f"tp={tp}: {cfg.new_kind} is not sharded "
+                             f"over tp yet")
+        return _init_new_kind(key, cfg)
     k = iter(jax.random.split(key, 4 + 10 * cfg.n_layers))
     dt = cfg.dtype
     scale = cfg.d_model ** -0.5
@@ -191,6 +280,111 @@ def init_transformer(key: jax.Array, cfg: TransformerConfig,
     return params
 
 
+def _normal(key, shape, dtype):
+    """Normal with std fan_in ** -0.5 (the first axis of a matrix, the
+    second of a stack of experts)."""
+    return jax.random.normal(key, shape, dtype) * shape[-2] ** -0.5
+
+
+def init_mla(key: jax.Array, cfg: TransformerConfig) -> dict:
+    """One latent attention's leaves: ``ln`` (the norm ahead of it),
+    ``wq_a`` -> ``q_norm`` -> ``wq_b`` (the query's bottleneck), ``wkv_a``
+    (hidden -> latent + shared rotary key), ``kv_norm``, ``wkv_b`` (latent
+    -> every head's nope key and value) and ``wo``. The two
+    up-projections out of a low rank take the std of the hidden size, not
+    of their own fan-in: under that init the lora scales (d_model / rank)
+    ** 0.5 keep q and k at unit variance, which is what they are for (at
+    rank ** -0.5 the scales multiply q.k by their product, the softmax
+    turns into an argmax and rounding grows threefold a double layer)."""
+    d, h, dt = cfg.d_model, cfg.n_heads, cfg.dtype
+    qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+    k = jax.random.split(key, 5)
+
+    def up(key, rank, width):
+        return jax.random.normal(key, (rank, width), dt) * d ** -0.5
+    return {
+        "ln": jnp.ones((d,), dt),
+        "wq_a": _normal(k[0], (d, cfg.q_lora_rank), dt),
+        "q_norm": jnp.ones((cfg.q_lora_rank,), dt),
+        "wq_b": up(k[1], cfg.q_lora_rank, h * qk),
+        "wkv_a": _normal(k[2], (d, cfg.latent_dim), dt),
+        "kv_norm": jnp.ones((cfg.kv_lora_rank,), dt),
+        "wkv_b": up(k[3], cfg.kv_lora_rank,
+                    h * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
+        "wo": _normal(k[4], (h * cfg.v_head_dim, d), dt),
+    }
+
+
+def _init_new_kind(key: jax.Array, cfg: TransformerConfig) -> dict:
+    """The tree of shortcut double layers: ``layers[i]`` holds ``mla``
+    and ``ffn`` (two of each; an FFN's ``ln`` is its half's post-attention
+    norm) and ``moe`` (parallel/ep.py ``init_expert_share``)."""
+    d, dt = cfg.d_model, cfg.dtype
+    kg = iter(jax.random.split(key, 2 + 8 * cfg.n_layers))
+
+    def ffn():
+        k1, k2, k3 = jax.random.split(next(kg), 3)
+        return {"ln": jnp.ones((d,), dt),
+                "w1": _normal(k1, (d, cfg.d_ff), dt),
+                "w3": _normal(k2, (d, cfg.d_ff), dt),
+                "w2": _normal(k3, (cfg.d_ff, d), dt)}
+
+    params = {"embed": jax.random.normal(next(kg), (cfg.vocab_size, d), dt)
+              * d ** -0.5,
+              "lm_head": _normal(next(kg), (d, cfg.vocab_size), dt),
+              "out_norm": jnp.ones((d,), dt), "layers": []}
+    for _ in range(cfg.n_layers):
+        params["layers"].append({
+            "mla": [init_mla(next(kg), cfg) for _ in range(2)],
+            "ffn": [ffn() for _ in range(2)],
+            "moe": init_expert_share(next(kg), d, cfg.experts, dt)})
+    return params
+
+
+def config_from_hf(hf: dict, max_seq: int, dtype=jnp.bfloat16,
+                   experts_held: Optional[tuple[int, int]] = None
+                   ) -> TransformerConfig:
+    """A :class:`TransformerConfig` from a published ``config.json`` (or a
+    benchmark configuration file that keeps its keys): the one place that
+    knows the key names. ``attention_method`` "MLA" with ``zero_expert_num``
+    is the shortcut double layer with latent attention, the one family
+    read so far (the dense block is built from the ``--d-model/...``
+    flags). ``experts_held`` = (offset, count) of the
+    ``n_routed_experts`` real experts that this chip holds (default: the
+    key ``experts_held`` of ``hf``, the one key that no ``config.json``
+    has; else all of them)."""
+    eps = float(hf.get("rms_norm_eps", 1e-6))
+    if hf.get("attention_method") != "MLA":
+        raise ValueError(
+            f"attention_method {hf.get('attention_method')!r}: only the "
+            f"MLA shortcut double layer is read from a config.json")
+    for key in ("mla_scale_q_lora", "mla_scale_kv_lora"):
+        if not hf.get(key, False):
+            raise ValueError(f"{key} false: latent attention without its "
+                             f"lora scale is not implemented")
+    if hf.get("zero_expert_type", "identity") != "identity":
+        raise ValueError(f"zero_expert_type {hf['zero_expert_type']!r}: "
+                         f"only identity experts are implemented")
+    n_real = hf["n_routed_experts"]
+    offset, count = experts_held or hf.get("experts_held", (0, n_real))
+    return TransformerConfig(
+        vocab_size=hf["vocab_size"], d_model=hf["hidden_size"],
+        n_heads=hf["num_attention_heads"], n_layers=hf["num_layers"],
+        d_ff=hf["ffn_hidden_size"], max_seq=max_seq, dtype=dtype,
+        rope=True, rope_theta=float(hf["rope_theta"]), ffn="swiglu",
+        norm_eps=eps, attention="mla", q_lora_rank=hf["q_lora_rank"],
+        kv_lora_rank=hf["kv_lora_rank"],
+        qk_nope_head_dim=hf["qk_nope_head_dim"],
+        qk_rope_head_dim=hf["qk_rope_head_dim"],
+        v_head_dim=hf["v_head_dim"], block="shortcut",
+        experts=ExpertShareConfig(
+            n_outputs=n_real + hf["zero_expert_num"],
+            n_identity=hf["zero_expert_num"], top_k=hf["moe_topk"],
+            scale=float(hf["routed_scaling_factor"]),
+            d_ff=hf["expert_ffn_hidden_size"],
+            held_offset=int(offset), held_count=int(count)))
+
+
 AttnFn = Callable[[jnp.ndarray, jnp.ndarray, jnp.ndarray], jnp.ndarray]
 
 
@@ -212,12 +406,17 @@ def transformer_block(layer: dict, x: jnp.ndarray, cfg: TransformerConfig,
     (the flash kernel consumes them natively, the pure-JAX paths expand).
     MoE layers keep their own expert FF (ffn="swiglu" shapes dense layers
     only)."""
+    if cfg.new_kind is not None:
+        raise NotImplementedError(
+            f"{cfg.new_kind} runs on the serving slot path only "
+            f"(models/generate.py `prefill` / `decode_step`): the full "
+            f"forward and the train step have no such block yet")
     b, t, _ = x.shape
     if attn_fn is None:  # default oracle, window-aware (see apply)
         def attn_fn(q, k, v):
             return local_causal_attention(q, k, v,
                                           window=cfg.attn_window)
-    h = rmsnorm(x, layer["ln1"])
+    h = rmsnorm(x, layer["ln1"], cfg.norm_eps)
     if tp_axis is not None:
         # identity fwd / psum('tp') bwd: completes dL/dh across the
         # column-parallel shards (parallel/tp.py)
@@ -241,7 +440,7 @@ def transformer_block(layer: dict, x: jnp.ndarray, cfg: TransformerConfig,
     else:
         x = x + attn @ layer["wo"]
 
-    h = rmsnorm(x, layer["ln2"])
+    h = rmsnorm(x, layer["ln2"], cfg.norm_eps)
     aux: dict = {}
     if "router" in layer:
         # Routed expert FF: dispatched over ep (parallel/ep.py). Replicated
@@ -348,7 +547,8 @@ def transformer_hidden_with_aux(params: dict, tokens: jnp.ndarray,
         x, aux = block(layer, x)
         aux_total = _merge_aux(aux_total, aux)
 
-    return rmsnorm(x, params["out_norm"]), _finalize_aux(aux_total)
+    return (rmsnorm(x, params["out_norm"], cfg.norm_eps),
+            _finalize_aux(aux_total))
 
 
 def transformer_apply(params: dict, tokens: jnp.ndarray,

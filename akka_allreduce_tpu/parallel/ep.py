@@ -46,6 +46,11 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from akka_allreduce_tpu.runtime.tracing import (
+    SCOPE_MOE_EXPERTS,
+    SCOPE_MOE_ROUTER,
+)
+
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
@@ -262,3 +267,156 @@ def moe_ffn(x: jnp.ndarray, params: dict, cfg: MoEConfig,
         y = jnp.einsum("ecd,nec->nd", expert_out, combine)
     aux = {"aux_loss": aux_loss, "dispatch_fraction": kept}
     return y.reshape(b, t, d), aux
+
+
+# -- the dropless expert layer of a chip that holds a share ---------------
+#
+# Beside ``moe_ffn`` (capacity, drops, an exchange over ``ep``): routing
+# with no capacity and no dropped token, so a token's output does not
+# depend on who shares its batch; a router wider than the experts with
+# weights (identity experts); and a grouped matmul over the experts HELD,
+# told by an offset and a count which those are. What the experts held
+# elsewhere would add is left out: on one chip the layer runs without its
+# exchange, and nothing here stands in for the absent chips.
+
+@dataclasses.dataclass(frozen=True)
+class ExpertShareConfig:
+    """``n_outputs`` is the router's width: ``n_outputs - n_identity``
+    experts with weights (SwiGLU, width ``d_ff``), then ``n_identity``
+    identity experts. A token takes its ``top_k`` outputs by score + bias
+    and weighs each by ``scale`` x its score alone, not renormalised. This
+    chip holds the real experts ``[held_offset, held_offset + held_count)``;
+    with all of them held the layer is the uncut one."""
+
+    n_outputs: int = 8
+    n_identity: int = 0
+    top_k: int = 2
+    scale: float = 1.0
+    d_ff: int = 512
+    held_offset: int = 0
+    held_count: int = 8
+
+    @property
+    def n_real(self) -> int:
+        return self.n_outputs - self.n_identity
+
+    def __post_init__(self):
+        if not 0 <= self.n_identity < self.n_outputs:
+            raise ValueError(f"n_identity={self.n_identity} of "
+                             f"n_outputs={self.n_outputs}")
+        if not 1 <= self.top_k <= self.n_outputs:
+            raise ValueError(f"top_k={self.top_k} of {self.n_outputs}")
+        if self.held_count < 1 or self.held_offset < 0 \
+                or self.held_offset + self.held_count > self.n_real:
+            raise ValueError(
+                f"held experts [{self.held_offset}, "
+                f"{self.held_offset + self.held_count}) are not among the "
+                f"{self.n_real} with weights")
+
+
+def init_expert_share(key: jax.Array, d_model: int, cfg: ExpertShareConfig,
+                      dtype=jnp.float32) -> dict:
+    """``router`` (d, n_outputs; no bias in the linear), ``bias`` (the
+    selection bias, f32, zeros) and the held experts' three stacks."""
+    kr, k1, k2, k3 = jax.random.split(key, 4)
+    e, f = cfg.held_count, cfg.d_ff
+
+    def normal(k, shape):
+        return jax.random.normal(k, shape, dtype) * shape[-2] ** -0.5
+    return {"router": normal(kr, (d_model, cfg.n_outputs)),
+            "bias": jnp.zeros((cfg.n_outputs,), jnp.float32),
+            "we1": normal(k1, (e, d_model, f)),
+            "we3": normal(k2, (e, d_model, f)),
+            "we2": normal(k3, (e, f, d_model))}
+
+
+def dropless_route(h: jnp.ndarray, params: dict, cfg: ExpertShareConfig
+                   ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """h (N, D) -> (pick (N, k) int32, weight (N, k) f32). Scores are a
+    float32 softmax over all ``n_outputs``; the bias enters the choice
+    only; no renormalisation."""
+    logits = jnp.matmul(h.astype(jnp.float32),
+                        params["router"].astype(jnp.float32),
+                        precision=lax.Precision.HIGHEST)
+    scores = jax.nn.softmax(logits, axis=-1)
+    _, pick = lax.top_k(scores + params["bias"], cfg.top_k)
+    weight = jnp.take_along_axis(scores, pick, axis=-1) * cfg.scale
+    return pick.astype(jnp.int32), weight
+
+
+def _row_buffer(rows: int) -> int:
+    """Rows of the sorted-assignment buffer: every assignment could fall
+    on a held expert, so at least N x k of them, static; rounded up to an
+    ODD multiple of 128. The TPU compiler tiles its grouped matmul by the
+    largest of 512, 256 and 128 rows that divides the buffer, and a share
+    sees a few rows an expert: at 128 lanes x top-12 (1,536 rows, ~2 an
+    expert) a 512-row tile spends four times the MXU time on padding and
+    the decode step takes 36.2 ms where 1,664 rows take 29.7 (chip runs,
+    PR 26; tests/test_compile_for_chip.py pins the tile)."""
+    tiles = -(-rows // 128)
+    return 128 * (tiles + 1 - tiles % 2)
+
+
+def _on_held(pick: jnp.ndarray, cfg: ExpertShareConfig
+             ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """(index among the held experts, whether the pick is held here)."""
+    local = pick - cfg.held_offset
+    return local, (local >= 0) & (local < cfg.held_count)
+
+
+def held_experts_ffn(h: jnp.ndarray, pick: jnp.ndarray,
+                     weight: jnp.ndarray, params: dict,
+                     cfg: ExpertShareConfig) -> jnp.ndarray:
+    """sum over a token's picks that fall on HELD experts of weight x
+    expert(h): (N, D) float32. The assignments are sorted by expert (those
+    on no held expert last), one grouped matmul a weight stack runs over
+    the groups - an expert's weights are read at most once, an expert
+    with no row not at all - and the rows go back to their tokens by the
+    inverse permutation. Shapes are static in N and k alone."""
+    n, k = pick.shape
+    local, held = _on_held(pick, cfg)
+    key = jnp.where(held, local, cfg.held_count).reshape(n * k)
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.zeros((cfg.held_count + 1,), jnp.int32).at[key].add(1)
+    sizes = sizes[:cfg.held_count]
+    m = _row_buffer(n * k)
+    rows = jnp.pad(h[order // k], ((0, m - n * k), (0, 0)))
+    gate = lax.ragged_dot(rows, params["we1"], sizes)
+    up = lax.ragged_dot(rows, params["we3"], sizes)
+    out = lax.ragged_dot(jax.nn.silu(gate) * up, params["we2"],
+                         sizes).astype(jnp.float32)
+    # rows past the last group belong to no expert; the grouped matmul
+    # leaves them unwritten
+    out = jnp.where((jnp.arange(m) < sizes.sum())[:, None], out, 0.0)
+    back = out[jnp.argsort(order)].reshape(n, k, -1)
+    return jnp.einsum("nkd,nk->nd", back, jnp.where(held, weight, 0.0))
+
+
+def dropless_moe(h: jnp.ndarray, params: dict, cfg: ExpertShareConfig,
+                 counted: Optional[jnp.ndarray] = None
+                 ) -> tuple[jnp.ndarray, dict]:
+    """This chip's share of the expert layer for tokens h (N, D): the held
+    experts' part plus the identity part, (sum of the weights of the
+    picked identity experts) x h, which the token's own chip computes in a
+    deployment. Returns (m (N, D) in h's dtype, counts): per token the
+    assignments on ``held`` and on ``identity`` experts (the rest of
+    ``top_k`` are on absent experts), and ``touched``, how many held
+    experts got a row. A token that is not ``counted`` (N,) bool (default:
+    all are; padding and idle lanes are not) counts nowhere."""
+    with jax.named_scope(SCOPE_MOE_ROUTER):
+        pick, weight = dropless_route(h, params, cfg)
+    on_identity = pick >= cfg.n_real
+    with jax.named_scope(SCOPE_MOE_EXPERTS):
+        y = held_experts_ffn(h, pick, weight, params, cfg)
+        y = y + jnp.where(on_identity, weight, 0.0).sum(
+            -1, keepdims=True) * h.astype(jnp.float32)
+    local, on_held = _on_held(pick, cfg)
+    if counted is not None:
+        on_held = on_held & counted[:, None]
+        on_identity = on_identity & counted[:, None]
+    rows = jnp.zeros((cfg.held_count + 1,), jnp.int32).at[
+        jnp.where(on_held, local, cfg.held_count)].add(1)
+    counts = {"held": on_held.sum(-1).astype(jnp.int32),
+              "identity": on_identity.sum(-1).astype(jnp.int32),
+              "touched": (rows[:cfg.held_count] > 0).sum().astype(jnp.int32)}
+    return y.astype(h.dtype), counts

@@ -289,17 +289,25 @@ SPANS = {
     TRAIN_ROUND: ("train loop (cli._cmd_train shape)", "-"),
 }
 
-# ``jax.named_scope`` names inside the jitted train step (no host cost: they
-# are metadata of the HLO): name -> (layer, the quantities that read it).
+# ``jax.named_scope`` names inside the jitted train step and the serving
+# programs (no host cost: they are metadata of the HLO): name -> (layer, the
+# quantities that read it). The last four are the cached-block functions'
+# (models/generate.py), so the decode and prefill programs carry them; the
+# dense block's attention goes under ``attention`` there too.
 SCOPE_SYNC_PACK = "grad_sync/pack"
 SCOPE_SYNC_REDUCE = "grad_sync/reduce"
 SCOPE_SYNC_UNPACK = "grad_sync/unpack"
 SCOPE_HEAD_LOSS = "lm_head_loss"
 SCOPE_OPTIMIZER = "optimizer"
 SCOPE_ATTENTION = "attention"
+SCOPE_MLA_ATTENTION = "mla_attention"
+SCOPE_DENSE_FFN = "dense_ffn"
+SCOPE_MOE_ROUTER = "moe_router"
+SCOPE_MOE_EXPERTS = "moe_experts"
 
 _SYNC = "gradient sync (parallel/dp.py, ops/collectives.py)"
 _STEP = "train step (models/train.py)"
+_EXPERTS = "expert layer (parallel/ep.py)"
 SCOPES = {
     SCOPE_SYNC_PACK: (_SYNC, "sync_device_pct, sync_staging_ms"),
     SCOPE_SYNC_REDUCE: (_SYNC, "sync_device_pct"),
@@ -308,7 +316,16 @@ SCOPES = {
     SCOPE_OPTIMIZER: (_STEP, "-"),
     SCOPE_ATTENTION: ("attention kernels (ops/pallas_kernels/attention.py)",
                       "-"),
+    SCOPE_MLA_ATTENTION: ("latent attention (models/generate.py)",
+                          "lcr_mla_device_pct"),
+    SCOPE_DENSE_FFN: ("engine, decode step (serving/engine.py)", "-"),
+    SCOPE_MOE_ROUTER: (_EXPERTS, "lcr_experts_device_pct"),
+    SCOPE_MOE_EXPERTS: (_EXPERTS, "lcr_experts_device_pct"),
 }
+
+# the scopes of the cached-block functions: in the serving programs only
+SERVING_SCOPES = frozenset({SCOPE_MLA_ATTENTION, SCOPE_DENSE_FFN,
+                            SCOPE_MOE_ROUTER, SCOPE_MOE_EXPERTS})
 
 _annotation = None   # the annotation-only span's class, made at first use
 

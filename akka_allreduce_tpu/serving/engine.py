@@ -107,12 +107,18 @@ import numpy as np
 from jax import lax
 
 from akka_allreduce_tpu.models.generate import (
+    CacheOps,
+    _rope_slots,
+    _slot_cached_attention,
+    _write_slot_rows,
     apply_sample_filters,
+    cached_blocks,
     dequantize_kv,
     init_kv_cache,
     init_kv_pool,
     multi_step_decode,
     prefill,
+    prefill_counted,
     quantize_kv,
     sample_step_key,
     sample_token_rows,
@@ -337,154 +343,46 @@ class PagedEngineConfig(EngineConfig):
                 "run speculation on the gather path")
 
 
-_KV_KEYS = ("k", "v", "k_scale", "v_scale")
+_KV_KEYS = ("k", "v", "k_scale", "v_scale", "latent")
 
 
-def _rope_slots(x: jnp.ndarray, positions: jnp.ndarray,
-                theta: float) -> jnp.ndarray:
-    """apply_rope (models/transformer.py) with a PER-ROW position:
-    x (slots, 1, heads, d), positions (slots,). Same formula, f32
-    phases, half-split pairing, cast points — the angle for row b here
-    is bitwise the angle decode_step computes for its whole batch at
-    scalar pos = positions[b], so per-slot rope output matches the
-    standalone decode exactly."""
-    d = x.shape[-1]
-    half = d // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
-    angles = positions.astype(jnp.float32)[:, None] * freqs[None, :]
-    cos = jnp.cos(angles)[:, None, None, :]  # (slots, 1, 1, D/2)
-    sin = jnp.sin(angles)[:, None, None, :]
-    xf = x.astype(jnp.float32)
-    x1, x2 = xf[..., :half], xf[..., half:]
-    return jnp.concatenate(
-        [x1 * cos - x2 * sin, x1 * sin + x2 * cos],
-        axis=-1).astype(x.dtype)
+# how many numbers a token's route counts are (held, identity, absent),
+# and the rows the shortcut kind's step adds to its packed readback
+_ROUTE_KINDS = ("held", "identity", "absent")
 
 
-def _slot_cached_attention(q: jnp.ndarray, k_all: jnp.ndarray,
-                           v_all: jnp.ndarray, pos: jnp.ndarray,
-                           window: "int | None" = None) -> jnp.ndarray:
-    """models/generate.py ``_cached_attention`` with the scalar decode
-    position generalized to (slots,): row b masks by ITS ``pos[b]``.
-    Same einsum structure, f32 score/softmax, and cast points; the
-    contraction runs over the full static ``max_seq`` buffer for every
-    row (the mask is per-row data, the shape is not), which is exactly
-    the no-window standalone program — so per-row outputs are bitwise
-    equal to a batch-1 ``decode_step`` at that position. Sliding-window
-    decode keeps the mask-only form (positions outside the window mask
-    to NEG_INF; exp underflows to exactly 0.0): per-step cost stays
-    O(max_seq) rather than generate()'s O(window) slice, a trade for
-    per-row window offsets that only shows at long max_seq."""
-    b, one, h, d = q.shape
-    h_kv = k_all.shape[2]
-    g = h // h_kv
-    qg = q.reshape(b, one, h_kv, g, d)
-    scale = d ** -0.5
-    k_idx = jnp.arange(k_all.shape[1])
-    scores = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k_all,
-                        preferred_element_type=jnp.float32) * scale
-    valid = k_idx[None, :] <= pos[:, None]  # (slots, max_seq)
-    if window is not None:
-        valid &= k_idx[None, :] > pos[:, None] - window
-    scores = jnp.where(valid[:, None, None, None, :], scores, NEG_INF)
-    p = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(v_all.dtype), v_all,
-                     preferred_element_type=jnp.float32)
-    return out.reshape(b, one, h, d).astype(q.dtype)
-
-
-def _write_slot_rows(cache: jnp.ndarray, layer: int, vals: jnp.ndarray,
-                     pos: jnp.ndarray,
-                     mask: "jnp.ndarray | None" = None) -> jnp.ndarray:
-    """Write ``vals[s]`` at ``cache[layer, s, pos[s]]`` for every slot.
-    An unrolled loop of ``dynamic_update_slice`` (slots is small and
-    static) rather than one ``.at[layer, rows, pos].set`` scatter: with
-    the engine state donated, DUS updates the buffer in place, and the
-    XLA:CPU scatter lowering measured ~5x slower per write. Placement
-    only — the written values are identical either way.
-
-    ``mask`` (slots,) bool: a False lane keeps its old cache value at
-    ``pos[s]`` (the multi-step block's frozen lanes — the write becomes
-    a read-select-write of one tiny row, still a DUS the donation keeps
-    in place)."""
-    for s in range(vals.shape[0]):
-        val = vals[s][None, None, None]
-        idx = (layer, s, pos[s]) + (0,) * (vals.ndim - 1)
-        if mask is not None:
-            old = lax.dynamic_slice(cache, idx, val.shape)
-            val = jnp.where(mask[s], val, old)
-        cache = lax.dynamic_update_slice(cache, val, idx)
-    return cache
+def _slot_decode(params: dict, kv: dict, token: jnp.ndarray,
+                 pos: jnp.ndarray, cfg: TransformerConfig,
+                 write_mask: "jnp.ndarray | None" = None):
+    """models/generate.py ``decode_step`` with the batch-wide position
+    scalar generalized to a per-slot vector — the engine's one compiled
+    decode program. The block math is generate.py's cached-block function
+    of the configuration's kind, op for op what ``decode_step`` and
+    ``prefill`` run; only the cache-write placement (per-slot positions
+    instead of one shared slice) and the mask source differ, neither of
+    which touches a row's arithmetic. kv: k/v (layers, slots, max_seq,
+    kv_heads, head_dim) [+ scales], or the latent cache (attentions,
+    slots, max_seq, latent_dim); token/pos (slots,). ``write_mask``
+    (slots,) freezes a lane's cache writes (multi-step blocks; never
+    changes an unmasked row's math). Returns (new kv, logits (slots,
+    vocab), the expert layers' counts or None); a lane parked at position
+    0 is idle and counts nowhere."""
+    x = params["embed"][token][:, None, :]
+    if not cfg.rope:
+        x = x + params["pos"][pos][:, None, :]
+    x, kv, counts = cached_blocks(
+        params, x, kv, cfg,
+        CacheOps(pos=pos, write_mask=write_mask, counted=pos > 0))
+    logits = lm_logits(
+        params, rmsnorm(x, params["out_norm"], cfg.norm_eps), cfg)
+    return kv, logits[:, 0, :], counts
 
 
 def _slot_decode_step(params: dict, kv: dict, token: jnp.ndarray,
                       pos: jnp.ndarray, cfg: TransformerConfig,
                       write_mask: "jnp.ndarray | None" = None):
-    """models/generate.py ``decode_step`` with the batch-wide position
-    scalar generalized to a per-slot vector — the engine's one compiled
-    decode program. Mirrors the block math op-for-op (same projections,
-    norms, residual order, cast points); only the cache-write placement
-    (per-slot positions instead of one shared slice) and the mask
-    source differ, neither of which touches a row's arithmetic. kv: k/v
-    (layers, slots, max_seq, kv_heads, head_dim) [+ scales]; token/pos
-    (slots,). ``write_mask`` (slots,) freezes a lane's cache writes
-    (multi-step blocks; never changes an unmasked row's math). Returns
-    (new kv, logits (slots, vocab))."""
-    s = token.shape[0]
-    quantized = "k_scale" in kv
-    x = params["embed"][token][:, None, :]
-    if not cfg.rope:
-        x = x + params["pos"][pos][:, None, :]
-    k_cache, v_cache = kv["k"], kv["v"]
-    if quantized:
-        k_scales, v_scales = kv["k_scale"], kv["v_scale"]
-    for i, layer in enumerate(params["layers"]):
-        h = rmsnorm(x, layer["ln1"])
-        q = (h @ layer["wq"]).reshape(s, 1, cfg.n_heads, cfg.head_dim)
-        k = (h @ layer["wk"]).reshape(s, 1, cfg.kv_heads, cfg.head_dim)
-        v = (h @ layer["wv"]).reshape(s, 1, cfg.kv_heads, cfg.head_dim)
-        if cfg.rope:
-            q = _rope_slots(q, pos, cfg.rope_theta)
-            k = _rope_slots(k, pos, cfg.rope_theta)
-        if quantized:
-            kq, ks = quantize_kv(k)
-            vq, vs = quantize_kv(v)
-            k_cache = _write_slot_rows(k_cache, i, kq[:, 0], pos,
-                                       write_mask)
-            v_cache = _write_slot_rows(v_cache, i, vq[:, 0], pos,
-                                       write_mask)
-            k_scales = _write_slot_rows(k_scales, i, ks[:, 0], pos,
-                                        write_mask)
-            v_scales = _write_slot_rows(v_scales, i, vs[:, 0], pos,
-                                        write_mask)
-            k_all = dequantize_kv(k_cache[i], k_scales[i], cfg.dtype)
-            v_all = dequantize_kv(v_cache[i], v_scales[i], cfg.dtype)
-        else:
-            k_cache = _write_slot_rows(
-                k_cache, i, k[:, 0].astype(k_cache.dtype), pos,
-                write_mask)
-            v_cache = _write_slot_rows(
-                v_cache, i, v[:, 0].astype(v_cache.dtype), pos,
-                write_mask)
-            k_all, v_all = k_cache[i], v_cache[i]
-        attn = _slot_cached_attention(q, k_all, v_all, pos,
-                                      window=cfg.attn_window)
-        x = x + attn.reshape(s, 1, -1) @ layer["wo"]
-
-        h = rmsnorm(x, layer["ln2"])
-        if "router" in layer:
-            y, _aux = moe_ffn(h, layer, cfg.moe, axis_name=None)
-            x = x + y
-        elif "w3" in layer:
-            x = x + (jax.nn.silu(h @ layer["w1"])
-                     * (h @ layer["w3"])) @ layer["w2"]
-        else:
-            x = x + jax.nn.gelu(h @ layer["w1"]) @ layer["w2"]
-    logits = lm_logits(params, rmsnorm(x, params["out_norm"]), cfg)
-    new_kv = {"k": k_cache, "v": v_cache}
-    if quantized:
-        new_kv["k_scale"], new_kv["v_scale"] = k_scales, v_scales
-    return new_kv, logits[:, 0, :]
+    """:func:`_slot_decode` without the counts: (new kv, logits)."""
+    return _slot_decode(params, kv, token, pos, cfg, write_mask)[:2]
 
 
 @partial(jax.jit, static_argnames=("cfg", "sample"), donate_argnums=(1,))
@@ -520,10 +418,22 @@ def _engine_step(params: dict, state: dict, pos: jnp.ndarray,
     else:
         tok = sample_token_rows(key_data, logits_in, step_idx, sample)
     finite = jnp.isfinite(logits_in).all(axis=-1)
-    kv = {n: state[n] for n in state if n != "logits"}
-    new_kv, logits = _slot_decode_step(params, kv, tok, pos, cfg)
+    kv = {n: state[n] for n in state if n not in ("logits", "route")}
+    new_kv, logits, counts = _slot_decode(params, kv, tok, pos, cfg)
     packed = jnp.stack([tok, finite.astype(jnp.int32)])
-    return {**new_kv, "logits": logits}, packed
+    if counts is None:
+        return {**new_kv, "logits": logits}, packed
+    # the shortcut kind: the same one readback, flat. After the two rows
+    # above, a row a lane of its assignments on held and on identity
+    # experts over the layers (zero for an idle lane), then the held
+    # experts touched by busy lanes, then what the prefills since the
+    # last step left in ``route`` (held, identity, absent, touched), which
+    # starts again from zero
+    packed = jnp.concatenate([
+        packed.reshape(-1), counts["held"], counts["identity"],
+        counts["touched"][None], state["route"]])
+    return ({**new_kv, "logits": logits,
+             "route": jnp.zeros_like(state["route"])}, packed)
 
 
 @partial(jax.jit, static_argnames=("cfg", "steps", "sample"),
@@ -601,10 +511,17 @@ def _engine_prefill(params: dict, state: dict, prompt: jnp.ndarray,
     the previous occupant is cleared, not merely masked."""
     quant = "k_scale" in state
     one = init_kv_cache(cfg, 1, kv_dtype="int8" if quant else None)
-    cache, logits = prefill(
+    cache, logits, counts = prefill_counted(
         params, one, prompt, cfg,
         logit_pos=true_len - 1 if gather else None)
     out = dict(state)
+    if counts is not None:
+        # true positions only (prefill_counted leaves the padding out)
+        held, identity = counts["held"].sum(), counts["identity"].sum()
+        n = true_len if gather else prompt.shape[1]
+        absent = n * cfg.experts.top_k * cfg.n_layers - held - identity
+        out["route"] = state["route"] + jnp.stack(
+            [held, identity, absent, counts["touched"]]).astype(jnp.int32)
     for n in _KV_KEYS:
         if n in cache:
             out[n] = lax.dynamic_update_slice(
@@ -677,7 +594,7 @@ def _paged_decode_step(params: dict, kv: dict, token: jnp.ndarray,
     if quantized:
         k_scales, v_scales = kv["k_scale"], kv["v_scale"]
     for i, layer in enumerate(params["layers"]):
-        h = rmsnorm(x, layer["ln1"])
+        h = rmsnorm(x, layer["ln1"], cfg.norm_eps)
         q = (h @ layer["wq"]).reshape(s, 1, cfg.n_heads, cfg.head_dim)
         k = (h @ layer["wk"]).reshape(s, 1, cfg.kv_heads, cfg.head_dim)
         v = (h @ layer["wv"]).reshape(s, 1, cfg.kv_heads, cfg.head_dim)
@@ -732,7 +649,7 @@ def _paged_decode_step(params: dict, kv: dict, token: jnp.ndarray,
                                               window=cfg.attn_window)
         x = x + attn.reshape(s, 1, -1) @ layer["wo"]
 
-        h = rmsnorm(x, layer["ln2"])
+        h = rmsnorm(x, layer["ln2"], cfg.norm_eps)
         if "router" in layer:
             y, _aux = moe_ffn(h, layer, cfg.moe, axis_name=None)
             x = x + y
@@ -741,7 +658,8 @@ def _paged_decode_step(params: dict, kv: dict, token: jnp.ndarray,
                      * (h @ layer["w3"])) @ layer["w2"]
         else:
             x = x + jax.nn.gelu(h @ layer["w1"]) @ layer["w2"]
-    logits = lm_logits(params, rmsnorm(x, params["out_norm"]), cfg)
+    logits = lm_logits(
+        params, rmsnorm(x, params["out_norm"], cfg.norm_eps), cfg)
     new_kv = {"k": k_pool, "v": v_pool}
     if quantized:
         new_kv["k_scale"], new_kv["v_scale"] = k_scales, v_scales
@@ -964,7 +882,7 @@ def _slot_extend(params: dict, kv: dict, tokens: jnp.ndarray,
     if quantized:
         k_scales, v_scales = kv["k_scale"], kv["v_scale"]
     for i, layer in enumerate(params["layers"]):
-        h = rmsnorm(x, layer["ln1"])
+        h = rmsnorm(x, layer["ln1"], cfg.norm_eps)
         q = (h @ layer["wq"]).reshape(s, t, cfg.n_heads, cfg.head_dim)
         k = (h @ layer["wk"]).reshape(s, t, cfg.kv_heads, cfg.head_dim)
         v = (h @ layer["wv"]).reshape(s, t, cfg.kv_heads, cfg.head_dim)
@@ -998,7 +916,7 @@ def _slot_extend(params: dict, kv: dict, tokens: jnp.ndarray,
                                      window=cfg.attn_window)
         x = x + attn.reshape(s, t, -1) @ layer["wo"]
 
-        h = rmsnorm(x, layer["ln2"])
+        h = rmsnorm(x, layer["ln2"], cfg.norm_eps)
         if "router" in layer:
             y, _aux = moe_ffn(h, layer, cfg.moe, axis_name=None)
             x = x + y
@@ -1007,7 +925,8 @@ def _slot_extend(params: dict, kv: dict, tokens: jnp.ndarray,
                      * (h @ layer["w3"])) @ layer["w2"]
         else:
             x = x + jax.nn.gelu(h @ layer["w1"]) @ layer["w2"]
-    logits = lm_logits(params, rmsnorm(x, params["out_norm"]), cfg)
+    logits = lm_logits(
+        params, rmsnorm(x, params["out_norm"], cfg.norm_eps), cfg)
     new_kv = {"k": k_cache, "v": v_cache}
     if quantized:
         new_kv["k_scale"], new_kv["v_scale"] = k_scales, v_scales
@@ -1033,7 +952,7 @@ def _paged_extend(params: dict, kv: dict, tokens: jnp.ndarray,
     if quantized:
         k_scales, v_scales = kv["k_scale"], kv["v_scale"]
     for i, layer in enumerate(params["layers"]):
-        h = rmsnorm(x, layer["ln1"])
+        h = rmsnorm(x, layer["ln1"], cfg.norm_eps)
         q = (h @ layer["wq"]).reshape(s, t, cfg.n_heads, cfg.head_dim)
         k = (h @ layer["wk"]).reshape(s, t, cfg.kv_heads, cfg.head_dim)
         v = (h @ layer["wv"]).reshape(s, t, cfg.kv_heads, cfg.head_dim)
@@ -1076,7 +995,7 @@ def _paged_extend(params: dict, kv: dict, tokens: jnp.ndarray,
                                      window=cfg.attn_window)
         x = x + attn.reshape(s, t, -1) @ layer["wo"]
 
-        h = rmsnorm(x, layer["ln2"])
+        h = rmsnorm(x, layer["ln2"], cfg.norm_eps)
         if "router" in layer:
             y, _aux = moe_ffn(h, layer, cfg.moe, axis_name=None)
             x = x + y
@@ -1085,7 +1004,8 @@ def _paged_extend(params: dict, kv: dict, tokens: jnp.ndarray,
                      * (h @ layer["w3"])) @ layer["w2"]
         else:
             x = x + jax.nn.gelu(h @ layer["w1"]) @ layer["w2"]
-    logits = lm_logits(params, rmsnorm(x, params["out_norm"]), cfg)
+    logits = lm_logits(
+        params, rmsnorm(x, params["out_norm"], cfg.norm_eps), cfg)
     new_kv = {"k": k_pool, "v": v_pool}
     if quantized:
         new_kv["k_scale"], new_kv["v_scale"] = k_scales, v_scales
@@ -1512,6 +1432,7 @@ class ServingEngine:
             raise ValueError(
                 f"largest prefill bucket {ecfg.prefill_buckets[-1]} "
                 f"exceeds max_seq {cfg.max_seq}")
+        self._refuse_new_kind()
         self._state = self._fresh_state()
         self._pos = np.zeros((ecfg.num_slots,), np.int32)
         self._slots: list[Optional[_SlotState]] = [None] * ecfg.num_slots
@@ -1548,6 +1469,9 @@ class ServingEngine:
         self._admitted: list = []
         self.decode_dispatches = 0
         self.prefill_dispatches = 0
+        # where the last step's tokens were routed (the shortcut kind
+        # only): {"decode": {held, identity, absent, touched}[, "prefill"]}
+        self.last_route: Optional[dict] = None
         # high-water mark of concurrently occupied slots/lanes — the
         # paged A/B's sustained-concurrency evidence (bench.py
         # measure_paged_serving)
@@ -1575,6 +1499,30 @@ class ServingEngine:
         # at the first dispatch so it lands on the metrics registry the
         # serve loop attaches AFTER construction
         self._dtimer = None
+
+    # what of this engine kind cannot run a configuration's new block
+    # kind, by what is missing; the slot engine at ``decode_steps`` 1 runs
+    # every kind
+    _new_kind_missing: Optional[str] = None
+
+    def _refuse_new_kind(self) -> None:
+        """None of the paths that copy the dense block's mathematics runs
+        a kind it does not know wrong: each refuses it, naming what is
+        missing."""
+        kind = self.cfg.new_kind
+        if kind is None:
+            return
+        missing = self._new_kind_missing
+        if missing is None and self.ecfg.decode_steps > 1:
+            missing = ("decode_steps > 1: the fused block program does not "
+                       "carry the expert layers' counts through its scan")
+        if missing is None and self.ecfg.kv_dtype is not None:
+            missing = (f"kv_dtype={self.ecfg.kv_dtype!r}: the latent cache "
+                       f"has no quantized format")
+        if missing is not None:
+            raise NotImplementedError(
+                f"{type(self).__name__} cannot run {kind}; missing: "
+                f"{missing}")
 
     def _needs_keys(self) -> bool:
         """Does any dispatch path of this engine consume PRNG keys?"""
@@ -1627,6 +1575,10 @@ class ServingEngine:
         base = init_kv_cache(self.cfg, self.ecfg.num_slots,
                              kv_dtype=self.ecfg.kv_dtype)
         del base["pos"]  # per-slot positions live host-side
+        if self.cfg.experts is not None:
+            # what the prefills since the last step routed where
+            # (held, identity, absent, touched); the step reads it out
+            base["route"] = jnp.zeros((len(_ROUTE_KINDS) + 1,), jnp.int32)
         return {**base, "logits": jnp.zeros(
             (self.ecfg.num_slots, self.cfg.vocab_size), self.cfg.dtype)}
 
@@ -2000,11 +1952,39 @@ class ServingEngine:
             with span(SERVE_STEP_COMMIT, self.tracer) as commit:
                 finished, n_tokens = self._commit_single(out)
                 commit.set(tokens=n_tokens, finished=len(finished))
+                if self.last_route is not None:
+                    commit.set(**{f"route_{k}": v for k, v in
+                                  self.last_route["decode"].items()})
             return finished
+
+    def _take_route(self, packed: np.ndarray) -> tuple:
+        """The shortcut kind's flat readback (``_engine_step``): its two
+        usual rows, and where this step's busy lanes and the prefills
+        since the last step sent their tokens, as ``{phase: {kind: n}}``
+        with the kinds of :data:`_ROUTE_KINDS` and ``touched``."""
+        n = self.num_slots
+        # an idle lane (parked at position 0) counted nowhere on the device
+        held = int(packed[2 * n:3 * n].sum())
+        identity = int(packed[3 * n:4 * n].sum())
+        picks = self.occupied * self.cfg.experts.top_k * self.cfg.n_layers
+        route = {"decode": {"held": held, "identity": identity,
+                            "absent": picks - held - identity,
+                            "touched": int(packed[4 * n])}}
+        pre = packed[4 * n + 1:]
+        if pre.any():
+            route["prefill"] = dict(zip(_ROUTE_KINDS + ("touched",),
+                                        map(int, pre)))
+        return packed[:2 * n].reshape(2, n), route
 
     def _commit_single(self, out: tuple) -> tuple:
         self._state, packed = out
         self.decode_dispatches += 1
+        self.last_route = None
+        if self.cfg.experts is not None:
+            packed, self.last_route = self._take_route(packed)
+            if self.metrics is not None:
+                for phase, counts in self.last_route.items():
+                    self.metrics.on_route(phase, **counts)
         toks, finite = packed[0], packed[1]
         finished = []
         n_tokens = 0
@@ -2242,6 +2222,10 @@ class _SpeculativeMixin:
     carry. Each concrete class supplies state layout, prefill and the
     dispatch itself."""
 
+    _new_kind_missing = (
+        "a block extend (`_slot_extend` / `_paged_extend` copy the dense "
+        "block) that verifies a draft through the latent cache")
+
     def _init_spec(self, draft_params: dict,
                    draft_cfg: TransformerConfig, cfg: TransformerConfig,
                    ecfg: EngineConfig) -> None:
@@ -2253,6 +2237,10 @@ class _SpeculativeMixin:
             raise ValueError(
                 f"draft and target must share a vocabulary: "
                 f"{draft_cfg.vocab_size} != {cfg.vocab_size}")
+        if draft_cfg.new_kind is not None:
+            raise NotImplementedError(
+                f"a draft model of {draft_cfg.new_kind}; missing: "
+                f"{self._new_kind_missing}")
         self.draft_params = draft_params
         self.draft_cfg = draft_cfg
         # the draft ledger (ISSUE 10 satellite): proposed == accepted +
@@ -2526,6 +2514,11 @@ class PagedServingEngine(ServingEngine):
       drain/restore are inherited; every slot-free path releases the
       lane's pages, so recovery leaves the pool empty and consistent.
     """
+
+    _new_kind_missing = (
+        "a latent page in the pool and cached-block functions that read "
+        "it through the page table (`_paged_decode_step` copies the dense "
+        "block)")
 
     def __init__(self, params: dict, cfg: TransformerConfig,
                  ecfg: PagedEngineConfig = PagedEngineConfig(),

@@ -129,6 +129,10 @@ class ServingMetrics:
             help="drained ResumableRequests persisted across a process "
                  "boundary (runtime/checkpoint.py save_drained)",
             labels=self.labels)
+        # where expert layers routed their tokens: registered at the
+        # first on_route, so an engine whose model has no dropless expert
+        # layer exports no such series
+        self._route: Optional[dict] = None
         self._register(self.registry)
 
     def _register(self, r) -> None:
@@ -278,6 +282,33 @@ class ServingMetrics:
         self.prefill_tokens += prompt_len
         self._record("serve_admit", rid=rid, slot=slot,
                      prompt_len=prompt_len)
+
+    def on_route(self, phase: str, held: int, identity: int, absent: int,
+                 touched: int) -> None:
+        """Where an expert layer's router sent the tokens of one decode
+        step (busy lanes) or of the prefills before it (true positions),
+        summed over the layers: assignments on experts this chip HOLDS,
+        on IDENTITY experts (no weights; the token's own chip adds them)
+        and on experts held on ABSENT chips (their part is left out on a
+        chip that holds a share), and how many held experts got a row."""
+        if self._route is None:
+            self._route = {
+                kind: self.registry.counter(
+                    "serve_route_assignments_total",
+                    help="router assignments of decode steps' busy lanes "
+                         "and prefills' true positions, by where the "
+                         "expert is",
+                    labels={**self.labels, "kind": kind})
+                for kind in ("held", "identity", "absent")}
+            self._route["touched"] = self.registry.counter(
+                "serve_route_experts_touched_total",
+                help="held experts that got at least one row, summed "
+                     "over layers and dispatches", labels=self.labels)
+        for kind, n in (("held", held), ("identity", identity),
+                        ("absent", absent), ("touched", touched)):
+            self._route[kind].inc(n)
+        self._record("serve_route", phase=phase, held=held,
+                     identity=identity, absent=absent, touched=touched)
 
     def on_token(self, rid: int, submitted_at: float) -> None:
         """Called per emitted token; the first emission banks TTFT."""

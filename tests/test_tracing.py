@@ -372,7 +372,7 @@ def test_train_step_scopes_are_in_the_hlo(dp, masked):
     # layout "leaves"): f32 leaves on the f32 wire with a mean factor of
     # exactly 1.0 leave nothing to pack or unpack
     empty = () if masked else (T.SCOPE_SYNC_PACK, T.SCOPE_SYNC_UNPACK)
-    for sc in T.SCOPES:
+    for sc in set(T.SCOPES) - T.SERVING_SCOPES:
         assert any(under(p, sc) for _l, p in named) == (sc not in empty), sc
     matrix = rf"f32\[({_TOY_BUCKETS},1024|{_TOY_BUCKETS * 1024})\]"
     # every collective that carries gradients is the sync's wire; the
@@ -474,9 +474,13 @@ def test_names_the_benchmark_reads_are_pinned():
             f"by name; a rename goes with a `benchmark` PR that changes "
             f"those files")
         assert readers.get(name), f"no metric file matches {name}"
-    assert set(T.SCOPES) == {"grad_sync/pack", "grad_sync/reduce",
-                             "grad_sync/unpack", "lm_head_loss",
-                             "optimizer", "attention"}, (
+    assert set(T.SCOPES) - T.SERVING_SCOPES == {
+        "grad_sync/pack", "grad_sync/reduce", "grad_sync/unpack",
+        "lm_head_loss", "optimizer", "attention"}, (
         "benchmark/program_trace.py (sync_device_pct, sync_staging_ms, "
         "head_loss_device_pct) reads these scope names; a rename goes "
         "with a `benchmark` PR")
+    assert T.SERVING_SCOPES == {
+        "mla_attention", "dense_ffn", "moe_router", "moe_experts"}, (
+        "benchmark/readers/latent_moe.py (lcr_experts_device_pct, "
+        "lcr_mla_device_pct) reads these scope names")
