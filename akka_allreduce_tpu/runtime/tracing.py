@@ -273,6 +273,12 @@ SERVE_ADMIT_COMMIT = "serve_admit.commit"
 SCHED_POP_READY = "sched_pop_ready"
 TRAIN_ROUND = "train_round"
 
+# ``serve_step`` carries ``occupied``, ``admitted`` and, from the slot
+# engine's S=1 step, ``ahead`` (1 where the call launched a dispatch before
+# its readback: every lane was busy) and ``discarded`` (lane steps its
+# commit dropped: the lane's request had ended in the dispatch before). In
+# such a call ``upload`` and ``dispatch`` are of the dispatch LAUNCHED,
+# ``readback`` and ``commit`` of the OLDER one, launched a call earlier.
 _DECODE = "engine, decode step (serving/engine.py)"
 _PREFILL = "engine, prefill (serving/engine.py)"
 SPANS = {

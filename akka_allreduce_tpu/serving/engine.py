@@ -53,6 +53,15 @@ and greedy output stays bitwise identical across S and vs
 waste (``wasted_tokens``) and block-granular admission — the
 ``multi_step_decode`` bench row is the A/B.
 
+At ``decode_steps=1`` on the slot engine the device does not wait for
+that readback while every lane is busy: the tokens are picked on the
+device and a busy lane's next position is known, so ``step()`` launches
+dispatch k+1 before it reads dispatch k (at most one ahead; see
+:meth:`ServingEngine.step`). The programs and every request's tokens
+are the synchronous engine's (tests/test_engine_lookahead.py); with a
+lane free the step is synchronous, because a launch ahead would stand
+between an arrival and its prefill.
+
 The no-recompile contract is ASSERTED, not just designed for: slot
 churn/refill runs under the zero-compile guard
 (tests/test_serving_engine.py::TestNoRecompileContract, `serve
@@ -1392,6 +1401,21 @@ class _SlotState:
     emitted: list
 
 
+@dataclasses.dataclass
+class _Flight:
+    """One S=1 decode dispatch between its launch and its commit: the
+    operands it is launched with, which occupant each lane it runs held
+    at the launch, and what the jitted step returned (device futures).
+    The commit gives a lane its token only where the lane still holds
+    that occupant."""
+
+    pos: jnp.ndarray                # (slots,), uploaded
+    ops: dict                       # the sampled step's operands
+    tables: tuple
+    lanes: dict                     # lane -> the _SlotState it ran
+    out: Optional[tuple] = None     # (state, packed) once launched
+
+
 @dataclasses.dataclass(frozen=True)
 class ResumableRequest:
     """A drained in-flight request: everything a fresh engine needs to
@@ -1469,6 +1493,12 @@ class ServingEngine:
         self._admitted: list = []
         self.decode_dispatches = 0
         self.prefill_dispatches = 0
+        # the S=1 dispatch launched ahead of the last readback, if any
+        # (:meth:`step`); how many calls launched one; and the lane steps
+        # a dispatch computed for an occupant that had ended meanwhile
+        self._flight: Optional[_Flight] = None
+        self.lookahead_dispatches = 0
+        self.discarded_lane_steps = 0
         # where the last step's tokens were routed (the shortcut kind
         # only): {"decode": {held, identity, absent, touched}[, "prefill"]}
         self.last_route: Optional[dict] = None
@@ -1547,6 +1577,7 @@ class ServingEngine:
         must not dispatch again. The happy-path counterpart of the
         tripped-watchdog replacement in :meth:`_guarded_dispatch` —
         `lint --host` pins that this teardown exists."""
+        self._drop_flight()
         if self._executor is not None:
             self._executor.shutdown(wait=False, cancel_futures=True)
             self._executor = None
@@ -1774,7 +1805,9 @@ class ServingEngine:
         the fleet summary surfaces). Not a failure: no retry, no
         failure event, no terminal record. Returns the discarded token
         count, or None when ``rid`` holds no lane here (it already
-        finished or was never admitted)."""
+        finished or was never admitted). A token that a dispatch in
+        flight holds for the lane is dropped at that dispatch's commit
+        (``discarded_lane_steps``), like an evicted lane's."""
         for i, slot in enumerate(self._slots):
             if slot is not None and slot.req.rid == rid:
                 n = len(slot.emitted)
@@ -1796,6 +1829,7 @@ class ServingEngine:
         failures = [self._fail_lane(i, reason)
                     for i, s in enumerate(self._slots) if s is not None]
         self._state = self._fresh_state()
+        self._drop_flight()    # it chained on the state just abandoned
         self._dev_vectors = None
         self._vectors_dirty = True
         if self._dtimer is not None:
@@ -1892,8 +1926,14 @@ class ServingEngine:
         """Snapshot every in-flight request as a
         :class:`ResumableRequest` (prompt + generated-so-far) and free
         its slot. Pure host bookkeeping — the device state is abandoned
-        with the process. The snapshots are also kept on
-        ``self.drained`` for the caller that owns the handoff."""
+        with the process, and with it a dispatch launched ahead that the
+        caller did not :meth:`harvest`: the token it holds for a lane
+        was never emitted, and ``restore`` decodes it again from the
+        replayed prefix. (Committing it here could FINISH a request, and
+        a drain has no completions to return.) The snapshots are also
+        kept on ``self.drained`` for the caller that owns the
+        handoff."""
+        self._drop_flight()
         out = []
         for i, slot in enumerate(self._slots):
             if slot is None:
@@ -1930,43 +1970,148 @@ class ServingEngine:
 
         Every kind of step is one ``serve_step`` span over four phases,
         ``upload``, ``dispatch``, ``readback`` and ``commit``
-        (runtime/tracing.py ``SPANS``)."""
+        (runtime/tracing.py ``SPANS``).
+
+        At S = 1 the step picks each lane's token on the device from the
+        logits the donated state carries, and the one operand the host
+        supplies, a busy lane's position, is its last plus one whatever
+        it drew. So WHILE NO LANE IS FREE a call launches the following
+        dispatch before it reads the older one back (``lookahead_
+        dispatches``), the device never waits for the host, and every
+        call still commits exactly one dispatch: its tokens, its
+        ``on_route`` record, its completions. With a lane free a launch
+        ahead would put a whole decode program between an arriving
+        request and its prefill, so the call reads back what it
+        launched, as ever; the engine sees which case it is in and no
+        option chooses. What a dispatch launched ahead cannot know is
+        that a lane ended on EOS, a stop token, the NaN guard, a cancel
+        or an eviction in the dispatch before it: it has then computed
+        one token for that lane, which its commit drops
+        (``discarded_lane_steps``). A lane whose BUDGET ends in the
+        dispatch in flight is known, and parks (:meth:`_plan`)."""
         if self.ecfg.decode_steps > 1:
             return self._step_block()
+        return self._step_single(launch=True)
+
+    def harvest(self) -> list[tuple[int, Request, list, str]]:
+        """Completions of what no :meth:`step` has returned yet: reads
+        back and commits the dispatch launched ahead, if there is one,
+        and launches nothing. Whoever is about to :meth:`drain` routes
+        these first, as the router does with a transport-backed
+        replica's: a token already computed then reaches its stream and
+        a NaN guard its request, where ``drain`` alone abandons the
+        dispatch uncommitted (still exact: ``restore`` decodes the token
+        again)."""
+        if self._flight is None:
+            return []
+        return self._step_single(launch=False)
+
+    def _step_single(self, launch: bool) -> list:
+        """One S=1 call: launch what there is to launch (the dispatch
+        this call reads back, unless one is in flight already; and with
+        no lane free the one after it), then read back and commit the
+        OLDER dispatch. ``launch=False`` is :meth:`harvest`."""
         with span(SERVE_STEP, self.tracer, occupied=self.occupied,
-                  admitted=self._take_admitted()):
+                  admitted=self._take_admitted()) as step_span:
+            older, following = self._flight, None
+            launches = []
             with span(SERVE_STEP_UPLOAD, self.tracer):
-                self._maybe_poison()
-                self._prepare_writes()
+                if launch:
+                    self._maybe_poison()
+                    self._prepare_writes()
+                if older is None:
+                    older = self._plan(None)
+                    launches.append(older)
+                if launch and self._launches_ahead() \
+                        and self.free_slot_count == 0:
+                    plan = self._plan(older)
+                    if plan.lanes:      # else every budget ends in `older`
+                        following = plan
+                        launches.append(plan)
                 # snapshot the dispatch inputs NOW: a hung watchdog
                 # worker may wake after recovery has already rebuilt
                 # self._state, and it must donate the abandoned buffers
                 # it was given, never the live rebuilt ones
-                state_in, pos_in = self._state, jnp.asarray(self._pos)
-                ops, tables = self._sample_operands(), self._step_tables()
-            out, failures = self._dispatch_guarded(
-                lambda: self._dispatch_single(state_in, pos_in, ops,
-                                              tables))
+                state_in = self._state
+
+            def launch_all():
+                state = state_in
+                for flight in launches:
+                    flight.out = self._dispatch_single(
+                        state, flight.pos, flight.ops, flight.tables)
+                    state = flight.out[0]
+                return older.out    # the readback is the older one's
+
+            out, failures = self._dispatch_guarded(launch_all)
             if out is None:
                 return failures
+            if launches:
+                self._state = launches[-1].out[0]
+            self._flight = following
+            ahead = following is not None
+            self.lookahead_dispatches += ahead
             with span(SERVE_STEP_COMMIT, self.tracer) as commit:
-                finished, n_tokens = self._commit_single(out)
+                finished, n_tokens, dropped = self._commit_single(
+                    older, out[1])
                 commit.set(tokens=n_tokens, finished=len(finished))
                 if self.last_route is not None:
                     commit.set(**{f"route_{k}": v for k, v in
                                   self.last_route["decode"].items()})
+            step_span.set(ahead=int(ahead), discarded=dropped)
+            if self.metrics is not None and (ahead or dropped):
+                self.metrics.on_lookahead(ahead, dropped)
             return finished
 
-    def _take_route(self, packed: np.ndarray) -> tuple:
+    def _launches_ahead(self) -> bool:
+        """May :meth:`step` launch a dispatch before the one in flight
+        is read back? The slot engine's S=1 dispatch takes nothing from
+        the host that the host does not know ahead of the readback."""
+        return True
+
+    def _plan(self, after: Optional[_Flight]) -> _Flight:
+        """The next S=1 dispatch, its operands uploaded: every occupied
+        lane at its position. ``after`` is the dispatch in flight that
+        this one is launched behind without waiting for its tokens: a
+        lane it runs for the same occupant moves one position and one
+        sample index on, whatever token it draws; a lane whose budget
+        ends there parks at position 0 like a free one (idle on the
+        device, counted nowhere, its write in a row the next prefill
+        overwrites whole)."""
+        pos, idx = self._pos, self._step_idx
+        lanes = {i: s for i, s in enumerate(self._slots) if s is not None}
+        if after is not None:
+            pos, idx = pos.copy(), idx.copy()
+            for i, slot in tuple(lanes.items()):
+                if after.lanes.get(i) is not slot:
+                    continue    # admitted since: its prefill set the lane
+                if self._remaining[i] == 1:
+                    pos[i] = idx[i] = 0
+                    del lanes[i]
+                else:
+                    pos[i] += 1
+                    idx[i] += 1
+        return _Flight(jnp.asarray(pos), self._sample_operands(idx),
+                       self._step_tables(), lanes)
+
+    def _drop_flight(self) -> None:
+        """Forget the dispatch in flight uncommitted (its lanes are being
+        abandoned, or the engine is): what it computed reaches no
+        stream."""
+        flight, self._flight = self._flight, None
+        if flight is not None:
+            self.discarded_lane_steps += len(flight.lanes)
+
+    def _take_route(self, packed: np.ndarray, counted: int) -> tuple:
         """The shortcut kind's flat readback (``_engine_step``): its two
-        usual rows, and where this step's busy lanes and the prefills
-        since the last step sent their tokens, as ``{phase: {kind: n}}``
-        with the kinds of :data:`_ROUTE_KINDS` and ``touched``."""
+        usual rows, and where the ``counted`` lanes this dispatch ran
+        and the prefills since the last step sent their tokens, as
+        ``{phase: {kind: n}}`` with the kinds of :data:`_ROUTE_KINDS`
+        and ``touched``."""
         n = self.num_slots
         # an idle lane (parked at position 0) counted nowhere on the device
         held = int(packed[2 * n:3 * n].sum())
         identity = int(packed[3 * n:4 * n].sum())
-        picks = self.occupied * self.cfg.experts.top_k * self.cfg.n_layers
+        picks = counted * self.cfg.experts.top_k * self.cfg.n_layers
         route = {"decode": {"held": held, "identity": identity,
                             "absent": picks - held - identity,
                             "touched": int(packed[4 * n])}}
@@ -1976,20 +2121,24 @@ class ServingEngine:
                                         map(int, pre)))
         return packed[:2 * n].reshape(2, n), route
 
-    def _commit_single(self, out: tuple) -> tuple:
-        self._state, packed = out
+    def _commit_single(self, flight: _Flight, packed: np.ndarray) -> tuple:
+        """Give ``flight``'s tokens to the lanes that still hold the
+        occupant it ran: (completions, tokens emitted, lane steps
+        dropped)."""
         self.decode_dispatches += 1
         self.last_route = None
         if self.cfg.experts is not None:
-            packed, self.last_route = self._take_route(packed)
+            packed, self.last_route = self._take_route(
+                packed, len(flight.lanes))
             if self.metrics is not None:
                 for phase, counts in self.last_route.items():
                     self.metrics.on_route(phase, **counts)
         toks, finite = packed[0], packed[1]
         finished = []
-        n_tokens = 0
-        for i, slot in enumerate(self._slots):
-            if slot is None:
+        n_tokens = dropped = 0
+        for i, slot in flight.lanes.items():
+            if self._slots[i] is not slot:
+                dropped += 1    # it ended while this dispatch was in flight
                 continue
             if not finite[i]:
                 finished.append(self._fail_lane(i, "nan"))
@@ -2012,8 +2161,9 @@ class ServingEngine:
                 if self.metrics is not None:
                     self.metrics.on_complete(req.rid, len(slot.emitted),
                                              reason)
+        self.discarded_lane_steps += dropped
         self._evict_expired(finished)
-        return finished, n_tokens
+        return finished, n_tokens, dropped
 
     def _take_admitted(self) -> list:
         admitted, self._admitted = self._admitted, []
@@ -2030,13 +2180,16 @@ class ServingEngine:
         return ()
 
     def _dispatch_guarded(self, launch, **fields):
-        """One decode dispatch and its one readback, under the
+        """One decode dispatch and one readback, under the
         DeviceTimer's bracket and the watchdog: ``(out, None)``, or
         ``(None, failures)`` after a recovery. ``launch()`` calls the
-        jitted program and returns its outputs, ``(state, packed,
-        ...)``. The two phases open where they run, which with the
-        watchdog armed is the executor's thread (a profiler annotation
-        and the tracer's span stack are both per thread)."""
+        jitted program and returns the outputs to read back, ``(state,
+        packed, ...)``: its own, or at S=1 with a dispatch launched
+        ahead those of the OLDER dispatch (:meth:`step`), so that the
+        bracket's device time is then the wait for that one. The two
+        phases open where they run, which with the watchdog armed is the
+        executor's thread (a profiler annotation and the tracer's span
+        stack are both per thread)."""
         def run():
             with span(SERVE_STEP_DISPATCH, self.tracer):
                 out = launch()
@@ -2084,7 +2237,7 @@ class ServingEngine:
             self._vectors_dirty = False
         return self._dev_vectors
 
-    def _sample_operands(self) -> dict:
+    def _sample_operands(self, step_idx: np.ndarray) -> dict:
         """The sampled dispatch's extra operands — empty in greedy mode
         so every greedy call site stays byte-for-byte the historical
         one (the parity + no-recompile pins)."""
@@ -2092,7 +2245,7 @@ class ServingEngine:
             return {}
         return {"sample": self.ecfg.sample,
                 "key_data": jnp.asarray(self._key_data),
-                "step_idx": jnp.asarray(self._step_idx)}
+                "step_idx": jnp.asarray(step_idx)}
 
     def _dispatch_single(self, state_in: dict, pos_in, ops: dict,
                          tables: tuple):
@@ -2308,6 +2461,12 @@ class _SpeculativeMixin:
 
     def step(self) -> list:
         return self._step_spec()
+
+    def _launches_ahead(self) -> bool:
+        """Never: how far a lane moves in a block is the number of
+        drafts the verify accepted, which the host learns at the
+        readback."""
+        return False
 
     def _step_spec(self) -> list:
         """One speculative block dispatch + unpack: the `_step_block`
@@ -2617,6 +2776,13 @@ class PagedServingEngine(ServingEngine):
         self._pt[i, :] = 0
         self._pt_dirty = True
         super()._free_slot(i)
+
+    def _launches_ahead(self) -> bool:
+        """Never: :meth:`_prepare_writes` and the page table resolve a
+        dispatch's page writes on the host from the COMMITTED positions
+        (a COW split, a registry drop, a lane's released pages), so each
+        dispatch waits for the one before it."""
+        return False
 
     # -- the pre-write (COW) pass ---------------------------------------
 
@@ -3026,6 +3192,16 @@ def serve_loop(engine: ServingEngine, scheduler: RequestScheduler,
             if metrics is not None:
                 metrics.on_drop(req.rid, reason)
 
+    def route(completions) -> None:
+        for slot, req, tokens, reason in completions:
+            scheduler.release(slot)
+            if reason in RETRYABLE_REASONS:
+                if scheduler.requeue_failed(req, reason) \
+                        and metrics is not None:
+                    metrics.on_retry(req.rid)
+            else:
+                results[req.rid] = (tokens, reason)
+
     while True:
         pt = maybe_fail("serve.loop")
         if pt is not None and pt.kind == "preempt":
@@ -3033,6 +3209,7 @@ def serve_loop(engine: ServingEngine, scheduler: RequestScheduler,
             if metrics is not None:
                 metrics.on_fault_survived("preempt")
         if engine.draining:
+            route(engine.harvest())
             for rr in engine.drain():
                 scheduler.release(rr.slot)
             # resumables not yet re-admitted stay resumable: a second
@@ -3095,11 +3272,4 @@ def serve_loop(engine: ServingEngine, scheduler: RequestScheduler,
                 f"serve_loop exceeded max_dispatches={max_dispatches} "
                 f"({len(results)} requests done, "
                 f"{scheduler.unfinished} unfinished)")
-        for slot, req, tokens, reason in engine.step():
-            scheduler.release(slot)
-            if reason in RETRYABLE_REASONS:
-                if scheduler.requeue_failed(req, reason) \
-                        and metrics is not None:
-                    metrics.on_retry(req.rid)
-            else:
-                results[req.rid] = (tokens, reason)
+        route(engine.step())

@@ -81,6 +81,13 @@ class ServingMetrics:
         self.prefill_tokens = 0
         self.decode_tokens = 0
         self.wasted_tokens = 0
+        # the slot engine's S=1 step at full occupancy (engine.step):
+        # calls that launched a dispatch ahead of their readback, and
+        # lane steps such a dispatch computed for a request that had
+        # ended in the dispatch before it (never emitted, so in neither
+        # the decode nor the wasted count)
+        self.lookahead_steps = 0
+        self.discarded_lane_steps = 0
         self.requests_submitted = 0
         self.requests_completed = 0
         self.requests_rejected = 0
@@ -175,6 +182,13 @@ class ServingMetrics:
             ("serve_wasted_tokens_total", lambda: self.wasted_tokens,
              "block tail waste + failure/eviction discards + rejected "
              "draft tokens"),
+            ("serve_lookahead_steps_total", lambda: self.lookahead_steps,
+             "decode steps that launched the next dispatch before "
+             "their readback (every lane busy)"),
+            ("serve_discarded_lane_steps_total",
+             lambda: self.discarded_lane_steps,
+             "lane steps a dispatch launched ahead computed for a "
+             "request that had already ended (dropped, never emitted)"),
             ("serve_draft_proposed_total", lambda: self.draft_proposed,
              "draft tokens proposed by the speculative engine"),
             ("serve_draft_accepted_total", lambda: self.draft_accepted,
@@ -309,6 +323,13 @@ class ServingMetrics:
             self._route[kind].inc(n)
         self._record("serve_route", phase=phase, held=held,
                      identity=identity, absent=absent, touched=touched)
+
+    def on_lookahead(self, ahead: bool, discarded: int) -> None:
+        """One decode step of the slot engine that launched the next
+        dispatch before its readback (``ahead``), or whose commit
+        dropped ``discarded`` lane steps of a dispatch so launched."""
+        self.lookahead_steps += ahead
+        self.discarded_lane_steps += discarded
 
     def on_token(self, rid: int, submitted_at: float) -> None:
         """Called per emitted token; the first emission banks TTFT."""
@@ -524,6 +545,10 @@ class ServingMetrics:
             "queue_depth": self.queue_depth.summary(digits=2),
             "slot_occupancy": self.slot_occupancy.summary(digits=3),
         }
+        if self.lookahead_steps:
+            out["lookahead"] = {
+                "steps": self.lookahead_steps,
+                "discarded_lane_steps": self.discarded_lane_steps}
         if self.draft_proposed:
             # the speculation story (speculative engines only): the
             # same cells the serve_draft_* collectors read

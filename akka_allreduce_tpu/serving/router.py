@@ -436,14 +436,12 @@ class ReplicaRouter:
     # -- replica drain / retirement -------------------------------------
 
     def _harvest(self, rep: ReplicaHandle, results: dict) -> None:
-        """Route completions a TRANSPORT-BACKED replica already
-        delivered but the round loop has not routed yet (a completion
-        that raced the drain/retire decision on the wire). In-process
-        engines return completions synchronously from step() and have
-        no harvest surface — this is a no-op for them."""
-        harvest = getattr(rep.engine, "harvest", None)
-        if harvest is not None:
-            self._route_completions(rep, harvest(), results)
+        """Route completions the round loop has not routed yet: those
+        a TRANSPORT-BACKED replica already delivered (a completion that
+        raced the drain/retire decision on the wire), and those of the
+        dispatch an in-process engine launched ahead of its last
+        readback (``ServingEngine.harvest``)."""
+        self._route_completions(rep, rep.engine.harvest(), results)
 
     def _retire(self, rep: ReplicaHandle, pending_resume: list,
                 results: dict) -> None:

@@ -355,6 +355,7 @@ def run_replica_worker(spec: ReplicaSpec, connect: "tuple[str, int]",
                 send_health()
                 last_health = time.monotonic()
         if draining and sup_alive:
+            send_completions(engine.harvest())
             snapshots = engine.drain()
             send_health()  # draining=True — the router's retire signal
             for rr in snapshots:

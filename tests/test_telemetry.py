@@ -467,6 +467,22 @@ class TestServingMetricsOnRegistry:
         assert prom[("serve_ttft_seconds_count", ())] \
             == summ["ttft_ms"]["count"]
 
+    def test_lookahead_counters_are_exported(self):
+        from akka_allreduce_tpu.serving import ServingMetrics
+        m = ServingMetrics()
+        assert "lookahead" not in m.summary()
+        m.on_lookahead(True, 0)
+        m.on_lookahead(True, 2)
+        m.on_lookahead(False, 1)    # the last commit after the backlog
+        prom = parse_prometheus_text(m.registry.to_prometheus_text())
+        assert prom[("serve_lookahead_steps_total", ())] == 2
+        assert prom[("serve_discarded_lane_steps_total", ())] == 3
+        assert m.summary()["lookahead"] == {"steps": 2,
+                                            "discarded_lane_steps": 3}
+        # a dropped lane step was never a decode token, nor a wasted one
+        assert m.summary()["tokens"] == {"prefill": 0, "decode": 0,
+                                         "wasted": 0}
+
     def test_drain_persisted_counter(self):
         from akka_allreduce_tpu.serving import ServingMetrics
         m = ServingMetrics()

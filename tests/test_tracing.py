@@ -274,6 +274,11 @@ def test_engine_step_is_four_phases_and_admit_holds_its_prefill(kind):
         commit = kids[-1]
         assert commit.fields["tokens"] >= step.fields["occupied"]
         assert commit.fields["finished"] == 0
+        if kind != "block":
+            # every lane busy from the second step on: the slot engine
+            # launches ahead of its readback, the paged engine never
+            assert step.fields["ahead"] == (kind == "slot" and i > 0)
+            assert step.fields["discarded"] == 0
     # the four phases are the step but for the tracer's and the
     # DeviceTimer's own reads and records (the median: a step that the
     # machine preempted between two phases proves nothing)
