@@ -167,14 +167,3 @@ def no_recompiles(what: str = "warmed step") -> assert_max_compiles:
     """The post-warmup contract: zero compiles in the window."""
     return assert_max_compiles(0, what=what)
 
-
-def maybe_no_recompiles(enabled: bool, what: str = "warmed step"):
-    """:func:`no_recompiles` behind a switch: the zero-compile guard
-    when ``enabled``, a no-op context otherwise — the one place the
-    measurement harnesses (bench MFU, profile_mfu) get their
-    guard-or-passthrough from, so guard semantics can't drift between
-    them."""
-    if not enabled:
-        import contextlib
-        return contextlib.nullcontext()
-    return no_recompiles(what)

@@ -19,7 +19,6 @@ the CLI surface maps as:
 * ``serve`` — the inference workload: the continuous-batching engine
   (serving/) under a synthetic closed/open-loop load generator, with a
   ``--selfcheck`` parity smoke for CI.
-* ``bench`` — the device-plane goodput benchmark (bench.py).
 * ``lint`` — the static-analysis plane (analysis/): trace the stack's
   jitted entry points to jaxprs on a virtual CPU mesh and machine-check
   collective-axis / donation / dtype / host-sync invariants; ``--hlo``
@@ -27,9 +26,6 @@ the CLI surface maps as:
   input_output_alias table, async start/done overlap, and collective
   census of the programs XLA actually built; exit-code gated for CI,
   ``--selfcheck`` proves every pass still fires.
-* ``perfgate`` — the perf-regression gate (telemetry/regression.py):
-  re-measure the A/B benchmark sections and fail (exit 1) any claim
-  row below the banked ``perf_capture/`` median minus tolerance.
 * ``info`` — topology summary: the master's membership view, hardware
   edition.
 
@@ -2119,12 +2115,6 @@ def _cmd_info(args: argparse.Namespace) -> int:
     return 0
 
 
-def _cmd_bench(_args: argparse.Namespace) -> int:
-    from akka_allreduce_tpu.bench import main as bench_main
-    bench_main()
-    return 0
-
-
 def _add_serve(sub: argparse._SubParsersAction) -> None:
     p = sub.add_parser(
         "serve", help="continuous-batching inference engine "
@@ -2452,8 +2442,9 @@ def _add_serve(sub: argparse._SubParsersAction) -> None:
                         "policy-only shedding, budget containment, "
                         "CO-safe latency >= naive, slow-client "
                         "backpressure, and scrape == summary for the "
-                        "serve_admission_*/serve_tenant_* series. The "
-                        "rate SWEEP (knee curves) is `cli.py stress`")
+                        "serve_admission_*/serve_tenant_* series. A "
+                        "rate sweep is `serve --load trace "
+                        "--arrival-rate R`, run once a rate")
     p.add_argument("--elastic", action="store_true",
                    help="with --selfcheck: the elastic-membership "
                         "drill (ISSUE 20) — a burst over a LIVE "
@@ -4644,8 +4635,8 @@ def _cmd_serve(args: argparse.Namespace) -> int:
     # -- stress plane + admission economics validation (ISSUE 12) -----
     if args.stress and not args.selfcheck:
         print("error: --stress is the overload-drill smoke and needs "
-              "--selfcheck; the arrival-rate sweep (knee curves) is "
-              "`python -m akka_allreduce_tpu.cli stress`",
+              "--selfcheck; an arrival-rate sweep is `serve --load "
+              "trace --arrival-rate R`, run once a rate",
               file=sys.stderr)
         return 2
     # -- elastic membership drill (ISSUE 20) ---------------------------
@@ -5346,125 +5337,6 @@ def _cmd_serve(args: argparse.Namespace) -> int:
 
 
 
-def _add_stress(sub: argparse._SubParsersAction) -> None:
-    p = sub.add_parser(
-        "stress", help="fleet overload sweep (ISSUE 12): drive the "
-        "seeded stress trace open-loop through the replica fleet at "
-        "increasing arrival rates with admission economics armed, "
-        "find the goodput knee, and emit the goodput-vs-p99 knee "
-        "curve (bench.measure_fleet_stress) — the capture that banks "
-        "perf_capture/fleet_stress.json")
-    # default mirrors bench.STRESS_RATES so a re-bank through this
-    # command sweeps the SAME range perfgate's fresh re-measure does
-    p.add_argument("--rates", default="8,16,32,64,128,256",
-                   help="comma list of mean arrival rates (req/s) to "
-                        "sweep, increasing; the top rate should sit "
-                        ">= 2x past the expected knee or the plateau "
-                        "claim has nothing to plateau over")
-    p.add_argument("--requests", type=int, default=40,
-                   help="trace length per rate point (one seeded "
-                        "trace serves every point — only the arrival "
-                        "schedule compresses)")
-    p.add_argument("--slots", type=int, default=2,
-                   help="decode slots per replica")
-    p.add_argument("--replicas", type=int, default=2,
-                   help="in-process engine replicas behind the router")
-    p.add_argument("--d-model", type=int, default=256)
-    p.add_argument("--n-layers", type=int, default=2)
-    p.add_argument("--d-ff", type=int, default=1024)
-    p.add_argument("--vocab", type=int, default=1024)
-    p.add_argument("--overload-backlog-s", type=float, default=0.5,
-                   help="overload controller bound: shed queue "
-                        "victims by policy once the estimated drain "
-                        "time exceeds this (priced at the calibrated "
-                        "tpot)")
-    p.add_argument("--tenant-budget", default="30:60",
-                   metavar="RATE:BURST",
-                   help="the metered 'free' tenant's token bucket "
-                        "(the other tenants run unmetered); empty = "
-                        "no budgets")
-    p.add_argument("--seed", type=int, default=0)
-    p.add_argument("--out", default=None, metavar="PATH",
-                   help="write the capture-style JSON document "
-                        "(section fleet_stress) here — e.g. "
-                        "perf_capture/fleet_stress.json; stdout gets "
-                        "the rows either way")
-    _add_backend_args(p)
-
-
-def _cmd_stress(args: argparse.Namespace) -> int:
-    _apply_backend_flags(args)
-    try:
-        rates = tuple(float(r) for r in args.rates.split(",")
-                      if r.strip())
-    except ValueError:
-        print(f"error: bad --rates {args.rates!r} (want a comma list "
-              f"of numbers)", file=sys.stderr)
-        return 2
-    if len(rates) < 2 or list(rates) != sorted(rates):
-        print(f"error: --rates must be an increasing sweep of >= 2 "
-              f"points, got {args.rates!r}", file=sys.stderr)
-        return 2
-    try:
-        budget = _parse_tenant_budget(args.tenant_budget)
-    except ValueError as exc:
-        print(f"error: {exc}", file=sys.stderr)
-        return 2
-    import jax
-
-    from akka_allreduce_tpu.bench import measure_fleet_stress
-    kw = {}
-    if budget is not None:
-        kw = {"budget_tokens_per_s": budget[0],
-              "budget_burst": budget[1]}
-    else:
-        # unmetered: an effectively infinite bucket (the controller
-        # still runs, the overload policy still sheds)
-        kw = {"budget_tokens_per_s": 1e9, "budget_burst": 1e9}
-    try:
-        rows = measure_fleet_stress(
-            d_model=args.d_model, n_layers=args.n_layers,
-            d_ff=args.d_ff, vocab=args.vocab,
-            n_requests=args.requests, slots=args.slots,
-            n_replicas=args.replicas, rates=rates,
-            overload_backlog_s=args.overload_backlog_s,
-            seed=args.seed, **kw)
-    except ValueError as exc:
-        print(f"error: {exc}", file=sys.stderr)
-        return 2
-    for row in rows:
-        print(json.dumps(row))
-    if args.out:
-        import datetime
-        plat = jax.devices()[0].platform
-        doc = {
-            "step": "fleet_stress",
-            "section": "fleet_stress",
-            "captured_at": datetime.datetime.now(
-                datetime.timezone.utc).isoformat(timespec="seconds"),
-            "device": plat,
-            "cmd": "python -m akka_allreduce_tpu.cli stress"
-                   + (f" --rates {args.rates}"
-                      if args.rates != "8,16,32,64,128,256" else ""),
-            "note": "open-loop fleet stress sweep "
-                    f"({args.replicas}x{args.slots} slots, "
-                    f"{args.requests}-request seeded tenant trace per "
-                    f"rate point, admission economics armed): goodput "
-                    f"and CO-safe p99 per rate, the knee, and the "
-                    f"gated fleet_stress_overload_speedup robustness "
-                    f"ratio (goodput at the top rate / at the knee)",
-            "rows": rows,
-        }
-        os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
-        tmp = args.out + ".tmp"
-        with open(tmp, "w") as fh:
-            json.dump(doc, fh, indent=1)
-            fh.write("\n")
-        os.replace(tmp, args.out)
-        print(f"banked -> {args.out}", file=sys.stderr)
-    return 0
-
-
 def _add_lint(sub: argparse._SubParsersAction) -> None:
     p = sub.add_parser(
         "lint", help="static-analysis plane (analysis/): trace the "
@@ -5710,147 +5582,6 @@ def _cmd_lint(args: argparse.Namespace) -> int:
     return exit_code(findings, strict=args.strict)
 
 
-def _add_perfgate(sub: argparse._SubParsersAction) -> None:
-    p = sub.add_parser(
-        "perfgate", help="perf-regression gate (telemetry/regression"
-        ".py): re-measure the A/B benchmark sections and compare "
-        "against the banked perf_capture/ medians within per-section "
-        "tolerances — exit 1 on any regressed claim row (ROADMAP item "
-        "5's closing half; runs as a tier-1 CI job)")
-    p.add_argument("--capture-dir",
-                   default=os.path.join(os.path.dirname(os.path.dirname(
-                       os.path.abspath(__file__))), "perf_capture"),
-                   help="banked captures directory (default: the "
-                        "repo's perf_capture/)")
-    p.add_argument("--sections",
-                   default="serving_throughput,multi_step_decode",
-                   help="comma list of sections to gate (known: "
-                        "serving_throughput, multi_step_decode, "
-                        "paged_serving, replicated_serving, "
-                        "ab_overlap, quantized_collectives — the last "
-                        "wants XLA_FLAGS=--xla_force_host_platform_"
-                        "device_count=8 on CPU or every arm is the "
-                        "identity sync). Sections with no banked rows "
-                        "skip with a note — the gate guards banked "
-                        "claims, it does not invent them")
-    p.add_argument("--tolerance", type=float, default=None,
-                   help="relative tolerance override for every "
-                        "section (default: per-section values derived "
-                        "from each capture's recorded run-to-run "
-                        "spread — see telemetry/regression.py)")
-    p.add_argument("--gate-all", action="store_true",
-                   help="gate every numeric row, not just the "
-                        "speedup/best claim rows (for quiet pinned "
-                        "boxes; raw tok/s rows are machine-dependent)")
-    p.add_argument("--fresh-file", default=None, metavar="PATH",
-                   help="compare these rows instead of re-measuring: "
-                        "a JSON object {section: [rows...]} or, with "
-                        "a single --sections entry, a JSON array / "
-                        "JSONL stream of {metric, value} rows (offline "
-                        "capture triage)")
-    p.add_argument("--out", default=None, metavar="PATH",
-                   help="also write the JSON verdict here (CI "
-                        "artifact)")
-    p.add_argument("--format", choices=("text", "json"),
-                   default="text")
-    _add_backend_args(p)
-
-
-def _cmd_perfgate(args: argparse.Namespace) -> int:
-    from akka_allreduce_tpu.telemetry.regression import (SECTIONS,
-                                                         run_gate)
-
-    sections = [s.strip() for s in args.sections.split(",")
-                if s.strip()]
-    if not sections:
-        print("error: --sections named no sections", file=sys.stderr)
-        return 2
-    unknown = [s for s in sections if s not in SECTIONS]
-    if unknown:
-        print(f"error: unknown section(s) {unknown}; have "
-              f"{list(SECTIONS)}", file=sys.stderr)
-        return 2
-    if args.tolerance is not None \
-            and not 0.0 <= args.tolerance < 0.5:
-        print(f"error: --tolerance must be in [0, 0.5) — at 0.5 a "
-              f"2x regression would pass the gate — got "
-              f"{args.tolerance}", file=sys.stderr)
-        return 2
-    fresh_by_section = None
-    if args.fresh_file:
-        try:
-            with open(args.fresh_file) as f:
-                text = f.read()
-            try:
-                doc = json.loads(text)
-            except ValueError:
-                # JSONL stream of row objects (the bench harness's
-                # native output format)
-                doc = [json.loads(line) for line in text.splitlines()
-                       if line.strip()]
-        except (OSError, ValueError) as exc:
-            print(f"error: cannot read --fresh-file: {exc}",
-                  file=sys.stderr)
-            return 2
-        if isinstance(doc, list):
-            if len(sections) != 1:
-                print("error: a row-array --fresh-file needs exactly "
-                      "one --sections entry to attribute the rows to",
-                      file=sys.stderr)
-                return 2
-            fresh_by_section = {sections[0]: doc}
-        else:
-            fresh_by_section = doc
-    uncovered = [s for s in sections
-                 if fresh_by_section is None
-                 or s not in fresh_by_section]
-    if uncovered:
-        # these sections will be measured LIVE (device programs
-        # dispatch) — honor the backend flags the way every measuring
-        # subcommand does, and say so when the user gave a rows file
-        # that only partially covers the request
-        if args.fresh_file:
-            print(f"note: --fresh-file covers "
-                  f"{sorted(fresh_by_section or {})} only; measuring "
-                  f"{uncovered} live", file=sys.stderr)
-        _apply_backend_flags(args)
-    report = run_gate(args.capture_dir, sections=sections,
-                      fresh_by_section=fresh_by_section,
-                      tolerance=args.tolerance, gate_all=args.gate_all)
-    verdict = report.as_dict()
-    if args.out:
-        with open(args.out, "w") as f:
-            json.dump(verdict, f, indent=1)
-    if args.format == "json":
-        print(json.dumps(verdict, indent=1))
-    else:
-        for section, results in report.sections.items():
-            for r in results:
-                if r.ok is None:
-                    tag = "  .."
-                else:
-                    tag = "PASS" if r.ok else "FAIL"
-                line = f"{tag} {section}/{r.metric}"
-                if r.fresh_value is not None:
-                    line += f": fresh {r.fresh_value:g}"
-                if r.banked_median is not None:
-                    line += f" vs banked median {r.banked_median:g}"
-                if r.threshold is not None:
-                    line += f" (floor {r.threshold:g})"
-                if r.note:
-                    line += f" — {r.note}"
-                print(line)
-        for section, reason in report.skipped.items():
-            print(f"SKIP {section}: {reason}")
-        n_fail = len(report.failed)
-        vacuous = ("" if report.gated or report.skipped else
-                   " (nothing gated: no claim rows banked for these "
-                   "sections — check --capture-dir / --sections)")
-        print(f"perfgate: {len(report.gated)} gated rows, {n_fail} "
-              f"regressed -> {'FAIL' if n_fail else 'PASS'}{vacuous}")
-    return 0 if report.ok else 1
-
-
 def _add_eval(sub: argparse._SubParsersAction) -> None:
     p = sub.add_parser(
         "eval", help="held-out perplexity of a trained checkpoint over a "
@@ -5970,47 +5701,55 @@ def _cmd_replica_worker(args: argparse.Namespace) -> int:
     return run_replica_worker(spec, (host, int(port)), args.replica)
 
 
-def main(argv: list[str] | None = None) -> int:
+def _add_info(sub: argparse._SubParsersAction) -> None:
+    p = sub.add_parser(
+        "info", help="topology summary; --scaling prints the analytic "
+        "ICI scaling curve")
+    p.add_argument("--scaling", action="store_true",
+                   help="print the modeled ring-allreduce bus-"
+                        "bandwidth curve 8->256 chips "
+                        "(parallel/scaling.py; BASELINE.md north "
+                        "star) — a MODEL over public ICI specs, "
+                        "floored by this repo's measured 1-chip "
+                        "overhead, not a fleet measurement")
+    p.add_argument("--payload-mfloats", type=float, default=100.0,
+                   help="allreduce payload in millions of f32 "
+                        "(north-star config: 100)")
+    p.add_argument("--goodput-gbps", type=float, default=345.91,
+                   help="measured 1-chip full-sync-path goodput "
+                        "GB/s used as the overhead floor (default: "
+                        "bench.py at commit 8629bde on one v5e "
+                        "chip, 2026-09-26; bench.py was deleted "
+                        "in PR 29, so nothing measures it now)")
+
+
+# subcommand -> (registers its subparser, runs it): the one table, so a
+# subparser cannot be registered without a handler
+_COMMANDS = {
+    "emulate": (_add_emulate, _cmd_emulate),
+    "master": (_add_master, _cmd_master),
+    "worker": (_add_worker, _cmd_worker),
+    "train": (_add_train, _cmd_train),
+    "generate": (_add_generate, _cmd_generate),
+    "serve": (_add_serve, _cmd_serve),
+    "eval": (_add_eval, _cmd_eval),
+    "lint": (_add_lint, _cmd_lint),
+    "replica-worker": (_add_replica_worker, _cmd_replica_worker),
+    "info": (_add_info, _cmd_info),
+}
+
+
+def _build_parser() -> argparse.ArgumentParser:
     parser = argparse.ArgumentParser(prog="akka_allreduce_tpu")
     sub = parser.add_subparsers(dest="cmd", required=True)
-    _add_emulate(sub)
-    _add_master(sub)
-    _add_worker(sub)
-    _add_train(sub)
-    _add_generate(sub)
-    _add_serve(sub)
-    _add_stress(sub)
-    _add_eval(sub)
-    _add_lint(sub)
-    _add_perfgate(sub)
-    _add_replica_worker(sub)
-    p_info = sub.add_parser("info", help="topology summary; --scaling "
-                            "prints the analytic ICI scaling curve")
-    p_info.add_argument("--scaling", action="store_true",
-                        help="print the modeled ring-allreduce bus-"
-                             "bandwidth curve 8->256 chips "
-                             "(parallel/scaling.py; BASELINE.md north "
-                             "star) — a MODEL over public ICI specs, "
-                             "floored by this repo's measured 1-chip "
-                             "overhead, not a fleet measurement")
-    p_info.add_argument("--payload-mfloats", type=float, default=100.0,
-                        help="allreduce payload in millions of f32 "
-                             "(north-star config: 100)")
-    p_info.add_argument("--goodput-gbps", type=float, default=345.91,
-                        help="measured 1-chip full-sync-path goodput "
-                             "GB/s used as the overhead floor (default: "
-                             "bench.py on one v5e chip, 2026-09-26 — "
-                             "PERF.md)")
-    sub.add_parser("bench", help="device-plane goodput benchmark")
-    args = parser.parse_args(argv)
-    return {"emulate": _cmd_emulate, "master": _cmd_master,
-            "worker": _cmd_worker, "train": _cmd_train,
-            "generate": _cmd_generate, "serve": _cmd_serve,
-            "stress": _cmd_stress,
-            "eval": _cmd_eval, "lint": _cmd_lint,
-            "perfgate": _cmd_perfgate,
-            "replica-worker": _cmd_replica_worker,
-            "info": _cmd_info, "bench": _cmd_bench}[args.cmd](args)
+    for add, _ in _COMMANDS.values():
+        add(sub)
+    return parser
+
+
+def main(argv: list[str] | None = None) -> int:
+    args = _build_parser().parse_args(argv)
+    return _COMMANDS[args.cmd][1](args)
 
 
 if __name__ == "__main__":

@@ -1298,8 +1298,9 @@ def make_train_step(cfg: TrainConfig, mesh: Mesh,
     ``donate=True`` donates params and opt_state to the step (halves their
     HBM residency — the lever that lets chip-filling configs fit). Only
     for callers that rebind both from the step's return and never touch
-    the old arrays again (the training-loop pattern; cli.py train and the
-    MFU bench use it). That the donations actually SURVIVE lowering
+    the old arrays again (the training-loop pattern; cli.py train and
+    benchmark/runners/train.py use it). That the donations actually
+    SURVIVE lowering
     (jax.buffer_donor markers — a dtype-mismatched donor is dropped
     with one easily-missed warning) is machine-checked by the
     ``donation`` lint pass over the traced step (``lint --target
@@ -1392,8 +1393,7 @@ def make_multi_step(cfg: TrainConfig, mesh: Mesh,
     dispatch count changes.
 
     Tokens arrive stacked ``(n, batch, seq)``: each scan tick consumes
-    a fresh batch (the bench's fixed-batch scan is a measurement
-    device; training must stream data). Metrics come back stacked
+    a fresh batch (training must stream data). Metrics come back stacked
     along axis 0. The inner step is un-donated — the scan carry
     aliases its buffers — and donation happens once at the outer jit
     boundary, so callers rebind ``params``/``opt_state`` from the

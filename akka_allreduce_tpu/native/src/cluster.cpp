@@ -264,8 +264,8 @@ extern "C" {
 // correctness assertion (assert_multiple > 0) failed. out_flushed (may be
 // null) receives the total number of sink flushes across workers.
 // round_times (may be null, cap entries) receives per-round MONOTONIC
-// completion stamps — the per-round spread canonical-scale benchmarks
-// quote alongside the mean rate (scripts/bench_canonical.py).
+// completion stamps, from which a caller reads the per-round spread
+// alongside the mean rate.
 long aat_cluster_run_timed(int workers, long data_size,
                            int max_chunk_size, int max_lag,
                            double th_reduce, double th_complete,

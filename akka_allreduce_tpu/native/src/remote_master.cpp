@@ -317,8 +317,7 @@ extern "C" {
 // Serve membership + round pacing natively until max_round rounds
 // complete (or timeout); returns rounds completed, or -3 when the
 // listen socket could not bind. round_times (may be null, cap entries)
-// receives per-round MONOTONIC completion stamps — the per-round
-// spread the canonical-scale WIRE benchmarks quote (same contract as
+// receives per-round MONOTONIC completion stamps (same contract as
 // aat_cluster_run_timed in cluster.cpp).
 long aat_remote_master_run_timed(const char* bind_host, int port,
                                  unsigned total_workers,

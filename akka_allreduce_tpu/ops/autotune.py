@@ -162,8 +162,8 @@ def _default_measure_cell(mesh, axis_name, wire: str, arm: str,
     """Median-of-``reps`` two-point-delta round time (seconds) of one
     (arm, shape) cell: all rounds inside ONE jitted ``lax.scan`` under a
     ``shard_map`` over the exact mesh axes, chained through the carry
-    via ``abs`` so XLA cannot collapse the chain (the bench.py
-    methodology), first run discarded as compile+warmup."""
+    via ``abs`` so XLA cannot collapse the chain, first run discarded
+    as compile+warmup."""
     import jax
     import jax.numpy as jnp
     import numpy as np

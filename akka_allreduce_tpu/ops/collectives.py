@@ -201,8 +201,9 @@ def _quantize_rows(x2d: jnp.ndarray, key: jax.Array
 
     On TPU the default is the in-kernel-PRNG Pallas kernel: producing the
     rounding bits is part of the job, and the hardware PRNG inside the
-    kernel beats threefry outside it by ~50-68% end to end (dispatch.py /
-    the pre-round ``ab_int8_e2e_*`` A/B). The bits-input kernel
+    kernel is cheaper than threefry outside it (dispatch.py: chosen
+    from an earlier round's A/B, not timed on this stack). The
+    bits-input kernel
     (AATPU_PALLAS_INT8_PRNG=0 AATPU_PALLAS_INT8=1 — the prng branch is
     consulted first) and the pure jnp form (CPU default) remain
     selectable; all three share the same floor+Bernoulli rounding rule

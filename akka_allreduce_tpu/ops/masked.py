@@ -61,9 +61,9 @@ def masked_reduce_staged(staged: jnp.ndarray, valid: jnp.ndarray,
 
     ``impl``: "pallas" (the one-VMEM-pass kernel,
     ops/pallas_kernels/reduce.py), "xla" (same math in jnp), or "auto"
-    (pallas on TPU — the real-chip A/B in scripts/bench_suite.py measured
-    it ~30% faster than the jnp form, 738-779 vs 567-581 GB/s on v5e —
-    xla elsewhere).
+    (pallas on TPU, xla elsewhere: default chosen from A/Bs of an
+    earlier round, ``git show b96eba3:PERF.md``; not timed on this
+    stack, ROADMAP D6).
     """
     if impl == "auto":
         impl = "pallas" if use_pallas("masked_reduce") else "xla"

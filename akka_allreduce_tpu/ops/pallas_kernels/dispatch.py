@@ -3,24 +3,21 @@
 The production code paths (ops/collectives.py int8 transport,
 ops/masked.py staged reduce) choose between the Pallas kernel and the
 equivalent jnp/XLA formulation at trace time, and say which
-(:func:`say`). The per-kernel defaults follow A/Bs taken on a v5e chip
-before this round, under another JAX (scripts/bench_suite.py ``ab_*``
-lines, 8 x 3.28M f32 inputs; ``git show b96eba3:PERF.md``) — none has
-been re-measured on the present stack (ROADMAP D5):
+(:func:`say`). Every per-kernel default was chosen from A/Bs of an
+earlier round, on a v5e chip under another JAX (``git show
+b96eba3:PERF.md``; the tool that took them is gone); none is timed on
+this stack (ROADMAP D6), and no benchmark cell runs a quantized wire or
+the masked reduce. The arguments the defaults rest on:
 
-* ``masked_reduce`` — Pallas WINS (738-779 GB/s vs 567-581 GB/s for the
-  jnp form, ~+30%): the one-VMEM-pass kernel beats XLA's mask+sum+rescale
-  fusion. Default on TPU: pallas.
-* ``int8`` (quantize/dequantize, PRE-GENERATED bits input) — XLA WINS
-  (167-170 GB/s vs 148-151 GB/s round-trip, ~+13%): XLA's fusion of the
-  scale/round/clip/cast chain beats the hand kernel, which pays for
-  materialising its random-bits input tile-by-tile. Default: jnp.
-* ``int8_prng`` (quantize with IN-KERNEL hardware PRNG) — Pallas WINS
-  end to end (164-182 vs ~109 GB/s round-trip INCLUDING bits generation,
-  +50-68% across captures; bench_suite.py ``ab_int8_e2e_*``):
-  production must generate rounding bits somewhere, and
-  threefry outside the kernel costs more than the hardware PRNG inside
-  it. Default on TPU: pallas (the production quantize path).
+* ``masked_reduce`` — pallas on TPU: the one-VMEM-pass kernel against
+  XLA's mask+sum+rescale fusion.
+* ``int8`` (quantize/dequantize, PRE-GENERATED bits input) — jnp: XLA
+  fuses the scale/round/clip/cast chain, and the hand kernel pays for
+  materialising its random-bits input tile-by-tile.
+* ``int8_prng`` (quantize with IN-KERNEL hardware PRNG) — pallas on TPU
+  (the production quantize path): production must generate rounding
+  bits somewhere, and threefry outside the kernel costs more than the
+  hardware PRNG inside it.
 
 On CPU (tests, the virtual 8-device mesh) the jnp form always runs —
 interpreter-mode Pallas would only be slower. Overrides for re-measuring:
@@ -38,7 +35,8 @@ import sys
 
 import jax
 
-# Measured winners on TPU (see module docstring). True = pallas.
+# Defaults on TPU, True = pallas: chosen from A/Bs of an earlier round
+# (git show b96eba3:PERF.md); not timed on this stack (ROADMAP D6).
 _TPU_DEFAULTS = {
     "masked_reduce": True,
     "int8": False,
@@ -49,20 +47,18 @@ _TPU_DEFAULTS = {
     # default here too (kernels stay exercised in interpret mode by
     # tests/test_pallas_kernels.py)
     "int8_block": False,
-    # in-kernel PRNG quantize: wins END TO END (bits generation included;
-    # see module docstring) — the production int8 quantize on TPU
+    # in-kernel PRNG quantize: judged END TO END (bits generation
+    # included; see module docstring) — the production int8 quantize on
+    # TPU
     "int8_prng": True,
-    # flash attention (ops/pallas_kernels/attention.py) — Pallas WINS by
-    # 5x (measured on this repo's TPU v5e, bench_suite.py ab_attn_*
-    # lines, B=4 T=4096 H=16 D=128 bf16 fwd+bwd at the swept-optimal
-    # block 1024: flash 62.4 TFLOP/s vs local 12.5 vs blockwise-scan
-    # 7.1): the fused VMEM pass keeps the score tile out of HBM in both
-    # directions. Default on TPU: pallas.
+    # flash attention (ops/pallas_kernels/attention.py): the fused VMEM
+    # pass keeps the score tile out of HBM in both directions. Default
+    # on TPU: pallas; it is what both training cells run (block 1024).
     "flash_attention": True,
     # ring flash attention (ops/pallas_kernels/ring_flash.py) — the ring
-    # INNER step is the same fused block computation the local A/B above
-    # measures (the ring only adds ppermute rotation between steps), so
-    # the local 5x win should carry; semantics are oracle-pinned on the
+    # INNER step is the same fused block computation as the local kernel
+    # above (the ring only adds ppermute rotation between steps), so
+    # what holds for it should carry; semantics are oracle-pinned on the
     # CPU mesh (tests/test_ring_flash.py), and the rotated path compiled
     # and trained at sp=2 on a four-chip v5e host (`train --dp 2 --sp 2`,
     # PERF.md) — correct, never timed against the pure-JAX ring.

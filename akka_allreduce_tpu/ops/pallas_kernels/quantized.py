@@ -37,8 +37,8 @@ from akka_allreduce_tpu.ops.pallas_kernels.tiling import col_tile, pad_cols
 
 def _stochastic_round(scaled, bits_u32):
     """THE floor+Bernoulli rounding rule, in one place: both kernels (and,
-    kept textually in sync, the jnp form in ops/collectives.py and the
-    bench's quant_xla) must produce this exact wire format. Uniform from
+    kept textually in sync, the jnp form in ops/collectives.py) must
+    produce this exact wire format. Uniform from
     the top 24 bits so the f32 conversion is exact; int32 bitcast because
     Mosaic has no uint32->f32 cast (values < 2^24 are sign-safe)."""
     low = jnp.floor(scaled)

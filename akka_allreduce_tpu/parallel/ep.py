@@ -68,12 +68,11 @@ class MoEConfig:
     # C grows with N; "scatter" routes by integer slot indices
     # (scatter-add in, gather out) — O(k*N) index memory, the long-context
     # regime. "auto" picks scatter once the dispatch tensor would exceed
-    # _EINSUM_DISPATCH_MAX elements. Measured on this repo's v5e
-    # (bench_suite.py ab_moe_dispatch_*): at N=8192 tokens (E=8,
-    # d_ff=2048, bf16 fwd+bwd) einsum 9.9 ms/step vs scatter 0.93 ms/step
-    # — 10.7x — so the threshold errs toward scatter well before the
-    # quadratic regime. Both paths share the slot-assignment math and are
-    # parity-pinned (tests/test_ep.py, on-chip outputs bit-compared).
+    # _EINSUM_DISPATCH_MAX elements; the threshold errs toward scatter
+    # well before the quadratic regime (default chosen from A/Bs of an
+    # earlier round, git show b96eba3:PERF.md; not timed on this stack,
+    # ROADMAP D6). Both paths share the slot-assignment math and are
+    # parity-pinned (tests/test_ep.py).
     dispatch: str = "auto"
 
 

@@ -104,12 +104,13 @@ def predict(payload_bytes: float, n: int, spec: Optional[IciSpec] = None,
     """One row of the scaling curve.
 
     ``measured_1chip_goodput_gbps`` grounds the model in this repo's own
-    measurement: the 1-chip full-sync-path goodput (``bench.py``'s
-    ``allreduce_goodput_25M_f32_1chip``) bounds the framework's
-    per-round non-wire overhead as ``S / goodput``; that floor runs
-    CONCURRENTLY with nothing (it is the pre/post processing around the
-    collective), so it adds to the wire time rather than maxing with it
-    — the pessimistic composition, chosen deliberately.
+    measurement: the 1-chip full-sync-path goodput (once ``bench.py``'s
+    ``allreduce_goodput_25M_f32_1chip``; the tool was deleted in PR 29
+    and nothing produces the number now, ROADMAP D13) bounds the
+    framework's per-round non-wire overhead as ``S / goodput``; that
+    floor runs CONCURRENTLY with nothing (it is the pre/post processing
+    around the collective), so it adds to the wire time rather than
+    maxing with it — the pessimistic composition, chosen deliberately.
     """
     spec = spec or default_spec()
     if measured_1chip_goodput_gbps is not None \

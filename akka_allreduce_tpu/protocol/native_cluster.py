@@ -25,8 +25,8 @@ def run_native_cluster(config: AllreduceConfig,
                        with_round_times: bool = False):
     """Run the whole cluster natively; returns (rounds_completed,
     outputs_flushed), plus a list of per-round monotonic completion
-    stamps when ``with_round_times`` — the per-round spread the
-    canonical-scale benchmarks quote alongside the mean rate.
+    stamps when ``with_round_times`` — from which a caller reads the
+    per-round spread alongside the mean rate.
 
     ``assert_multiple > 0`` enables the reference sink's correctness
     invariant on EVERY flush (output == N x input, counts == N — valid

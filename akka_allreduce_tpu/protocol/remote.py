@@ -229,8 +229,7 @@ def run_master_native(config: AllreduceConfig,
     :func:`run_master`, so Python and native workers join it
     interchangeably. Returns rounds completed, or ``(rounds, stamps)``
     with per-round monotonic completion stamps when
-    ``with_round_times`` (the canonical-wire benchmark's spread
-    methodology, same contract as run_native_cluster's)."""
+    ``with_round_times`` (same contract as run_native_cluster's)."""
     import ctypes
 
     from akka_allreduce_tpu.native import load_library

@@ -9,7 +9,7 @@ Every program is cached (no compile-time or size floor): a restart
 replays the small programs too.
 
 Called by every process that compiles: the CLI (``_apply_backend_flags``),
-``bench.main``, the replica worker, ``chip_smoke.py`` and
+the replica worker, ``chip_smoke.py``, ``benchmark/run.py`` and
 ``tests/conftest.py``. A cache is never an input: nothing reads it except
 JAX, and deleting it only costs compile time.
 """

@@ -70,8 +70,7 @@ def latency_hiding_scheduler_requested(
     (bare flag, ``true``/``t``/``yes``/``y``/``1``, case-insensitive —
     absl::SimpleAtob's rule). This answers "was it REQUESTED at env
     level", not "is it live": flags set after libtpu loaded are
-    requested-but-dead, which only the caller can know
-    (bench.measure_ab_overlap's ``flags_live``)."""
+    requested-but-dead, which only the caller can know."""
     if env is None:
         env = os.environ
     val = None

@@ -50,8 +50,8 @@ EOS/stop/budget vectors; frozen lanes stop advancing ``pos`` and
 writing KV), the host replays the same conditions to unpack the block,
 and greedy output stays bitwise identical across S and vs
 ``generate()`` (tests/test_multi_step_decode.py). The trade is tail
-waste (``wasted_tokens``) and block-granular admission — the
-``multi_step_decode`` bench row is the A/B.
+waste (``wasted_tokens``) and block-granular admission; no benchmark
+cell runs S > 1, so the trade is not timed on this stack.
 
 At ``decode_steps=1`` on the slot engine the device does not wait for
 that readback while every lane is busy: the tokens are picked on the
@@ -1502,9 +1502,9 @@ class ServingEngine:
         # where the last step's tokens were routed (the shortcut kind
         # only): {"decode": {held, identity, absent, touched}[, "prefill"]}
         self.last_route: Optional[dict] = None
-        # high-water mark of concurrently occupied slots/lanes — the
-        # paged A/B's sustained-concurrency evidence (bench.py
-        # measure_paged_serving)
+        # high-water mark of concurrently occupied slots/lanes (what
+        # the paged selfcheck and tests/test_paged_engine.py read for
+        # sustained concurrency)
         self.peak_occupied = 0
         # block steps computed for a lane AFTER its done-mask latched
         # (S>1 tail waste — the quantity an operator tunes decode_steps

@@ -47,8 +47,8 @@ the workload that can falsify those claims:
 
 The module is pure host Python (no jax): traces and ledgers are
 unit-testable with fake clocks. The drivers that put a trace through a
-real engine/fleet live in the bench/CLI layer (``cli.py stress``,
-``bench.measure_fleet_stress``).
+real engine/fleet live in the CLI (``serve --load trace``, its
+``--soak-s`` smoke, ``serve --selfcheck --stress``).
 """
 
 from __future__ import annotations

@@ -53,8 +53,8 @@ class ServingMetrics:
     """Request-lifecycle metrics for one serve run.
 
     The engine/loop call the ``on_*`` hooks; ``summary()`` renders one
-    JSON-able dict (the serve CLI prints it as its single stdout line,
-    the same one-JSON-line contract as bench.py)."""
+    JSON-able dict (the serve CLI prints it as its single stdout
+    line)."""
 
     def __init__(self, clock=time.monotonic, tracer=None, registry=None,
                  labels=None):

@@ -36,8 +36,8 @@ module applies them ACROSS engines:
 
 Transport note: the fleet is transport-agnostic by construction (the
 router sees admissions and completions, not call stacks). The DEFAULT
-fleet is in-process — N engines, one device context, how tests and
-the CPU bench run it, and the parity oracle for everything else. The
+fleet is in-process — N engines, one device context, how tests run
+it, and the parity oracle for everything else. The
 SUBPROCESS fleet (serving/supervisor.py, ``--replica-mode
 subprocess``) drives this same router over
 :class:`~akka_allreduce_tpu.serving.supervisor.RemoteEngine` handles:

@@ -5,11 +5,11 @@ The paper's value proposition — partial completion under thresholds and
 ``maxLag`` — makes the interesting production questions distributional:
 which contributions missed, how late, how often, at what waste. Before
 this package the repo answered them through three disconnected planes
-(JSONL tracer, host sampler, serving summary dicts) with no exporter,
-no device-time attribution, and no guard on the banked perf trajectory.
-The telemetry plane supplies all four, each host-side only (nothing
-here ever enters jitted code — pinned by the ``engine_step_telemetry``
-lint entry):
+(JSONL tracer, host sampler, serving summary dicts) with no exporter
+and no device-time attribution. The telemetry plane supplies both, each
+host-side only (nothing here ever enters jitted code — pinned by the
+``engine_step_telemetry`` lint entry). It offers marks; the verdict on
+them is ``benchmark/``'s:
 
 * ``registry`` — :class:`MetricsRegistry`: named counters / gauges /
   histograms with labels, Prometheus-text + JSON exporters, periodic
@@ -24,10 +24,6 @@ lint entry):
   yielding host-vs-device time and the ``dispatch_gap_ms`` host-bubble
   series (what a profile shows of a dispatch comes from
   ``runtime/tracing.py``'s ``span``, not from here).
-* ``regression`` — the perf-regression gate behind ``cli.py perfgate``:
-  fresh A/B rows vs the banked ``perf_capture/`` medians within
-  per-section tolerances, exit-nonzero on regression (ROADMAP item 5's
-  closing half), wired as a tier-1 CI job.
 """
 
 from akka_allreduce_tpu.telemetry.chrome_trace import (

@@ -410,21 +410,3 @@ class TestChaosUnderOverload:
         assert s["faults"]["fault_survived"] >= 1 \
             or s["faults"]["retries_total"] >= 1
 
-
-class TestStressCliDefaults:
-    def test_cli_default_rates_match_bench_sweep(self):
-        """The `cli stress` default sweep must equal bench.STRESS_RATES:
-        OPERATIONS.md tells the operator to re-bank with the bare
-        command, and perfgate's fresh re-measure uses the bench
-        default — a drift would gate the overload-speedup ratio
-        across two different sweep ranges."""
-        import argparse
-
-        from akka_allreduce_tpu.bench import STRESS_RATES
-        from akka_allreduce_tpu.cli import _add_stress
-
-        parser = argparse.ArgumentParser()
-        _add_stress(parser.add_subparsers(dest="cmd"))
-        args = parser.parse_args(["stress"])
-        assert tuple(float(r) for r in args.rates.split(",")) \
-            == tuple(STRESS_RATES)

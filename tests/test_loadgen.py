@@ -55,7 +55,7 @@ class TestTraceDeterminism:
         """Under the flat poisson curve the thinning never rejects, so
         two traces at different rates draw IDENTICAL lengths / tenants
         / seeds — a rate sweep varies offered load and nothing else
-        (the property measure_fleet_stress leans on)."""
+        (the property a rate sweep leans on)."""
         lo = generate_trace(TraceConfig(seed=3, n_requests=24,
                                         rate=8.0))
         hi = generate_trace(TraceConfig(seed=3, n_requests=24,

@@ -4,10 +4,8 @@ step-for-step the per-step loop's program.
 The scan body IS make_train_step's step (same gradient sync, optimizer
 chain, int8 quant seeding from the adam counter), so k chunked steps over
 a stacked batch must reproduce k sequential per-step calls over the same
-batches — params, opt state, and the per-step loss trail. This is the
-production rendering of the bench's scan-steps measurement
-(bench.py measure_train_mfu), with fresh data each tick instead of a
-repeated batch.
+batches — params, opt state, and the per-step loss trail, with fresh
+data each tick.
 """
 
 import sys

@@ -1,6 +1,5 @@
 """Telemetry plane (ISSUE 6): registry export golden-texts, nested
-span parentage, Chrome-trace rendering, device-time attribution, and
-the perf-regression gate.
+span parentage, Chrome-trace rendering and device-time attribution.
 
 Everything here is host-plane and device-free except nothing — the
 telemetry plane's whole design constraint is that it never touches
@@ -22,13 +21,6 @@ from akka_allreduce_tpu.telemetry import (
     MetricsRegistry,
     chrome_trace,
     parse_prometheus_text,
-)
-from akka_allreduce_tpu.telemetry.regression import (
-    GateReport,
-    default_gated,
-    gate_section,
-    load_banked,
-    run_gate,
 )
 
 
@@ -498,141 +490,3 @@ class TestServingMetricsOnRegistry:
         with pytest.raises(ValueError, match="already registered"):
             ServingMetrics(registry=m.registry)
 
-
-BANKED = {
-    "serving_sequential_tok_s_cpu": [159.3],
-    "serving_engine_s4_tok_s_cpu": [307.7],
-    "serving_throughput_speedup_s4": [1.932, 1.8],  # re-capture: median
-}
-
-
-def rows(**kv):
-    return [{"metric": k, "value": v} for k, v in kv.items()]
-
-
-class TestRegressionGate:
-    def test_default_gated_is_the_claim_rows(self):
-        assert default_gated("serving_throughput_speedup_s4")
-        assert default_gated("multi_step_decode_best")
-        assert not default_gated("serving_engine_s4_tok_s_cpu")
-        assert not default_gated("allreduce_goodput_25M_f32_1cpu")
-
-    def test_passes_on_banked_equal_rows(self):
-        res = gate_section("serving_throughput", BANKED, rows(
-            serving_sequential_tok_s_cpu=159.3,
-            serving_engine_s4_tok_s_cpu=307.7,
-            serving_throughput_speedup_s4=1.866))
-        gated = [r for r in res if r.ok is not None]
-        assert len(gated) == 1 and gated[0].ok
-        assert gated[0].banked_median == pytest.approx(1.866)  # median
-
-    def test_fails_on_2x_regression(self):
-        res = gate_section("serving_throughput", BANKED, rows(
-            serving_throughput_speedup_s4=1.866 / 2))
-        bad = [r for r in res if r.ok is False]
-        assert len(bad) == 1
-        assert bad[0].metric == "serving_throughput_speedup_s4"
-        assert "regressed" in bad[0].note
-
-    def test_within_tolerance_passes(self):
-        # the banked capture's own recorded repeat-run swing must pass
-        res = gate_section("serving_throughput", BANKED, rows(
-            serving_throughput_speedup_s4=1.63))
-        assert all(r.ok is not False for r in res)
-
-    def test_missing_gated_fresh_row_fails(self):
-        res = gate_section("serving_throughput", BANKED, [])
-        bad = {r.metric for r in res if r.ok is False}
-        assert bad == {"serving_throughput_speedup_s4"}
-
-    def test_error_row_fails_gated_metric(self):
-        res = gate_section("serving_throughput", BANKED, [
-            {"metric": "serving_throughput_speedup_s4", "value": 0.0,
-             "error": "OOM"}])
-        (bad,) = [r for r in res if r.ok is False]
-        assert "OOM" in bad.note
-
-    def test_gate_all_gates_value_rows(self):
-        res = gate_section("serving_throughput", BANKED, rows(
-            serving_sequential_tok_s_cpu=10.0,
-            serving_engine_s4_tok_s_cpu=307.7,
-            serving_throughput_speedup_s4=1.9), gate_all=True)
-        assert any(r.metric == "serving_sequential_tok_s_cpu"
-                   and r.ok is False for r in res)
-
-    def test_tolerance_validation(self):
-        with pytest.raises(ValueError, match="tolerance"):
-            gate_section("s", BANKED, [], tolerance=1.5)
-        # the hard cap: at tol 0.5 an exact 2x regression would PASS
-        # the >= comparison — the acceptance property forbids it
-        with pytest.raises(ValueError, match="2x"):
-            gate_section("s", BANKED, [], tolerance=0.5)
-
-    def test_exact_2x_regression_fails_every_section(self):
-        """The acceptance case at the boundary: fresh == median/2 must
-        fail under every section's DEFAULT tolerance (all < 0.5)."""
-        from akka_allreduce_tpu.telemetry.regression import (
-            SECTION_TOLERANCE)
-        for section, tol in SECTION_TOLERANCE.items():
-            assert tol < 0.5, section
-            res = gate_section(section,
-                               {"x_speedup_s4": [2.0]},
-                               rows(x_speedup_s4=1.0))
-            (gated,) = [r for r in res if r.ok is not None]
-            assert gated.ok is False, section
-
-    def test_load_banked_reads_the_repo_bank(self):
-        import os
-        bank = load_banked(os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            "perf_capture"))
-        assert "serving_throughput" in bank
-        assert "multi_step_decode" in bank
-        assert bank["serving_throughput"][
-            "serving_throughput_speedup_s4"]
-        assert "multi_step_decode_best" in bank["multi_step_decode"]
-
-    def test_run_gate_offline_pass_and_fail(self, tmp_path):
-        cap = tmp_path / "caps"
-        cap.mkdir()
-        (cap / "serving.json").write_text(json.dumps({
-            "section": "serving_throughput",
-            "rows": [{"metric": "serving_throughput_speedup_s4",
-                      "value": 2.0, "unit": "x"}]}))
-        ok = run_gate(str(cap), sections=["serving_throughput"],
-                      fresh_by_section={"serving_throughput": rows(
-                          serving_throughput_speedup_s4=1.9)})
-        assert isinstance(ok, GateReport) and ok.ok
-        bad = run_gate(str(cap), sections=["serving_throughput"],
-                       fresh_by_section={"serving_throughput": rows(
-                           serving_throughput_speedup_s4=1.0)})
-        assert not bad.ok
-        assert bad.failed[0].metric == "serving_throughput_speedup_s4"
-        doc = json.loads(json.dumps(bad.as_dict()))  # CI artifact shape
-        assert doc["ok"] is False and doc["failed"]
-
-    def test_zero_gated_rows_is_a_pass_not_a_red(self):
-        """Banked rows with no claim metrics gate nothing: the verdict
-        must be a (noted) pass — the text summary and the exit code
-        read the same `ok`, so CI never sees a red log that says
-        PASS."""
-        banked = {"serving_sequential_tok_s_cpu": [100.0]}
-        res = gate_section("serving_throughput", banked,
-                           rows(serving_sequential_tok_s_cpu=10.0))
-        rep = GateReport(sections={"serving_throughput": res},
-                         skipped={}, tolerance=None)
-        assert rep.ok and not rep.gated and not rep.failed
-
-    def test_run_gate_skips_unbanked_sections(self, tmp_path):
-        rep = run_gate(str(tmp_path), sections=["ab_overlap"],
-                       fresh_by_section={"ab_overlap": []})
-        assert rep.skipped and "ab_overlap" in rep.skipped
-        # nothing gated anywhere + an explained skip is still a pass
-        assert rep.ok
-
-    def test_merge_best_takes_per_metric_max(self):
-        from akka_allreduce_tpu.telemetry.regression import _merge_best
-        merged = _merge_best(rows(a=1.0, b=5.0),
-                             rows(a=2.0, b=3.0, c=7.0))
-        assert {r["metric"]: r["value"] for r in merged} \
-            == {"a": 2.0, "b": 5.0, "c": 7.0}
