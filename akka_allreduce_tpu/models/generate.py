@@ -38,6 +38,12 @@ from akka_allreduce_tpu.models.transformer import (
     lm_logits,
     rmsnorm,
 )
+from akka_allreduce_tpu.ops.pallas_kernels.attention import (
+    latent_decode_attention,
+    latent_keys_lie_minor,
+    pick_latent_tiling,
+)
+from akka_allreduce_tpu.ops.pallas_kernels.dispatch import say_attention
 from akka_allreduce_tpu.parallel.ep import dropless_moe, moe_ffn
 from akka_allreduce_tpu.parallel.ring_attention import (
     NEG_INF,
@@ -388,7 +394,15 @@ def _dense_cached_block(layer: dict, x: jnp.ndarray, kv: dict, i: int,
 def _latent_attention(q: jnp.ndarray, latent: jnp.ndarray,
                       pos: jnp.ndarray, rank: int,
                       scale: float) -> jnp.ndarray:
-    """Decode attention over the latent itself (absorbed projections).
+    """Decode attention over the latent itself (absorbed projections):
+    the pure-JAX formula. It is what runs on the CPU (so every parity pin
+    that holds the engine bitwise to ``generate()`` keeps its jaxpr), for
+    ``decode_step``'s scalar position on any backend, and as the oracle
+    of the fused kernel that takes its place in the slot engine's step on
+    the TPU (:func:`latent_decode_path`). It reads every one of the
+    ``max_seq`` positions twice and holds the f32 scores between the
+    reads, whatever ``pos`` says: the cost the kernel exists to drop.
+
     q (b, 1, h, rank + rope): each head's query folded through the key
     half of the up-projection, then its rotary part; latent (b, max_seq,
     rank + rope): what the cache holds, positions <= pos valid (a scalar,
@@ -410,6 +424,32 @@ def _latent_attention(q: jnp.ndarray, latent: jnp.ndarray,
     return out[:, None, :, :rank].astype(q.dtype)
 
 
+def latent_decode_path(pos, latent) -> "tuple[bool, tuple[int, int]] | None":
+    """How a decode step attends the latent cache ``latent`` (attentions,
+    lanes, max_seq, rank + rope) at ``pos``, from what the code can see:
+    None for the pure-JAX :func:`_latent_attention`; else (the fused
+    kernel's ``interpret`` flag, its (lanes a grid step, key block)
+    tiling). The kernel (ops/pallas_kernels/attention.py
+    ``latent_decode_attention``) runs where all of these hold: the default
+    backend is the TPU (interpreter-mode Pallas on a CPU would only be
+    slower, and the CPU's jaxpr is what the parity pins hold); ``pos`` has
+    a position a row (the slot engine; ``decode_step``'s scalar position
+    keeps the formula ``generate()`` is the tests' reference with); the
+    cache is a float dtype; a tiling exists; and the device keeps the
+    cache with its positions minor, the way the kernel reads it (at the
+    published 512 + 64 columns it does; where it does not, the kernel's
+    view of the cache would be a transpose of all of it a call). No
+    option chooses."""
+    if (pos.ndim != 1 or jax.default_backend() != "tpu"
+            or not jnp.issubdtype(latent.dtype, jnp.floating)):
+        return None
+    tiling = pick_latent_tiling(*latent.shape[1:], latent.dtype)
+    if tiling is None or not latent_keys_lie_minor(
+            tuple(latent.shape), jnp.dtype(latent.dtype)):
+        return None
+    return False, tiling
+
+
 def _mla_cached_attention(p: dict, x: jnp.ndarray, kv: dict, a: int,
                           cfg: TransformerConfig, ops: CacheOps):
     """Latent attention ``a`` over x (b, t, d) through ``kv["latent"]``:
@@ -417,8 +457,13 @@ def _mla_cached_attention(p: dict, x: jnp.ndarray, kv: dict, a: int,
     RoPE), ``latent_dim`` numbers a token. A prefill expands keys and
     values from its fresh latents; a decode step folds the up-projection's
     key half into the query and attends the cached latent directly. Both
-    are the same function of the same cache. Returns (the attention's
-    output through ``wo``, kv)."""
+    are the same function of the same cache. The decode's read of the
+    cache is :func:`latent_decode_path`'s choice, said once on stderr
+    (``attention[latent_decode]``): the fused kernel over each lane's live
+    key blocks in the slot engine's step on the TPU, the pure-JAX formula
+    over the whole buffer everywhere else; projections, rotary phases and
+    the cache write are the same on both. Returns (the attention's output
+    through ``wo``, kv)."""
     b, t, _ = x.shape
     heads, rank = cfg.n_heads, cfg.kv_lora_rank
     nope, vd = cfg.qk_nope_head_dim, cfg.v_head_dim
@@ -446,10 +491,26 @@ def _mla_cached_attention(p: dict, x: jnp.ndarray, kv: dict, a: int,
             jnp.concatenate([q_nope, q_rope], axis=-1), k,
             kv_heads[..., nope:])
     else:
-        q_lat = jnp.einsum("bthn,rhn->bthr", q_nope, up[..., :nope])
-        out_lat = _latent_attention(
-            jnp.concatenate([q_lat, q_rope], axis=-1), kv["latent"][a],
-            ops.pos, rank, (nope + q_rope.shape[-1]) ** -0.5)
+        q_lat = jnp.concatenate(
+            [jnp.einsum("bthn,rhn->bthr", q_nope, up[..., :nope]), q_rope],
+            axis=-1)
+        scale = (nope + q_rope.shape[-1]) ** -0.5
+        path = latent_decode_path(ops.pos, kv["latent"])
+        if path is None:
+            say_attention("latent_decode", "reference:_latent_attention",
+                          q_lat)
+            out_lat = _latent_attention(q_lat, kv["latent"][a], ops.pos,
+                                        rank, scale)
+        else:
+            interpret, tiling = path
+            say_attention("latent_decode", "latent_decode_attention",
+                          q_lat, interpret=interpret, group=tiling[0],
+                          blk=tiling[1])
+            # the whole cache goes in and the kernel's index maps take
+            # ``a``: kv["latent"][a] ahead of a custom call is a copy
+            out_lat = latent_decode_attention(
+                q_lat[:, 0], kv["latent"], a, ops.pos, rank, scale,
+                tiling=tiling, interpret=interpret)[:, None]
         out = jnp.einsum("bthr,rhv->bthv", out_lat, up[..., nope:])
     return out.reshape(b, t, heads * vd) @ p["wo"], kv
 

@@ -76,11 +76,16 @@ def _tile_live_local(iq, ik, blk_q, blk_k, causal, window=None,
     return live
 
 
-def _softmax_tile(q, k, v, m_prev, l_prev, acc_prev, mask, scale):
+def _softmax_tile(q, k, v, m_prev, l_prev, acc_prev, mask, scale,
+                  keys_on_lanes=False):
     """One online-softmax accumulation tile (shared by the local forward
-    kernel and the ring step kernel — ONE copy of the flash numerics).
-    m/l: (blk_q, 1) f32; acc: (blk_q, D) f32; mask None = unmasked."""
-    s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+    kernel, the ring step kernel and the decode kernels — ONE copy of the
+    flash numerics). m/l: (blk_q, 1) f32; acc: (blk_q, D) f32; mask None =
+    unmasked. k and v are (blk_k, D) and (blk_k, Dv), or with
+    ``keys_on_lanes`` (D, blk_k) and (Dv, blk_k): the same two products
+    over a block that lies with its positions minor."""
+    over_k, over_v = (0, 1) if keys_on_lanes else (1, 0)
+    s = lax.dot_general(q, k, (((1,), (over_k,)), ((), ())),
                         preferred_element_type=jnp.float32) * scale
     if mask is not None:
         s = jnp.where(mask, s, NEG_INF)
@@ -91,7 +96,8 @@ def _softmax_tile(q, k, v, m_prev, l_prev, acc_prev, mask, scale):
     # windows) would see exp(NEG_INF - NEG_INF) == 1 on its masked lanes
     p = jnp.where(s > NEG_INF / 2, jnp.exp(s - m_new), 0.0)
     l_new = l_prev * corr + p.sum(axis=-1, keepdims=True)
-    pv = lax.dot_general(p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+    pv = lax.dot_general(p.astype(v.dtype), v,
+                         (((1,), (over_v,)), ((), ())),
                          preferred_element_type=jnp.float32)
     return m_new, l_new, acc_prev * corr + pv
 
@@ -627,6 +633,230 @@ def paged_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
         interpret=interpret,
     )(page_table, pos, qk, kk, vk)
     return out.reshape(b, one, h, d)
+
+
+# -- latent decode attention (the slot engine's latent-cache read path) --
+#
+# The slot engine keeps a latent attention's cache as (attentions, lanes,
+# max_seq, rank + rope): what multi-head latent attention stores a token
+# is one latent row, which is BOTH the key (all its columns) and the
+# value (its first ``rank`` columns). The pure-JAX decode
+# (models/generate.py ``_latent_attention``) contracts two einsums over
+# every one of the ``max_seq`` positions of every lane, with the f32
+# score tensor written, masked and re-read between them: at a quarter
+# live it moves eight times the bytes the attention needs. The kernel
+# below reads, for each lane, the key blocks at or below that lane's
+# position, once each, and keeps scores, softmax and the weighted sum in
+# VMEM. It is to the latent cache what ``paged_attention`` is to the page
+# pool, with two differences: a dead grid step names the block already
+# resident, so it issues no DMA (``paged_attention`` skips the arithmetic
+# only); and several lanes share a grid step, each through its own
+# operand view of the one cache, so a step's fixed cost is paid once a
+# group of lanes and not once a lane.
+#
+# WHICH WAY THE CACHE LIES. At the published 512 + 64 columns the TPU
+# keeps a (..., max_seq, 576) bf16 array with ``max_seq`` MINOR (576 is
+# 4.5 lane tiles, and the compiler pads no buffer it is free to turn: on
+# a v5e ``jnp.zeros((8, 128, 2048, 576), bf16).format`` says
+# major_to_minor (0, 1, 3, 2), PERF.md section 6, PR 30). A kernel that
+# asked for the rows as the program writes them would make XLA re-lay the
+# whole cache ahead of every call (measured: slower than the formula). So
+# the kernel takes the cache as it lies, ``swapaxes(cache, 2, 3)``, which
+# on such a buffer is a bitcast: a key block is (rank + rope, blk) with
+# the positions on the lanes, the scores are a plain q @ block, and the
+# weighted sum contracts the block's first ``rank`` ROWS with the
+# probabilities over its lane axis. models/generate.py
+# ``latent_decode_path`` engages the kernel only where the device does lay
+# the cache out that way (it asks the compiler), so the swap is never a
+# transpose.
+
+# the double-buffered key blocks of one grid step may take this much VMEM:
+# half of the 16 MiB a Mosaic kernel gets unasked, the other half being
+# q, the output, the f32 accumulators and the compiler's own
+_LATENT_KEY_VMEM = 8 << 20
+# the key block the tiling rule aims at and the lanes a grid step, both
+# from the sweep on the chip (PERF.md section 5, PR 30): at the cell's
+# shape 256 and 512 positions a block time alike and 128 a third slower;
+# 4 lanes a step time within 2% of 8 and trace and lower in a tenth of
+# the time (the kernel body is unrolled over the group, twice)
+_LATENT_BLOCK = 256
+_LATENT_GROUP = 4
+
+
+@functools.lru_cache(maxsize=None)
+def latent_keys_lie_minor(shape: tuple, dtype) -> bool:
+    """Does the default device keep a (attentions, lanes, max_seq, width)
+    cache of this shape with ``max_seq`` minor and ``width`` next to it,
+    so that ``swapaxes(cache, 2, 3)`` is a bitcast? Asked of the compiler
+    (the layout it gives the argument of an identity program: what every
+    jitted program's arguments and results of this shape have), once a
+    shape; nothing is allocated."""
+    formats = jax.jit(lambda x: x).lower(
+        jax.ShapeDtypeStruct(shape, dtype)).compile().input_formats
+    return tuple(formats[0][0].layout.major_to_minor) == (0, 1, 3, 2)
+
+
+def latent_block_index(a, lane, j, pos, blk: int) -> tuple:
+    """The block of the swapped (attentions, lanes, width, max_seq) cache
+    that grid step ``j`` of ``lane`` names: key block ``j`` while it holds
+    a position <= ``pos[lane]``, and after that the lane's LAST live block
+    again, which is resident, so the pipeline copies nothing. Over all
+    ``j`` a lane names exactly ``pos[lane] // blk + 1`` distinct blocks."""
+    return (a, lane, 0, jnp.minimum(j, pos[lane] // blk))
+
+
+def pick_latent_tiling(lanes: int, max_seq: int, width: int,
+                       dtype) -> "tuple[int, int] | None":
+    """(lanes a grid step, key block) for ``latent_decode_attention``, from
+    what the caller's shapes say and nothing else; None where no legal
+    tiling exists (the caller keeps the pure-JAX formula).
+
+    The key block lies along the lanes of a VMEM tile, so it is 256 or
+    128, whichever divides ``max_seq`` first, or the whole of a shorter
+    or odd buffer (a block equal to the array's dimension is always
+    legal). The group is the largest of 4, 2, 1 that divides ``lanes`` and
+    whose double-buffered key blocks, padded as VMEM pads them (rows to
+    the dtype's sublane packing, columns to 128), fit
+    ``_LATENT_KEY_VMEM``."""
+    itemsize = jnp.dtype(dtype).itemsize
+    blk = next((b for b in (_LATENT_BLOCK, 128) if max_seq % b == 0),
+               max_seq)
+    sublane = 8 * max(1, 4 // itemsize)
+    block_bytes = (-(-width // sublane) * sublane
+                   * -(-blk // 128) * 128 * itemsize)
+    for group in (_LATENT_GROUP, 2, 1):
+        if lanes % group == 0 and 2 * group * block_bytes \
+                <= _LATENT_KEY_VMEM:
+            return group, blk
+    return None
+
+
+def _latent_decode_kernel(pos_ref, q_ref, *refs, group, blk, rank, scale):
+    """One (lane group, key block) grid step: for each of the group's
+    lanes whose block ``j`` is live, one online-softmax tile over it. The
+    index maps have already kept dead blocks out of VMEM; a block wholly
+    at or below the lane's position takes no mask, the one that holds the
+    position masks the scores past it and zeroes the value columns past it
+    (a masked probability is exactly 0, and 0 x NaN is not)."""
+    k_refs, o_ref = refs[:group], refs[group]
+    m_scr, l_scr, acc_scr = refs[group + 1:]
+    i, j = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    for g in range(group):
+        pos = pos_ref[i * group + g]
+        first = j * blk
+
+        def tile(frontier, g=g, pos=pos, first=first):
+            k = k_refs[g][0, 0]                  # (rank + rope, blk)
+            v = k_refs[g][0, 0, :rank, :]        # the value: its first rows
+            mask = None
+            if frontier:
+                # (1, blk): over the heads' scores and the value's rows
+                mask = first + lax.broadcasted_iota(
+                    jnp.int32, (1, blk), 1) <= pos
+                v = jnp.where(mask, v, jnp.zeros_like(v))
+            m_new, l_new, acc_new = _softmax_tile(
+                q_ref[g], k, v, m_scr[g, :, 0:1], l_scr[g, :, 0:1],
+                acc_scr[g], mask, scale, keys_on_lanes=True)
+            acc_scr[g] = acc_new
+            m_scr[g] = jnp.broadcast_to(m_new, m_scr.shape[1:])
+            l_scr[g] = jnp.broadcast_to(l_new, l_scr.shape[1:])
+
+        whole = first + blk - 1 <= pos
+        pl.when(whole)(functools.partial(tile, False))
+        pl.when((first <= pos) & jnp.logical_not(whole))(
+            functools.partial(tile, True))
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _emit():
+        # position 0 is always <= pos, so l > 0 for every lane (a parked
+        # lane sits at 0, reads one block and emits garbage the host
+        # ignores: garbage, not NaN)
+        o_ref[:] = (acc_scr[:] / l_scr[:, :, 0:1]).astype(o_ref.dtype)
+
+
+def latent_decode_attention(q: jnp.ndarray, cache: jnp.ndarray, a: int,
+                            pos: jnp.ndarray, rank: int, scale: float,
+                            tiling: "tuple[int, int] | None" = None,
+                            interpret: bool = False) -> jnp.ndarray:
+    """Fused decode attention over the latent cache (one token a lane).
+
+    q (lanes, heads, rank + rope): each head's query folded through the
+    key half of the up-projection, then its rotary part; cache
+    (attentions, lanes, max_seq, rank + rope): the WHOLE cache, float
+    dtypes only, of which attention ``a`` is read (the index maps take
+    ``a``: a slice ahead of a custom call would be a copy of the cache);
+    pos (lanes,) int32: lane b attends its positions <= pos[b]. Returns
+    (lanes, heads, rank) in ``q.dtype``: the softmax-weighted sum of the
+    latents' first ``rank`` columns, what ``_latent_attention`` returns
+    up to the reassociation of an online softmax (``_softmax_tile``: f32
+    scores from the operands' dtype, f32 running max, sum and accumulator,
+    the probabilities cast to the cache's dtype ahead of the second
+    matmul exactly as there).
+
+    Grid (lanes / group, max_seq / blk), the key axis innermost. The
+    swapped cache (see the section's comment: a bitcast where the kernel
+    is engaged) is passed ``group`` times, view g blocked (1, 1, width,
+    blk) at :func:`latent_block_index` of lane ``i * group + g``, so every
+    lane of a step has its own frontier: per lane the HBM reads are its
+    ``pos // blk + 1`` live blocks, once each (the value is the key
+    block's first ``rank`` rows, in VMEM), and a step whose lanes are all
+    past their frontier costs its fixed overhead only. ``tiling`` (group,
+    blk) defaults to :func:`pick_latent_tiling`; ``interpret`` runs the
+    Pallas interpreter (the CPU tests)."""
+    if not jnp.issubdtype(cache.dtype, jnp.floating):
+        raise ValueError(
+            "latent_decode_attention reads float caches only, got "
+            f"{cache.dtype}")
+    lanes, heads, width = q.shape
+    max_seq = cache.shape[2]
+    if tiling is None:
+        tiling = pick_latent_tiling(lanes, max_seq, width, cache.dtype)
+        if tiling is None:
+            raise ValueError(
+                f"no latent decode tiling for lanes={lanes} "
+                f"max_seq={max_seq} width={width} dtype={cache.dtype}")
+    group, blk = tiling
+    if lanes % group or max_seq % blk:
+        raise ValueError(f"tiling {tiling} does not divide lanes={lanes} "
+                         f"x max_seq={max_seq}")
+
+    def kspec(g):
+        return pl.BlockSpec(
+            (1, 1, width, blk),
+            lambda i, j, ps: latent_block_index(a, i * group + g, j, ps,
+                                                blk),
+            memory_space=pltpu.VMEM)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(lanes // group, max_seq // blk),
+        in_specs=[pl.BlockSpec((group, heads, width),
+                               lambda i, j, ps: (i, 0, 0),
+                               memory_space=pltpu.VMEM)]
+        + [kspec(g) for g in range(group)],
+        out_specs=pl.BlockSpec((group, heads, rank),
+                               lambda i, j, ps: (i, 0, 0),
+                               memory_space=pltpu.VMEM),
+        scratch_shapes=[
+            pltpu.VMEM((group, heads, 128), jnp.float32),   # running max
+            pltpu.VMEM((group, heads, 128), jnp.float32),   # running sum
+            pltpu.VMEM((group, heads, rank), jnp.float32),  # accumulator
+        ])
+    return pl.pallas_call(
+        functools.partial(_latent_decode_kernel, group=group, blk=blk,
+                          rank=rank, scale=scale),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((lanes, heads, rank), q.dtype),
+        interpret=interpret,
+        name="latent_decode_attention",
+    )(pos.astype(jnp.int32), q, *([jnp.swapaxes(cache, 2, 3)] * group))
 
 
 def pick_flash_block(t: int, want: int) -> "int | None":

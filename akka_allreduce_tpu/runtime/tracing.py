@@ -279,6 +279,12 @@ TRAIN_ROUND = "train_round"
 # commit dropped: the lane's request had ended in the dispatch before). In
 # such a call ``upload`` and ``dispatch`` are of the dispatch LAUNCHED,
 # ``readback`` and ``commit`` of the OLDER one, launched a call earlier.
+# It also carries ``kv_blocks_live`` and ``kv_blocks_skipped``: the key
+# blocks of the latent cache that the committed dispatch's fused decode
+# attentions read and left unread, counted on the host from the positions it
+# uploaded (zero where the step's attention is not that kernel). Fields of
+# a span that belong to another layer than the span's own have a row in
+# ``SPAN_FIELDS``.
 _DECODE = "engine, decode step (serving/engine.py)"
 _PREFILL = "engine, prefill (serving/engine.py)"
 SPANS = {
@@ -293,6 +299,15 @@ SPANS = {
     SCHED_POP_READY: ("scheduler (serving/scheduler.py)",
                       "flood_idle_outside_ms"),
     TRAIN_ROUND: ("train loop (cli._cmd_train shape)", "-"),
+}
+
+KV_BLOCKS_LIVE = "kv_blocks_live"
+KV_BLOCKS_SKIPPED = "kv_blocks_skipped"
+_LATENT = "latent attention (models/generate.py)"
+# span -> field -> (layer, the quantity that reads it; none does yet)
+SPAN_FIELDS = {
+    SERVE_STEP: {KV_BLOCKS_LIVE: (_LATENT, "-"),
+                 KV_BLOCKS_SKIPPED: (_LATENT, "-")},
 }
 
 # ``jax.named_scope`` names inside the jitted train step and the serving
@@ -322,8 +337,7 @@ SCOPES = {
     SCOPE_OPTIMIZER: (_STEP, "-"),
     SCOPE_ATTENTION: ("attention kernels (ops/pallas_kernels/attention.py)",
                       "-"),
-    SCOPE_MLA_ATTENTION: ("latent attention (models/generate.py)",
-                          "lcr_mla_device_pct"),
+    SCOPE_MLA_ATTENTION: (_LATENT, "lcr_mla_device_pct"),
     SCOPE_DENSE_FFN: ("engine, decode step (serving/engine.py)", "-"),
     SCOPE_MOE_ROUTER: (_EXPERTS, "lcr_experts_device_pct"),
     SCOPE_MOE_EXPERTS: (_EXPERTS, "lcr_experts_device_pct"),

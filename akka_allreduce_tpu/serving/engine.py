@@ -115,6 +115,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from akka_allreduce_tpu.models import generate
 from akka_allreduce_tpu.models.generate import (
     CacheOps,
     _rope_slots,
@@ -1414,6 +1415,8 @@ class _Flight:
     tables: tuple
     lanes: dict                     # lane -> the _SlotState it ran
     out: Optional[tuple] = None     # (state, packed) once launched
+    # key blocks of the latent cache its attentions read and skipped
+    kv_blocks: tuple = (0, 0)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -1502,6 +1505,17 @@ class ServingEngine:
         # where the last step's tokens were routed (the shortcut kind
         # only): {"decode": {held, identity, absent, touched}[, "prefill"]}
         self.last_route: Optional[dict] = None
+        # the latent decode kernel's key block, where the step's
+        # attention is that kernel (models/generate.py
+        # ``latent_decode_path``): what the host counts the blocks a
+        # dispatch reads and skips with; None on the reference path and
+        # for every other cache
+        self._kv_block: Optional[int] = None
+        if "latent" in self._state:
+            path = generate.latent_decode_path(self._pos,
+                                               self._state["latent"])
+            if path is not None:
+                _interpret, (_group, self._kv_block) = path
         # high-water mark of concurrently occupied slots/lanes (what
         # the paged selfcheck and tests/test_paged_engine.py read for
         # sustained concurrency)
@@ -2057,9 +2071,14 @@ class ServingEngine:
                 if self.last_route is not None:
                     commit.set(**{f"route_{k}": v for k, v in
                                   self.last_route["decode"].items()})
-            step_span.set(ahead=int(ahead), discarded=dropped)
-            if self.metrics is not None and (ahead or dropped):
-                self.metrics.on_lookahead(ahead, dropped)
+            live, skipped = older.kv_blocks
+            step_span.set(ahead=int(ahead), discarded=dropped,
+                          kv_blocks_live=live, kv_blocks_skipped=skipped)
+            if self.metrics is not None:
+                if ahead or dropped:
+                    self.metrics.on_lookahead(ahead, dropped)
+                if live:
+                    self.metrics.on_kv_blocks(live, skipped)
             return finished
 
     def _launches_ahead(self) -> bool:
@@ -2091,7 +2110,20 @@ class ServingEngine:
                     pos[i] += 1
                     idx[i] += 1
         return _Flight(jnp.asarray(pos), self._sample_operands(idx),
-                       self._step_tables(), lanes)
+                       self._step_tables(), lanes,
+                       kv_blocks=self._count_kv_blocks(pos))
+
+    def _count_kv_blocks(self, pos: np.ndarray) -> tuple:
+        """(read, skipped) key blocks of the latent cache in one dispatch
+        at the positions ``pos`` it uploads, over all lanes and
+        attentions: the fused kernel reads a lane's ``pos // blk + 1``
+        blocks (a parked lane's one) and no other. (0, 0) where the
+        step's attention is not that kernel."""
+        if self._kv_block is None:
+            return 0, 0
+        attentions, lanes, max_seq, _w = self._state["latent"].shape
+        live = attentions * int((pos // self._kv_block + 1).sum())
+        return live, attentions * lanes * (max_seq // self._kv_block) - live
 
     def _drop_flight(self) -> None:
         """Forget the dispatch in flight uncommitted (its lanes are being

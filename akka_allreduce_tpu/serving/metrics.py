@@ -88,6 +88,12 @@ class ServingMetrics:
         # the decode nor the wasted count)
         self.lookahead_steps = 0
         self.discarded_lane_steps = 0
+        # the latent decode kernel's key blocks (engine.step on the
+        # latent cache, TPU): blocks the committed dispatches read, and
+        # blocks of the buffer past their lanes' positions that they
+        # skipped, over lanes and attentions
+        self.kv_blocks_live = 0
+        self.kv_blocks_skipped = 0
         self.requests_submitted = 0
         self.requests_completed = 0
         self.requests_rejected = 0
@@ -331,6 +337,26 @@ class ServingMetrics:
         self.lookahead_steps += ahead
         self.discarded_lane_steps += discarded
 
+    def on_kv_blocks(self, live: int, skipped: int) -> None:
+        """One committed decode dispatch whose latent attentions ran the
+        fused kernel: the key blocks of the cache it read (``live``: each
+        lane's blocks up to its position, over the attentions) and the
+        blocks of the buffer it left in HBM (``skipped``)."""
+        if not self.kv_blocks_live:
+            # registered at the first such dispatch (it reads a block a
+            # lane at least), so an engine on the formula's path exports
+            # no such series; the collectors read the summary's cells
+            for kind in ("live", "skipped"):
+                self.registry.register_callback(
+                    "serve_kv_blocks_total",
+                    lambda k=kind: getattr(self, f"kv_blocks_{k}"),
+                    kind="counter",
+                    help="key blocks of the latent cache that the decode "
+                         "kernel read (live) and left unread (skipped)",
+                    labels={**self.labels, "kind": kind})
+        self.kv_blocks_live += live
+        self.kv_blocks_skipped += skipped
+
     def on_token(self, rid: int, submitted_at: float) -> None:
         """Called per emitted token; the first emission banks TTFT."""
         self.on_block_tokens(rid, submitted_at, 1)
@@ -549,6 +575,12 @@ class ServingMetrics:
             out["lookahead"] = {
                 "steps": self.lookahead_steps,
                 "discarded_lane_steps": self.discarded_lane_steps}
+        if self.kv_blocks_live:
+            out["kv_blocks"] = {
+                "live": self.kv_blocks_live,
+                "skipped": self.kv_blocks_skipped,
+                "skipped_share": round(self.kv_blocks_skipped / (
+                    self.kv_blocks_live + self.kv_blocks_skipped), 4)}
         if self.draft_proposed:
             # the speculation story (speculative engines only): the
             # same cells the serve_draft_* collectors read

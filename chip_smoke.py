@@ -19,6 +19,13 @@ from a seed:
 * ``serve-slot`` / ``serve-paged``: ``cli serve`` in float32 on the slot
   engine and with ``--paged``: every request completed, no failed attempt,
   decode tokens == requests x max_new_tokens, a bounded program count.
+* ``serve-latent``: ``cli serve --model-config`` on one shortcut double
+  layer at LongCat-Flash-Chat's published widths (latent attention 512 + 64
+  x 64 heads, two held experts; bf16, 0.95B parameters), the same checks,
+  and the fused latent decode kernel in the step: the program's
+  ``attention[latent_decode]:`` notice has to say ``mosaic:``. A silent
+  fall back to the pure-JAX formula on the chip is a failed smoke, not a
+  slow benchmark cell.
 
 One process per chip: this parent never imports JAX; each phase is a child
 process (``--phase``) that owns the chip for its lifetime, checks that
@@ -58,6 +65,25 @@ FLAGSHIP = ["--d-model", "2048", "--n-layers", "8", "--n-heads", "16",
 TOY = ["--d-model", "64", "--n-layers", "2", "--n-heads", "4",
        "--d-ff", "128", "--vocab", "256"]
 TRAIN_STEPS = 5
+# the ``serve-latent`` phase's model: one double layer of
+# meituan-longcat/LongCat-Flash-Chat's config.json, every width as
+# published, depth and the held experts cut (benchmark/configs/ has the
+# cell's four layers and sixteen experts)
+LATENT = dict(
+    vocab_size=16384, hidden_size=6144, ffn_hidden_size=12288,
+    expert_ffn_hidden_size=2048, num_layers=1, num_attention_heads=64,
+    kv_lora_rank=512, q_lora_rank=1536, qk_rope_head_dim=64, v_head_dim=128,
+    qk_nope_head_dim=128, mla_scale_q_lora=True, mla_scale_kv_lora=True,
+    routed_scaling_factor=6, n_routed_experts=512, rms_norm_eps=1e-5,
+    rope_theta=1e7, attention_method="MLA", zero_expert_num=256,
+    zero_expert_type="identity", moe_topk=12, experts_held=[0, 2],
+    torch_dtype="bfloat16")
+LATENT_TOY = {**LATENT, "vocab_size": 256, "hidden_size": 64,
+              "ffn_hidden_size": 128, "expert_ffn_hidden_size": 32,
+              "num_attention_heads": 4, "kv_lora_rank": 16,
+              "q_lora_rank": 24, "qk_rope_head_dim": 8, "v_head_dim": 16,
+              "qk_nope_head_dim": 16, "n_routed_experts": 16,
+              "zero_expert_num": 8, "moe_topk": 4}
 
 
 def phase_argv(phase: str, rehearse: bool) -> "tuple[list[str], dict]":
@@ -77,6 +103,13 @@ def phase_argv(phase: str, rehearse: bool) -> "tuple[list[str], dict]":
     load = (["--slots", "2", "--max-seq", "64", "--prompt-len", "8:8"]
             if rehearse else
             ["--slots", "8", "--max-seq", "512", "--prompt-len", "128:128"])
+    if phase == "serve-latent":
+        # the file is written where the records go (git-ignored)
+        path = os.path.join(OUT_DIR, "chip_smoke.serve-latent.config.json")
+        os.makedirs(OUT_DIR, exist_ok=True)
+        with open(path, "w") as f:
+            json.dump(LATENT_TOY if rehearse else LATENT, f)
+        model = ["--model-config", path]
     argv = ["serve", *model, *load, "--requests", str(want["requests"]),
             "--max-new-tokens", str(want["max_new_tokens"]),
             "--load", "closed"]
@@ -85,7 +118,7 @@ def phase_argv(phase: str, rehearse: bool) -> "tuple[list[str], dict]":
     return argv, want
 
 
-PHASES = ("train", "serve-slot", "serve-paged")
+PHASES = ("train", "serve-slot", "serve-paged", "serve-latent")
 # one prompt length, so one prefill program; the rest are the decode
 # step and first-use helpers. Exact-length prefill compiles one program
 # per distinct length (ROADMAP S2) — a regression there shows here.
@@ -167,6 +200,21 @@ def _check_serve(out: str, want: dict) -> "list[dict]":
     ]
 
 
+def _check_latent(err: str, rehearse: bool) -> "list[dict]":
+    said = re.findall(r"^attention\[latent_decode\]: (\S+) (.*)$", err,
+                      flags=re.M)
+    kernel_ok = bool(said) and all(
+        impl.startswith("mosaic:latent_decode_attention")
+        for impl, _ in said)
+    return [
+        {"name": ("latent decode notice present (rehearsal: any "
+                  "implementation)" if rehearse else
+                  "Mosaic latent decode kernel in the step"),
+         "ok": bool(said) if rehearse else kernel_ok,
+         "detail": [" ".join(a) for a in said]},
+    ]
+
+
 def run_phase(phase: str, record_path: str, rehearse: bool) -> int:
     import contextlib
 
@@ -229,6 +277,8 @@ def run_phase(phase: str, record_path: str, rehearse: bool) -> int:
                                programs.get("jit(step)", 0))
     else:
         checks += _check_serve(out.text(), want)
+        if phase == "serve-latent":
+            checks += _check_latent(err.text(), rehearse)
     native = sys.modules.get("akka_allreduce_tpu.native")
     checks.append({"name": "native library not loaded on this path",
                    "ok": native is None or native._lib is None,

@@ -7,9 +7,11 @@
 For each Pallas kernel an entry point can select — flash forward/backward
 (bf16 block 1024, f32 block 512), the banded (sliding-window) flash,
 ring-flash inside a ``ppermute`` ring, the paged decode kernel (page sizes
-8 and 16, float32), the int8 quantizers (hardware-PRNG, bits-input, ef8
-block) and the masked reduce — this jits it at the shape the flagship model
-(d_model 2048, 16 heads x 128, seq 2048) gives it, runs it, and compares
+8 and 16, float32), the latent decode kernel (512 + 64 columns, 64 heads,
+bf16, at the tiling its rule picks), the int8 quantizers (hardware-PRNG,
+bits-input, ef8 block) and the masked reduce — this jits it at the shape
+the flagship model (d_model 2048, 16 heads x 128, seq 2048) or, for the
+latent kernel, LongCat-Flash-Chat's attention gives it, runs it, and compares
 with the repo's pure-JAX reference on the same input. A kernel Mosaic
 refuses is recorded with the compiler's message, and the script goes on to
 the next: it is a survey, and exits 1 if any kernel failed. One JSON object
@@ -47,7 +49,9 @@ def main() -> int:
 
     from akka_allreduce_tpu.ops.pallas_kernels import quantized as qk
     from akka_allreduce_tpu.ops.pallas_kernels.attention import (
-        flash_causal_attention, paged_attention, paged_gather_attention)
+        flash_causal_attention, latent_decode_attention,
+        latent_keys_lie_minor, paged_attention, paged_gather_attention,
+        pick_latent_tiling)
     from akka_allreduce_tpu.ops.pallas_kernels.reduce import \
         fused_masked_reduce
     from akka_allreduce_tpu.ops.pallas_kernels.ring_flash import \
@@ -147,6 +151,31 @@ def main() -> int:
                     "max_err": max_err(got, want)}
         return run
 
+    def latent_case():
+        # the slot engine's latent cache at LongCat-Flash-Chat's widths
+        # (512 + 64 columns, 64 heads), against the formula it replaces
+        from akka_allreduce_tpu.models.generate import _latent_attention
+        n, lanes, max_seq, heads, rank, rope = (
+            (2, 4, 64, 4, 16, 8) if tiny else (2, 16, 2048, 64, 512, 64))
+        kq, kc = jax.random.split(key)
+        q = jax.random.normal(kq, (lanes, heads, rank + rope),
+                              jnp.float32).astype(jnp.bfloat16)
+        cache = jax.random.normal(kc, (n, lanes, max_seq, rank + rope),
+                                  jnp.float32).astype(jnp.bfloat16)
+        pos = jnp.asarray(np.linspace(0, max_seq - 1, lanes), jnp.int32)
+        scale = (rank + rope) ** -0.5
+        tiling = pick_latent_tiling(lanes, max_seq, rank + rope,
+                                    cache.dtype)
+        got = jax.jit(lambda q, c, p: latent_decode_attention(
+            q, c, 1, p, rank, scale, interpret=interpret))(q, cache, pos)
+        want = jax.jit(lambda q, c, p: _latent_attention(
+            q[:, None], c[1], p, rank, scale)[:, 0])(q, cache, pos)
+        return {"lanes": lanes, "heads": heads, "width": rank + rope,
+                "max_seq": max_seq, "group": tiling[0], "blk": tiling[1],
+                "keys_lie_minor": latent_keys_lie_minor(
+                    tuple(cache.shape), cache.dtype),
+                "max_err": max_err(got, want)}
+
     x = jax.random.normal(key, (rows, cols), jnp.float32)
 
     def roundtrip(quantize, dequantize, **note):
@@ -212,6 +241,7 @@ def main() -> int:
         ("ring_flash bf16", ring_case),
         ("paged_attention f32 page 8", paged_case(8)),
         ("paged_attention f32 page 16", paged_case(16)),
+        ("latent_decode_attention bf16", latent_case),
         # pltpu.prng_* has no interpreter path
         *([] if tiny else [("quantize_int8_prng", prng_case)]),
         ("quantize_int8 bits-input + dequantize_int8", bits_case),

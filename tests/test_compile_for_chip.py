@@ -96,3 +96,51 @@ def test_the_absorbed_decode_attends_the_latent_without_copying_it(one_chip,
     assert not re.search(rf"= {whole}\S* copy\(", entry)
     # the cache (604 MB here) is updated in place, never doubled
     assert compiled.memory_analysis().temp_size_in_bytes < 400 << 20
+
+
+def test_the_chip_keeps_the_published_cache_with_its_positions_minor(
+        one_chip):
+    """What ``latent_decode_path`` asks the compiler on the TPU, asked of
+    the described chip: the layout it gives a jitted program's argument of
+    the cell's cache shape (a v5e said the same of a live array, PERF.md
+    section 6, PR 30). The kernel reads the cache that way; were this to
+    change, the kernel would stand down and the formula run."""
+    cache = jax.ShapeDtypeStruct((8, LANES, MAX_SEQ, 576), jnp.bfloat16,
+                                 sharding=one_chip)
+    formats = _compile(lambda x: x, cache).input_formats
+    assert tuple(formats[0][0].layout.major_to_minor) == (0, 1, 3, 2)
+
+
+def test_the_fused_decode_reads_the_latent_where_it_lies(one_chip, cfg,
+                                                         monkeypatch):
+    """The slot engine's decode attention with the kernel engaged as on the
+    TPU (Mosaic, not the interpreter): one custom call, its view of the
+    cache a bitcast, no copy, slice or transpose of the cache, and no
+    f32 score tensor over the whole buffer."""
+    from akka_allreduce_tpu.ops.pallas_kernels.attention import (
+        pick_latent_tiling)
+    tiling = pick_latent_tiling(LANES, MAX_SEQ, cfg.latent_dim, cfg.dtype)
+    assert tiling == (4, 256)
+    monkeypatch.setattr(G, "latent_decode_path",
+                        lambda pos, latent: (False, tiling))
+    p = jax.eval_shape(lambda k: init_mla(k, cfg), jax.random.key(0))
+    kv = {"latent": jax.ShapeDtypeStruct(
+        (2, LANES, MAX_SEQ, cfg.latent_dim), cfg.dtype)}
+    x = jax.ShapeDtypeStruct((LANES, 1, cfg.d_model), cfg.dtype)
+    pos = jax.ShapeDtypeStruct((LANES,), jnp.int32)
+
+    def step(p, x, kv, pos):
+        return G._mla_cached_attention(p, x, kv, 1, cfg, G.CacheOps(pos=pos))
+    compiled = _compile(step, *_on(one_chip, (p, x, kv, pos)), donate=(2,))
+    hlo = compiled.as_text()
+    entry = hlo[hlo.index("ENTRY "):]
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"',
+                          entry)) == 1
+    whole = rf"bf16\[(2,)?{LANES},({MAX_SEQ},{cfg.latent_dim}|" \
+            rf"{cfg.latent_dim},{MAX_SEQ})\]"
+    assert not re.search(
+        rf"= {whole}\S* (copy|transpose|slice|dynamic-slice)\(", entry)
+    assert re.search(rf"= {whole}\S* bitcast\(", entry)
+    assert not re.search(rf"f32\[{LANES},{cfg.n_heads},{MAX_SEQ}\]", hlo)
+    # nothing the size of a cache (604 MB here) or of its f32 scores (67 MB)
+    assert compiled.memory_analysis().temp_size_in_bytes < 32 << 20

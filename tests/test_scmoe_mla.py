@@ -293,6 +293,165 @@ def test_route_counts_reach_the_registry():
     assert "serve_route_experts_touched_total 3" in text
 
 
+# -- the fused latent decode attention, forced on (interpret mode) ----------
+#
+# On the CPU ``latent_decode_path`` chooses the formula, so these tests
+# patch the choice (never an option of the program) and take a ``max_seq``
+# no other test uses: ``_engine_step``'s trace is cached a configuration.
+
+def _force_kernel(monkeypatch):
+    from akka_allreduce_tpu.models import generate as G
+    from akka_allreduce_tpu.ops.pallas_kernels.attention import (
+        pick_latent_tiling)
+    monkeypatch.setattr(G, "latent_decode_path", lambda pos, latent: (
+        None if pos.ndim != 1 else
+        (True, pick_latent_tiling(*latent.shape[1:], latent.dtype))))
+
+
+def _all_eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _all_eqns(sub)
+
+
+def _serve_two(cfg, params, n_new, sink=None, tracer=None):
+    """A 100-token prompt (so its answer crosses the first 128-position
+    key block), a short one beside it, two lanes parked: every request's
+    tokens, and the logits each of rid 1's tokens was picked from."""
+    e = eng.ServingEngine(params, cfg, eng.EngineConfig(
+        num_slots=4, prefill_buckets=(16, 128)), metrics=sink,
+        tracer=tracer)
+    long_slot = e.admit(Request(rid=1, prompt=tuple(_tokens(100, 3)),
+                                max_new_tokens=n_new, submitted_at=0.0))
+    e.admit(Request(rid=2, prompt=tuple(_tokens(9, 4)),
+                    max_new_tokens=n_new, submitted_at=0.0))
+    rows, toks, uploaded = [], {}, []
+    for _ in range(n_new):
+        rows.append(np.asarray(e._state["logits"][long_slot], np.float32))
+        uploaded.append(e._pos.copy())
+        for _s, req, emitted, _why in e.step():
+            toks[req.rid] = list(emitted)
+    e.close()
+    return toks, np.stack(rows), uploaded
+
+
+def test_the_kernel_serves_the_tokens_the_formula_serves(monkeypatch):
+    cfg, params = _model(max_seq=256)
+    want_toks, want_rows, _ = _serve_two(cfg, params, 40)
+    cfg, params = _model(max_seq=384)      # block 128 either way
+    ref_toks, ref_rows, _ = _serve_two(cfg, params, 40)
+    assert ref_toks == want_toks           # max_seq alone changes nothing
+    _force_kernel(monkeypatch)
+    cfg, params = _model(max_seq=640)
+    got_toks, got_rows, _ = _serve_two(cfg, params, 40)
+    assert got_toks == want_toks and len(got_toks[1]) == 40
+    assert np.abs(got_rows - want_rows).max() <= F32_TOL
+
+
+def test_one_kernel_an_attention_under_its_scope_and_none_elsewhere(
+        monkeypatch):
+    _force_kernel(monkeypatch)
+    cfg, params = _model(max_seq=896)
+    e = _engine(cfg, params)
+    step = jax.make_jaxpr(
+        lambda p, s, pos: eng._engine_step.__wrapped__(p, s, pos, cfg))(
+        params, e._state, jnp.asarray(e._pos))
+    pre = str(jax.make_jaxpr(
+        lambda p, s, t: eng._engine_prefill.__wrapped__(
+            p, s, t, jnp.asarray(5), jnp.asarray(0), cfg, True))(
+        params, e._state, jnp.zeros((1, 8), jnp.int32)))
+    e.close()
+    scopes = [str(eqn.source_info.name_stack)
+              for eqn in _all_eqns(step.jaxpr)
+              if eqn.primitive.name == "pallas_call"]
+    assert len(scopes) == 2 * cfg.n_layers      # one an attention
+    assert all("mla_attention" in sc for sc in scopes), scopes
+    assert "pallas_call" not in pre        # the prefill expands its keys
+    dense = TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
+                              n_layers=1, d_ff=64, max_seq=16, rope=True,
+                              ffn="swiglu")
+    dp = init_transformer(jax.random.key(0), dense)
+    d = eng.ServingEngine(dp, dense, eng.EngineConfig(num_slots=2))
+    dense_jaxpr = str(jax.make_jaxpr(
+        lambda p, s, pos: eng._engine_step.__wrapped__(p, s, pos, dense))(
+        dp, d._state, jnp.asarray(d._pos)))
+    d.close()
+    assert "pallas_call" not in dense_jaxpr
+
+
+def test_the_choice_is_said_once(monkeypatch, capfd):
+    from akka_allreduce_tpu.ops.pallas_kernels import dispatch
+    monkeypatch.setattr(dispatch, "_said", set())
+    cfg, params = _model(max_seq=1152)
+    with _engine(cfg, params) as e:
+        e.admit(Request(rid=1, prompt=tuple(_tokens(9)), max_new_tokens=2))
+        e.step()
+    lines = [ln for ln in capfd.readouterr().err.splitlines()
+             if ln.startswith("attention[latent_decode]")]
+    assert len(lines) == 1 and "reference:_latent_attention" in lines[0]
+    _force_kernel(monkeypatch)
+    cfg, params = _model(max_seq=1408)
+    with _engine(cfg, params) as e:
+        e.admit(Request(rid=1, prompt=tuple(_tokens(9)), max_new_tokens=3))
+        e.step()
+        e.step()
+    lines = [ln for ln in capfd.readouterr().err.splitlines()
+             if ln.startswith("attention[latent_decode]")]
+    assert len(lines) == 1, lines          # four call sites, one line
+    assert "interpret:latent_decode_attention" in lines[0]
+    assert "group=4 blk=128" in lines[0]
+
+
+def test_the_key_blocks_read_and_skipped_are_counted_from_pos(monkeypatch):
+    from akka_allreduce_tpu.runtime import tracing as T
+    from akka_allreduce_tpu.serving.metrics import ServingMetrics
+    _force_kernel(monkeypatch)
+    cfg, params = _model(max_seq=640)      # five key blocks of 128 a lane
+    tracer, m = T.Tracer(), ServingMetrics()
+    _toks, _rows, uploaded = _serve_two(cfg, params, 40, sink=m,
+                                        tracer=tracer)
+    steps = [ev for ev in tracer.events if ev.kind == T.SERVE_STEP]
+    assert len(steps) == len(uploaded) == 40
+    attentions = 2 * cfg.n_layers
+    live = skipped = 0
+    for ev, pos in zip(steps, uploaded):
+        want = attentions * int(sum(p // 128 + 1 for p in pos))
+        assert ev.fields[T.KV_BLOCKS_LIVE] == want
+        assert ev.fields[T.KV_BLOCKS_SKIPPED] == attentions * 4 * 5 - want
+        live, skipped = live + want, skipped + attentions * 20 - want
+    # a step before the long request crosses position 128, and one after
+    assert steps[0].fields[T.KV_BLOCKS_LIVE] == attentions * 4
+    assert steps[-1].fields[T.KV_BLOCKS_LIVE] == attentions * 5
+    assert (m.kv_blocks_live, m.kv_blocks_skipped) == (live, skipped)
+    assert m.summary()["kv_blocks"] == {
+        "live": live, "skipped": skipped,
+        "skipped_share": round(skipped / (live + skipped), 4)}
+    text = m.registry.to_prometheus_text()
+    assert f'serve_kv_blocks_total{{kind="live"}} {live}' in text
+    assert f'serve_kv_blocks_total{{kind="skipped"}} {skipped}' in text
+    assert set(T.SPAN_FIELDS[T.SERVE_STEP]) == {
+        T.KV_BLOCKS_LIVE, T.KV_BLOCKS_SKIPPED}
+
+
+def test_on_the_formulas_path_no_key_block_is_counted():
+    from akka_allreduce_tpu.runtime import tracing as T
+    from akka_allreduce_tpu.serving.metrics import ServingMetrics
+    cfg, params = _model()
+    tracer, m = T.Tracer(), ServingMetrics()
+    e = eng.ServingEngine(params, cfg, eng.EngineConfig(
+        num_slots=4, prefill_buckets=(16,)), metrics=m, tracer=tracer)
+    e.admit(Request(rid=1, prompt=tuple(_tokens(9)), max_new_tokens=2,
+                    submitted_at=0.0))
+    e.step()
+    e.close()
+    step = [ev for ev in tracer.events if ev.kind == T.SERVE_STEP][0]
+    assert step.fields[T.KV_BLOCKS_LIVE] == 0
+    assert step.fields[T.KV_BLOCKS_SKIPPED] == 0
+    assert "kv_blocks" not in m.summary()
+    assert "serve_kv_blocks" not in m.registry.to_prometheus_text()
+
+
 # -- what cannot run the new kinds refuses them ------------------------------
 
 def _dense_draft():

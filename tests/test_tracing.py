@@ -190,6 +190,12 @@ class TestSpanPrimitive:
                 T.SCOPES.values()):
             assert layer and metric
 
+    def test_span_fields_of_another_layer_have_their_rows(self):
+        assert set(T.SPAN_FIELDS) <= set(T.SPANS)
+        assert T.SPAN_FIELDS[T.SERVE_STEP] == {
+            "kv_blocks_live": (T.SCOPES[T.SCOPE_MLA_ATTENTION][0], "-"),
+            "kv_blocks_skipped": (T.SCOPES[T.SCOPE_MLA_ATTENTION][0], "-")}
+
 
 # -- the engine's phases ----------------------------------------------------
 
