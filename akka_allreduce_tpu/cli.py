@@ -2167,6 +2167,13 @@ def _add_serve(sub: argparse._SubParsersAction) -> None:
                         "program count); empty = one exact-length "
                         "program per distinct prompt length (the "
                         "bitwise-parity mode)")
+    p.add_argument("--prefill-chunk", type=int, default=0,
+                   help="send a prompt longer than the largest prefill "
+                        "bucket through the cache in chunks of this many "
+                        "positions, one compiled program run once a chunk "
+                        "(the in-process slot engine, for a --model-config "
+                        "whose attention reads an indexer's selection; "
+                        "must divide --max-seq; 0 = off)")
     # -- sampling + speculative decode (ISSUE 10)
     p.add_argument("--temperature", type=float, default=0.0,
                    help="sampling temperature for every decode pick: "
@@ -4813,7 +4820,7 @@ def _cmd_serve(args: argparse.Namespace) -> int:
         print(f"error: bad --prefill-buckets "
               f"{args.prefill_buckets!r}", file=sys.stderr)
         return 2
-    if buckets and max(buckets) < p_hi:
+    if buckets and max(buckets) < p_hi and not args.prefill_chunk:
         print(f"error: largest prefill bucket {max(buckets)} smaller "
               f"than --prompt-len max {p_hi}", file=sys.stderr)
         return 2
@@ -5009,6 +5016,7 @@ def _cmd_serve(args: argparse.Namespace) -> int:
                 from akka_allreduce_tpu.serving import SpeculativeEngine
                 ecfg = EngineConfig(
                     num_slots=args.slots, prefill_buckets=buckets,
+                    prefill_chunk=args.prefill_chunk,
                     kv_dtype="int8" if args.kv_cache == "int8"
                     else None,
                     decode_steps=args.decode_steps,

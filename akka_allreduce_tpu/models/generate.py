@@ -53,7 +53,16 @@ from akka_allreduce_tpu.runtime.tracing import (
     SCOPE_ATTENTION,
     SCOPE_DENSE_FFN,
     SCOPE_MLA_ATTENTION,
+    SCOPE_SPARSE_INDEXER,
 )
+
+# the eps of the index key's LayerNorm (the source family's; no key of a
+# config.json states it)
+INDEX_NORM_EPS = 1e-6
+# query rows that score the index keys, choose and attend at once: what
+# bounds a prefill chunk's temporaries (scores of rows x index heads x
+# max_seq in f32, rows x index_topk gathered latents)
+QUERY_ROWS = 128
 
 
 def init_kv_cache(cfg: TransformerConfig, batch: int,
@@ -83,10 +92,17 @@ def init_kv_cache(cfg: TransformerConfig, batch: int,
             raise NotImplementedError(
                 f"kv_dtype={kv_dtype!r}: the latent cache has no "
                 f"quantized format (missing: a scale a cached latent and "
-                f"its dequantize-on-read in `_latent_attention`)")
-        return {"latent": jnp.zeros((2 * cfg.n_layers, batch, cfg.max_seq,
-                                     cfg.latent_dim), cfg.dtype),
-                "pos": jnp.zeros((), jnp.int32)}
+                f"its dequantize-on-read in `_latent_attention`; where an "
+                f"indexer chooses, a scale an index key too)")
+        cache = {"latent": jnp.zeros((cfg.n_attentions, batch, cfg.max_seq,
+                                      cfg.latent_row), cfg.dtype),
+                 "pos": jnp.zeros((), jnp.int32)}
+        if cfg.layerwise:
+            # one index key a token for each layer that has an indexer
+            cache["index_k"] = jnp.zeros(
+                (len(cfg.full_layers), batch, cfg.max_seq,
+                 cfg.index_head_dim), cfg.dtype)
+        return cache
     shape = (cfg.n_layers, batch, cfg.max_seq, cfg.kv_heads, cfg.head_dim)
     if kv_dtype is None:
         return {
@@ -131,8 +147,9 @@ def init_kv_pool(cfg: TransformerConfig, num_pages: int, page_size: int,
     if cfg.new_kind is not None:
         raise NotImplementedError(
             f"the paged pool cannot hold {cfg.new_kind} (missing: a latent "
-            f"page and cached-block functions that read it through the "
-            f"page table)")
+            f"page, an index-key page where an indexer chooses, and "
+            f"cached-block functions that read them through the page "
+            f"table)")
     shape = (cfg.n_layers, num_pages, page_size, cfg.kv_heads,
              cfg.head_dim)
     if kv_dtype is None:
@@ -309,14 +326,22 @@ class CacheOps:
     the block's fresh keys); a scalar for one decode position of the whole
     batch; (b,) for a position a row (the slot engine). ``write_mask``
     (b,) freezes rows' cache writes (per-row positions only). ``counted``
-    (b*t,) bool: the tokens an expert layer's counts see (None: all)."""
+    (b*t,) bool: the tokens an expert layer's counts see (None: all).
+    ``offset`` and ``lane`` (scalars, with ``pos`` None and b = 1) make
+    the prefill a CHUNK: positions offset..offset+t-1 of cache lane
+    ``lane``, which holds the positions before them (the layer-by-layer
+    kind only: its queries attend through the cache)."""
     pos: Optional[jnp.ndarray] = None
     write_mask: Optional[jnp.ndarray] = None
     counted: Optional[jnp.ndarray] = None
+    offset: Optional[jnp.ndarray] = None
+    lane: Optional[jnp.ndarray] = None
 
     def rope(self, x: jnp.ndarray, theta: float) -> jnp.ndarray:
         if self.pos is None:
-            return apply_rope(x, jnp.arange(x.shape[1]), theta)
+            at = jnp.arange(x.shape[1])
+            return apply_rope(
+                x, at if self.offset is None else self.offset + at, theta)
         if self.pos.ndim == 0:
             return apply_rope(x, self.pos[None], theta)
         return _rope_slots(x, self.pos, theta)
@@ -328,8 +353,27 @@ class CacheOps:
             return _write_slot_rows(buf, i, vals[:, 0], self.pos,
                                     self.write_mask)
         at = 0 if self.pos is None else self.pos
+        if self.offset is not None:
+            at = self.offset
         return lax.dynamic_update_slice(
-            buf, vals[None], (i, 0, at) + (0,) * (vals.ndim - 2))
+            buf, vals[None], (i, 0 if self.lane is None else self.lane, at)
+            + (0,) * (vals.ndim - 2))
+
+    def positions(self, b: int, t: int) -> jnp.ndarray:
+        """The (b, t) positions of the block's tokens."""
+        if self.pos is None:
+            at = jnp.arange(t, dtype=jnp.int32)
+            if self.offset is not None:
+                at = self.offset + at
+            return jnp.broadcast_to(at, (b, t))
+        return jnp.broadcast_to(self.pos, (b,))[:, None].astype(jnp.int32)
+
+    def lane_rows(self, buf: jnp.ndarray, i: int) -> jnp.ndarray:
+        """``buf[i]`` as the block's batch sees it: (b, max_seq, ...), a
+        chunk's one lane alone."""
+        if self.lane is None:
+            return buf[i]
+        return lax.dynamic_index_in_dim(buf[i], self.lane, 0)
 
 
 def _dense_cached_block(layer: dict, x: jnp.ndarray, kv: dict, i: int,
@@ -545,17 +589,207 @@ def _shortcut_cached_block(layer: dict, x: jnp.ndarray, kv: dict, i: int,
     return x + ffn(layer["ffn"][1], h) + m.reshape(b, t, d), kv, counts
 
 
+def _over_query_rows(fn, *arrays):
+    """``fn`` over arrays (b, t, ...) -> (b, t, ...), at most
+    :data:`QUERY_ROWS` of the t query rows at a time (one after the other:
+    a chunk's temporaries are one block's)."""
+    t = arrays[0].shape[1]
+    if t <= QUERY_ROWS:
+        return fn(*arrays)
+    blocks = -(-t // QUERY_ROWS)
+    pad = blocks * QUERY_ROWS - t
+
+    def split(a):
+        a = jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+        return jnp.moveaxis(a.reshape(
+            (a.shape[0], blocks, QUERY_ROWS) + a.shape[2:]), 1, 0)
+    out = lax.map(lambda xs: fn(*xs), tuple(split(a) for a in arrays))
+    out = jnp.moveaxis(out, 0, 1)
+    return out.reshape((out.shape[0], blocks * QUERY_ROWS)
+                       + out.shape[3:])[:, :t]
+
+
+def _layernorm(x: jnp.ndarray, gain: jnp.ndarray, bias: jnp.ndarray,
+               eps: float) -> jnp.ndarray:
+    """LayerNorm with a gain and a bias, statistics in f32 (rmsnorm's
+    precision rule)."""
+    xf = x.astype(jnp.float32)
+    mean = jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(xf - mean), axis=-1, keepdims=True)
+    return ((xf - mean) * lax.rsqrt(var + eps)).astype(x.dtype) * gain + bias
+
+
+def _rope_first(x: jnp.ndarray, n: int, ops: CacheOps,
+                theta: float) -> jnp.ndarray:
+    """RoPE on the first ``n`` of x's (b, t, heads, d) last axis."""
+    return jnp.concatenate([ops.rope(x[..., :n], theta), x[..., n:]],
+                           axis=-1)
+
+
+def _index_select(p: dict, c_q: jnp.ndarray, h: jnp.ndarray, kv: dict,
+                  f: int, cfg: TransformerConfig, ops: CacheOps,
+                  positions: jnp.ndarray):
+    """A full layer's indexer over h (b, t, d) and the query's bottleneck
+    c_q: writes the tokens' index keys into ``kv["index_k"][f]``, scores
+    every cached position of each token's lane, ``I[t, s] = sum_h w[t, h]
+    relu(q[t, h] . k[s])``, and picks the ``index_topk`` best among the
+    positions at or before the token's own. Returns (chosen (b, t, k)
+    int32 positions, kv). Where fewer than k positions are live the rest
+    of ``chosen`` lie past the token's position: whoever attends holds
+    ``chosen <= position`` to be the live ones. Exact: ``lax.top_k``, no
+    approximation."""
+    b, t, _ = h.shape
+    heads, hd = cfg.index_n_heads, cfg.index_head_dim
+    kv = dict(kv)
+    q = _rope_first((c_q @ p["wq_b"]).reshape(b, t, heads, hd),
+                    cfg.qk_rope_head_dim, ops, cfg.rope_theta)
+    k = _layernorm(h @ p["wk"], p["k_norm"], p["k_bias"], INDEX_NORM_EPS)
+    k = _rope_first(k[:, :, None], cfg.qk_rope_head_dim, ops,
+                    cfg.rope_theta)[:, :, 0]
+    kv["index_k"] = ops.write(kv["index_k"], f,
+                              k.astype(kv["index_k"].dtype))
+    w = (h @ p["ww"]).astype(jnp.float32) * (heads ** -0.5 * hd ** -0.5)
+    keys = ops.lane_rows(kv["index_k"], f)           # (b, max_seq, hd)
+    n_keys = keys.shape[1]
+    top = min(cfg.index_topk, n_keys)
+
+    def choose(q, w, positions):
+        scores = jnp.einsum("bthd,bsd->bths", q, keys,
+                            preferred_element_type=jnp.float32)
+        scores = jnp.einsum("bths,bth->bts", jax.nn.relu(scores), w)
+        live = jnp.arange(n_keys)[None, None, :] <= positions[:, :, None]
+        scores = jnp.where(live, scores, -jnp.inf)
+        # the top-k over a matrix of rows: as (b, 1, max_seq) the chip lays
+        # one row a tile and the decode step's sort takes 4.8 ms where
+        # this takes under 2 (chip runs, PR 31)
+        rows = scores.reshape(-1, n_keys)
+        return lax.top_k(rows, top)[1].reshape(scores.shape[:2] + (top,))
+
+    return _over_query_rows(choose, q, w, positions).astype(jnp.int32), kv
+
+
+def _selected_latent_attention(q: jnp.ndarray, latent: jnp.ndarray, a: int,
+                               lanes: jnp.ndarray, chosen: jnp.ndarray,
+                               positions: jnp.ndarray, rank: int,
+                               scale: float) -> jnp.ndarray:
+    """Attention over the CHOSEN rows of the latent cache and no other:
+    q (b, t, h, rank + rope) (the absorbed query of
+    :func:`_latent_attention`), ``latent`` the whole cache (attentions,
+    lanes, max_seq, row), batch row i reading lane ``lanes[i]`` of
+    attention ``a`` at ``chosen`` (b, t, k); of those the positions past
+    the token's own are not attended. The rows are gathered (k of them a
+    query, whatever the lane holds) and the softmax runs over them. The
+    gather takes rows of the cache seen as one table of positions (a
+    bitcast): on the v5e 1.0 ms for 32 lanes x 2,048 rows of 640 where
+    the same gather through a four-axis index takes 1.6 (chip run, PR
+    31). Returns (b, t, h, rank)."""
+    width = q.shape[-1]
+    _n_a, n_lanes, n_seq, row = latent.shape
+    table = latent.reshape(-1, row)
+    base = ((a * n_lanes + lanes) * n_seq)[:, None, None]
+
+    def attend(q, chosen, positions):
+        # (b, t, k, row); every index lies in the table (a position is
+        # < max_seq), so no pass over the rows to blank the ones that do not
+        rows = table.at[base + chosen].get(mode="promise_in_bounds")
+        # gathered ONCE for the scores and the weighted sum: left to itself
+        # the compiler gathers the rows a second time for the second matmul
+        # sooner than keep them (0.85 ms more a layer of a decode step, 64
+        # ms more a layer of a chunk; chip runs, PR 31)
+        rows = lax.optimization_barrier(rows)
+        scores = jnp.einsum("bthc,btkc->bthk", q, rows[..., :width],
+                            preferred_element_type=jnp.float32) * scale
+        valid = chosen <= positions[:, :, None]
+        scores = jnp.where(valid[:, :, None, :], scores, NEG_INF)
+        p = jax.nn.softmax(scores, axis=-1)
+        out = jnp.einsum("bthk,btkc->bthc", p.astype(rows.dtype), rows,
+                         preferred_element_type=jnp.float32)
+        return out[..., :rank].astype(q.dtype)
+
+    return _over_query_rows(attend, q, chosen, positions)
+
+
+def _layerwise_cached_block(layer: dict, x: jnp.ndarray, kv: dict, i: int,
+                            cfg: TransformerConfig, ops: CacheOps,
+                            chosen: "jnp.ndarray | None"):
+    """One layer of the model described layer by layer: latent attention
+    over the positions an indexer chose, then a dense SwiGLU or the expert
+    share with its shared expert. A full layer (``layer["indexer"]``)
+    chooses; a shared layer attends what ``chosen`` hands it from the
+    nearest full layer before. A prefill (of a whole prompt, or of a
+    chunk behind what the cache holds) and a decode step are the same
+    function of the cache: every token's keys are written first, then
+    each token chooses among and attends the cache's rows at or before
+    its own position. Returns (x, kv, the expert layer's counts or None,
+    chosen)."""
+    b, t, d = x.shape
+    p = layer["mla"]
+    heads, rank = cfg.n_heads, cfg.kv_lora_rank
+    nope, vd = cfg.qk_nope_head_dim, cfg.v_head_dim
+    s_q, s_kv = cfg.mla_scales
+    positions = ops.positions(b, t)
+    lanes = (jnp.arange(b) if ops.lane is None
+             else jnp.reshape(ops.lane, (1,)))
+    kv = dict(kv)
+    with jax.named_scope(SCOPE_MLA_ATTENTION):
+        h = rmsnorm(x, p["ln"], cfg.norm_eps)
+        c_q = rmsnorm(h @ p["wq_a"], p["q_norm"], cfg.norm_eps)
+        q = ((c_q @ p["wq_b"]) * s_q).reshape(b, t, heads, -1)
+        q_rope = ops.rope(q[..., nope:], cfg.rope_theta)
+        down = h @ p["wkv_a"]
+        c_kv = rmsnorm(down[..., :rank], p["kv_norm"], cfg.norm_eps) * s_kv
+        k_rope = ops.rope(down[:, :, None, rank:], cfg.rope_theta)
+        row = jnp.concatenate([c_kv, k_rope[:, :, 0]], axis=-1)
+        row = jnp.pad(row, ((0, 0), (0, 0),
+                            (0, kv["latent"].shape[-1] - row.shape[-1])))
+        kv["latent"] = ops.write(kv["latent"], i,
+                                 row.astype(kv["latent"].dtype))
+    if "indexer" in layer:
+        with jax.named_scope(SCOPE_SPARSE_INDEXER):
+            chosen, kv = _index_select(
+                layer["indexer"], c_q, h, kv, cfg.full_layers.index(i), cfg,
+                ops, positions)
+    with jax.named_scope(SCOPE_MLA_ATTENTION):
+        up = p["wkv_b"].reshape(rank, heads, nope + vd)
+        q_lat = jnp.concatenate(
+            [jnp.einsum("bthn,rhn->bthr", q[..., :nope], up[..., :nope]),
+             q_rope], axis=-1)
+        say_attention("sparse_latent", "reference:_selected_latent_attention",
+                      q_lat, chosen=chosen.shape[-1],
+                      of=kv["latent"].shape[2])
+        out_lat = _selected_latent_attention(
+            q_lat, kv["latent"], i, lanes, chosen, positions, rank,
+            (nope + q_rope.shape[-1]) ** -0.5)
+        out = jnp.einsum("bthr,rhv->bthv", out_lat, up[..., nope:])
+        x = x + out.reshape(b, t, heads * vd) @ p["wo"]
+    h = rmsnorm(x, layer["ln2"], cfg.norm_eps)
+    if "moe" in layer:
+        m, counts = dropless_moe(h.reshape(b * t, d), layer["moe"],
+                                 cfg.experts, ops.counted)
+        return x + m.reshape(b, t, d), kv, counts, chosen
+    with jax.named_scope(SCOPE_DENSE_FFN):
+        x = x + (jax.nn.silu(h @ layer["w1"])
+                 * (h @ layer["w3"])) @ layer["w2"]
+    return x, kv, None, chosen
+
+
 def cached_blocks(params: dict, x: jnp.ndarray, kv: dict,
                   cfg: TransformerConfig, ops: CacheOps):
     """Every block of the model over x (b, t, d) through the cache:
     (x, kv, counts). ``counts`` is None for the dense kind; for the
-    shortcut kind the expert layers' counts summed over the layers
-    (``held`` and ``identity`` a token, (b*t,); ``touched`` a scalar)."""
+    shortcut and the layer-by-layer kinds the expert layers' counts summed
+    over the layers (``held`` and ``identity`` a token, (b*t,);
+    ``touched`` a scalar). The layer-by-layer kind's selection goes from
+    a full layer to the shared layers after it here."""
     block = (_shortcut_cached_block if cfg.block == "shortcut"
              else _dense_cached_block)
-    total = None
+    total = chosen = None
     for i, layer in enumerate(params["layers"]):
-        x, kv, counts = block(layer, x, kv, i, cfg, ops)
+        if cfg.layerwise:
+            x, kv, counts, chosen = _layerwise_cached_block(
+                layer, x, kv, i, cfg, ops, chosen)
+        else:
+            x, kv, counts = block(layer, x, kv, i, cfg, ops)
         if counts is not None:
             total = counts if total is None else jax.tree.map(
                 jnp.add, total, counts)

@@ -90,6 +90,16 @@ class TransformerConfig:
     #   (``experts``, parallel/ep.py ``dropless_moe``) read from the first
     #   half's post-attention norm and added at the end of the second
     #   half. ``n_layers`` counts double layers.
+    # * block = "standard" with attention = "mla" is the model described
+    #   LAYER BY LAYER: ``layer_ffn[i]`` says whether layer i's FFN is a
+    #   "dense" SwiGLU of ``d_ff`` or "sparse" (``experts``, with its
+    #   shared expert), ``layer_indexer[i]`` whether its attention has an
+    #   indexer of its own ("full": ``index_n_heads`` heads of
+    #   ``index_head_dim`` score every cached position and the token
+    #   attends the ``index_topk`` best) or attends the set the nearest
+    #   full layer before it chose ("shared"). The cache holds a latent a
+    #   layer and an index key a FULL layer. ``mla_lora_scales`` False
+    #   runs the latent attention without its two lora scales.
     attention: str = "gqa"
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
@@ -98,6 +108,12 @@ class TransformerConfig:
     v_head_dim: int = 0
     block: str = "standard"
     experts: Optional[ExpertShareConfig] = None
+    layer_ffn: tuple = ()
+    layer_indexer: tuple = ()
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    mla_lora_scales: bool = True
 
     @property
     def head_dim(self) -> int:
@@ -109,11 +125,55 @@ class TransformerConfig:
         return self.kv_lora_rank + self.qk_rope_head_dim
 
     @property
+    def latent_row(self) -> int:
+        """Columns of a cached latent's row: ``latent_dim``, and for the
+        layer-by-layer kind that rounded up to whole vector registers of
+        128 (zeros), so that the device keeps a position's row contiguous
+        and a gather of chosen positions reads those rows alone. (At
+        ``latent_dim`` 576 the device would keep ``max_seq`` minor, as
+        ops/pallas_kernels/attention.py ``latent_keys_lie_minor`` finds
+        for the double layer's cache, whose fused kernel reads it so: a
+        gather of rows from that layout touches every tile of the
+        lane.)"""
+        if not self.layerwise:
+            return self.latent_dim
+        return -(-self.latent_dim // 128) * 128
+
+    @property
     def mla_scales(self) -> tuple[float, float]:
         """The two lora scales, (d_model / rank) ** 0.5 each: on the
-        query after its up-projection, on the latent after its norm."""
+        query after its up-projection, on the latent after its norm
+        (1, 1 where the configuration has none)."""
+        if not self.mla_lora_scales:
+            return (1.0, 1.0)
         return ((self.d_model / self.q_lora_rank) ** 0.5,
                 (self.d_model / self.kv_lora_rank) ** 0.5)
+
+    @property
+    def layerwise(self) -> bool:
+        """Is this the model described layer by layer (latent attention
+        over an indexer's selection in a standard block)?"""
+        return self.block == "standard" and self.attention == "mla"
+
+    @property
+    def n_attentions(self) -> int:
+        """Latent attentions, each with a cache entry of its own."""
+        return 2 * self.n_layers if self.block == "shortcut" \
+            else self.n_layers
+
+    @property
+    def full_layers(self) -> tuple:
+        """The layers that have an indexer, in order: layer
+        ``full_layers[f]`` writes index cache entry ``f``."""
+        return tuple(i for i, kind in enumerate(self.layer_indexer)
+                     if kind == "full")
+
+    @property
+    def n_expert_layers(self) -> int:
+        """Expert layers (``experts``) a token passes."""
+        if self.layerwise:
+            return sum(kind == "sparse" for kind in self.layer_ffn)
+        return self.n_layers if self.experts is not None else 0
 
     @property
     def kv_heads(self) -> int:
@@ -161,18 +221,60 @@ class TransformerConfig:
                 raise ValueError(
                     "mla runs with rope, without a window and without "
                     "n_kv_heads (its keys come from the latent)")
-        if (self.block == "shortcut") != (self.attention == "mla") \
-                or (self.block == "shortcut") != (self.experts is not None):
+        if self.block == "shortcut" and (
+                self.attention != "mla" or self.experts is None):
             raise ValueError(
-                "latent attention and an `experts` share come with the "
-                "shortcut double layer and it with them: mla in a standard "
-                "block is not implemented")
+                "the shortcut double layer comes with latent attention "
+                "and an `experts` share")
+        if self.experts is not None and self.attention != "mla":
+            raise ValueError(
+                "an `experts` share comes with latent attention (the "
+                "shortcut double layer, or layer by layer)")
+        described = (self.layer_ffn, self.layer_indexer, self.index_n_heads,
+                     self.index_head_dim, self.index_topk)
+        if self.layerwise:
+            self._check_layerwise()
+        elif any(described) or not self.mla_lora_scales:
+            raise ValueError(
+                f"layer_ffn / layer_indexer / index_* / mla_lora_scales "
+                f"describe latent attention in a standard block "
+                f"(block='standard', attention='mla'), got block="
+                f"{self.block!r} attention={self.attention!r}")
         if self.block == "shortcut" and (
                 self.ffn != "swiglu" or self.moe is not None
                 or self.tie_embeddings):
             raise ValueError(
                 "the shortcut double layer has swiglu dense FFNs and an "
                 "untied head, and its expert layer is `experts`, not `moe`")
+
+    def _check_layerwise(self) -> None:
+        n = self.n_layers
+        if len(self.layer_ffn) != n or len(self.layer_indexer) != n \
+                or set(self.layer_ffn) - {"dense", "sparse"} \
+                or set(self.layer_indexer) - {"full", "shared"}:
+            raise ValueError(
+                f"latent attention in a standard block is described layer "
+                f"by layer: layer_ffn ('dense' | 'sparse') and "
+                f"layer_indexer ('full' | 'shared'), {n} entries each, got "
+                f"{self.layer_ffn} and {self.layer_indexer}")
+        if self.layer_indexer[0] != "full":
+            raise ValueError("layer 0 shares an indexer's choice and no "
+                             "layer before it has one")
+        if min(self.index_n_heads, self.index_topk) < 1 \
+                or self.index_head_dim < self.qk_rope_head_dim:
+            raise ValueError(
+                f"the indexer needs index_n_heads, index_topk >= 1 and "
+                f"index_head_dim >= qk_rope_head_dim, got "
+                f"{self.index_n_heads}, {self.index_topk}, "
+                f"{self.index_head_dim}")
+        if ("sparse" in self.layer_ffn) != (self.experts is not None):
+            raise ValueError("`experts` is the sparse layers' share: set "
+                             "where a layer is 'sparse', and only there")
+        if self.ffn != "swiglu" or self.moe is not None \
+                or self.tie_embeddings:
+            raise ValueError(
+                "the layer-by-layer model has swiglu dense FFNs and an "
+                "untied head, and its expert layers are `experts`")
 
     @property
     def new_kind(self) -> Optional[str]:
@@ -181,6 +283,9 @@ class TransformerConfig:
         if self.block == "shortcut":
             return ("the shortcut double layer with latent attention "
                     "(block='shortcut', attention='mla')")
+        if self.layerwise:
+            return ("latent attention over an indexer's selection, layer "
+                    "by layer (block='standard', attention='mla')")
         return None
 
 
@@ -301,7 +406,9 @@ def init_mla(key: jax.Array, cfg: TransformerConfig) -> dict:
     k = jax.random.split(key, 5)
 
     def up(key, rank, width):
-        return jax.random.normal(key, (rank, width), dt) * d ** -0.5
+        # without the scales: fan-in, like every other matrix
+        std = (d if cfg.mla_lora_scales else rank) ** -0.5
+        return jax.random.normal(key, (rank, width), dt) * std
     return {
         "ln": jnp.ones((d,), dt),
         "wq_a": _normal(k[0], (d, cfg.q_lora_rank), dt),
@@ -315,10 +422,30 @@ def init_mla(key: jax.Array, cfg: TransformerConfig) -> dict:
     }
 
 
+def init_indexer(key: jax.Array, cfg: TransformerConfig) -> dict:
+    """A full layer's indexer: ``wq_b`` (the query's bottleneck ->
+    ``index_n_heads`` heads of ``index_head_dim``), ``wk`` (hidden -> the
+    one index key a token), ``k_norm`` / ``k_bias`` (the key's LayerNorm)
+    and ``ww`` (hidden -> a weight a head)."""
+    d, dt = cfg.d_model, cfg.dtype
+    k = jax.random.split(key, 3)
+    return {
+        "wq_b": _normal(k[0], (cfg.q_lora_rank,
+                               cfg.index_n_heads * cfg.index_head_dim), dt),
+        "wk": _normal(k[1], (d, cfg.index_head_dim), dt),
+        "k_norm": jnp.ones((cfg.index_head_dim,), dt),
+        "k_bias": jnp.zeros((cfg.index_head_dim,), dt),
+        "ww": _normal(k[2], (d, cfg.index_n_heads), dt),
+    }
+
+
 def _init_new_kind(key: jax.Array, cfg: TransformerConfig) -> dict:
     """The tree of shortcut double layers: ``layers[i]`` holds ``mla``
     and ``ffn`` (two of each; an FFN's ``ln`` is its half's post-attention
-    norm) and ``moe`` (parallel/ep.py ``init_expert_share``)."""
+    norm) and ``moe`` (parallel/ep.py ``init_expert_share``). Of the
+    layer-by-layer model: ``mla`` (one), ``indexer`` in a full layer,
+    ``ln2`` and either the dense FFN's ``w1`` / ``w3`` / ``w2`` or
+    ``moe``."""
     d, dt = cfg.d_model, cfg.dtype
     kg = iter(jax.random.split(key, 2 + 8 * cfg.n_layers))
 
@@ -333,11 +460,23 @@ def _init_new_kind(key: jax.Array, cfg: TransformerConfig) -> dict:
               * d ** -0.5,
               "lm_head": _normal(next(kg), (d, cfg.vocab_size), dt),
               "out_norm": jnp.ones((d,), dt), "layers": []}
-    for _ in range(cfg.n_layers):
-        params["layers"].append({
-            "mla": [init_mla(next(kg), cfg) for _ in range(2)],
-            "ffn": [ffn() for _ in range(2)],
-            "moe": init_expert_share(next(kg), d, cfg.experts, dt)})
+    for i in range(cfg.n_layers):
+        if not cfg.layerwise:
+            params["layers"].append({
+                "mla": [init_mla(next(kg), cfg) for _ in range(2)],
+                "ffn": [ffn() for _ in range(2)],
+                "moe": init_expert_share(next(kg), d, cfg.experts, dt)})
+            continue
+        layer = {"mla": init_mla(next(kg), cfg)}
+        if cfg.layer_indexer[i] == "full":
+            layer["indexer"] = init_indexer(next(kg), cfg)
+        if cfg.layer_ffn[i] == "sparse":
+            layer["ln2"] = jnp.ones((d,), dt)
+            layer["moe"] = init_expert_share(next(kg), d, cfg.experts, dt)
+        else:
+            dense = ffn()
+            layer.update(ln2=dense.pop("ln"), **dense)
+        params["layers"].append(layer)
     return params
 
 
@@ -346,18 +485,23 @@ def config_from_hf(hf: dict, max_seq: int, dtype=jnp.bfloat16,
                    ) -> TransformerConfig:
     """A :class:`TransformerConfig` from a published ``config.json`` (or a
     benchmark configuration file that keeps its keys): the one place that
-    knows the key names. ``attention_method`` "MLA" with ``zero_expert_num``
-    is the shortcut double layer with latent attention, the one family
-    read so far (the dense block is built from the ``--d-model/...``
-    flags). ``experts_held`` = (offset, count) of the
-    ``n_routed_experts`` real experts that this chip holds (default: the
-    key ``experts_held`` of ``hf``, the one key that no ``config.json``
-    has; else all of them)."""
+    knows the key names. Two families are read (the dense block is built
+    from the ``--d-model/...`` flags): ``model_type`` "glm_moe_dsa" is the
+    model described layer by layer, latent attention over an indexer's
+    selection with sigmoid routing and a shared expert
+    (:func:`_config_from_glm_moe_dsa`); ``attention_method`` "MLA" with
+    ``zero_expert_num`` is the shortcut double layer with latent
+    attention. ``experts_held`` = (offset, count) of the real experts that
+    this chip holds (default: the key ``experts_held`` of ``hf``, the one
+    key that no ``config.json`` has; else all of them)."""
+    if hf.get("model_type") == "glm_moe_dsa":
+        return _config_from_glm_moe_dsa(hf, max_seq, dtype, experts_held)
     eps = float(hf.get("rms_norm_eps", 1e-6))
     if hf.get("attention_method") != "MLA":
         raise ValueError(
-            f"attention_method {hf.get('attention_method')!r}: only the "
-            f"MLA shortcut double layer is read from a config.json")
+            f"attention_method {hf.get('attention_method')!r}, model_type "
+            f"{hf.get('model_type')!r}: only the MLA shortcut double layer "
+            f"and glm_moe_dsa are read from a config.json")
     for key in ("mla_scale_q_lora", "mla_scale_kv_lora"):
         if not hf.get(key, False):
             raise ValueError(f"{key} false: latent attention without its "
@@ -383,6 +527,62 @@ def config_from_hf(hf: dict, max_seq: int, dtype=jnp.bfloat16,
             scale=float(hf["routed_scaling_factor"]),
             d_ff=hf["expert_ffn_hidden_size"],
             held_offset=int(offset), held_count=int(count)))
+
+
+def _config_from_glm_moe_dsa(hf: dict, max_seq: int, dtype,
+                             experts_held) -> TransformerConfig:
+    """``model_type`` "glm_moe_dsa": the two per-layer lists
+    (``mlp_layer_types``, ``indexer_types``), the indexer's sizes, sigmoid
+    routing renormalised over the picked with ``n_shared_experts`` shared
+    experts of the routed width each, ``rope_parameters.rope_theta``, and
+    no ``mla_scale_*`` key (scale 1). ``n_routed_experts`` is the router's
+    width. What cannot run is refused by the name of its key."""
+    n = hf["num_hidden_layers"]
+    for key, can in (("n_group", 1), ("topk_group", 1),
+                     ("topk_method", "noaux_tc"),
+                     ("scoring_func", "sigmoid"), ("hidden_act", "silu"),
+                     ("attention_bias", False),
+                     ("tie_word_embeddings", False)):
+        if hf.get(key, can) != can:
+            raise ValueError(f"{key} {hf[key]!r}: only {can!r} is "
+                             f"implemented for glm_moe_dsa")
+    if hf.get("num_nextn_predict_layers", 0):
+        raise ValueError(
+            "num_nextn_predict_layers > 0: the multi-token-prediction "
+            "layer is a draft head that no engine runs; cut it to 0")
+    for key in ("mlp_layer_types", "indexer_types"):
+        if len(hf.get(key, ())) != n:
+            raise ValueError(f"{key} needs one entry a layer "
+                             f"(num_hidden_layers {n})")
+    n_real = hf["n_routed_experts"]
+    offset, count = experts_held or hf.get("experts_held", (0, n_real))
+    sparse = "sparse" in hf["mlp_layer_types"]
+    return TransformerConfig(
+        vocab_size=hf["vocab_size"], d_model=hf["hidden_size"],
+        n_heads=hf["num_attention_heads"], n_layers=n,
+        d_ff=hf["intermediate_size"], max_seq=max_seq, dtype=dtype,
+        rope=True, rope_theta=float(hf["rope_parameters"]["rope_theta"]),
+        ffn="swiglu", norm_eps=float(hf.get("rms_norm_eps", 1e-6)),
+        attention="mla", q_lora_rank=hf["q_lora_rank"],
+        kv_lora_rank=hf["kv_lora_rank"],
+        qk_nope_head_dim=hf["qk_nope_head_dim"],
+        qk_rope_head_dim=hf["qk_rope_head_dim"],
+        v_head_dim=hf["v_head_dim"], block="standard",
+        layer_ffn=tuple(hf["mlp_layer_types"]),
+        layer_indexer=tuple(hf["indexer_types"]),
+        index_n_heads=hf["index_n_heads"],
+        index_head_dim=hf["index_head_dim"], index_topk=hf["index_topk"],
+        mla_lora_scales=False,
+        experts=ExpertShareConfig(
+            n_outputs=n_real, top_k=hf["num_experts_per_tok"],
+            scale=float(hf["routed_scaling_factor"]),
+            d_ff=hf["moe_intermediate_size"],
+            scoring="sigmoid",
+            renormalise=bool(hf.get("norm_topk_prob", True)),
+            d_shared=hf.get("n_shared_experts", 0)
+            * hf["moe_intermediate_size"],
+            held_offset=int(offset), held_count=int(count))
+        if sparse else None)
 
 
 AttnFn = Callable[[jnp.ndarray, jnp.ndarray, jnp.ndarray], jnp.ndarray]

@@ -49,6 +49,7 @@ from jax import lax
 from akka_allreduce_tpu.runtime.tracing import (
     SCOPE_MOE_EXPERTS,
     SCOPE_MOE_ROUTER,
+    SCOPE_MOE_SHARED,
 )
 
 
@@ -283,9 +284,14 @@ class ExpertShareConfig:
     """``n_outputs`` is the router's width: ``n_outputs - n_identity``
     experts with weights (SwiGLU, width ``d_ff``), then ``n_identity``
     identity experts. A token takes its ``top_k`` outputs by score + bias
-    and weighs each by ``scale`` x its score alone, not renormalised. This
-    chip holds the real experts ``[held_offset, held_offset + held_count)``;
-    with all of them held the layer is the uncut one."""
+    and weighs each by ``scale`` x its score alone. ``scoring`` is what
+    turns the router's outputs into scores, a "softmax" over all of them
+    or a "sigmoid" of each; with ``renormalise`` the picked scores are
+    divided by their sum before the scale. ``d_shared`` > 0 is a shared
+    expert of that width beside the routed ones: a SwiGLU every token
+    takes at weight 1, which every chip of a deployment computes alike.
+    This chip holds the real experts ``[held_offset, held_offset +
+    held_count)``; with all of them held the layer is the uncut one."""
 
     n_outputs: int = 8
     n_identity: int = 0
@@ -294,6 +300,9 @@ class ExpertShareConfig:
     d_ff: int = 512
     held_offset: int = 0
     held_count: int = 8
+    scoring: str = "softmax"
+    renormalise: bool = False
+    d_shared: int = 0
 
     @property
     def n_real(self) -> int:
@@ -305,6 +314,10 @@ class ExpertShareConfig:
                              f"n_outputs={self.n_outputs}")
         if not 1 <= self.top_k <= self.n_outputs:
             raise ValueError(f"top_k={self.top_k} of {self.n_outputs}")
+        if self.scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown scoring {self.scoring!r}")
+        if self.d_shared < 0:
+            raise ValueError(f"d_shared={self.d_shared}")
         if self.held_count < 1 or self.held_offset < 0 \
                 or self.held_offset + self.held_count > self.n_real:
             raise ValueError(
@@ -316,31 +329,44 @@ class ExpertShareConfig:
 def init_expert_share(key: jax.Array, d_model: int, cfg: ExpertShareConfig,
                       dtype=jnp.float32) -> dict:
     """``router`` (d, n_outputs; no bias in the linear), ``bias`` (the
-    selection bias, f32, zeros) and the held experts' three stacks."""
+    selection bias, f32, zeros), the held experts' three stacks and,
+    with ``d_shared``, the shared expert's ``ws1`` / ``ws3`` / ``ws2``."""
     kr, k1, k2, k3 = jax.random.split(key, 4)
     e, f = cfg.held_count, cfg.d_ff
 
     def normal(k, shape):
         return jax.random.normal(k, shape, dtype) * shape[-2] ** -0.5
-    return {"router": normal(kr, (d_model, cfg.n_outputs)),
-            "bias": jnp.zeros((cfg.n_outputs,), jnp.float32),
-            "we1": normal(k1, (e, d_model, f)),
-            "we3": normal(k2, (e, d_model, f)),
-            "we2": normal(k3, (e, f, d_model))}
+    out = {"router": normal(kr, (d_model, cfg.n_outputs)),
+           "bias": jnp.zeros((cfg.n_outputs,), jnp.float32),
+           "we1": normal(k1, (e, d_model, f)),
+           "we3": normal(k2, (e, d_model, f)),
+           "we2": normal(k3, (e, f, d_model))}
+    if cfg.d_shared:
+        s1, s2, s3 = jax.random.split(jax.random.fold_in(key, 1), 3)
+        out.update(ws1=normal(s1, (d_model, cfg.d_shared)),
+                   ws3=normal(s2, (d_model, cfg.d_shared)),
+                   ws2=normal(s3, (cfg.d_shared, d_model)))
+    return out
 
 
 def dropless_route(h: jnp.ndarray, params: dict, cfg: ExpertShareConfig
                    ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """h (N, D) -> (pick (N, k) int32, weight (N, k) f32). Scores are a
-    float32 softmax over all ``n_outputs``; the bias enters the choice
-    only; no renormalisation."""
+    """h (N, D) -> (pick (N, k) int32, weight (N, k) f32). Scores are in
+    float32 over all ``n_outputs``, a softmax or a sigmoid each
+    (``cfg.scoring``); the bias enters the choice only; the picked scores
+    are renormalised to sum to 1 only under ``cfg.renormalise``."""
     logits = jnp.matmul(h.astype(jnp.float32),
                         params["router"].astype(jnp.float32),
                         precision=lax.Precision.HIGHEST)
-    scores = jax.nn.softmax(logits, axis=-1)
+    if cfg.scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        scores = jax.nn.softmax(logits, axis=-1)
     _, pick = lax.top_k(scores + params["bias"], cfg.top_k)
-    weight = jnp.take_along_axis(scores, pick, axis=-1) * cfg.scale
-    return pick.astype(jnp.int32), weight
+    weight = jnp.take_along_axis(scores, pick, axis=-1)
+    if cfg.renormalise:
+        weight = weight / (weight.sum(-1, keepdims=True) + 1e-20)
+    return pick.astype(jnp.int32), weight * cfg.scale
 
 
 def _row_buffer(rows: int) -> int:
@@ -391,15 +417,24 @@ def held_experts_ffn(h: jnp.ndarray, pick: jnp.ndarray,
     return jnp.einsum("nkd,nk->nd", back, jnp.where(held, weight, 0.0))
 
 
+def shared_expert_ffn(h: jnp.ndarray, params: dict) -> jnp.ndarray:
+    """The shared expert over h (N, D): a SwiGLU every token takes at
+    weight 1, under its own scope."""
+    with jax.named_scope(SCOPE_MOE_SHARED):
+        return (jax.nn.silu(h @ params["ws1"])
+                * (h @ params["ws3"])) @ params["ws2"]
+
+
 def dropless_moe(h: jnp.ndarray, params: dict, cfg: ExpertShareConfig,
                  counted: Optional[jnp.ndarray] = None
                  ) -> tuple[jnp.ndarray, dict]:
     """This chip's share of the expert layer for tokens h (N, D): the held
     experts' part plus the identity part, (sum of the weights of the
     picked identity experts) x h, which the token's own chip computes in a
-    deployment. Returns (m (N, D) in h's dtype, counts): per token the
-    assignments on ``held`` and on ``identity`` experts (the rest of
-    ``top_k`` are on absent experts), and ``touched``, how many held
+    deployment, plus the shared expert where there is one
+    (:func:`shared_expert_ffn`). Returns (m (N, D) in h's dtype, counts):
+    per token the assignments on ``held`` and on ``identity`` experts (the
+    rest of ``top_k`` are on absent experts), and ``touched``, how many held
     experts got a row. A token that is not ``counted`` (N,) bool (default:
     all are; padding and idle lanes are not) counts nowhere."""
     with jax.named_scope(SCOPE_MOE_ROUTER):
@@ -409,6 +444,8 @@ def dropless_moe(h: jnp.ndarray, params: dict, cfg: ExpertShareConfig,
         y = held_experts_ffn(h, pick, weight, params, cfg)
         y = y + jnp.where(on_identity, weight, 0.0).sum(
             -1, keepdims=True) * h.astype(jnp.float32)
+    if cfg.d_shared:
+        y = y + shared_expert_ffn(h, params).astype(jnp.float32)
     local, on_held = _on_held(pick, cfg)
     if counted is not None:
         on_held = on_held & counted[:, None]

@@ -269,6 +269,7 @@ SERVE_STEP_READBACK = "serve_step.readback"
 SERVE_STEP_COMMIT = "serve_step.commit"
 SERVE_ADMIT = "serve_admit"
 SERVE_PREFILL = "serve_prefill"
+SERVE_PREFILL_CHUNK = "serve_prefill.chunk"
 SERVE_ADMIT_COMMIT = "serve_admit.commit"
 SCHED_POP_READY = "sched_pop_ready"
 TRAIN_ROUND = "train_round"
@@ -282,9 +283,15 @@ TRAIN_ROUND = "train_round"
 # It also carries ``kv_blocks_live`` and ``kv_blocks_skipped``: the key
 # blocks of the latent cache that the committed dispatch's fused decode
 # attentions read and left unread, counted on the host from the positions it
-# uploaded (zero where the step's attention is not that kernel). Fields of
-# a span that belong to another layer than the span's own have a row in
-# ``SPAN_FIELDS``.
+# uploaded (zero where the step's attention is not that kernel). Of a model
+# whose attentions read an indexer's selection it carries ``index_scanned``
+# and ``index_selected``: the index keys that the committed dispatch's full
+# layers scored (``pos + 1`` a busy lane a full layer) and the latent rows
+# its attentions read (``min(pos + 1, index_topk)`` a busy lane a layer),
+# counted the same way (zero for every other model). ``serve_admit`` carries
+# ``chunks``, the dispatches of its prefill, each a ``serve_prefill.chunk``
+# where a prompt goes through the cache in chunks. Fields of a span that
+# belong to another layer than the span's own have a row in ``SPAN_FIELDS``.
 _DECODE = "engine, decode step (serving/engine.py)"
 _PREFILL = "engine, prefill (serving/engine.py)"
 SPANS = {
@@ -295,6 +302,7 @@ SPANS = {
     SERVE_STEP_COMMIT: (_DECODE, "flood_idle_commit_ms"),
     SERVE_ADMIT: (_PREFILL, "chat_admit_idle_ms"),
     SERVE_PREFILL: (_PREFILL, "chat_admit_idle_ms"),
+    SERVE_PREFILL_CHUNK: (_PREFILL, "-"),
     SERVE_ADMIT_COMMIT: (_PREFILL, "chat_admit_idle_ms"),
     SCHED_POP_READY: ("scheduler (serving/scheduler.py)",
                       "flood_idle_outside_ms"),
@@ -303,18 +311,27 @@ SPANS = {
 
 KV_BLOCKS_LIVE = "kv_blocks_live"
 KV_BLOCKS_SKIPPED = "kv_blocks_skipped"
+INDEX_SCANNED = "index_scanned"
+INDEX_SELECTED = "index_selected"
 _LATENT = "latent attention (models/generate.py)"
-# span -> field -> (layer, the quantity that reads it; none does yet)
+_INDEXER = "sparse attention indexer (models/generate.py)"
+# span -> field -> (layer, the quantity that reads it)
 SPAN_FIELDS = {
     SERVE_STEP: {KV_BLOCKS_LIVE: (_LATENT, "-"),
-                 KV_BLOCKS_SKIPPED: (_LATENT, "-")},
+                 KV_BLOCKS_SKIPPED: (_LATENT, "-"),
+                 INDEX_SCANNED: (_INDEXER, "glm_decode_roofline"),
+                 INDEX_SELECTED: (_INDEXER,
+                                  "glm_selected_pct, glm_decode_roofline")},
 }
 
 # ``jax.named_scope`` names inside the jitted train step and the serving
 # programs (no host cost: they are metadata of the HLO): name -> (layer, the
-# quantities that read it). The last four are the cached-block functions'
+# quantities that read it). The last six are the cached-block functions'
 # (models/generate.py), so the decode and prefill programs carry them; the
 # dense block's attention goes under ``attention`` there too.
+# ``sparse_indexer`` holds a full layer's index projections, its scores over
+# the index keys and the top-k; the gather of the chosen rows and the
+# attention over them stay under ``mla_attention``.
 SCOPE_SYNC_PACK = "grad_sync/pack"
 SCOPE_SYNC_REDUCE = "grad_sync/reduce"
 SCOPE_SYNC_UNPACK = "grad_sync/unpack"
@@ -325,6 +342,8 @@ SCOPE_MLA_ATTENTION = "mla_attention"
 SCOPE_DENSE_FFN = "dense_ffn"
 SCOPE_MOE_ROUTER = "moe_router"
 SCOPE_MOE_EXPERTS = "moe_experts"
+SCOPE_SPARSE_INDEXER = "sparse_indexer"
+SCOPE_MOE_SHARED = "moe_shared"
 
 _SYNC = "gradient sync (parallel/dp.py, ops/collectives.py)"
 _STEP = "train step (models/train.py)"
@@ -337,15 +356,22 @@ SCOPES = {
     SCOPE_OPTIMIZER: (_STEP, "-"),
     SCOPE_ATTENTION: ("attention kernels (ops/pallas_kernels/attention.py)",
                       "-"),
-    SCOPE_MLA_ATTENTION: (_LATENT, "lcr_mla_device_pct"),
+    SCOPE_MLA_ATTENTION: (_LATENT, "lcr_mla_device_pct, glm_mla_device_pct"),
     SCOPE_DENSE_FFN: ("engine, decode step (serving/engine.py)", "-"),
-    SCOPE_MOE_ROUTER: (_EXPERTS, "lcr_experts_device_pct"),
-    SCOPE_MOE_EXPERTS: (_EXPERTS, "lcr_experts_device_pct"),
+    SCOPE_MOE_ROUTER: (_EXPERTS,
+                       "lcr_experts_device_pct, glm_experts_device_pct"),
+    SCOPE_MOE_EXPERTS: (_EXPERTS,
+                        "lcr_experts_device_pct, glm_experts_device_pct"),
+    SCOPE_SPARSE_INDEXER: (_INDEXER, "glm_indexer_device_pct"),
+    SCOPE_MOE_SHARED: (_EXPERTS, "glm_experts_device_pct"),
 }
 
 # the scopes of the cached-block functions: in the serving programs only
+# (the last two in the programs of a model that has an indexer and a shared
+# expert)
 SERVING_SCOPES = frozenset({SCOPE_MLA_ATTENTION, SCOPE_DENSE_FFN,
-                            SCOPE_MOE_ROUTER, SCOPE_MOE_EXPERTS})
+                            SCOPE_MOE_ROUTER, SCOPE_MOE_EXPERTS,
+                            SCOPE_SPARSE_INDEXER, SCOPE_MOE_SHARED})
 
 _annotation = None   # the annotation-only span's class, made at first use
 

@@ -146,6 +146,7 @@ from akka_allreduce_tpu.runtime.tracing import (
     SERVE_ADMIT,
     SERVE_ADMIT_COMMIT,
     SERVE_PREFILL,
+    SERVE_PREFILL_CHUNK,
     SERVE_STEP,
     SERVE_STEP_COMMIT,
     SERVE_STEP_DISPATCH,
@@ -220,10 +221,20 @@ class EngineConfig:
     the multi-token dispatch) and with ``prefill_buckets``
     (speculative prefill is exact-length, the parity mode). 0 on the
     plain engines.
+
+    ``prefill_chunk``: > 0 sends a prompt longer than the largest
+    prefill bucket (or, with no buckets, than the chunk) through the
+    cache in chunks of this many positions, ONE compiled program run
+    ``ceil(n / chunk)`` times back to back, each chunk attending what
+    the lane's cache already holds (the last one padded; its padding is
+    neither live nor counted). For the model whose cached block attends
+    through the cache (``TransformerConfig.layerwise``); the other
+    kinds' prefill attends its fresh keys and is refused.
     """
 
     num_slots: int = 4
     prefill_buckets: tuple = ()
+    prefill_chunk: int = 0
     kv_dtype: Optional[str] = None
     decode_steps: int = 1
     max_stop_tokens: int = 4
@@ -282,6 +293,9 @@ class EngineConfig:
             raise ValueError(
                 "prefill_buckets is a plain-engine knob; speculative "
                 "prefill is exact-length (the parity mode)")
+        if self.prefill_chunk < 0:
+            raise ValueError(f"prefill_chunk must be >= 0 (0 = one "
+                             f"program a prompt), got {self.prefill_chunk}")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -353,7 +367,7 @@ class PagedEngineConfig(EngineConfig):
                 "run speculation on the gather path")
 
 
-_KV_KEYS = ("k", "v", "k_scale", "v_scale", "latent")
+_KV_KEYS = ("k", "v", "k_scale", "v_scale", "latent", "index_k")
 
 
 # how many numbers a token's route counts are (held, identity, absent),
@@ -527,11 +541,8 @@ def _engine_prefill(params: dict, state: dict, prompt: jnp.ndarray,
     out = dict(state)
     if counts is not None:
         # true positions only (prefill_counted leaves the padding out)
-        held, identity = counts["held"].sum(), counts["identity"].sum()
         n = true_len if gather else prompt.shape[1]
-        absent = n * cfg.experts.top_k * cfg.n_layers - held - identity
-        out["route"] = state["route"] + jnp.stack(
-            [held, identity, absent, counts["touched"]]).astype(jnp.int32)
+        out["route"] = state["route"] + _prefill_route(counts, n, cfg)
     for n in _KV_KEYS:
         if n in cache:
             out[n] = lax.dynamic_update_slice(
@@ -539,6 +550,49 @@ def _engine_prefill(params: dict, state: dict, prompt: jnp.ndarray,
                 (0, slot) + (0,) * (cache[n].ndim - 2))
     out["logits"] = lax.dynamic_update_slice(
         state["logits"], logits.astype(state["logits"].dtype),
+        (slot, 0))
+    return out
+
+
+def _prefill_route(counts: dict, n, cfg: TransformerConfig) -> jnp.ndarray:
+    """(held, identity, absent, touched) of a prefill's ``n`` true
+    positions, from the expert layers' counts (which leave padding out)."""
+    held, identity = counts["held"].sum(), counts["identity"].sum()
+    absent = n * cfg.experts.top_k * cfg.n_expert_layers - held - identity
+    return jnp.stack(
+        [held, identity, absent, counts["touched"]]).astype(jnp.int32)
+
+
+@partial(jax.jit, static_argnames=("cfg",), donate_argnums=(1,))
+def _engine_prefill_chunk(params: dict, state: dict, tokens: jnp.ndarray,
+                          offset: jnp.ndarray, n_valid: jnp.ndarray,
+                          slot: jnp.ndarray, cfg: TransformerConfig):
+    """Extend ``slot``'s lane by ``tokens`` (1, L) at positions
+    ``offset .. offset + L``, of which the first ``n_valid`` are the
+    prompt's and the rest padding (written past the lane's frontier,
+    where no position mask admits them and decode overwrites them;
+    counted nowhere). The tokens' keys go into the lane in place and
+    each token attends, through the cache, what the lane holds at or
+    before its own position: ``offset`` 0 with a bucket's L is a short
+    prompt's whole prefill, a long prompt runs L = the chunk size once a
+    chunk. L is static, ``offset``, ``n_valid`` and ``slot`` are data:
+    one program a length, whatever the prompt. The carried logits are
+    those of position ``n_valid - 1`` (the last chunk's are the
+    prompt's)."""
+    kv = {n: state[n] for n in _KV_KEYS if n in state}
+    length = tokens.shape[1]
+    x, kv, counts = cached_blocks(
+        params, params["embed"][tokens], kv, cfg,
+        CacheOps(offset=offset, lane=slot,
+                 counted=jnp.arange(length) < n_valid))
+    x_last = lax.dynamic_slice_in_dim(x, n_valid - 1, 1, axis=1)
+    logits = lm_logits(
+        params, rmsnorm(x_last, params["out_norm"], cfg.norm_eps), cfg)
+    out = {**state, **kv}
+    if counts is not None:
+        out["route"] = state["route"] + _prefill_route(counts, n_valid, cfg)
+    out["logits"] = lax.dynamic_update_slice(
+        state["logits"], logits[:, 0].astype(state["logits"].dtype),
         (slot, 0))
     return out
 
@@ -1417,6 +1471,8 @@ class _Flight:
     out: Optional[tuple] = None     # (state, packed) once launched
     # key blocks of the latent cache its attentions read and skipped
     kv_blocks: tuple = (0, 0)
+    # index keys its indexers scored, latent rows its attentions read
+    index: tuple = (0, 0)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -1459,6 +1515,10 @@ class ServingEngine:
             raise ValueError(
                 f"largest prefill bucket {ecfg.prefill_buckets[-1]} "
                 f"exceeds max_seq {cfg.max_seq}")
+        if ecfg.prefill_chunk and cfg.max_seq % ecfg.prefill_chunk:
+            raise ValueError(
+                f"prefill_chunk {ecfg.prefill_chunk} must divide max_seq "
+                f"{cfg.max_seq}: a padded last chunk is written whole")
         self._refuse_new_kind()
         self._state = self._fresh_state()
         self._pos = np.zeros((ecfg.num_slots,), np.int32)
@@ -1554,6 +1614,13 @@ class ServingEngine:
         a kind it does not know wrong: each refuses it, naming what is
         missing."""
         kind = self.cfg.new_kind
+        if self.ecfg.prefill_chunk and not (
+                self.cfg.layerwise and self._new_kind_missing is None):
+            raise NotImplementedError(
+                f"{type(self).__name__} cannot prefill "
+                f"{kind or 'the dense block'} in chunks; missing: a cached "
+                f"block whose prefill attends what the cache already holds "
+                f"(this one's attends its fresh keys)")
         if kind is None:
             return
         missing = self._new_kind_missing
@@ -1562,7 +1629,8 @@ class ServingEngine:
                        "carry the expert layers' counts through its scan")
         if missing is None and self.ecfg.kv_dtype is not None:
             missing = (f"kv_dtype={self.ecfg.kv_dtype!r}: the latent cache "
-                       f"has no quantized format")
+                       f"(and an indexer's index cache) has no quantized "
+                       f"format")
         if missing is not None:
             raise NotImplementedError(
                 f"{type(self).__name__} cannot run {kind}; missing: "
@@ -1708,6 +1776,8 @@ class ServingEngine:
         bucket-padded lane write; the paged engine overrides with page
         allocation + pool scatter. Returns the length dispatched."""
         n_full = len(full)
+        if self.cfg.layerwise:
+            return self._prefill_through_cache(slot, req, full)
         length = self._bucket_len(n_full)
         padded = np.zeros((1, length), np.int32)
         padded[0, :n_full] = full
@@ -1721,6 +1791,40 @@ class ServingEngine:
         self.prefill_dispatches += 1
         self.prefill_shapes.add((length, length != n_full))
         return length
+
+    def _prefill_through_cache(self, slot: int, req: Request,
+                               full: tuple) -> int:
+        """The layer-by-layer kind's prefill, in place in ``slot``'s lane
+        (:func:`_engine_prefill_chunk`): one dispatch of a bucket's length
+        for a prompt that fits one (or, with no bucket and no chunk, of
+        its own length), else ``ceil(n / prefill_chunk)`` dispatches of
+        the ONE chunk program back to back, each a
+        ``serve_prefill.chunk`` span. Returns the positions dispatched."""
+        n_full, chunk = len(full), self.ecfg.prefill_chunk
+        buckets = self.ecfg.prefill_buckets
+        if chunk and n_full > (buckets[-1] if buckets else chunk):
+            length = chunk
+        else:
+            length = self._bucket_len(n_full)
+        chunks = -(-n_full // length)
+        padded = np.zeros((chunks * length,), np.int32)
+        padded[:n_full] = full
+        with span(SERVE_PREFILL, self.tracer, rid=req.rid, slot=slot,
+                  prompt_len=n_full, bucket=length, chunks=chunks):
+            for c in range(chunks):
+                offset = c * length
+                with span(SERVE_PREFILL_CHUNK, self.tracer, rid=req.rid,
+                          offset=offset):
+                    self._state = _engine_prefill_chunk(
+                        self.params, self._state,
+                        jnp.asarray(padded[None, offset:offset + length]),
+                        jnp.asarray(offset, jnp.int32),
+                        jnp.asarray(min(length, n_full - offset),
+                                    jnp.int32),
+                        jnp.asarray(slot, jnp.int32), self.cfg)
+        self.prefill_dispatches += chunks
+        self.prefill_shapes.add((length, True))
+        return chunks * length
 
     def admit(self, req: Request, emitted: tuple = ()) -> int:
         """Prefill ``req`` into a free slot; returns the slot index.
@@ -1741,8 +1845,10 @@ class ServingEngine:
                                    "free_slot_count)") from None
             sp.set(slot=slot)
             full = tuple(req.prompt) + tuple(emitted)
+            before = self.prefill_dispatches
             self._admitted.append(
                 (req.rid, self._prefill_into(slot, req, full)))
+            sp.set(chunks=self.prefill_dispatches - before)
             with span(SERVE_ADMIT_COMMIT, self.tracer):
                 self._commit_admit(slot, req, stops, emitted, len(full))
             return slot
@@ -2072,13 +2178,17 @@ class ServingEngine:
                     commit.set(**{f"route_{k}": v for k, v in
                                   self.last_route["decode"].items()})
             live, skipped = older.kv_blocks
+            scanned, selected = older.index
             step_span.set(ahead=int(ahead), discarded=dropped,
-                          kv_blocks_live=live, kv_blocks_skipped=skipped)
+                          kv_blocks_live=live, kv_blocks_skipped=skipped,
+                          index_scanned=scanned, index_selected=selected)
             if self.metrics is not None:
                 if ahead or dropped:
                     self.metrics.on_lookahead(ahead, dropped)
                 if live:
                     self.metrics.on_kv_blocks(live, skipped)
+                if selected:
+                    self.metrics.on_index(scanned, selected)
             return finished
 
     def _launches_ahead(self) -> bool:
@@ -2111,7 +2221,21 @@ class ServingEngine:
                     idx[i] += 1
         return _Flight(jnp.asarray(pos), self._sample_operands(idx),
                        self._step_tables(), lanes,
-                       kv_blocks=self._count_kv_blocks(pos))
+                       kv_blocks=self._count_kv_blocks(pos),
+                       index=self._count_index(pos, lanes))
+
+    def _count_index(self, pos: np.ndarray, lanes: dict) -> tuple:
+        """(scanned, selected) of one dispatch at the positions ``pos`` it
+        uploads, over its busy ``lanes``: the index keys its full layers
+        score, ``pos + 1`` a lane a full layer, and the latent rows its
+        attentions read, ``min(pos + 1, index_topk)`` a lane a layer.
+        (0, 0) for a model without an indexer."""
+        if not self.cfg.layerwise or not lanes:
+            return 0, 0
+        live = pos[list(lanes)].astype(np.int64) + 1
+        return (len(self.cfg.full_layers) * int(live.sum()),
+                self.cfg.n_layers * int(np.minimum(
+                    live, self.cfg.index_topk).sum()))
 
     def _count_kv_blocks(self, pos: np.ndarray) -> tuple:
         """(read, skipped) key blocks of the latent cache in one dispatch
@@ -2143,7 +2267,7 @@ class ServingEngine:
         # an idle lane (parked at position 0) counted nowhere on the device
         held = int(packed[2 * n:3 * n].sum())
         identity = int(packed[3 * n:4 * n].sum())
-        picks = counted * self.cfg.experts.top_k * self.cfg.n_layers
+        picks = counted * self.cfg.experts.top_k * self.cfg.n_expert_layers
         route = {"decode": {"held": held, "identity": identity,
                             "absent": picks - held - identity,
                             "touched": int(packed[4 * n])}}
@@ -2409,7 +2533,8 @@ class _SpeculativeMixin:
 
     _new_kind_missing = (
         "a block extend (`_slot_extend` / `_paged_extend` copy the dense "
-        "block) that verifies a draft through the latent cache")
+        "block) that verifies a draft through the latent cache (and, where "
+        "an indexer chooses what is attended, through its index cache)")
 
     def _init_spec(self, draft_params: dict,
                    draft_cfg: TransformerConfig, cfg: TransformerConfig,
@@ -2707,9 +2832,10 @@ class PagedServingEngine(ServingEngine):
     """
 
     _new_kind_missing = (
-        "a latent page in the pool and cached-block functions that read "
-        "it through the page table (`_paged_decode_step` copies the dense "
-        "block)")
+        "a latent page in the pool (and an index-key page where an "
+        "indexer chooses what is attended) and cached-block functions that "
+        "read them through the page table (`_paged_decode_step` copies the "
+        "dense block)")
 
     def __init__(self, params: dict, cfg: TransformerConfig,
                  ecfg: PagedEngineConfig = PagedEngineConfig(),

@@ -94,6 +94,11 @@ class ServingMetrics:
         # skipped, over lanes and attentions
         self.kv_blocks_live = 0
         self.kv_blocks_skipped = 0
+        # a model whose attentions read an indexer's selection: index
+        # keys the committed dispatches' full layers scored, and latent
+        # rows their attentions read, over lanes and layers
+        self.index_scanned = 0
+        self.index_selected = 0
         self.requests_submitted = 0
         self.requests_completed = 0
         self.requests_rejected = 0
@@ -337,6 +342,16 @@ class ServingMetrics:
         self.lookahead_steps += ahead
         self.discarded_lane_steps += discarded
 
+    def _register_kinds(self, name: str, attr: str, kinds: tuple,
+                        help: str) -> None:
+        """One counter series a ``kind`` label, each reading the cell
+        ``<attr><kind>`` of this object."""
+        for kind in kinds:
+            self.registry.register_callback(
+                name, lambda k=kind: getattr(self, attr + k),
+                kind="counter", help=help,
+                labels={**self.labels, "kind": kind})
+
     def on_kv_blocks(self, live: int, skipped: int) -> None:
         """One committed decode dispatch whose latent attentions ran the
         fused kernel: the key blocks of the cache it read (``live``: each
@@ -346,16 +361,29 @@ class ServingMetrics:
             # registered at the first such dispatch (it reads a block a
             # lane at least), so an engine on the formula's path exports
             # no such series; the collectors read the summary's cells
-            for kind in ("live", "skipped"):
-                self.registry.register_callback(
-                    "serve_kv_blocks_total",
-                    lambda k=kind: getattr(self, f"kv_blocks_{k}"),
-                    kind="counter",
-                    help="key blocks of the latent cache that the decode "
-                         "kernel read (live) and left unread (skipped)",
-                    labels={**self.labels, "kind": kind})
+            self._register_kinds(
+                "serve_kv_blocks_total", "kv_blocks_", ("live", "skipped"),
+                "key blocks of the latent cache that the decode kernel "
+                "read (live) and left unread (skipped)")
         self.kv_blocks_live += live
         self.kv_blocks_skipped += skipped
+
+    def on_index(self, scanned: int, selected: int) -> None:
+        """One committed decode dispatch of a model whose attentions read
+        an indexer's selection: the index keys its full layers scored
+        (``scanned``: ``pos + 1`` a busy lane a full layer) and the latent
+        rows its attentions read (``selected``: ``min(pos + 1,
+        index_topk)`` a busy lane a layer)."""
+        if not self.index_selected:
+            # registered at the first such dispatch, as the key blocks'
+            # series is: every other model exports no such series
+            self._register_kinds(
+                "serve_index_positions_total", "index_",
+                ("scanned", "selected"),
+                "cached positions the indexers scored (scanned) and the "
+                "attentions read (selected)")
+        self.index_scanned += scanned
+        self.index_selected += selected
 
     def on_token(self, rid: int, submitted_at: float) -> None:
         """Called per emitted token; the first emission banks TTFT."""
@@ -581,6 +609,9 @@ class ServingMetrics:
                 "skipped": self.kv_blocks_skipped,
                 "skipped_share": round(self.kv_blocks_skipped / (
                     self.kv_blocks_live + self.kv_blocks_skipped), 4)}
+        if self.index_selected:
+            out["index"] = {"scanned": self.index_scanned,
+                            "selected": self.index_selected}
         if self.draft_proposed:
             # the speculation story (speculative engines only): the
             # same cells the serve_draft_* collectors read

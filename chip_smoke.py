@@ -26,6 +26,15 @@ from a seed:
   ``attention[latent_decode]:`` notice has to say ``mosaic:``. A silent
   fall back to the pure-JAX formula on the chip is a failed smoke, not a
   slow benchmark cell.
+* ``serve-sparse-latent``: ``cli serve --model-config --prefill-chunk
+  2048`` on three layers at GLM-5.2's published widths (a dense and a
+  sparse layer with an indexer each, a sparse layer that shares the
+  second's choice; two held experts; bf16, 1.3B parameters): prompts of
+  2,560 go through the cache in two chunks, then a few decode steps. The
+  same checks, and: the program's ``attention[sparse_latent]:`` notice
+  names the gathered attention over 2,048 chosen rows, and the report's
+  ``index`` counts show that the selection BOUND (the attentions read
+  fewer rows than the indexers scored).
 
 One process per chip: this parent never imports JAX; each phase is a child
 process (``--phase``) that owns the chip for its lifetime, checks that
@@ -86,6 +95,33 @@ LATENT_TOY = {**LATENT, "vocab_size": 256, "hidden_size": 64,
               "zero_expert_num": 8, "moe_topk": 4}
 
 
+# the ``serve-sparse-latent`` phase's model: three layers of
+# zai-org/GLM-5.2's config.json, every width as published, depth, the held
+# experts and the vocabulary cut (benchmark/configs/ has the cell's five
+# layers and sixteen experts)
+SPARSE_LATENT = dict(
+    model_type="glm_moe_dsa", vocab_size=19360, hidden_size=6144,
+    intermediate_size=12288, moe_intermediate_size=2048,
+    num_hidden_layers=3, num_attention_heads=64, kv_lora_rank=512,
+    q_lora_rank=2048, qk_rope_head_dim=64, qk_nope_head_dim=192,
+    v_head_dim=256, index_n_heads=32, index_head_dim=128, index_topk=2048,
+    indexer_types=["full", "full", "shared"],
+    mlp_layer_types=["dense", "sparse", "sparse"], n_routed_experts=256,
+    n_shared_experts=1, num_experts_per_tok=8, norm_topk_prob=True,
+    routed_scaling_factor=2.5, scoring_func="sigmoid",
+    topk_method="noaux_tc", n_group=1, topk_group=1, rms_norm_eps=1e-5,
+    rope_parameters={"rope_theta": 8000000, "rope_type": "default"},
+    num_nextn_predict_layers=0, experts_held=[0, 2],
+    torch_dtype="bfloat16")
+SPARSE_LATENT_TOY = {
+    **SPARSE_LATENT, "vocab_size": 256, "hidden_size": 64,
+    "intermediate_size": 128, "moe_intermediate_size": 32,
+    "num_attention_heads": 4, "kv_lora_rank": 16, "q_lora_rank": 24,
+    "qk_rope_head_dim": 8, "qk_nope_head_dim": 16, "v_head_dim": 16,
+    "index_n_heads": 4, "index_head_dim": 16, "index_topk": 16,
+    "n_routed_experts": 16, "num_experts_per_tok": 4}
+
+
 def phase_argv(phase: str, rehearse: bool) -> "tuple[list[str], dict]":
     """The ``cli`` command line of a phase, and the numbers its checks
     compare against."""
@@ -110,6 +146,21 @@ def phase_argv(phase: str, rehearse: bool) -> "tuple[list[str], dict]":
         with open(path, "w") as f:
             json.dump(LATENT_TOY if rehearse else LATENT, f)
         model = ["--model-config", path]
+    if phase == "serve-sparse-latent":
+        path = os.path.join(OUT_DIR,
+                            "chip_smoke.serve-sparse-latent.config.json")
+        os.makedirs(OUT_DIR, exist_ok=True)
+        with open(path, "w") as f:
+            json.dump(SPARSE_LATENT_TOY if rehearse else SPARSE_LATENT, f)
+        model = ["--model-config", path]
+        # prompts a quarter past index_topk, through two chunks
+        want = ({"requests": 2, "max_new_tokens": 4, "index_topk": 16}
+                if rehearse else
+                {"requests": 2, "max_new_tokens": 8, "index_topk": 2048})
+        load = (["--slots", "2", "--max-seq", "64", "--prompt-len", "20:20",
+                 "--prefill-chunk", "16"] if rehearse else
+                ["--slots", "2", "--max-seq", "4096", "--prompt-len",
+                 "2560:2560", "--prefill-chunk", "2048"])
     argv = ["serve", *model, *load, "--requests", str(want["requests"]),
             "--max-new-tokens", str(want["max_new_tokens"]),
             "--load", "closed"]
@@ -118,7 +169,8 @@ def phase_argv(phase: str, rehearse: bool) -> "tuple[list[str], dict]":
     return argv, want
 
 
-PHASES = ("train", "serve-slot", "serve-paged", "serve-latent")
+PHASES = ("train", "serve-slot", "serve-paged", "serve-latent",
+          "serve-sparse-latent")
 # one prompt length, so one prefill program; the rest are the decode
 # step and first-use helpers. Exact-length prefill compiles one program
 # per distinct length (ROADMAP S2) — a regression there shows here.
@@ -215,6 +267,28 @@ def _check_latent(err: str, rehearse: bool) -> "list[dict]":
     ]
 
 
+def _check_sparse_latent(out: str, err: str, want: dict) -> "list[dict]":
+    said = re.findall(r"^attention\[sparse_latent\]: (\S+) (.*)$", err,
+                      flags=re.M)
+    lines = [ln for ln in out.strip().splitlines() if ln.startswith("{")]
+    index = (json.loads(lines[-1]) if lines else {}).get("index", {})
+    layers, full = (len(SPARSE_LATENT["indexer_types"]),
+                    SPARSE_LATENT["indexer_types"].count("full"))
+    read = index.get("selected", 0) / layers
+    scored = index.get("scanned", 0) / full
+    return [
+        {"name": f"attention over {want['index_topk']} gathered rows in "
+                 f"both programs",
+         "ok": bool(said) and all(
+             impl == "reference:_selected_latent_attention"
+             and f"chosen={want['index_topk']} " in detail
+             for impl, detail in said),
+         "detail": [" ".join(a) for a in said]},
+        {"name": "the selection bound: rows read < keys scored, a layer",
+         "ok": 0 < read < scored, "detail": index},
+    ]
+
+
 def run_phase(phase: str, record_path: str, rehearse: bool) -> int:
     import contextlib
 
@@ -279,6 +353,8 @@ def run_phase(phase: str, record_path: str, rehearse: bool) -> int:
         checks += _check_serve(out.text(), want)
         if phase == "serve-latent":
             checks += _check_latent(err.text(), rehearse)
+        if phase == "serve-sparse-latent":
+            checks += _check_sparse_latent(out.text(), err.text(), want)
     native = sys.modules.get("akka_allreduce_tpu.native")
     checks.append({"name": "native library not loaded on this path",
                    "ok": native is None or native._lib is None,

@@ -144,3 +144,119 @@ def test_the_fused_decode_reads_the_latent_where_it_lies(one_chip, cfg,
     assert not re.search(rf"f32\[{LANES},{cfg.n_heads},{MAX_SEQ}\]", hlo)
     # nothing the size of a cache (604 MB here) or of its f32 scores (67 MB)
     assert compiled.memory_analysis().temp_size_in_bytes < 32 << 20
+
+
+# -- the layer-by-layer model at its cell's sizes (PR 31) --------------------
+
+GLM = dict(
+    model_type="glm_moe_dsa", vocab_size=19360, hidden_size=6144,
+    intermediate_size=12288, moe_intermediate_size=2048,
+    num_hidden_layers=5, num_attention_heads=64, kv_lora_rank=512,
+    q_lora_rank=2048, qk_rope_head_dim=64, qk_nope_head_dim=192,
+    v_head_dim=256, index_n_heads=32, index_head_dim=128, index_topk=2048,
+    indexer_types=["full", "shared", "shared", "shared", "full"],
+    mlp_layer_types=["dense", "sparse", "sparse", "sparse", "sparse"],
+    n_routed_experts=256, n_shared_experts=1, num_experts_per_tok=8,
+    norm_topk_prob=True, routed_scaling_factor=2.5, scoring_func="sigmoid",
+    topk_method="noaux_tc", n_group=1, topk_group=1, rms_norm_eps=1e-5,
+    rope_parameters={"rope_theta": 8000000, "rope_type": "default"},
+    num_nextn_predict_layers=0, experts_held=[0, 16])
+GLM_LANES, GLM_MAX_SEQ, GLM_CHUNK = 32, 24576, 2048
+CHIP_BYTES = int(15.75 * 2 ** 30)
+
+
+@pytest.fixture(scope="module")
+def glm(one_chip):
+    """(cfg, params, engine state, both on the described chip as shapes)."""
+    from akka_allreduce_tpu.models.transformer import init_transformer
+    cfg = config_from_hf(GLM, GLM_MAX_SEQ, jnp.bfloat16)
+    params = jax.eval_shape(lambda k: init_transformer(k, cfg),
+                            jax.random.key(0))
+
+    def state():
+        base = G.init_kv_cache(cfg, GLM_LANES)
+        del base["pos"]
+        return {**base, "route": jnp.zeros((4,), jnp.int32),
+                "logits": jnp.zeros((GLM_LANES, cfg.vocab_size), cfg.dtype)}
+    return cfg, _on(one_chip, params), _on(one_chip, jax.eval_shape(state))
+
+
+def _lower_off_cache(lowered):
+    from jax.experimental.compilation_cache import compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        return lowered.compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+
+
+def _device_bytes(compiled) -> int:
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.temp_size_in_bytes
+            + m.output_size_in_bytes - m.alias_size_in_bytes)
+
+
+def test_the_chip_keeps_a_padded_latent_row_contiguous(one_chip):
+    """Why ``TransformerConfig.latent_row`` pads 576 to 640: rows of whole
+    registers stay row-major, so a gather of chosen positions reads rows;
+    at 576 the chip keeps ``max_seq`` minor (on the v5e the same gather
+    takes 16 ms where this takes 1.0: PERF.md section 6, PR 31)."""
+    for width, order in ((640, (0, 1, 2, 3)), (576, (0, 1, 3, 2))):
+        cache = jax.ShapeDtypeStruct(
+            (5, GLM_LANES, GLM_MAX_SEQ, width), jnp.bfloat16,
+            sharding=one_chip)
+        formats = _compile(lambda x: x, cache).input_formats
+        assert tuple(formats[0][0].layout.major_to_minor) == order, width
+
+
+def test_the_sparse_decode_step_fits_and_copies_no_cache(one_chip, glm):
+    from akka_allreduce_tpu.serving import engine as eng
+    cfg, params, state = glm
+    hand = {"params": 7_763_036_160, "cache": 5_435_817_984}
+    assert sum(x.size * x.dtype.itemsize
+               for x in jax.tree.leaves(params)) == hand["params"]
+    assert sum(state[n].size * 2 for n in ("latent", "index_k")) \
+        == hand["cache"] == GLM_LANES * GLM_MAX_SEQ * (5 * 640 + 2 * 128) * 2
+    pos = jax.ShapeDtypeStruct((GLM_LANES,), jnp.int32, sharding=one_chip)
+    compiled = _lower_off_cache(
+        eng._engine_step.lower(params, state, pos, cfg))
+    assert _device_bytes(compiled) <= CHIP_BYTES
+    # the selected rows (32 x 2,048 x 640 a layer) and a full layer's
+    # scores, never a lane's cache: nothing near 0.9 GB of temporaries
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
+    entry = compiled.as_text()
+    entry = entry[entry.index("ENTRY "):]
+    whole = rf"bf16\[(5,)?{GLM_LANES},{GLM_MAX_SEQ},640\]"
+    assert not re.search(rf"= {whole}\S* (copy|transpose)\(", entry)
+    # the indexers' exact top-k sorts a matrix of rows (8 rows a tile): as
+    # (lanes, 1, max_seq) the chip lays a row a tile and the sort takes
+    # 4.8 ms a full layer where this takes a third (chip runs, PR 31)
+    sorts = re.findall(r"sort\(.*sparse_indexer/top_k", entry)
+    assert len(sorts) == 2
+    assert len(re.findall(
+        rf"= \(f32\[{GLM_LANES},{GLM_MAX_SEQ}\]\{{1,0:T\(8,128\)\}}, .*"
+        rf"sparse_indexer/top_k", entry)) == 2
+    # the gather of the chosen rows carries no pass that blanks rows, and
+    # runs once a layer (the compiler would sooner gather again for the
+    # second matmul than keep 84 MB: `_selected_latent_attention`)
+    assert "broadcast_select_fusion" not in entry
+    assert len(re.findall(rf"= bf16\[{GLM_LANES * 2048},640\]\S* fusion\(",
+                          entry)) == 5
+
+
+def test_the_chunk_program_fits_beside_weights_and_cache(one_chip, glm):
+    from akka_allreduce_tpu.serving import engine as eng
+    cfg, params, state = glm
+    i32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    tokens = jax.ShapeDtypeStruct((1, GLM_CHUNK), jnp.int32,
+                                  sharding=one_chip)
+    compiled = _lower_off_cache(eng._engine_prefill_chunk.lower(
+        params, state, tokens, i32, i32, i32, cfg))
+    assert _device_bytes(compiled) <= CHIP_BYTES
+    # a block of 128 query rows at a time: 2 GB would be four blocks' worth
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
+    # one gather of a block's 128 x 2,048 rows a layer
+    assert len(re.findall(r"= bf16\[262144,640\]\S* fusion\(",
+                          compiled.as_text())) == 5

@@ -192,9 +192,13 @@ class TestSpanPrimitive:
 
     def test_span_fields_of_another_layer_have_their_rows(self):
         assert set(T.SPAN_FIELDS) <= set(T.SPANS)
+        indexer = T.SCOPES[T.SCOPE_SPARSE_INDEXER][0]
         assert T.SPAN_FIELDS[T.SERVE_STEP] == {
             "kv_blocks_live": (T.SCOPES[T.SCOPE_MLA_ATTENTION][0], "-"),
-            "kv_blocks_skipped": (T.SCOPES[T.SCOPE_MLA_ATTENTION][0], "-")}
+            "kv_blocks_skipped": (T.SCOPES[T.SCOPE_MLA_ATTENTION][0], "-"),
+            "index_scanned": (indexer, "glm_decode_roofline"),
+            "index_selected": (indexer,
+                               "glm_selected_pct, glm_decode_roofline")}
 
 
 # -- the engine's phases ----------------------------------------------------
@@ -492,6 +496,8 @@ def test_names_the_benchmark_reads_are_pinned():
         "head_loss_device_pct) reads these scope names; a rename goes "
         "with a `benchmark` PR")
     assert T.SERVING_SCOPES == {
-        "mla_attention", "dense_ffn", "moe_router", "moe_experts"}, (
+        "mla_attention", "dense_ffn", "moe_router", "moe_experts",
+        "sparse_indexer", "moe_shared"}, (
         "benchmark/readers/latent_moe.py (lcr_experts_device_pct, "
-        "lcr_mla_device_pct) reads these scope names")
+        "lcr_mla_device_pct, glm_indexer_device_pct, glm_mla_device_pct, "
+        "glm_experts_device_pct) reads these scope names")
