@@ -25,6 +25,7 @@ tokens at decode time would be strictly worse, not more faithful).
 from __future__ import annotations
 
 import dataclasses
+import math
 from functools import partial
 from typing import Optional
 
@@ -63,6 +64,11 @@ INDEX_NORM_EPS = 1e-6
 # bounds a prefill chunk's temporaries (scores of rows x index heads x
 # max_seq in f32, rows x index_topk gathered latents)
 QUERY_ROWS = 128
+# cached rows of one lane that a block of query rows scores at once where
+# the attention over an indexer's choice runs masked, in place
+# (:func:`selected_attention_path`): f32 scores of 128 rows x 64 heads x
+# 1,024 keys are 32 MB a block
+KEY_ROWS = 1024
 
 
 def init_kv_cache(cfg: TransformerConfig, batch: int,
@@ -637,7 +643,9 @@ def _index_select(p: dict, c_q: jnp.ndarray, h: jnp.ndarray, kv: dict,
     int32 positions, kv). Where fewer than k positions are live the rest
     of ``chosen`` lie past the token's position: whoever attends holds
     ``chosen <= position`` to be the live ones. Exact: ``lax.top_k``, no
-    approximation."""
+    approximation; a chunk's queries, which read one lane, score and sort
+    the shortest prefix of it that holds their live keys
+    (:func:`_key_prefixes`)."""
     b, t, _ = h.shape
     heads, hd = cfg.index_n_heads, cfg.index_head_dim
     kv = dict(kv)
@@ -652,27 +660,63 @@ def _index_select(p: dict, c_q: jnp.ndarray, h: jnp.ndarray, kv: dict,
     keys = ops.lane_rows(kv["index_k"], f)           # (b, max_seq, hd)
     n_keys = keys.shape[1]
     top = min(cfg.index_topk, n_keys)
+    prefixes = _key_prefixes(n_keys, top, ops.lane is not None)
+
+    def among(n: int):
+        """The choice among the lane's first ``n`` keys (they hold every
+        live one)."""
+        def run(q, w, positions):
+            scores = jnp.einsum("bthd,bsd->bths", q, keys[:, :n],
+                                preferred_element_type=jnp.float32)
+            scores = jnp.einsum("bths,bth->bts", jax.nn.relu(scores), w)
+            live = jnp.arange(n)[None, None, :] <= positions[:, :, None]
+            scores = jnp.where(live, scores, -jnp.inf)
+            # the top-k over a matrix of rows: as (b, 1, max_seq) the chip
+            # lays one row a tile and the decode step's sort takes 4.8 ms
+            # where this takes under 2 (chip runs, PR 31)
+            rows = scores.reshape(-1, n)
+            return lax.top_k(rows, top)[1].reshape(scores.shape[:2] + (top,))
+        return run
 
     def choose(q, w, positions):
-        scores = jnp.einsum("bthd,bsd->bths", q, keys,
-                            preferred_element_type=jnp.float32)
-        scores = jnp.einsum("bths,bth->bts", jax.nn.relu(scores), w)
-        live = jnp.arange(n_keys)[None, None, :] <= positions[:, :, None]
-        scores = jnp.where(live, scores, -jnp.inf)
-        # the top-k over a matrix of rows: as (b, 1, max_seq) the chip lays
-        # one row a tile and the decode step's sort takes 4.8 ms where
-        # this takes under 2 (chip runs, PR 31)
-        rows = scores.reshape(-1, n_keys)
-        return lax.top_k(rows, top)[1].reshape(scores.shape[:2] + (top,))
+        if len(prefixes) == 1:
+            return among(n_keys)(q, w, positions)
+        # the shortest prefix past the block's last position: the first
+        # whose length exceeds it
+        which = jnp.sum(jnp.asarray(prefixes) <= jnp.max(positions))
+        return lax.switch(which, [among(n) for n in prefixes], q, w,
+                          positions)
 
     return _over_query_rows(choose, q, w, positions).astype(jnp.int32), kv
+
+
+def _key_prefixes(n_keys: int, top: int, one_lane: bool) -> tuple:
+    """The prefixes of a lane's ``n_keys`` index keys that an indexer may
+    score and sort in place of all of them, shortest first, the whole lane
+    last. A sort costs what its width costs whatever the lane holds (on
+    the v5e 2.2 ms a block of 128 query rows x 24,576 keys, 70 ms of every
+    chunk; chip run, PR 33), and a chunk's block of query rows reads ONE
+    lane up to one last position: it takes the shortest prefix that holds
+    every live key (an eighth, a quarter, a half of the lane; none shorter
+    than ``top``, so the top-k's shape is the same), and the same exact
+    top-k over it chooses the same positions in the same order. A decode
+    step's lanes each end elsewhere and keep the one sort over the lane."""
+    if not one_lane:
+        return (n_keys,)
+    return tuple(sorted({n_keys // d for d in (8, 4, 2, 1)
+                         if n_keys % d == 0 and n_keys // d >= top}))
 
 
 def _selected_latent_attention(q: jnp.ndarray, latent: jnp.ndarray, a: int,
                                lanes: jnp.ndarray, chosen: jnp.ndarray,
                                positions: jnp.ndarray, rank: int,
                                scale: float) -> jnp.ndarray:
-    """Attention over the CHOSEN rows of the latent cache and no other:
+    """Attention over the CHOSEN rows of the latent cache and no other,
+    each query gathering its own (what a decode step runs: one query a
+    lane, ``index_topk`` of the thousands of rows it holds; a chunk's many
+    queries of one lane attend the same set through
+    :func:`_masked_latent_attention`, and :func:`selected_attention_path`
+    says which):
     q (b, t, h, rank + rope) (the absorbed query of
     :func:`_latent_attention`), ``latent`` the whole cache (attentions,
     lanes, max_seq, row), batch row i reading lane ``lanes[i]`` of
@@ -709,6 +753,109 @@ def _selected_latent_attention(q: jnp.ndarray, latent: jnp.ndarray, a: int,
     return _over_query_rows(attend, q, chosen, positions)
 
 
+def selected_attention_path(t: int, k: int, n_seq: int,
+                            one_lane: bool) -> "int | None":
+    """How ``t`` query rows attend the ``k`` rows of an ``n_seq``-row lane
+    that the indexer chose for each, from the shapes alone: None for the
+    gather of :func:`_selected_latent_attention` (t x k rows fetched, one
+    at a time), else the key block of :func:`_masked_latent_attention`,
+    which scores the lane's live rows where they lie, once a block of
+    :data:`QUERY_ROWS` queries, and masks the scores to the chosen set. The
+    masked pass runs where every query reads ONE lane (a prefill chunk:
+    ``CacheOps.lane``) and the gather would fetch at least as many rows as
+    those passes can read at most: a chunk of 2,048 queries x 2,048 chosen
+    is 4.2 M gathered rows (64 ms a layer on the v5e) against 16 passes
+    over at most 24,576. A decode step's lanes hold a query each (32 x
+    2,048 chosen of 5k-24k live a lane: the masked pass would read five
+    times the rows) and keep the gather. The key block divides the lane, so
+    no block straddles its end. No option chooses."""
+    if not one_lane or t * k < -(-t // QUERY_ROWS) * n_seq:
+        return None
+    return math.gcd(n_seq, KEY_ROWS)
+
+
+def _chosen_mask(chosen: jnp.ndarray, positions: jnp.ndarray,
+                 n_seq: int) -> jnp.ndarray:
+    """``chosen`` (b, t, k) positions as a membership mask (b, t, n_seq):
+    True at s where s is one of ``chosen[b, t]`` and s <= ``positions[b,
+    t]`` - exactly the set the gather attends, ties of the indexer's scores
+    included, because it is made from the top-k's own answer. No scatter:
+    position s = hi x 128 + lo, and the mask is the product of the one-hot
+    rows of hi and of lo summed over the k chosen, a batched matmul of 0s
+    and 1s (exact: the positions of a row differ), :data:`QUERY_ROWS` rows
+    at a time. On the v5e 3.7 ms for a chunk's 2,048 x 2,048 marks in
+    24,576 positions where the scatter takes 26 (chip run, PR 33)."""
+    lo_n = math.gcd(n_seq, 128)
+    hi_n = n_seq // lo_n
+
+    def member(chosen, positions):
+        # a position past the token's own falls outside every one-hot row
+        at = jnp.where(chosen <= positions[..., None], chosen, n_seq)
+        hi = jax.nn.one_hot(at // lo_n, hi_n, dtype=jnp.bfloat16)
+        lo = jax.nn.one_hot(at % lo_n, lo_n, dtype=jnp.bfloat16)
+        hits = jnp.einsum("btkh,btkl->bthl", hi, lo,
+                          preferred_element_type=jnp.float32)
+        return (hits > 0).reshape(hits.shape[:2] + (n_seq,))
+
+    return _over_query_rows(member, chosen, positions)
+
+
+def _masked_latent_attention(q: jnp.ndarray, latent: jnp.ndarray, a: int,
+                             lane: jnp.ndarray, member: jnp.ndarray,
+                             positions: jnp.ndarray, rank: int, scale: float,
+                             blk: int) -> jnp.ndarray:
+    """:func:`_selected_latent_attention` for many queries of ONE lane:
+    q (1, t, h, rank + rope) attends lane ``lane`` of attention ``a`` at
+    the rows ``member`` (1, t, max_seq) marks (:func:`_chosen_mask`: the
+    chosen positions at or before the token's own). Nothing is gathered: a
+    block of :data:`QUERY_ROWS` queries takes the lane's rows in key blocks
+    of ``blk`` up to the block that holds its last position (the blocks
+    past it are never scored), scores all its queries and heads against a
+    key block in one matmul, masks the scores to ``member``, and carries a
+    running softmax (max, sum, weighted sum) from block to block; the
+    weighted sum is a second matmul over the same rows. Same products as
+    the gather's, summed in another order. On the v5e both matmuls run at
+    83-89% of the peak, 0.12 ms a query block and key block (chip run, PR
+    33): the cost is the live positions', not ``index_topk``'s. Returns
+    (1, t, h, rank)."""
+    width, row = q.shape[-1], latent.shape[-1]
+    # the ONE lane is cut from the cache here, once (31 MB at 24,576 x 640),
+    # and the key blocks from it: cut from the cache inside the loops, the
+    # compiler lays the WHOLE cache the way the score matmul wants its keys
+    # (positions minor) and copies all of it ahead of the loop, 4.7 GB that
+    # do not fit (compiled for the described v5e, PR 33)
+    lane_rows = lax.optimization_barrier(lax.dynamic_slice(
+        latent, (a, lane, 0, 0), (1, 1) + latent.shape[2:])[0])
+
+    def attend(q, member, positions):
+        def block(j, carry):
+            m, norm, acc = carry
+            rows = lax.dynamic_slice_in_dim(lane_rows, j * blk, blk, axis=1)
+            keep = lax.dynamic_slice_in_dim(member, j * blk, blk, axis=2)
+            scores = jnp.einsum("bthc,bkc->bthk", q, rows[..., :width],
+                                preferred_element_type=jnp.float32) * scale
+            scores = jnp.where(keep[:, :, None, :], scores, NEG_INF)
+            m_new = jnp.maximum(m, scores.max(axis=-1))
+            # a row that has met no chosen key yet carries exp(0) a key;
+            # its first chosen key's ``fade`` is exp(NEG_INF - score) = 0
+            p = jnp.exp(scores - m_new[..., None])
+            fade = jnp.exp(m - m_new)
+            acc = fade[..., None] * acc + jnp.einsum(
+                "bthk,bkc->bthc", p.astype(rows.dtype), rows,
+                preferred_element_type=jnp.float32)
+            return m_new, fade * norm + p.sum(axis=-1), acc
+
+        stat = q.shape[:3]
+        _m, norm, acc = lax.fori_loop(
+            0, jnp.max(positions) // blk + 1, block,
+            (jnp.full(stat, NEG_INF, jnp.float32),
+             jnp.zeros(stat, jnp.float32),
+             jnp.zeros(stat + (row,), jnp.float32)))
+        return (acc[..., :rank] / norm[..., None]).astype(q.dtype)
+
+    return _over_query_rows(attend, q, member, positions)
+
+
 def _layerwise_cached_block(layer: dict, x: jnp.ndarray, kv: dict, i: int,
                             cfg: TransformerConfig, ops: CacheOps,
                             chosen: "jnp.ndarray | None"):
@@ -720,7 +867,11 @@ def _layerwise_cached_block(layer: dict, x: jnp.ndarray, kv: dict, i: int,
     chunk behind what the cache holds) and a decode step are the same
     function of the cache: every token's keys are written first, then
     each token chooses among and attends the cache's rows at or before
-    its own position. Returns (x, kv, the expert layer's counts or None,
+    its own position. ``chosen`` is what a full layer hands the shared
+    ones: the positions (b, t, k) where the attention gathers them, their
+    membership mask (b, t, max_seq) where it runs masked over the lane
+    (:func:`selected_attention_path`, the same answer in every layer of
+    one program). Returns (x, kv, the expert layer's counts or None,
     chosen)."""
     b, t, d = x.shape
     p = layer["mla"]
@@ -730,6 +881,9 @@ def _layerwise_cached_block(layer: dict, x: jnp.ndarray, kv: dict, i: int,
     positions = ops.positions(b, t)
     lanes = (jnp.arange(b) if ops.lane is None
              else jnp.reshape(ops.lane, (1,)))
+    n_seq = kv["latent"].shape[2]
+    top = min(cfg.index_topk, n_seq)
+    blk = selected_attention_path(t, top, n_seq, ops.lane is not None)
     kv = dict(kv)
     with jax.named_scope(SCOPE_MLA_ATTENTION):
         h = rmsnorm(x, p["ln"], cfg.norm_eps)
@@ -750,16 +904,27 @@ def _layerwise_cached_block(layer: dict, x: jnp.ndarray, kv: dict, i: int,
                 layer["indexer"], c_q, h, kv, cfg.full_layers.index(i), cfg,
                 ops, positions)
     with jax.named_scope(SCOPE_MLA_ATTENTION):
+        if blk is not None and "indexer" in layer:
+            chosen = _chosen_mask(chosen, positions, n_seq)
         up = p["wkv_b"].reshape(rank, heads, nope + vd)
         q_lat = jnp.concatenate(
             [jnp.einsum("bthn,rhn->bthr", q[..., :nope], up[..., :nope]),
              q_rope], axis=-1)
-        say_attention("sparse_latent", "reference:_selected_latent_attention",
-                      q_lat, chosen=chosen.shape[-1],
-                      of=kv["latent"].shape[2])
-        out_lat = _selected_latent_attention(
-            q_lat, kv["latent"], i, lanes, chosen, positions, rank,
-            (nope + q_rope.shape[-1]) ** -0.5)
+        scale = (nope + q_rope.shape[-1]) ** -0.5
+        if blk is None:
+            say_attention("sparse_latent",
+                          "reference:_selected_latent_attention", q_lat,
+                          chosen=top, of=n_seq)
+            out_lat = _selected_latent_attention(
+                q_lat, kv["latent"], i, lanes, chosen, positions, rank,
+                scale)
+        else:
+            say_attention("sparse_latent",
+                          "reference:_masked_latent_attention", q_lat,
+                          chosen=top, of=n_seq, key_block=blk)
+            out_lat = _masked_latent_attention(
+                q_lat, kv["latent"], i, ops.lane, chosen, positions, rank,
+                scale, blk)
         out = jnp.einsum("bthr,rhv->bthv", out_lat, up[..., nope:])
         x = x + out.reshape(b, t, heads * vd) @ p["wo"]
     h = rmsnorm(x, layer["ln2"], cfg.norm_eps)

@@ -290,8 +290,14 @@ TRAIN_ROUND = "train_round"
 # its attentions read (``min(pos + 1, index_topk)`` a busy lane a layer),
 # counted the same way (zero for every other model). ``serve_admit`` carries
 # ``chunks``, the dispatches of its prefill, each a ``serve_prefill.chunk``
-# where a prompt goes through the cache in chunks. Fields of a span that
-# belong to another layer than the span's own have a row in ``SPAN_FIELDS``.
+# where a prompt goes through the cache in chunks. Such a chunk carries
+# ``key_blocks_live`` and ``key_blocks_skipped``: the key blocks of its lane
+# that the chunk program's masked attentions scored (up to the block that
+# holds the chunk's last position, times the layers) and the blocks of the
+# lane they left unscored, counted on the host from the offset and the
+# length it uploaded (zero where the program's attention gathers its chosen
+# rows). Fields of a span that belong to another layer than the span's own
+# have a row in ``SPAN_FIELDS``.
 _DECODE = "engine, decode step (serving/engine.py)"
 _PREFILL = "engine, prefill (serving/engine.py)"
 SPANS = {
@@ -313,6 +319,8 @@ KV_BLOCKS_LIVE = "kv_blocks_live"
 KV_BLOCKS_SKIPPED = "kv_blocks_skipped"
 INDEX_SCANNED = "index_scanned"
 INDEX_SELECTED = "index_selected"
+KEY_BLOCKS_LIVE = "key_blocks_live"
+KEY_BLOCKS_SKIPPED = "key_blocks_skipped"
 _LATENT = "latent attention (models/generate.py)"
 _INDEXER = "sparse attention indexer (models/generate.py)"
 # span -> field -> (layer, the quantity that reads it)
@@ -322,6 +330,8 @@ SPAN_FIELDS = {
                  INDEX_SCANNED: (_INDEXER, "glm_decode_roofline"),
                  INDEX_SELECTED: (_INDEXER,
                                   "glm_selected_pct, glm_decode_roofline")},
+    SERVE_PREFILL_CHUNK: {KEY_BLOCKS_LIVE: (_LATENT, "-"),
+                          KEY_BLOCKS_SKIPPED: (_LATENT, "-")},
 }
 
 # ``jax.named_scope`` names inside the jitted train step and the serving

@@ -1813,8 +1813,12 @@ class ServingEngine:
                   prompt_len=n_full, bucket=length, chunks=chunks):
             for c in range(chunks):
                 offset = c * length
+                live, skipped = self._count_key_blocks(offset, length)
+                if live and self.metrics is not None:
+                    self.metrics.on_key_blocks(live, skipped)
                 with span(SERVE_PREFILL_CHUNK, self.tracer, rid=req.rid,
-                          offset=offset):
+                          offset=offset, key_blocks_live=live,
+                          key_blocks_skipped=skipped):
                     self._state = _engine_prefill_chunk(
                         self.params, self._state,
                         jnp.asarray(padded[None, offset:offset + length]),
@@ -1825,6 +1829,22 @@ class ServingEngine:
         self.prefill_dispatches += chunks
         self.prefill_shapes.add((length, True))
         return chunks * length
+
+    def _count_key_blocks(self, offset: int, length: int) -> tuple:
+        """(scored, left unscored) key blocks of the lane in one dispatch
+        of the chunk program at ``offset``, over its attentions: where the
+        program's attention runs masked over the lane
+        (models/generate.py ``selected_attention_path``, asked with the
+        shapes the program is traced with) its passes score the lane's
+        blocks up to the one that holds position ``offset + length - 1``
+        and no later one. (0, 0) where the attention gathers."""
+        max_seq = self.cfg.max_seq
+        blk = generate.selected_attention_path(
+            length, min(self.cfg.index_topk, max_seq), max_seq, True)
+        if blk is None:
+            return 0, 0
+        live = self.cfg.n_layers * -(-(offset + length) // blk)
+        return live, self.cfg.n_layers * (max_seq // blk) - live
 
     def admit(self, req: Request, emitted: tuple = ()) -> int:
         """Prefill ``req`` into a free slot; returns the slot index.

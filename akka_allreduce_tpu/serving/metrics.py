@@ -99,6 +99,10 @@ class ServingMetrics:
         # rows their attentions read, over lanes and layers
         self.index_scanned = 0
         self.index_selected = 0
+        # a prefill chunk whose attentions run masked over its lane: the
+        # lane's key blocks they scored and left unscored, over the layers
+        self.key_blocks_live = 0
+        self.key_blocks_skipped = 0
         self.requests_submitted = 0
         self.requests_completed = 0
         self.requests_rejected = 0
@@ -368,6 +372,22 @@ class ServingMetrics:
         self.kv_blocks_live += live
         self.kv_blocks_skipped += skipped
 
+    def on_key_blocks(self, live: int, skipped: int) -> None:
+        """One dispatch of the chunk program whose attentions run masked
+        over the lane: the lane's key blocks its passes scored (``live``:
+        up to the block that holds the chunk's last position, over the
+        layers) and the blocks of the lane they left unscored
+        (``skipped``)."""
+        if not self.key_blocks_live:
+            # registered at the first such chunk, as the decode kernel's
+            # series is: a model whose chunks gather exports no such series
+            self._register_kinds(
+                "serve_key_blocks_total", "key_blocks_", ("live", "skipped"),
+                "key blocks of a lane that a prefill chunk's masked "
+                "attentions scored (live) and left unscored (skipped)")
+        self.key_blocks_live += live
+        self.key_blocks_skipped += skipped
+
     def on_index(self, scanned: int, selected: int) -> None:
         """One committed decode dispatch of a model whose attentions read
         an indexer's selection: the index keys its full layers scored
@@ -603,12 +623,13 @@ class ServingMetrics:
             out["lookahead"] = {
                 "steps": self.lookahead_steps,
                 "discarded_lane_steps": self.discarded_lane_steps}
-        if self.kv_blocks_live:
-            out["kv_blocks"] = {
-                "live": self.kv_blocks_live,
-                "skipped": self.kv_blocks_skipped,
-                "skipped_share": round(self.kv_blocks_skipped / (
-                    self.kv_blocks_live + self.kv_blocks_skipped), 4)}
+        for name in ("kv_blocks", "key_blocks"):
+            live = getattr(self, name + "_live")
+            skipped = getattr(self, name + "_skipped")
+            if live:
+                out[name] = {"live": live, "skipped": skipped,
+                             "skipped_share": round(
+                                 skipped / (live + skipped), 4)}
         if self.index_selected:
             out["index"] = {"scanned": self.index_scanned,
                             "selected": self.index_selected}

@@ -31,10 +31,12 @@ from a seed:
   sparse layer with an indexer each, a sparse layer that shares the
   second's choice; two held experts; bf16, 1.3B parameters): prompts of
   2,560 go through the cache in two chunks, then a few decode steps. The
-  same checks, and: the program's ``attention[sparse_latent]:`` notice
-  names the gathered attention over 2,048 chosen rows, and the report's
-  ``index`` counts show that the selection BOUND (the attentions read
-  fewer rows than the indexers scored).
+  same checks, and: the programs' ``attention[sparse_latent]:`` notices
+  (printed in the record) say which path each took over its 2,048 chosen
+  rows - the chunk program the masked pass over the lane's key blocks, the
+  decode step the gather - and the report's ``index`` counts show that the
+  selection BOUND (the attentions read fewer rows than the indexers
+  scored).
 
 One process per chip: this parent never imports JAX; each phase is a child
 process (``--phase``) that owns the chip for its lifetime, checks that
@@ -276,13 +278,17 @@ def _check_sparse_latent(out: str, err: str, want: dict) -> "list[dict]":
                     SPARSE_LATENT["indexer_types"].count("full"))
     read = index.get("selected", 0) / layers
     scored = index.get("scanned", 0) / full
+    # a chunk's queries share a lane (q=1xLx...): masked, in place; the
+    # step holds a query a lane (q=<slots>x1x...): gathered
+    took = {"chunk" if detail.startswith("q=1x") else "step": impl
+            for impl, detail in said}
     return [
-        {"name": f"attention over {want['index_topk']} gathered rows in "
-                 f"both programs",
-         "ok": bool(said) and all(
-             impl == "reference:_selected_latent_attention"
-             and f"chosen={want['index_topk']} " in detail
-             for impl, detail in said),
+        {"name": f"attention over {want['index_topk']} chosen rows: in "
+                 f"place in the chunk program, gathered in the step",
+         "ok": took == {"chunk": "reference:_masked_latent_attention",
+                        "step": "reference:_selected_latent_attention"}
+         and all(f"chosen={want['index_topk']} " in detail
+                 for _impl, detail in said),
          "detail": [" ".join(a) for a in said]},
         {"name": "the selection bound: rows read < keys scored, a layer",
          "ok": 0 < read < scored, "detail": index},

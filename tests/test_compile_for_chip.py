@@ -244,6 +244,9 @@ def test_the_sparse_decode_step_fits_and_copies_no_cache(one_chip, glm):
     assert "broadcast_select_fusion" not in entry
     assert len(re.findall(rf"= bf16\[{GLM_LANES * 2048},640\]\S* fusion\(",
                           entry)) == 5
+    # a query a lane: the step keeps the gather (a masked pass over every
+    # live row would read five times the rows)
+    assert G.selected_attention_path(1, 2048, GLM_MAX_SEQ, False) is None
 
 
 def test_the_chunk_program_fits_beside_weights_and_cache(one_chip, glm):
@@ -255,8 +258,21 @@ def test_the_chunk_program_fits_beside_weights_and_cache(one_chip, glm):
     compiled = _lower_off_cache(eng._engine_prefill_chunk.lower(
         params, state, tokens, i32, i32, i32, cfg))
     assert _device_bytes(compiled) <= CHIP_BYTES
-    # a block of 128 query rows at a time: 2 GB would be four blocks' worth
-    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
-    # one gather of a block's 128 x 2,048 rows a layer
-    assert len(re.findall(r"= bf16\[262144,640\]\S* fusion\(",
-                          compiled.as_text())) == 5
+    # a block of 128 query rows at a time, against one key block of the
+    # lane: no more than the gathering program's 0.93 GB (PR 31)
+    assert compiled.memory_analysis().temp_size_in_bytes <= 930 << 20
+    hlo = compiled.as_text()
+    # the chunk's queries share a lane and attend it in place: no gather of
+    # a block's 128 x 2,048 chosen rows (5.4 GB a layer); the cache is
+    # neither copied nor re-laid (what the key blocks are cut from is the
+    # ONE lane, 31 MB a layer, which the compiler lays as its matmuls want:
+    # sliced inside the loops it re-lays all 4.7 GB and the program no
+    # longer fits, PERF.md section 6, PR 33)
+    assert G.selected_attention_path(GLM_CHUNK, 2048, GLM_MAX_SEQ,
+                                     True) == G.KEY_ROWS
+    assert not re.search(r"= bf16\[(1,)?262144,640\]", hlo)
+    assert not re.search(r"= bf16\[(1,)*128,2048,640\]", hlo)
+    whole = rf"bf16\[(5,|1,)?{GLM_LANES},{GLM_MAX_SEQ},640\]"
+    assert not re.search(rf"= {whole}\S* (copy|transpose|slice)\(", hlo)
+    lane = rf"= bf16\[1,{GLM_MAX_SEQ},640\]\S* copy\("
+    assert len(re.findall(lane, hlo)) <= cfg.n_layers

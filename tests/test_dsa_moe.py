@@ -343,6 +343,149 @@ def test_drain_and_restore_continue_the_stream_through_both_caches():
     assert got == want
 
 
+
+# -- the two realisations of the attention over the chosen rows -----------
+
+def _attention_case(case, dtype):
+    """(q, latent, a, lane, chosen, positions) of one lane's block of
+    queries: ``case`` is (t, offset, k, tie): t queries at positions
+    offset.., k chosen a query by ``lax.top_k`` over random index scores
+    (rounded to eighths where ``tie``, so that the k-th place is shared by
+    several positions and the top-k takes the first of them)."""
+    t, offset, k, tie = case
+    n_seq, heads, rank, rope, row = 256, 4, 16, 8, 32
+    kq, kl, ks = jax.random.split(jax.random.key(t + offset + k), 3)
+    q = jax.random.normal(kq, (1, t, heads, rank + rope), dtype)
+    latent = jax.random.normal(kl, (2, 3, n_seq, row), dtype)
+    positions = offset + jnp.arange(t, dtype=jnp.int32)[None]
+    scores = jax.random.normal(ks, (1, t, n_seq))
+    if tie:
+        scores = jnp.round(scores * 8) / 8
+    live = jnp.arange(n_seq)[None, None] <= positions[..., None]
+    scores = jnp.where(live, scores, -jnp.inf)
+    chosen = jax.lax.top_k(scores, k)[1].astype(jnp.int32)
+    if tie:
+        kth = jnp.take_along_axis(scores, chosen[..., -1:], -1)
+        assert int(((scores == kth) & live).sum(-1).max()) > 1
+    return q, latent, 1, jnp.asarray(2, jnp.int32), chosen, positions
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-6),
+                                       (jnp.bfloat16, 2e-2)])
+@pytest.mark.parametrize("case", [
+    pytest.param((40, 0, 16, False), id="a_chunk_at_offset_0"),
+    pytest.param((40, 120, 16, False), id="a_chunk_behind_3x_its_length"),
+    pytest.param((40, 0, 64, False), id="fewer_live_than_index_topk"),
+    pytest.param((40, 120, 16, True), id="ties_at_the_kth_place"),
+    pytest.param((160, 64, 16, True), id="more_than_a_block_of_query_rows"),
+])
+def test_the_masked_attention_equals_the_gather(monkeypatch, case, dtype,
+                                                tol):
+    """Same cache, same ``chosen``: the pass over the lane's key blocks
+    under the membership mask attends exactly the set the gather fetches
+    (the mask is that set, checked against a scatter of ``chosen``), in
+    key blocks of 64 so that a query block crosses several."""
+    monkeypatch.setattr(G, "KEY_ROWS", 64)
+    q, latent, a, lane, chosen, positions = _attention_case(case, dtype)
+    t, n_seq = q.shape[1], latent.shape[2]
+    blk = G.selected_attention_path(t, chosen.shape[-1], n_seq, True)
+    assert blk == 64
+    member = G._chosen_mask(chosen, positions, n_seq)
+    want = np.zeros((1, t, n_seq), bool)
+    for i in range(t):
+        row = np.asarray(chosen[0, i])
+        want[0, i, row[row <= int(positions[0, i])]] = True
+    np.testing.assert_array_equal(np.asarray(member), want)
+    assert want.sum(-1).min() >= 1
+    gathered = G._selected_latent_attention(
+        q, latent, a, lane[None], chosen, positions, 16, 24 ** -0.5)
+    masked = G._masked_latent_attention(
+        q, latent, a, lane, member, positions, 16, 24 ** -0.5, blk)
+    assert masked.shape == gathered.shape == (1, t, 4, 16)
+    assert masked.dtype == gathered.dtype == dtype
+    assert np.abs(np.asarray(masked, np.float32)
+                  - np.asarray(gathered, np.float32)).max() <= tol
+
+
+@pytest.mark.parametrize("n_keys,top,one_lane,want", [
+    (24576, 2048, True, (3072, 6144, 12288, 24576)),    # the cell's chunk
+    (24576, 2048, False, (24576,)),         # a decode step: one sort
+    (4096, 2048, True, (2048, 4096)),       # none shorter than index_topk
+    (64, 8, True, (8, 16, 32, 64)),
+    (80, 8, True, (10, 20, 40, 80)),
+])
+def test_a_chunk_sorts_a_prefix_that_holds_its_live_keys(n_keys, top,
+                                                         one_lane, want):
+    assert G._key_prefixes(n_keys, top, one_lane) == want
+
+
+@pytest.mark.parametrize("offset", [0, 3, 8, 24, 47])
+def test_the_prefix_chooses_what_the_whole_lane_chooses(offset):
+    """A chunk's indexer over the shortest prefix of its lane against the
+    same indexer over the whole lane (``CacheOps.lane`` unset, the lane
+    handed over as a batch of one): the same positions in the same order,
+    at every prefix, with ties (index keys that repeat) and with fewer
+    live keys than ``index_topk``."""
+    cfg, params = _model()
+    idx = params["layers"][0]["indexer"]
+    t, n_seq = 16, cfg.max_seq
+    kh, kc, kk = jax.random.split(jax.random.key(offset), 3)
+    h = jax.random.normal(kh, (1, t, cfg.d_model))
+    c_q = jax.random.normal(kc, (1, t, cfg.q_lora_rank))
+    lane = jax.random.normal(kk, (n_seq, cfg.index_head_dim))
+    lane = lane.at[1::3].set(lane[0])       # equal keys: equal scores
+    cache = jnp.zeros((2, 3, n_seq, cfg.index_head_dim)).at[0, 1].set(lane)
+    chunk = G.CacheOps(offset=jnp.asarray(offset, jnp.int32),
+                       lane=jnp.asarray(1, jnp.int32))
+    got, _kv = G._index_select(idx, c_q, h, {"index_k": cache}, 0, cfg,
+                               chunk, chunk.positions(1, t))
+    whole = G.CacheOps(offset=jnp.asarray(offset, jnp.int32))
+    want, _kv = G._index_select(idx, c_q, h, {"index_k": cache[:, 1:2]}, 0,
+                                cfg, whole, whole.positions(1, t))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("t,k,n_seq,one_lane,want", [
+    (1, 2048, 24576, False, None),      # the decode step: a query a lane
+    (1, 2048, 24576, True, None),       # one query: 2,048 rows < a lane's
+    (2048, 2048, 24576, True, 1024),    # the cell's chunk
+    (2048, 2048, 24576, False, None),   # queries of several lanes
+    (2560, 2048, 4096, True, 1024),     # chip_smoke's bucket
+    (16, 8, 80, True, 16),              # the key block divides the lane
+    (4, 8, 64, True, None),             # 32 gathered rows < the lane's 64
+])
+def test_the_path_is_chosen_from_the_shapes(t, k, n_seq, one_lane, want):
+    assert G.selected_attention_path(t, k, n_seq, one_lane) == want
+
+
+def test_the_chunk_attends_in_place_and_the_step_gathers(monkeypatch):
+    """Through the engine: the chunk program never calls the gather, the
+    decode step never the masked pass, and chunks in key blocks of 16
+    serve the logits the gathering chunks serve."""
+    cfg, params = _model()
+    prompt = _tokens(37, 2)
+    calls = []
+    for name in ("_selected_latent_attention", "_masked_latent_attention"):
+        real = getattr(G, name)
+        monkeypatch.setattr(G, name, lambda *a, _n=name, _f=real, **kw: (
+            calls.append((_n, a[0].shape[1])), _f(*a, **kw))[1])
+    monkeypatch.setattr(G, "KEY_ROWS", 16)
+    eng._engine_prefill_chunk.clear_cache()
+    eng._engine_step.clear_cache()
+    with _engine(cfg, params) as e:
+        got, toks = _engine_logits(e, 1, prompt, 4)
+    assert {(n, t) for n, t in calls} == {
+        ("_masked_latent_attention", 16), ("_selected_latent_attention", 1)}
+    monkeypatch.setattr(G, "selected_attention_path", lambda *a: None)
+    eng._engine_prefill_chunk.clear_cache()
+    with _engine(cfg, params) as e:
+        want, again = _engine_logits(e, 1, prompt, 4)
+    eng._engine_prefill_chunk.clear_cache()
+    eng._engine_step.clear_cache()
+    assert toks == again
+    assert np.abs(got - want).max() <= F32_TOL
+
+
 # -- the counts ----------------------------------------------------------
 
 def test_index_counts_equal_what_the_positions_say():
@@ -375,6 +518,54 @@ def test_index_counts_equal_what_the_positions_say():
     assert chunks == [0, 0, 16, 32]
 
 
+def test_key_block_counts_equal_what_the_chunks_say(monkeypatch):
+    """Key blocks of 16 in lanes of 64: a chunk of 16 at offset o scores
+    the blocks up to the one that holds o + 15, in each of five layers."""
+    monkeypatch.setattr(G, "KEY_ROWS", 16)
+    eng._engine_prefill_chunk.clear_cache()
+    cfg, params = _model()
+    tracer, m = T.Tracer(), ServingMetrics()
+    with _engine(cfg, params, metrics=m, tracer=tracer) as e:
+        e.admit(Request(rid=1, prompt=tuple(_tokens(5)), max_new_tokens=2,
+                        submitted_at=0.0))
+        e.admit(Request(rid=2, prompt=tuple(_tokens(37)), max_new_tokens=2,
+                        submitted_at=0.0))
+        e.step()
+    eng._engine_prefill_chunk.clear_cache()
+    chunks = [ev.fields for ev in tracer.events
+              if ev.kind == T.SERVE_PREFILL_CHUNK]
+    # the bucket of 8 (8 x 8 gathered rows are the lane's 64), then the
+    # three chunks of 16
+    assert [(c["offset"], c[T.KEY_BLOCKS_LIVE], c[T.KEY_BLOCKS_SKIPPED])
+            for c in chunks] == [(0, 5, 15), (0, 5, 15), (16, 10, 10),
+                                 (32, 15, 5)]
+    assert m.summary()["key_blocks"] == {
+        "live": 35, "skipped": 45, "skipped_share": round(45 / 80, 4)}
+    text = m.registry.to_prometheus_text()
+    assert 'serve_key_blocks_total{kind="live"} 35' in text
+    assert 'serve_key_blocks_total{kind="skipped"} 45' in text
+    steps = [ev.fields for ev in tracer.events if ev.kind == T.SERVE_STEP]
+    assert all(T.KEY_BLOCKS_LIVE not in s for s in steps)
+
+
+def test_a_gathering_chunk_counts_no_key_block():
+    """A bucket of 4 queries x 8 chosen gathers 32 rows where the lane
+    holds 64: the chunk keeps the gather and counts nothing."""
+    cfg, params = _model()
+    tracer, m = T.Tracer(), ServingMetrics()
+    with _engine(cfg, params, buckets=(4,), chunk=0, metrics=m,
+                 tracer=tracer) as e:
+        e.admit(Request(rid=1, prompt=tuple(_tokens(3)), max_new_tokens=2,
+                        submitted_at=0.0))
+        e.step()
+    chunks = [ev.fields for ev in tracer.events
+              if ev.kind == T.SERVE_PREFILL_CHUNK]
+    assert [(c[T.KEY_BLOCKS_LIVE], c[T.KEY_BLOCKS_SKIPPED])
+            for c in chunks] == [(0, 0)]
+    assert "key_blocks" not in m.summary()
+    assert "serve_key_blocks" not in m.registry.to_prometheus_text()
+
+
 def test_other_models_count_no_index_position():
     cfg = TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
                             n_layers=1, d_ff=64, max_seq=16, rope=True,
@@ -391,6 +582,11 @@ def test_other_models_count_no_index_position():
         T.INDEX_SELECTED] == 0
     assert "index" not in m.summary()
     assert "serve_index_positions" not in m.registry.to_prometheus_text()
+    # nor a key block: its prefill is no chunk through the cache
+    assert not [ev for ev in tracer.events
+                if ev.kind == T.SERVE_PREFILL_CHUNK]
+    assert "key_blocks" not in m.summary()
+    assert "serve_key_blocks" not in m.registry.to_prometheus_text()
 
 
 def test_route_counts_see_the_sparse_layers_only():
@@ -557,8 +753,11 @@ def test_the_scopes_are_in_the_decode_and_the_chunk_programs():
         for sc in T.SERVING_SCOPES:
             assert f"/{sc}/" in names, sc
         assert "/attention/" not in names
-        # the choice (a top-k beside the router's) is the indexer's
-        top_ks = set(re.findall(r'op_name="[^"]*/(\w+)/top_k', hlo))
+        # the choice (a top-k beside the router's) is the indexer's; in
+        # the chunk program it lies inside the switch over the lane's
+        # prefixes, under the same scope
+        top_ks = {next(sc for sc in T.SERVING_SCOPES if f"/{sc}/" in name)
+                  for name in re.findall(r'op_name="([^"]*/top_k)', hlo)}
         assert top_ks == {T.SCOPE_SPARSE_INDEXER, T.SCOPE_MOE_ROUTER}
 
 
@@ -571,6 +770,13 @@ def test_the_choice_of_path_is_said_once(capfd):
     err = capfd.readouterr().err
     said = [ln for ln in err.splitlines()
             if ln.startswith("attention[sparse_latent]")]
-    assert said and all("reference:_selected_latent_attention" in ln
-                        and "chosen=8" in ln for ln in said)
+    assert said and all("chosen=8 of=80" in ln for ln in said)
     assert len(said) == len(set(said))
+    # a chunk's 16 queries share a lane and attend it in place; the step's
+    # one query a lane gathers its rows
+    chunk = [ln for ln in said if "q=1x16x" in ln]
+    step = [ln for ln in said if "q=3x1x" in ln]
+    assert len(chunk) == len(step) == 1
+    assert "reference:_masked_latent_attention" in chunk[0] \
+        and "key_block=16" in chunk[0]
+    assert "reference:_selected_latent_attention" in step[0]

@@ -199,6 +199,14 @@ class TestSpanPrimitive:
             "index_scanned": (indexer, "glm_decode_roofline"),
             "index_selected": (indexer,
                                "glm_selected_pct, glm_decode_roofline")}
+        # a prefill chunk's masked attentions: the lane's key blocks they
+        # scored and left unscored
+        assert T.SPAN_FIELDS[T.SERVE_PREFILL_CHUNK] == {
+            "key_blocks_live": (T.SCOPES[T.SCOPE_MLA_ATTENTION][0], "-"),
+            "key_blocks_skipped": (T.SCOPES[T.SCOPE_MLA_ATTENTION][0],
+                                   "-")}
+        assert (T.KEY_BLOCKS_LIVE, T.KEY_BLOCKS_SKIPPED) == (
+            "key_blocks_live", "key_blocks_skipped")
 
 
 # -- the engine's phases ----------------------------------------------------
