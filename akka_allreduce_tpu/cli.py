@@ -2135,8 +2135,13 @@ def _add_serve(sub: argparse._SubParsersAction) -> None:
                         "attention_method MLA the shortcut double layer "
                         "with latent attention and a dropless expert share "
                         "(experts_held: [offset, count] in the file says "
-                        "which experts this chip holds). torch_dtype "
-                        "bfloat16 serves in bf16")
+                        "which experts this chip holds); with model_type "
+                        "glm_moe_dsa latent attention over an indexer's "
+                        "selection, layer by layer; with model_type "
+                        "granitemoehybrid state-space mixers beside "
+                        "attention without positions (a float32 recurrent "
+                        "state a lane). torch_dtype bfloat16 serves in "
+                        "bf16")
     p.add_argument("--max-seq", type=int, default=128,
                    help="KV-cache length per slot; every request needs "
                         "prompt + max-new-tokens <= this")
@@ -2172,8 +2177,10 @@ def _add_serve(sub: argparse._SubParsersAction) -> None:
                         "bucket through the cache in chunks of this many "
                         "positions, one compiled program run once a chunk "
                         "(the in-process slot engine, for a --model-config "
-                        "whose attention reads an indexer's selection; "
-                        "must divide --max-seq; 0 = off)")
+                        "described layer by layer: attention over an "
+                        "indexer's selection, or state-space mixers that "
+                        "scan on from the lane's state; must divide "
+                        "--max-seq; 0 = off)")
     # -- sampling + speculative decode (ISSUE 10)
     p.add_argument("--temperature", type=float, default=0.0,
                    help="sampling temperature for every decode pick: "

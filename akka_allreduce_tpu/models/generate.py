@@ -36,6 +36,7 @@ from jax import lax
 from akka_allreduce_tpu.models.transformer import (
     TransformerConfig,
     apply_rope,
+    embed_tokens,
     lm_logits,
     rmsnorm,
 )
@@ -55,6 +56,9 @@ from akka_allreduce_tpu.runtime.tracing import (
     SCOPE_DENSE_FFN,
     SCOPE_MLA_ATTENTION,
     SCOPE_SPARSE_INDEXER,
+    SCOPE_SSM_MIXER,
+    SCOPE_SSM_SCAN,
+    SCOPE_SSM_STEP,
 )
 
 # the eps of the index key's LayerNorm (the source family's; no key of a
@@ -103,12 +107,46 @@ def init_kv_cache(cfg: TransformerConfig, batch: int,
         cache = {"latent": jnp.zeros((cfg.n_attentions, batch, cfg.max_seq,
                                       cfg.latent_row), cfg.dtype),
                  "pos": jnp.zeros((), jnp.int32)}
-        if cfg.layerwise:
+        if cfg.indexed:
             # one index key a token for each layer that has an indexer
             cache["index_k"] = jnp.zeros(
                 (len(cfg.full_layers), batch, cfg.max_seq,
                  cfg.index_head_dim), cfg.dtype)
         return cache
+    if cfg.hybrid:
+        # by layer kind: a state-space layer holds what its recurrence has
+        # come to (float32, ``ssm_heads`` x ``ssm_head_dim`` x ``ssm_state``
+        # a lane; ``ssm_state[j]`` is layer ``ssm_layers[j]``'s) and the
+        # last ``ssm_conv - 1`` inputs of its convolution (``conv_state``);
+        # both are OVERWRITTEN as the lane advances and neither grows with
+        # the context. An attention layer holds keys and values a position.
+        if kv_dtype is not None:
+            raise NotImplementedError(
+                f"kv_dtype={kv_dtype!r}: the hybrid's cache has no quantized "
+                f"format (missing: a scale a written key and value of its "
+                f"attention layers; the recurrent state stays float32)")
+        n_ssm, n_attn = len(cfg.ssm_layers), len(cfg.attention_layers)
+        kv_shape = (n_attn, batch, cfg.max_seq, cfg.kv_heads, cfg.head_dim)
+        # a buffer a state-space layer, not one stacked over the layers: a
+        # step rewrites ALL of a layer's state, so each layer's update is
+        # an elementwise program over its own donated buffer. (Stacked, the
+        # step is a chain of in-place slice updates of one 2.4 GB buffer
+        # from which each layer also reads its slice, and the TPU's
+        # compiler rematerialised the first layer's update for its two
+        # readers in place: that layer's state advanced twice a step; chip
+        # runs, PR 34, PERF.md section 6.)
+        return {
+            "ssm_state": tuple(
+                jnp.zeros((batch, cfg.ssm_heads, cfg.ssm_head_dim,
+                           cfg.ssm_state), jnp.float32)
+                for _ in range(n_ssm)),
+            "conv_state": tuple(
+                jnp.zeros((batch, cfg.ssm_conv - 1, cfg.ssm_conv_dim),
+                          cfg.dtype) for _ in range(n_ssm)),
+            "k": jnp.zeros(kv_shape, cfg.dtype),
+            "v": jnp.zeros(kv_shape, cfg.dtype),
+            "pos": jnp.zeros((), jnp.int32),
+        }
     shape = (cfg.n_layers, batch, cfg.max_seq, cfg.kv_heads, cfg.head_dim)
     if kv_dtype is None:
         return {
@@ -150,6 +188,12 @@ def init_kv_pool(cfg: TransformerConfig, num_pages: int, page_size: int,
     if num_pages < 1 or page_size < 1:
         raise ValueError(f"num_pages/page_size must be >= 1, got "
                          f"{num_pages}/{page_size}")
+    if cfg.hybrid:
+        raise NotImplementedError(
+            f"the paged pool cannot hold {cfg.new_kind} (missing: no page "
+            f"holds a recurrent state - it is one buffer a lane that every "
+            f"step overwrites, so it has no positions to page, and a shared "
+            f"prefix would need a snapshot of it a page boundary)")
     if cfg.new_kind is not None:
         raise NotImplementedError(
             f"the paged pool cannot hold {cfg.new_kind} (missing: a latent "
@@ -261,7 +305,8 @@ def _rope_slots(x: jnp.ndarray, positions: jnp.ndarray,
 
 def _slot_cached_attention(q: jnp.ndarray, k_all: jnp.ndarray,
                            v_all: jnp.ndarray, pos: jnp.ndarray,
-                           window: "int | None" = None) -> jnp.ndarray:
+                           window: "int | None" = None,
+                           scale: "float | None" = None) -> jnp.ndarray:
     """``_cached_attention`` with the scalar decode position generalized
     to (slots,): row b masks by ITS ``pos[b]``.
     Same einsum structure, f32 score/softmax, and cast points; the
@@ -272,23 +317,37 @@ def _slot_cached_attention(q: jnp.ndarray, k_all: jnp.ndarray,
     decode keeps the mask-only form (positions outside the window mask
     to NEG_INF; exp underflows to exactly 0.0): per-step cost stays
     O(max_seq) rather than generate()'s O(window) slice, a trade for
-    per-row window offsets that only shows at long max_seq."""
-    b, one, h, d = q.shape
+    per-row window offsets that only shows at long max_seq.
+
+    ``pos`` (slots, t) gives every one of t queries a row its own
+    position (a prefill's or a chunk's queries through the cache: the
+    hybrid's attention without positions, where the mask alone orders the
+    tokens), :data:`QUERY_ROWS` queries against the whole lane at a time
+    (a chunk's 2,048 queries x 32 heads x 6,144 keys in float32 would be
+    1.6 GB at once). ``scale`` is a STATED score scale (default
+    ``d ** -0.5``)."""
+    h, d = q.shape[2:]
     h_kv = k_all.shape[2]
-    g = h // h_kv
-    qg = q.reshape(b, one, h_kv, g, d)
-    scale = d ** -0.5
-    k_idx = jnp.arange(k_all.shape[1])
-    scores = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k_all,
-                        preferred_element_type=jnp.float32) * scale
-    valid = k_idx[None, :] <= pos[:, None]  # (slots, max_seq)
-    if window is not None:
-        valid &= k_idx[None, :] > pos[:, None] - window
-    scores = jnp.where(valid[:, None, None, None, :], scores, NEG_INF)
-    p = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(v_all.dtype), v_all,
-                     preferred_element_type=jnp.float32)
-    return out.reshape(b, one, h, d).astype(q.dtype)
+    if scale is None:
+        scale = d ** -0.5
+
+    def attend(q, pos):
+        qg = q.reshape(q.shape[:2] + (h_kv, h // h_kv, d))
+        k_idx = jnp.arange(k_all.shape[1])
+        scores = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k_all,
+                            preferred_element_type=jnp.float32) * scale
+        valid = k_idx[None, :] <= pos[..., None]  # (slots, [t,] max_seq)
+        if window is not None:
+            valid &= k_idx[None, :] > pos[..., None] - window
+        # -> (slots, 1, 1, t, max_seq)
+        valid = jnp.expand_dims(valid, (1, 2, 3)[:5 - valid.ndim])
+        scores = jnp.where(valid, scores, NEG_INF)
+        p = jax.nn.softmax(scores, axis=-1)
+        out = jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(v_all.dtype), v_all,
+                         preferred_element_type=jnp.float32)
+        return out.reshape(q.shape).astype(q.dtype)
+
+    return _over_query_rows(attend, q, pos)
 
 
 def _write_slot_rows(cache: jnp.ndarray, layer: int, vals: jnp.ndarray,
@@ -336,7 +395,10 @@ class CacheOps:
     ``offset`` and ``lane`` (scalars, with ``pos`` None and b = 1) make
     the prefill a CHUNK: positions offset..offset+t-1 of cache lane
     ``lane``, which holds the positions before them (the layer-by-layer
-    kind only: its queries attend through the cache)."""
+    kinds only: their queries attend through the cache, and a state-space
+    layer scans on from the lane's state; at ``offset`` 0 from zeros).
+    For a state-space layer ``counted`` also says which tokens advance the
+    state and enter the convolution's tail (padding does neither)."""
     pos: Optional[jnp.ndarray] = None
     write_mask: Optional[jnp.ndarray] = None
     counted: Optional[jnp.ndarray] = None
@@ -938,6 +1000,196 @@ def _layerwise_cached_block(layer: dict, x: jnp.ndarray, kv: dict, i: int,
     return x, kv, None, chosen
 
 
+def ssm_scan_path(t: int, chunk: int) -> "int | None":
+    """How a state-space mixer runs ``t`` tokens a sequence, from the shape
+    alone: None for ONE step of the recurrence (a decode step: the state
+    is multiplied and added to, read out once, and nothing is a matmul);
+    else the block length of the chunked form (:func:`_ssd_scan`): the
+    published ``chunk`` (256), or all ``t`` tokens where they are fewer.
+    Same numbers either way. No option chooses."""
+    return None if t == 1 else min(t, chunk)
+
+
+def _ssd_scan(x, dt, a, bm, cm, h0, blk: int, dtype):
+    """The recurrence ``H_t = exp(dt_t a) H_{t-1} + dt_t x_t (x) B_t``,
+    ``y_t = H_t C_t``, over x (b, t, heads, p), dt (b, t, heads) float32
+    (0 where a token is padding: it neither decays the state nor adds to
+    it), a (heads,) negative, bm / cm (b, t, n), from h0 (b, heads, p, n)
+    float32, in the chunked form: a ``lax.scan`` over blocks of ``blk``
+    tokens that carries the state in float32; inside a block every token's
+    read-out of the tokens before it in the block is a masked matmul
+    (``C B^T`` times the decay between the two tokens), the carried state's
+    part one more, the block's own sum into the state a third. Matmul
+    operands in ``dtype`` (the configuration's), sums, decays and the state
+    in float32. Returns (y (b, t, heads, p) float32, H_t (b, heads, p, n)
+    float32)."""
+    b, t, heads, p = x.shape
+    blocks = -(-t // blk)
+    pad = blocks * blk - t
+
+    def split(v):
+        v = jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),) * (v.ndim - 2))
+        return jnp.moveaxis(
+            v.reshape((b, blocks, blk) + v.shape[2:]), 1, 0)
+
+    xs = (split(x.astype(jnp.float32) * dt[..., None]), split(dt * a),
+          split(bm), split(cm))
+    causal = jnp.tril(jnp.ones((blk, blk), bool))
+
+    def block(h, args):
+        xdt, da, bb, cb = args
+        # a head at a time inside a block: (b, heads, tokens, ...), so that
+        # the decays between two tokens lie tokens-minor and every product
+        # below is one batched matmul over the heads
+        xdt = jnp.moveaxis(xdt, 2, 1)                     # (b, heads, s, p)
+        cum = jnp.cumsum(da, axis=1).swapaxes(1, 2)       # (b, heads, q)
+        # decay from token s to token q >= s, a head: exp of a sum of
+        # negatives, never above 1 (masked BEFORE the exp)
+        gap = cum[:, :, :, None] - cum[:, :, None, :]     # (b, heads, q, s)
+        decay = jnp.exp(jnp.where(causal, gap, -jnp.inf))
+        g = jnp.einsum("bqn,bsn->bqs", cb, bb,
+                       preferred_element_type=jnp.float32)
+        m = (g[:, None] * decay).astype(dtype)
+        y = jnp.einsum("bhqs,bhsp->bhqp", m, xdt.astype(dtype),
+                       preferred_element_type=jnp.float32)
+        # what the carried state adds: C_q . H, faded by the decay since
+        # the block began
+        y = y + jnp.einsum("bqn,bhpn->bhqp", cb, h.astype(dtype),
+                           preferred_element_type=jnp.float32) \
+            * jnp.exp(cum)[..., None]
+        # the state at the block's end
+        to_end = jnp.exp(cum[:, :, -1:] - cum)            # (b, heads, s)
+        h = h * jnp.exp(cum[:, :, -1])[:, :, None, None] + jnp.einsum(
+            "bhsp,bsn->bhpn", (xdt * to_end[..., None]).astype(dtype), bb,
+            preferred_element_type=jnp.float32)
+        return h, y
+
+    h, ys = lax.scan(block, h0, xs)          # ys (blocks, b, heads, q, p)
+    y = jnp.transpose(ys, (1, 0, 3, 2, 4)).reshape(
+        b, blocks * blk, heads, p)
+    return y[:, :t], h
+
+
+def _ssm_mixer(p: dict, u: jnp.ndarray, kv: dict, j: int,
+               cfg: TransformerConfig, ops: CacheOps):
+    """State-space mixer ``j`` (Mamba-2) over its normed input u (b, t, d)
+    through ``kv["ssm_state"][j]`` and ``kv["conv_state"][j]`` (a buffer
+    a layer), the first cache entries that are OVERWRITTEN and not
+    appended to: ``[z | xBC |
+    dt] = u w_in``; a causal depthwise convolution of width ``ssm_conv``
+    over xBC, continued from the lane's tail (its last ``ssm_conv - 1``
+    inputs), then SiLU; ``H <- exp(delta A) H + delta x (x) B``, ``y = H C +
+    D x`` a head, delta = softplus(dt + dt_bias), A = -exp(a_log); ``y <-
+    rmsnorm(y * silu(z))`` (the gate BEFORE the norm, one group); ``w_out``.
+    A decode step (t = 1) is one step of the recurrence for every row of
+    the batch; a prefill or a chunk is :func:`_ssd_scan` from the lane's
+    state (a chunk at ``offset`` > 0) or from zeros (``offset`` 0, or a
+    whole prefill), and which it is :func:`ssm_scan_path` says once on
+    stderr (``attention[ssm_scan]``). Tokens that are not ``ops.counted``
+    leave state and tail as the last counted token left them. Returns (the
+    mixer's output (b, t, d), kv)."""
+    b, t, _ = u.shape
+    heads, hd, n = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state
+    inner, width = cfg.ssm_inner, cfg.ssm_conv
+    kv = dict(kv)
+    proj = u @ p["w_in"]
+    z, xbc, dt = (proj[..., :inner], proj[..., inner:inner + cfg.ssm_conv_dim],
+                  proj[..., inner + cfg.ssm_conv_dim:])
+    tail, h0 = kv["conv_state"][j], kv["ssm_state"][j]
+    if ops.lane is not None:
+        tail = lax.dynamic_index_in_dim(tail, ops.lane, 0)
+        h0 = lax.dynamic_index_in_dim(h0, ops.lane, 0)
+    if ops.pos is None:
+        # what a lane held belongs to its last request: a sequence that
+        # starts here starts from nothing
+        fresh = True if ops.offset is None else ops.offset == 0
+        tail = jnp.where(fresh, jnp.zeros_like(tail), tail)
+        h0 = jnp.where(fresh, jnp.zeros_like(h0), h0)
+    counted = (jnp.ones((b, t), bool) if ops.counted is None or t == 1
+               else ops.counted.reshape(b, t))
+    seq = jnp.concatenate([tail, xbc], axis=1)       # (b, width - 1 + t, c)
+    conv = sum(seq[:, i:i + t].astype(jnp.float32)
+               * p["conv_w"][:, i].astype(jnp.float32)
+               for i in range(width))
+    xbc = jax.nn.silu(conv + p["conv_b"].astype(jnp.float32)).astype(u.dtype)
+    if t == 1:
+        new_tail = seq[:, 1:]
+    else:
+        # the last width - 1 inputs at or before the last counted token
+        n_valid = counted.sum(axis=1)
+        new_tail = jax.vmap(lambda s, at: lax.dynamic_slice_in_dim(
+            s, at, width - 1, axis=0))(seq, n_valid)
+    x = xbc[..., :inner].reshape(b, t, heads, hd)
+    bm, cm = xbc[..., inner:inner + n], xbc[..., inner + n:]
+    a = -jnp.exp(p["a_log"].astype(jnp.float32))
+    delta = jax.nn.softplus(dt.astype(jnp.float32)
+                            + p["dt_bias"].astype(jnp.float32))
+    delta = jnp.where(counted[..., None], delta, 0.0)
+    blk = ssm_scan_path(t, cfg.ssm_chunk)
+    if blk is None:
+        say_attention("ssm_scan", "reference:recurrence_step", x)
+        with jax.named_scope(SCOPE_SSM_STEP):
+            d1, x1 = delta[:, 0], x[:, 0].astype(jnp.float32)
+            h = h0 * jnp.exp(d1 * a)[:, :, None, None] \
+                + (d1[..., None] * x1)[..., None] \
+                * bm[:, 0].astype(jnp.float32)[:, None, None, :]
+            y = jnp.einsum("bhpn,bn->bhp", h,
+                           cm[:, 0].astype(jnp.float32))[:, None]
+    else:
+        say_attention("ssm_scan", "reference:_ssd_scan", x, block=blk,
+                      blocks=-(-t // blk))
+        with jax.named_scope(SCOPE_SSM_SCAN):
+            y, h = _ssd_scan(x, delta, a, bm, cm, h0, blk, u.dtype)
+    y = y + p["d"].astype(jnp.float32)[:, None] * x.astype(jnp.float32)
+    new_tail = new_tail.astype(kv["conv_state"][j].dtype)
+    if ops.lane is not None:
+        h = lax.dynamic_update_slice(kv["ssm_state"][j], h,
+                                     (ops.lane, 0, 0, 0))
+        new_tail = lax.dynamic_update_slice(kv["conv_state"][j], new_tail,
+                                            (ops.lane, 0, 0))
+    for name, new in (("ssm_state", h), ("conv_state", new_tail)):
+        kv[name] = kv[name][:j] + (new,) + kv[name][j + 1:]
+    y = y.reshape(b, t, inner).astype(u.dtype) * jax.nn.silu(z)
+    return rmsnorm(y, p["norm"], cfg.norm_eps) @ p["w_out"], kv
+
+
+def _hybrid_cached_block(layer: dict, x: jnp.ndarray, kv: dict, i: int,
+                         cfg: TransformerConfig, ops: CacheOps):
+    """One layer of the hybrid: its mixer - a state-space recurrence
+    (:func:`_ssm_mixer`) or GQA attention WITHOUT any position signal, at
+    the stated score scale, through the ``k`` / ``v`` cache as the dense
+    block has it (:func:`_slot_cached_attention` over the lane's rows, the
+    fresh ones written first: a decode step's query, a prefill's or a
+    chunk's queries alike) - then the expert share
+    with its shared expert; each branch joins the residual times
+    ``residual_scale``. Returns (x, kv, the expert layer's counts)."""
+    b, t, d = x.shape
+    scale = jnp.asarray(cfg.residual_scale, x.dtype)
+    h = rmsnorm(x, layer["ln1"], cfg.norm_eps)
+    if "ssm" in layer:
+        with jax.named_scope(SCOPE_SSM_MIXER):
+            out, kv = _ssm_mixer(layer["ssm"], h, kv,
+                                 cfg.ssm_layers.index(i), cfg, ops)
+    else:
+        a = cfg.attention_layers.index(i)
+        kv = dict(kv)
+        with jax.named_scope(SCOPE_ATTENTION):
+            q = (h @ layer["wq"]).reshape(b, t, cfg.n_heads, cfg.head_dim)
+            k = (h @ layer["wk"]).reshape(b, t, cfg.kv_heads, cfg.head_dim)
+            v = (h @ layer["wv"]).reshape(b, t, cfg.kv_heads, cfg.head_dim)
+            kv["k"] = ops.write(kv["k"], a, k.astype(kv["k"].dtype))
+            kv["v"] = ops.write(kv["v"], a, v.astype(kv["v"].dtype))
+            attn = _slot_cached_attention(
+                q, ops.lane_rows(kv["k"], a), ops.lane_rows(kv["v"], a),
+                ops.positions(b, t), scale=cfg.attn_scale)
+            out = attn.reshape(b, t, -1) @ layer["wo"]
+    x = x + out * scale
+    h = rmsnorm(x, layer["ln2"], cfg.norm_eps)
+    m, counts = dropless_moe(h.reshape(b * t, d), layer["moe"], cfg.experts,
+                             ops.counted)
+    return x + m.reshape(b, t, d) * scale, kv, counts
+
+
 def cached_blocks(params: dict, x: jnp.ndarray, kv: dict,
                   cfg: TransformerConfig, ops: CacheOps):
     """Every block of the model over x (b, t, d) through the cache:
@@ -945,12 +1197,14 @@ def cached_blocks(params: dict, x: jnp.ndarray, kv: dict,
     shortcut and the layer-by-layer kinds the expert layers' counts summed
     over the layers (``held`` and ``identity`` a token, (b*t,);
     ``touched`` a scalar). The layer-by-layer kind's selection goes from
-    a full layer to the shared layers after it here."""
+    a full layer to the shared layers after it here; the hybrid's layers
+    each take their mixer's kind from ``cfg.layer_mixer``."""
     block = (_shortcut_cached_block if cfg.block == "shortcut"
+             else _hybrid_cached_block if cfg.hybrid
              else _dense_cached_block)
     total = chosen = None
     for i, layer in enumerate(params["layers"]):
-        if cfg.layerwise:
+        if cfg.indexed:
             x, kv, counts, chosen = _layerwise_cached_block(
                 layer, x, kv, i, cfg, ops, chosen)
         else:
@@ -971,8 +1225,8 @@ def decode_step(params: dict, cache: dict, token: jnp.ndarray,
     with the full forward is pinned by tests/test_generate.py.
     """
     pos = cache["pos"]
-    x = params["embed"][token][:, None, :]
-    if not cfg.rope:
+    x = embed_tokens(params, token, cfg)[:, None, :]
+    if cfg.learned_positions:
         x = x + lax.dynamic_slice_in_dim(params["pos"], pos, 1,
                                          axis=0)[None]
     kv = {n: c for n, c in cache.items() if n != "pos"}
@@ -989,8 +1243,8 @@ def prefill_counted(params: dict, cache: dict, prompt: jnp.ndarray,
     (None for the dense kind). With ``logit_pos`` the positions after it
     are padding and count nowhere."""
     b, t = prompt.shape
-    x = params["embed"][prompt]
-    if not cfg.rope:
+    x = embed_tokens(params, prompt, cfg)
+    if cfg.learned_positions:
         x = x + params["pos"][:t][None]
     counted = None
     if logit_pos is not None:

@@ -18,6 +18,7 @@ dp-only data-parallel trainer, and the full dp x tp x sp training step
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Callable, Optional
 
 import jax
@@ -100,6 +101,21 @@ class TransformerConfig:
     #   full layer before it chose ("shared"). The cache holds a latent a
     #   layer and an index key a FULL layer. ``mla_lora_scales`` False
     #   runs the latent attention without its two lora scales.
+    # * block = "standard" with ``layer_mixer`` is the HYBRID described layer
+    #   by layer: ``layer_mixer[i]`` says whether layer i mixes its tokens
+    #   with a state-space recurrence ("ssm": Mamba-2, ``ssm_heads`` heads of
+    #   ``ssm_head_dim`` over a state of ``ssm_state`` numbers a channel that
+    #   all heads' B and C share, a causal depthwise convolution of width
+    #   ``ssm_conv`` ahead of it, ``ssm_chunk`` the published block length
+    #   of its chunked form) or with an "attention": GQA (``n_heads`` /
+    #   ``n_kv_heads``) with NO position signal (``rope`` False and no
+    #   ``pos`` table) and the stated score scale ``attn_scale``. Every
+    #   layer's FFN is the ``experts`` share. The cache holds a recurrent
+    #   state and a convolution tail a lane an ssm layer (OVERWRITTEN each
+    #   step, whatever the context) and keys and values an attention layer.
+    #   ``embed_scale`` multiplies the embedding, ``residual_scale`` every
+    #   branch before it joins the residual, ``logit_divisor`` divides the
+    #   head's output (all 1 for every other kind).
     attention: str = "gqa"
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
@@ -114,6 +130,16 @@ class TransformerConfig:
     index_head_dim: int = 0
     index_topk: int = 0
     mla_lora_scales: bool = True
+    layer_mixer: tuple = ()
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_conv: int = 0
+    ssm_chunk: int = 0
+    attn_scale: Optional[float] = None
+    embed_scale: float = 1.0
+    residual_scale: float = 1.0
+    logit_divisor: float = 1.0
 
     @property
     def head_dim(self) -> int:
@@ -135,7 +161,7 @@ class TransformerConfig:
         for the double layer's cache, whose fused kernel reads it so: a
         gather of rows from that layout touches every tile of the
         lane.)"""
-        if not self.layerwise:
+        if not self.indexed:
             return self.latent_dim
         return -(-self.latent_dim // 128) * 128
 
@@ -150,10 +176,55 @@ class TransformerConfig:
                 (self.d_model / self.kv_lora_rank) ** 0.5)
 
     @property
-    def layerwise(self) -> bool:
-        """Is this the model described layer by layer (latent attention
-        over an indexer's selection in a standard block)?"""
+    def learned_positions(self) -> bool:
+        """Does the model add a learned ``pos`` table to its embedding?
+        (Not under rope; not the hybrid, whose state-space layers order
+        the tokens and whose attention has no position signal.)"""
+        return not self.rope and not self.hybrid
+
+    @property
+    def hybrid(self) -> bool:
+        """Does ``layer_mixer`` say, layer by layer, which layers carry a
+        recurrent state and which attend without positions?"""
+        return bool(self.layer_mixer)
+
+    @property
+    def indexed(self) -> bool:
+        """Latent attention over an indexer's selection in a standard
+        block (``layer_ffn`` / ``layer_indexer``)."""
         return self.block == "standard" and self.attention == "mla"
+
+    @property
+    def layerwise(self) -> bool:
+        """Is this a model described layer by layer (:attr:`indexed` or
+        :attr:`hybrid`)? Its cached block reads and writes the cache in
+        place, so its prompts go through the cache, in chunks where they
+        are long."""
+        return self.indexed or self.hybrid
+
+    @property
+    def ssm_layers(self) -> tuple:
+        """The layers whose mixer is a state-space recurrence, in order:
+        layer ``ssm_layers[j]`` owns state and tail entry ``j``."""
+        return tuple(i for i, kind in enumerate(self.layer_mixer)
+                     if kind == "ssm")
+
+    @property
+    def attention_layers(self) -> tuple:
+        """The hybrid's attention layers, in order: layer
+        ``attention_layers[a]`` owns ``k`` / ``v`` cache entry ``a``."""
+        return tuple(i for i, kind in enumerate(self.layer_mixer)
+                     if kind == "attention")
+
+    @property
+    def ssm_inner(self) -> int:
+        """Channels of a state-space mixer (heads x head size)."""
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Channels the convolution runs over: x, B and C side by side."""
+        return self.ssm_inner + 2 * self.ssm_state
 
     @property
     def n_attentions(self) -> int:
@@ -226,13 +297,25 @@ class TransformerConfig:
             raise ValueError(
                 "the shortcut double layer comes with latent attention "
                 "and an `experts` share")
-        if self.experts is not None and self.attention != "mla":
+        if self.experts is not None and self.attention != "mla" \
+                and not self.hybrid:
             raise ValueError(
                 "an `experts` share comes with latent attention (the "
-                "shortcut double layer, or layer by layer)")
+                "shortcut double layer, or layer by layer) or with "
+                "`layer_mixer`")
         described = (self.layer_ffn, self.layer_indexer, self.index_n_heads,
                      self.index_head_dim, self.index_topk)
-        if self.layerwise:
+        hybrid_sizes = (self.ssm_heads, self.ssm_head_dim, self.ssm_state,
+                        self.ssm_conv, self.ssm_chunk)
+        if self.hybrid:
+            self._check_hybrid(hybrid_sizes)
+        elif any(hybrid_sizes) or self.attn_scale is not None or (
+                self.embed_scale, self.residual_scale,
+                self.logit_divisor) != (1.0, 1.0, 1.0):
+            raise ValueError(
+                "ssm_* / attn_scale / embed_scale / residual_scale / "
+                "logit_divisor describe the hybrid of `layer_mixer`")
+        elif self.indexed:
             self._check_layerwise()
         elif any(described) or not self.mla_lora_scales:
             raise ValueError(
@@ -276,6 +359,32 @@ class TransformerConfig:
                 "the layer-by-layer model has swiglu dense FFNs and an "
                 "untied head, and its expert layers are `experts`")
 
+    def _check_hybrid(self, sizes: tuple) -> None:
+        n = self.n_layers
+        if len(self.layer_mixer) != n \
+                or set(self.layer_mixer) - {"ssm", "attention"}:
+            raise ValueError(
+                f"layer_mixer says 'ssm' | 'attention' for each of the {n} "
+                f"layers, got {self.layer_mixer}")
+        if min(sizes) < 1:
+            raise ValueError(
+                f"the state-space mixer needs its five sizes (ssm_heads, "
+                f"ssm_head_dim, ssm_state, ssm_conv, ssm_chunk), got {sizes}")
+        if self.block != "standard" or self.attention != "gqa" or self.rope \
+                or self.attn_window is not None or self.attn_scale is None:
+            raise ValueError(
+                "the hybrid's attention is GQA in a standard block with no "
+                "position signal (rope False), no window and a stated "
+                "attn_scale")
+        if self.experts is None or self.ffn != "swiglu" \
+                or self.moe is not None \
+                or self.layer_ffn != ("sparse",) * n or self.layer_indexer \
+                or self.index_n_heads or self.index_head_dim \
+                or self.index_topk or not self.mla_lora_scales:
+            raise ValueError(
+                "every layer of the hybrid ends in the `experts` share "
+                "(layer_ffn all 'sparse', no indexer, no `moe`)")
+
     @property
     def new_kind(self) -> Optional[str]:
         """None for the Llama-family block every path runs; else what a
@@ -283,7 +392,11 @@ class TransformerConfig:
         if self.block == "shortcut":
             return ("the shortcut double layer with latent attention "
                     "(block='shortcut', attention='mla')")
-        if self.layerwise:
+        if self.hybrid:
+            return ("state-space mixers beside attention without "
+                    "positions, layer by layer (block='standard', "
+                    "layer_mixer)")
+        if self.indexed:
             return ("latent attention over an indexer's selection, layer "
                     "by layer (block='standard', attention='mla')")
         return None
@@ -321,10 +434,26 @@ def rmsnorm(x: jnp.ndarray, scale: jnp.ndarray,
 def lm_logits(params: dict, x: jnp.ndarray,
               cfg: TransformerConfig) -> jnp.ndarray:
     """Output head: the lm_head matmul, or the transposed embedding under
-    weight tying (one shared matrix serving both ends)."""
+    weight tying (one shared matrix serving both ends); divided by
+    ``cfg.logit_divisor`` where the configuration has one."""
     if cfg.tie_embeddings:
-        return x @ params["embed"].T
-    return x @ params["lm_head"]
+        logits = x @ params["embed"].T
+    else:
+        logits = x @ params["lm_head"]
+    if cfg.logit_divisor != 1.0:
+        logits = logits / cfg.logit_divisor
+    return logits
+
+
+def embed_tokens(params: dict, tokens: jnp.ndarray,
+                 cfg: TransformerConfig) -> jnp.ndarray:
+    """The embedding rows of ``tokens``, times ``cfg.embed_scale`` where
+    the configuration has one (the learned ``pos`` table, where a model has
+    one, is its caller's to add)."""
+    x = params["embed"][tokens]
+    if cfg.embed_scale != 1.0:
+        x = x * jnp.asarray(cfg.embed_scale, x.dtype)
+    return x
 
 
 def init_transformer(key: jax.Array, cfg: TransformerConfig,
@@ -439,6 +568,62 @@ def init_indexer(key: jax.Array, cfg: TransformerConfig) -> dict:
     }
 
 
+def init_ssm_mixer(key: jax.Array, cfg: TransformerConfig) -> dict:
+    """One state-space mixer's leaves (Mamba-2): ``w_in`` (hidden -> z |
+    x B C | dt), the depthwise convolution ``conv_w`` (channels, width) and
+    ``conv_b``, a head's ``dt_bias``, ``a_log`` and ``d`` (float32), the
+    gated norm's gain ``norm`` and ``w_out``. The recurrence's constants
+    take the Mamba-2 initialisation: ``A = exp(a_log)`` uniform in 1-16,
+    ``softplus(dt_bias)`` log-uniform in 0.001-0.1, ``d`` ones, so that a
+    head remembers tens to thousands of tokens."""
+    d, dt = cfg.d_model, cfg.dtype
+    inner, heads = cfg.ssm_inner, cfg.ssm_heads
+    k = jax.random.split(key, 5)
+    step = jnp.exp(jax.random.uniform(
+        k[3], (heads,), jnp.float32, math.log(1e-3), math.log(1e-1)))
+    return {
+        "w_in": _normal(k[0], (d, 2 * inner + 2 * cfg.ssm_state + heads),
+                        dt),
+        "conv_w": jax.random.normal(k[1], (cfg.ssm_conv_dim, cfg.ssm_conv),
+                                    dt) * cfg.ssm_conv ** -0.5,
+        "conv_b": jnp.zeros((cfg.ssm_conv_dim,), dt),
+        # the inverse of softplus
+        "dt_bias": step + jnp.log(-jnp.expm1(-step)),
+        "a_log": jnp.log(jax.random.uniform(k[4], (heads,), jnp.float32,
+                                            1.0, 16.0)),
+        "d": jnp.ones((heads,), jnp.float32),
+        "norm": jnp.ones((inner,), dt),
+        "w_out": _normal(k[2], (inner, d), dt),
+    }
+
+
+def _init_hybrid(key: jax.Array, cfg: TransformerConfig) -> dict:
+    """The hybrid's tree: ``layers[i]`` holds ``ln1``, either ``ssm``
+    (:func:`init_ssm_mixer`) or the attention's ``wq`` / ``wk`` / ``wv`` /
+    ``wo``, ``ln2`` and ``moe`` (parallel/ep.py ``init_expert_share``); a
+    tied head has no ``lm_head``."""
+    d, dt = cfg.d_model, cfg.dtype
+    d_kv = cfg.kv_heads * cfg.head_dim
+    kg = iter(jax.random.split(key, 2 + 6 * cfg.n_layers))
+    params = {"embed": jax.random.normal(next(kg), (cfg.vocab_size, d), dt)
+              * d ** -0.5,
+              "out_norm": jnp.ones((d,), dt), "layers": []}
+    if not cfg.tie_embeddings:
+        params["lm_head"] = _normal(next(kg), (d, cfg.vocab_size), dt)
+    for kind in cfg.layer_mixer:
+        layer = {"ln1": jnp.ones((d,), dt), "ln2": jnp.ones((d,), dt),
+                 "moe": init_expert_share(next(kg), d, cfg.experts, dt)}
+        if kind == "ssm":
+            layer["ssm"] = init_ssm_mixer(next(kg), cfg)
+        else:
+            layer.update(wq=_normal(next(kg), (d, d), dt),
+                         wk=_normal(next(kg), (d, d_kv), dt),
+                         wv=_normal(next(kg), (d, d_kv), dt),
+                         wo=_normal(next(kg), (d, d), dt))
+        params["layers"].append(layer)
+    return params
+
+
 def _init_new_kind(key: jax.Array, cfg: TransformerConfig) -> dict:
     """The tree of shortcut double layers: ``layers[i]`` holds ``mla``
     and ``ffn`` (two of each; an FFN's ``ln`` is its half's post-attention
@@ -446,6 +631,8 @@ def _init_new_kind(key: jax.Array, cfg: TransformerConfig) -> dict:
     layer-by-layer model: ``mla`` (one), ``indexer`` in a full layer,
     ``ln2`` and either the dense FFN's ``w1`` / ``w3`` / ``w2`` or
     ``moe``."""
+    if cfg.hybrid:
+        return _init_hybrid(key, cfg)
     d, dt = cfg.d_model, cfg.dtype
     kg = iter(jax.random.split(key, 2 + 8 * cfg.n_layers))
 
@@ -461,7 +648,7 @@ def _init_new_kind(key: jax.Array, cfg: TransformerConfig) -> dict:
               "lm_head": _normal(next(kg), (d, cfg.vocab_size), dt),
               "out_norm": jnp.ones((d,), dt), "layers": []}
     for i in range(cfg.n_layers):
-        if not cfg.layerwise:
+        if not cfg.indexed:
             params["layers"].append({
                 "mla": [init_mla(next(kg), cfg) for _ in range(2)],
                 "ffn": [ffn() for _ in range(2)],
@@ -485,23 +672,28 @@ def config_from_hf(hf: dict, max_seq: int, dtype=jnp.bfloat16,
                    ) -> TransformerConfig:
     """A :class:`TransformerConfig` from a published ``config.json`` (or a
     benchmark configuration file that keeps its keys): the one place that
-    knows the key names. Two families are read (the dense block is built
+    knows the key names. Three families are read (the dense block is built
     from the ``--d-model/...`` flags): ``model_type`` "glm_moe_dsa" is the
     model described layer by layer, latent attention over an indexer's
     selection with sigmoid routing and a shared expert
-    (:func:`_config_from_glm_moe_dsa`); ``attention_method`` "MLA" with
+    (:func:`_config_from_glm_moe_dsa`); ``model_type`` "granitemoehybrid"
+    is the hybrid of state-space mixers and attention without positions
+    (:func:`_config_from_granitemoehybrid`); ``attention_method`` "MLA" with
     ``zero_expert_num`` is the shortcut double layer with latent
     attention. ``experts_held`` = (offset, count) of the real experts that
     this chip holds (default: the key ``experts_held`` of ``hf``, the one
     key that no ``config.json`` has; else all of them)."""
     if hf.get("model_type") == "glm_moe_dsa":
         return _config_from_glm_moe_dsa(hf, max_seq, dtype, experts_held)
+    if hf.get("model_type") == "granitemoehybrid":
+        return _config_from_granitemoehybrid(hf, max_seq, dtype,
+                                             experts_held)
     eps = float(hf.get("rms_norm_eps", 1e-6))
     if hf.get("attention_method") != "MLA":
         raise ValueError(
             f"attention_method {hf.get('attention_method')!r}, model_type "
-            f"{hf.get('model_type')!r}: only the MLA shortcut double layer "
-            f"and glm_moe_dsa are read from a config.json")
+            f"{hf.get('model_type')!r}: only the MLA shortcut double layer, "
+            f"glm_moe_dsa and granitemoehybrid are read from a config.json")
     for key in ("mla_scale_q_lora", "mla_scale_kv_lora"):
         if not hf.get(key, False):
             raise ValueError(f"{key} false: latent attention without its "
@@ -583,6 +775,65 @@ def _config_from_glm_moe_dsa(hf: dict, max_seq: int, dtype,
             * hf["moe_intermediate_size"],
             held_offset=int(offset), held_count=int(count))
         if sparse else None)
+
+
+def _config_from_granitemoehybrid(hf: dict, max_seq: int, dtype,
+                                  experts_held) -> TransformerConfig:
+    """``model_type`` "granitemoehybrid": ``layer_types`` ("mamba" |
+    "attention" a layer), the Mamba-2 sizes (``mamba_n_heads`` x
+    ``mamba_d_head`` = ``mamba_expand`` x hidden, ``mamba_d_state``,
+    ``mamba_d_conv``, ``mamba_chunk_size``), GQA without positions at the
+    score scale ``attention_multiplier``, after every mixer the expert
+    block (``num_local_experts`` outputs of width ``intermediate_size``,
+    the ``num_experts_per_tok`` largest weighed by a softmax over THEM -
+    the softmax over all renormalised over the picked - and a shared
+    expert of ``shared_intermediate_size``), the embedding's, the
+    residual's and the logits' multipliers and a tied head.
+    ``num_local_experts`` is the router's width, as the source's
+    ``config.json`` has it; ``experts_held`` says which of them this chip
+    holds. What cannot run is refused by the name of its key."""
+    n = hf["num_hidden_layers"]
+    for key, can in (("mamba_n_groups", 1),
+                     ("position_embedding_type", "nope"),
+                     ("mamba_proj_bias", False), ("attention_bias", False),
+                     ("mamba_conv_bias", True), ("hidden_act", "silu"),
+                     ("normalization_function", "rmsnorm")):
+        if hf.get(key, can) != can:
+            raise ValueError(f"{key} {hf[key]!r}: only {can!r} is "
+                             f"implemented for granitemoehybrid")
+    kinds = {"mamba": "ssm", "attention": "attention"}
+    types = hf.get("layer_types", ())
+    if len(types) != n or set(types) - set(kinds):
+        raise ValueError(f"layer_types needs 'mamba' | 'attention' for "
+                         f"each of num_hidden_layers {n}, got {types}")
+    heads, hd = hf["mamba_n_heads"], hf["mamba_d_head"]
+    if heads * hd != hf.get("mamba_expand", 2) * hf["hidden_size"]:
+        raise ValueError(
+            f"mamba_n_heads {heads} x mamba_d_head {hd} is not mamba_expand "
+            f"{hf.get('mamba_expand', 2)} x hidden_size {hf['hidden_size']}")
+    n_real = hf["num_local_experts"]
+    offset, count = experts_held or hf.get("experts_held", (0, n_real))
+    return TransformerConfig(
+        vocab_size=hf["vocab_size"], d_model=hf["hidden_size"],
+        n_heads=hf["num_attention_heads"],
+        n_kv_heads=hf["num_key_value_heads"], n_layers=n,
+        d_ff=hf["intermediate_size"], max_seq=max_seq, dtype=dtype,
+        rope=False, ffn="swiglu",
+        norm_eps=float(hf.get("rms_norm_eps", 1e-6)),
+        tie_embeddings=bool(hf.get("tie_word_embeddings", True)),
+        layer_mixer=tuple(kinds[t] for t in types),
+        layer_ffn=("sparse",) * n,
+        ssm_heads=heads, ssm_head_dim=hd, ssm_state=hf["mamba_d_state"],
+        ssm_conv=hf["mamba_d_conv"], ssm_chunk=hf["mamba_chunk_size"],
+        attn_scale=float(hf["attention_multiplier"]),
+        embed_scale=float(hf.get("embedding_multiplier", 1.0)),
+        residual_scale=float(hf.get("residual_multiplier", 1.0)),
+        logit_divisor=float(hf.get("logits_scaling", 1.0)),
+        experts=ExpertShareConfig(
+            n_outputs=n_real, top_k=hf["num_experts_per_tok"], scale=1.0,
+            d_ff=hf["intermediate_size"], scoring="softmax",
+            renormalise=True, d_shared=hf.get("shared_intermediate_size", 0),
+            held_offset=int(offset), held_count=int(count)))
 
 
 AttnFn = Callable[[jnp.ndarray, jnp.ndarray, jnp.ndarray], jnp.ndarray]
@@ -732,7 +983,7 @@ def transformer_hidden_with_aux(params: dict, tokens: jnp.ndarray,
     # attn_fn=None resolves inside transformer_block to the window-aware
     # oracle; train-step callers inject their own (kernel) attn_fn
     x = params["embed"][tokens]
-    if not cfg.rope:
+    if cfg.learned_positions:
         x = x + params["pos"][positions]
 
     def block(layer, h):

@@ -296,7 +296,15 @@ TRAIN_ROUND = "train_round"
 # holds the chunk's last position, times the layers) and the blocks of the
 # lane they left unscored, counted on the host from the offset and the
 # length it uploaded (zero where the program's attention gathers its chosen
-# rows). Fields of a span that belong to another layer than the span's own
+# rows). Of a model whose layers carry a recurrent state ``serve_step``
+# carries ``ssm_lanes`` and ``ssm_idle_lanes``: the lane-layers whose state
+# the committed dispatch advanced for a request (busy lanes x state-space
+# layers: the state's bytes follow) and those it stepped for no one (parked
+# lanes, and lanes whose request had ended in the dispatch before); its
+# ``serve_prefill.chunk`` carries ``scan_tokens`` and ``scan_padded``: the
+# positions the layers' scans counted and the padding they ran over and let
+# advance nothing, both times the layers (all four zero for every other
+# model). Fields of a span that belong to another layer than the span's own
 # have a row in ``SPAN_FIELDS``.
 _DECODE = "engine, decode step (serving/engine.py)"
 _PREFILL = "engine, prefill (serving/engine.py)"
@@ -321,17 +329,27 @@ INDEX_SCANNED = "index_scanned"
 INDEX_SELECTED = "index_selected"
 KEY_BLOCKS_LIVE = "key_blocks_live"
 KEY_BLOCKS_SKIPPED = "key_blocks_skipped"
+SSM_LANES = "ssm_lanes"
+SSM_IDLE_LANES = "ssm_idle_lanes"
+SCAN_TOKENS = "scan_tokens"
+SCAN_PADDED = "scan_padded"
 _LATENT = "latent attention (models/generate.py)"
 _INDEXER = "sparse attention indexer (models/generate.py)"
+_SSM = "state-space mixer (models/generate.py)"
 # span -> field -> (layer, the quantity that reads it)
 SPAN_FIELDS = {
     SERVE_STEP: {KV_BLOCKS_LIVE: (_LATENT, "-"),
                  KV_BLOCKS_SKIPPED: (_LATENT, "-"),
                  INDEX_SCANNED: (_INDEXER, "glm_decode_roofline"),
                  INDEX_SELECTED: (_INDEXER,
-                                  "glm_selected_pct, glm_decode_roofline")},
+                                  "glm_selected_pct, glm_decode_roofline"),
+                 SSM_LANES: (_SSM, "grn_decode_roofline"),
+                 SSM_IDLE_LANES: (_SSM, "-")},
     SERVE_PREFILL_CHUNK: {KEY_BLOCKS_LIVE: (_LATENT, "-"),
-                          KEY_BLOCKS_SKIPPED: (_LATENT, "-")},
+                          KEY_BLOCKS_SKIPPED: (_LATENT, "-"),
+                          SCAN_TOKENS: (_SSM, "grn_scan_roofline, "
+                                        "grn_scan_padded_pct"),
+                          SCAN_PADDED: (_SSM, "grn_scan_padded_pct")},
 }
 
 # ``jax.named_scope`` names inside the jitted train step and the serving
@@ -341,7 +359,11 @@ SPAN_FIELDS = {
 # dense block's attention goes under ``attention`` there too.
 # ``sparse_indexer`` holds a full layer's index projections, its scores over
 # the index keys and the top-k; the gather of the chosen rows and the
-# attention over them stay under ``mla_attention``.
+# attention over them stay under ``mla_attention``. ``ssm_mixer`` holds a
+# state-space mixer from its input projection to ``w_out``; inside it
+# ``ssm_scan`` is a prefill's scan (everything between the convolution and
+# the gated norm) and ``ssm_step`` a decode step's state update and
+# read-out. The hybrid's one attention layer goes under ``attention``.
 SCOPE_SYNC_PACK = "grad_sync/pack"
 SCOPE_SYNC_REDUCE = "grad_sync/reduce"
 SCOPE_SYNC_UNPACK = "grad_sync/unpack"
@@ -354,6 +376,9 @@ SCOPE_MOE_ROUTER = "moe_router"
 SCOPE_MOE_EXPERTS = "moe_experts"
 SCOPE_SPARSE_INDEXER = "sparse_indexer"
 SCOPE_MOE_SHARED = "moe_shared"
+SCOPE_SSM_MIXER = "ssm_mixer"
+SCOPE_SSM_SCAN = "ssm_scan"
+SCOPE_SSM_STEP = "ssm_step"
 
 _SYNC = "gradient sync (parallel/dp.py, ops/collectives.py)"
 _STEP = "train step (models/train.py)"
@@ -369,11 +394,20 @@ SCOPES = {
     SCOPE_MLA_ATTENTION: (_LATENT, "lcr_mla_device_pct, glm_mla_device_pct"),
     SCOPE_DENSE_FFN: ("engine, decode step (serving/engine.py)", "-"),
     SCOPE_MOE_ROUTER: (_EXPERTS,
-                       "lcr_experts_device_pct, glm_experts_device_pct"),
+                       "lcr_experts_device_pct, glm_experts_device_pct, "
+                       "grn_experts_device_pct"),
     SCOPE_MOE_EXPERTS: (_EXPERTS,
-                        "lcr_experts_device_pct, glm_experts_device_pct"),
+                        "lcr_experts_device_pct, glm_experts_device_pct, "
+                        "grn_experts_device_pct"),
     SCOPE_SPARSE_INDEXER: (_INDEXER, "glm_indexer_device_pct"),
-    SCOPE_MOE_SHARED: (_EXPERTS, "glm_experts_device_pct"),
+    SCOPE_MOE_SHARED: (_EXPERTS,
+                       "glm_experts_device_pct, grn_experts_device_pct"),
+    # the two inner scopes ahead of the mixer's: a reader that takes the
+    # first name it finds in an op's path (benchmark/program_trace.py
+    # ``scope_of``) then files an op under the innermost
+    SCOPE_SSM_SCAN: (_SSM, "grn_ssm_device_pct, grn_scan_roofline"),
+    SCOPE_SSM_STEP: (_SSM, "grn_ssm_device_pct"),
+    SCOPE_SSM_MIXER: (_SSM, "grn_ssm_device_pct"),
 }
 
 # the scopes of the cached-block functions: in the serving programs only
@@ -381,7 +415,9 @@ SCOPES = {
 # expert)
 SERVING_SCOPES = frozenset({SCOPE_MLA_ATTENTION, SCOPE_DENSE_FFN,
                             SCOPE_MOE_ROUTER, SCOPE_MOE_EXPERTS,
-                            SCOPE_SPARSE_INDEXER, SCOPE_MOE_SHARED})
+                            SCOPE_SPARSE_INDEXER, SCOPE_MOE_SHARED,
+                            SCOPE_SSM_MIXER, SCOPE_SSM_SCAN,
+                            SCOPE_SSM_STEP})
 
 _annotation = None   # the annotation-only span's class, made at first use
 

@@ -135,6 +135,7 @@ from akka_allreduce_tpu.models.generate import (
 )
 from akka_allreduce_tpu.models.transformer import (
     TransformerConfig,
+    embed_tokens,
     lm_logits,
     rmsnorm,
 )
@@ -227,8 +228,10 @@ class EngineConfig:
     cache in chunks of this many positions, ONE compiled program run
     ``ceil(n / chunk)`` times back to back, each chunk attending what
     the lane's cache already holds (the last one padded; its padding is
-    neither live nor counted). For the model whose cached block attends
-    through the cache (``TransformerConfig.layerwise``); the other
+    neither live nor counted, and advances no recurrent state). For the
+    models whose cached block reads and writes the cache in place
+    (``TransformerConfig.layerwise``: attention through the cache, a
+    state-space layer scanning on from the lane's state); the other
     kinds' prefill attends its fresh keys and is refused.
     """
 
@@ -367,7 +370,8 @@ class PagedEngineConfig(EngineConfig):
                 "run speculation on the gather path")
 
 
-_KV_KEYS = ("k", "v", "k_scale", "v_scale", "latent", "index_k")
+_KV_KEYS = ("k", "v", "k_scale", "v_scale", "latent", "index_k",
+            "ssm_state", "conv_state")
 
 
 # how many numbers a token's route counts are (held, identity, absent),
@@ -391,8 +395,8 @@ def _slot_decode(params: dict, kv: dict, token: jnp.ndarray,
     changes an unmasked row's math). Returns (new kv, logits (slots,
     vocab), the expert layers' counts or None); a lane parked at position
     0 is idle and counts nowhere."""
-    x = params["embed"][token][:, None, :]
-    if not cfg.rope:
+    x = embed_tokens(params, token, cfg)[:, None, :]
+    if cfg.learned_positions:
         x = x + params["pos"][pos][:, None, :]
     x, kv, counts = cached_blocks(
         params, x, kv, cfg,
@@ -578,11 +582,15 @@ def _engine_prefill_chunk(params: dict, state: dict, tokens: jnp.ndarray,
     chunk. L is static, ``offset``, ``n_valid`` and ``slot`` are data:
     one program a length, whatever the prompt. The carried logits are
     those of position ``n_valid - 1`` (the last chunk's are the
-    prompt's)."""
+    prompt's). A state-space layer scans the chunk on from the lane's
+    state and convolution tail - at ``offset`` 0 from zeros, whatever the
+    lane's last request, a parked step or a dispatch launched ahead of an
+    ended one left there - and its padding leaves both as the last counted
+    token left them."""
     kv = {n: state[n] for n in _KV_KEYS if n in state}
     length = tokens.shape[1]
     x, kv, counts = cached_blocks(
-        params, params["embed"][tokens], kv, cfg,
+        params, embed_tokens(params, tokens, cfg), kv, cfg,
         CacheOps(offset=offset, lane=slot,
                  counted=jnp.arange(length) < n_valid))
     x_last = lax.dynamic_slice_in_dim(x, n_valid - 1, 1, axis=1)
@@ -1608,6 +1616,8 @@ class ServingEngine:
     # kind, by what is missing; the slot engine at ``decode_steps`` 1 runs
     # every kind
     _new_kind_missing: Optional[str] = None
+    # the same for a model whose layers carry a recurrent state
+    _recurrent_missing: Optional[str] = None
 
     def _refuse_new_kind(self) -> None:
         """None of the paths that copy the dense block's mathematics runs
@@ -1624,12 +1634,15 @@ class ServingEngine:
         if kind is None:
             return
         missing = self._new_kind_missing
+        if missing is not None and self.cfg.hybrid:
+            missing = self._recurrent_missing
         if missing is None and self.ecfg.decode_steps > 1:
             missing = ("decode_steps > 1: the fused block program does not "
                        "carry the expert layers' counts through its scan")
         if missing is None and self.ecfg.kv_dtype is not None:
             missing = (f"kv_dtype={self.ecfg.kv_dtype!r}: the latent cache "
-                       f"(and an indexer's index cache) has no quantized "
+                       f"(and an indexer's index cache; a hybrid's keys and "
+                       f"values beside its float32 state) has no quantized "
                        f"format")
         if missing is not None:
             raise NotImplementedError(
@@ -1710,8 +1723,10 @@ class ServingEngine:
         return self.num_slots - self.occupied
 
     def kv_cache_bytes(self) -> int:
-        return sum(int(self._state[n].size * self._state[n].dtype.itemsize)
-                   for n in _KV_KEYS if n in self._state)
+        # a recurrent state is a buffer a layer: count the leaves
+        return sum(int(x.size * x.dtype.itemsize)
+                   for n in _KV_KEYS if n in self._state
+                   for x in jax.tree.leaves(self._state[n]))
 
     def devices(self) -> "list[str]":
         """The devices this engine's weights and cache occupy."""
@@ -1816,9 +1831,17 @@ class ServingEngine:
                 live, skipped = self._count_key_blocks(offset, length)
                 if live and self.metrics is not None:
                     self.metrics.on_key_blocks(live, skipped)
+                # positions the state-space layers' scans run over:
+                # counted ones, and padding that advances nothing
+                n_ssm = len(self.cfg.ssm_layers)
+                scanned = n_ssm * min(length, n_full - offset)
+                padded_out = n_ssm * length - scanned
+                if scanned and self.metrics is not None:
+                    self.metrics.on_scan(scanned, padded_out)
                 with span(SERVE_PREFILL_CHUNK, self.tracer, rid=req.rid,
                           offset=offset, key_blocks_live=live,
-                          key_blocks_skipped=skipped):
+                          key_blocks_skipped=skipped, scan_tokens=scanned,
+                          scan_padded=padded_out):
                     self._state = _engine_prefill_chunk(
                         self.params, self._state,
                         jnp.asarray(padded[None, offset:offset + length]),
@@ -1839,9 +1862,9 @@ class ServingEngine:
         blocks up to the one that holds position ``offset + length - 1``
         and no later one. (0, 0) where the attention gathers."""
         max_seq = self.cfg.max_seq
-        blk = generate.selected_attention_path(
+        blk = self.cfg.indexed and generate.selected_attention_path(
             length, min(self.cfg.index_topk, max_seq), max_seq, True)
-        if blk is None:
+        if not blk:
             return 0, 0
         live = self.cfg.n_layers * -(-(offset + length) // blk)
         return live, self.cfg.n_layers * (max_seq // blk) - live
@@ -2199,9 +2222,16 @@ class ServingEngine:
                                   self.last_route["decode"].items()})
             live, skipped = older.kv_blocks
             scanned, selected = older.index
+            # lane-layers whose recurrent state the committed dispatch
+            # advanced for a request, and those it stepped for no one (a
+            # parked lane, or one whose request had ended meanwhile)
+            n_ssm = len(self.cfg.ssm_layers)
+            ssm_busy = n_ssm * (len(older.lanes) - dropped)
+            ssm_idle = n_ssm * self.num_slots - ssm_busy
             step_span.set(ahead=int(ahead), discarded=dropped,
                           kv_blocks_live=live, kv_blocks_skipped=skipped,
-                          index_scanned=scanned, index_selected=selected)
+                          index_scanned=scanned, index_selected=selected,
+                          ssm_lanes=ssm_busy, ssm_idle_lanes=ssm_idle)
             if self.metrics is not None:
                 if ahead or dropped:
                     self.metrics.on_lookahead(ahead, dropped)
@@ -2209,6 +2239,8 @@ class ServingEngine:
                     self.metrics.on_kv_blocks(live, skipped)
                 if selected:
                     self.metrics.on_index(scanned, selected)
+                if n_ssm:
+                    self.metrics.on_ssm(ssm_busy, ssm_idle)
             return finished
 
     def _launches_ahead(self) -> bool:
@@ -2250,7 +2282,7 @@ class ServingEngine:
         score, ``pos + 1`` a lane a full layer, and the latent rows its
         attentions read, ``min(pos + 1, index_topk)`` a lane a layer.
         (0, 0) for a model without an indexer."""
-        if not self.cfg.layerwise or not lanes:
+        if not self.cfg.indexed or not lanes:
             return 0, 0
         live = pos[list(lanes)].astype(np.int64) + 1
         return (len(self.cfg.full_layers) * int(live.sum()),
@@ -2556,6 +2588,13 @@ class _SpeculativeMixin:
         "block) that verifies a draft through the latent cache (and, where "
         "an indexer chooses what is attended, through its index cache)")
 
+    _recurrent_missing = (
+        "a rolled-back recurrent state: a rejected draft token has already "
+        "been folded into the state that every accepted one overwrites, so "
+        "a verify block needs the state of each draft position kept (or a "
+        "snapshot and a replay) where a key-value cache only moves its "
+        "frontier back")
+
     def _init_spec(self, draft_params: dict,
                    draft_cfg: TransformerConfig, cfg: TransformerConfig,
                    ecfg: EngineConfig) -> None:
@@ -2856,6 +2895,10 @@ class PagedServingEngine(ServingEngine):
         "indexer chooses what is attended) and cached-block functions that "
         "read them through the page table (`_paged_decode_step` copies the "
         "dense block)")
+    _recurrent_missing = (
+        "a page that holds a recurrent state: the state is one buffer a "
+        "lane that every step overwrites, with no positions to page, and a "
+        "shared prefix would need a snapshot of it at the prefix's end")
 
     def __init__(self, params: dict, cfg: TransformerConfig,
                  ecfg: PagedEngineConfig = PagedEngineConfig(),

@@ -103,6 +103,14 @@ class ServingMetrics:
         # lane's key blocks they scored and left unscored, over the layers
         self.key_blocks_live = 0
         self.key_blocks_skipped = 0
+        # a model whose layers carry a recurrent state: lane-layers whose
+        # state the committed decode dispatches advanced for a request and
+        # those they stepped for no one; positions the prefill chunks'
+        # scans counted and those that were padding, over the layers
+        self.ssm_lanes = 0
+        self.ssm_idle_lanes = 0
+        self.scan_tokens = 0
+        self.scan_padded = 0
         self.requests_submitted = 0
         self.requests_completed = 0
         self.requests_rejected = 0
@@ -405,6 +413,36 @@ class ServingMetrics:
         self.index_scanned += scanned
         self.index_selected += selected
 
+    def on_ssm(self, lanes: int, idle_lanes: int) -> None:
+        """One committed decode dispatch of a model whose layers carry a
+        recurrent state: the lane-layers whose state it advanced for a
+        request (``lanes``: busy lanes x state-space layers; 4 MB read and
+        written each at the published sizes) and those it stepped for no
+        one (``idle_lanes``: parked lanes, and lanes whose request had
+        ended in the dispatch before)."""
+        if not self.ssm_lanes + self.ssm_idle_lanes:
+            # registered at the first such dispatch, as the key blocks'
+            # series is: every other model exports no such series
+            self._register_kinds(
+                "serve_ssm_lane_steps_total", "ssm_", ("lanes", "idle_lanes"),
+                "lane-layers whose recurrent state a decode step advanced "
+                "for a request (lanes) and for no one (idle_lanes)")
+        self.ssm_lanes += lanes
+        self.ssm_idle_lanes += idle_lanes
+
+    def on_scan(self, tokens: int, padded: int) -> None:
+        """One dispatch of a prefill program of such a model: the
+        positions its state-space layers' scans counted (``tokens``: the
+        prompt's, x the layers) and the padding they ran over and let
+        advance nothing (``padded``)."""
+        if not self.scan_tokens:
+            self._register_kinds(
+                "serve_scan_positions_total", "scan_", ("tokens", "padded"),
+                "positions a prefill's state-space scans counted (tokens) "
+                "and ran over as padding (padded)")
+        self.scan_tokens += tokens
+        self.scan_padded += padded
+
     def on_token(self, rid: int, submitted_at: float) -> None:
         """Called per emitted token; the first emission banks TTFT."""
         self.on_block_tokens(rid, submitted_at, 1)
@@ -633,6 +671,11 @@ class ServingMetrics:
         if self.index_selected:
             out["index"] = {"scanned": self.index_scanned,
                             "selected": self.index_selected}
+        if self.ssm_lanes + self.ssm_idle_lanes:
+            out["ssm"] = {"lanes": self.ssm_lanes,
+                          "idle_lanes": self.ssm_idle_lanes,
+                          "scan_tokens": self.scan_tokens,
+                          "scan_padded": self.scan_padded}
         if self.draft_proposed:
             # the speculation story (speculative engines only): the
             # same cells the serve_draft_* collectors read

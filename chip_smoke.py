@@ -38,6 +38,17 @@ from a seed:
   selection BOUND (the attentions read fewer rows than the indexers
   scored).
 
+* ``serve-hybrid-ssm``: ``cli serve --model-config --prefill-chunk 2048``
+  on three layers at Granite-4.0-H-Small's published widths (a state-space
+  mixer, the attention without positions, a state-space mixer; two held
+  experts of 72; bf16, 0.57B parameters): prompts of 2,560 are scanned
+  through the lanes' recurrent state in two chunks (the second padded),
+  then a few decode steps. The same checks, and: the programs'
+  ``attention[ssm_scan]:`` notices say that the chunk program took the
+  chunked scan at the published block of 256 and the step one step of the
+  recurrence, and the report's ``ssm`` counts are what the dispatches say
+  (the padding of the second chunk counted apart, advancing nothing).
+
 One process per chip: this parent never imports JAX; each phase is a child
 process (``--phase``) that owns the chip for its lifetime, checks that
 ``jax.devices()[0].platform`` is ``tpu`` before compiling anything, and
@@ -124,6 +135,32 @@ SPARSE_LATENT_TOY = {
     "n_routed_experts": 16, "num_experts_per_tok": 4}
 
 
+# the ``serve-hybrid-ssm`` phase's model: three layers of
+# ibm-granite/granite-4.0-h-small's config.json, every width as published,
+# depth and the held experts cut (benchmark/configs/ has the cell's ten
+# layers and 36 experts)
+HYBRID_SSM = dict(
+    model_type="granitemoehybrid", vocab_size=50176, hidden_size=4096,
+    num_hidden_layers=3, layer_types=["mamba", "attention", "mamba"],
+    mamba_n_heads=128, mamba_d_head=64, mamba_expand=2, mamba_d_state=128,
+    mamba_d_conv=4, mamba_chunk_size=256, mamba_n_groups=1,
+    mamba_conv_bias=True, mamba_proj_bias=False, attention_bias=False,
+    num_attention_heads=32, num_key_value_heads=8,
+    attention_multiplier=0.0078125, position_embedding_type="nope",
+    num_local_experts=72, num_experts_per_tok=10, intermediate_size=768,
+    shared_intermediate_size=1536, embedding_multiplier=12,
+    residual_multiplier=0.22, logits_scaling=16, rms_norm_eps=1e-5,
+    tie_word_embeddings=True, hidden_act="silu", experts_held=[0, 2],
+    torch_dtype="bfloat16")
+HYBRID_SSM_TOY = {
+    **HYBRID_SSM, "vocab_size": 256, "hidden_size": 64,
+    "mamba_n_heads": 4, "mamba_d_head": 32, "mamba_d_state": 16,
+    "mamba_chunk_size": 8, "num_attention_heads": 4,
+    "num_key_value_heads": 2, "num_local_experts": 8,
+    "num_experts_per_tok": 3, "intermediate_size": 32,
+    "shared_intermediate_size": 48}
+
+
 def phase_argv(phase: str, rehearse: bool) -> "tuple[list[str], dict]":
     """The ``cli`` command line of a phase, and the numbers its checks
     compare against."""
@@ -163,6 +200,22 @@ def phase_argv(phase: str, rehearse: bool) -> "tuple[list[str], dict]":
                  "--prefill-chunk", "16"] if rehearse else
                 ["--slots", "2", "--max-seq", "4096", "--prompt-len",
                  "2560:2560", "--prefill-chunk", "2048"])
+    if phase == "serve-hybrid-ssm":
+        path = os.path.join(OUT_DIR,
+                            "chip_smoke.serve-hybrid-ssm.config.json")
+        os.makedirs(OUT_DIR, exist_ok=True)
+        with open(path, "w") as f:
+            json.dump(HYBRID_SSM_TOY if rehearse else HYBRID_SSM, f)
+        model = ["--model-config", path]
+        # prompts a quarter past a chunk: two chunks, the second padded
+        want = ({"requests": 2, "max_new_tokens": 4, "prompt": 20,
+                 "chunk": 16, "block": 8} if rehearse else
+                {"requests": 2, "max_new_tokens": 8, "prompt": 2560,
+                 "chunk": 2048, "block": 256})
+        load = (["--slots", "2", "--max-seq", "64", "--prompt-len", "20:20",
+                 "--prefill-chunk", "16"] if rehearse else
+                ["--slots", "2", "--max-seq", "4096", "--prompt-len",
+                 "2560:2560", "--prefill-chunk", "2048"])
     argv = ["serve", *model, *load, "--requests", str(want["requests"]),
             "--max-new-tokens", str(want["max_new_tokens"]),
             "--load", "closed"]
@@ -172,7 +225,7 @@ def phase_argv(phase: str, rehearse: bool) -> "tuple[list[str], dict]":
 
 
 PHASES = ("train", "serve-slot", "serve-paged", "serve-latent",
-          "serve-sparse-latent")
+          "serve-sparse-latent", "serve-hybrid-ssm")
 # one prompt length, so one prefill program; the rest are the decode
 # step and first-use helpers. Exact-length prefill compiles one program
 # per distinct length (ROADMAP S2) — a regression there shows here.
@@ -295,6 +348,37 @@ def _check_sparse_latent(out: str, err: str, want: dict) -> "list[dict]":
     ]
 
 
+def _check_hybrid_ssm(out: str, err: str, want: dict) -> "list[dict]":
+    said = re.findall(r"^attention\[ssm_scan\]: (\S+) (.*)$", err,
+                      flags=re.M)
+    lines = [ln for ln in out.strip().splitlines() if ln.startswith("{")]
+    ssm = (json.loads(lines[-1]) if lines else {}).get("ssm", {})
+    layers = HYBRID_SSM["layer_types"].count("mamba")
+    chunks = -(-want["prompt"] // want["chunk"])
+    # a chunk's tokens share a lane (q=1xLx...): the chunked scan; the step
+    # holds a token a lane (q=<slots>x1x...): one step of the recurrence
+    took = {"chunk" if detail.startswith("q=1x") else "step":
+            (impl, detail) for impl, detail in said}
+    return [
+        {"name": f"the chunk program scans in blocks of {want['block']}, "
+                 f"the step runs the recurrence once",
+         "ok": set(took) == {"chunk", "step"}
+         and took["chunk"][0] == "reference:_ssd_scan"
+         and f"block={want['block']} " in took["chunk"][1] + " "
+         and took["step"][0] == "reference:recurrence_step",
+         "detail": [" ".join(a) for a in said]},
+        {"name": "scan counts: the prompts' positions counted, the last "
+                 "chunk's padding apart",
+         "ok": ssm.get("scan_tokens") == layers * want["requests"]
+         * want["prompt"]
+         and ssm.get("scan_padded") == layers * want["requests"]
+         * (chunks * want["chunk"] - want["prompt"])
+         and ssm.get("lanes", 0) >= layers * want["requests"]
+         * (want["max_new_tokens"] - 1),
+         "detail": ssm},
+    ]
+
+
 def run_phase(phase: str, record_path: str, rehearse: bool) -> int:
     import contextlib
 
@@ -361,6 +445,8 @@ def run_phase(phase: str, record_path: str, rehearse: bool) -> int:
             checks += _check_latent(err.text(), rehearse)
         if phase == "serve-sparse-latent":
             checks += _check_sparse_latent(out.text(), err.text(), want)
+        if phase == "serve-hybrid-ssm":
+            checks += _check_hybrid_ssm(out.text(), err.text(), want)
     native = sys.modules.get("akka_allreduce_tpu.native")
     checks.append({"name": "native library not loaded on this path",
                    "ok": native is None or native._lib is None,

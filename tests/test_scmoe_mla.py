@@ -604,9 +604,11 @@ def test_the_new_scopes_are_in_the_decode_and_prefill_programs():
     e.close()
     for hlo in (step, pre):
         names = " ".join(re.findall(r'op_name="([^"]*)"', hlo))
-        # the double layer has no indexer and no shared expert
+        # the double layer has no indexer, no shared expert and no
+        # state-space mixer
         for sc in T.SERVING_SCOPES - {T.SCOPE_SPARSE_INDEXER,
-                                      T.SCOPE_MOE_SHARED}:
+                                      T.SCOPE_MOE_SHARED, T.SCOPE_SSM_MIXER,
+                                      T.SCOPE_SSM_SCAN, T.SCOPE_SSM_STEP}:
             assert f"/{sc}/" in names, sc
         assert "/attention/" not in names
         assert "sparse_indexer" not in names and "moe_shared" not in names

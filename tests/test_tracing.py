@@ -193,20 +193,32 @@ class TestSpanPrimitive:
     def test_span_fields_of_another_layer_have_their_rows(self):
         assert set(T.SPAN_FIELDS) <= set(T.SPANS)
         indexer = T.SCOPES[T.SCOPE_SPARSE_INDEXER][0]
+        ssm = T.SCOPES[T.SCOPE_SSM_MIXER][0]
+        assert ssm == "state-space mixer (models/generate.py)"
         assert T.SPAN_FIELDS[T.SERVE_STEP] == {
             "kv_blocks_live": (T.SCOPES[T.SCOPE_MLA_ATTENTION][0], "-"),
             "kv_blocks_skipped": (T.SCOPES[T.SCOPE_MLA_ATTENTION][0], "-"),
             "index_scanned": (indexer, "glm_decode_roofline"),
             "index_selected": (indexer,
-                               "glm_selected_pct, glm_decode_roofline")}
+                               "glm_selected_pct, glm_decode_roofline"),
+            # a model whose layers carry a recurrent state: lane-layers
+            # the committed dispatch advanced for a request, and for no one
+            "ssm_lanes": (ssm, "grn_decode_roofline"),
+            "ssm_idle_lanes": (ssm, "-")}
         # a prefill chunk's masked attentions: the lane's key blocks they
-        # scored and left unscored
+        # scored and left unscored; its state-space scans: the positions
+        # they counted and the padding they ran over
         assert T.SPAN_FIELDS[T.SERVE_PREFILL_CHUNK] == {
             "key_blocks_live": (T.SCOPES[T.SCOPE_MLA_ATTENTION][0], "-"),
             "key_blocks_skipped": (T.SCOPES[T.SCOPE_MLA_ATTENTION][0],
-                                   "-")}
+                                   "-"),
+            "scan_tokens": (ssm, "grn_scan_roofline, grn_scan_padded_pct"),
+            "scan_padded": (ssm, "grn_scan_padded_pct")}
         assert (T.KEY_BLOCKS_LIVE, T.KEY_BLOCKS_SKIPPED) == (
             "key_blocks_live", "key_blocks_skipped")
+        assert (T.SSM_LANES, T.SSM_IDLE_LANES, T.SCAN_TOKENS,
+                T.SCAN_PADDED) == ("ssm_lanes", "ssm_idle_lanes",
+                                   "scan_tokens", "scan_padded")
 
 
 # -- the engine's phases ----------------------------------------------------
@@ -505,7 +517,10 @@ def test_names_the_benchmark_reads_are_pinned():
         "with a `benchmark` PR")
     assert T.SERVING_SCOPES == {
         "mla_attention", "dense_ffn", "moe_router", "moe_experts",
-        "sparse_indexer", "moe_shared"}, (
+        "sparse_indexer", "moe_shared", "ssm_mixer", "ssm_scan",
+        "ssm_step"}, (
         "benchmark/readers/latent_moe.py (lcr_experts_device_pct, "
         "lcr_mla_device_pct, glm_indexer_device_pct, glm_mla_device_pct, "
-        "glm_experts_device_pct) reads these scope names")
+        "glm_experts_device_pct, grn_ssm_device_pct, "
+        "grn_experts_device_pct) and benchmark/readers/hybrid_ssm.py "
+        "(grn_scan_roofline) read these scope names")
