@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import operator
 from functools import partial
 from typing import Optional
 
@@ -1196,9 +1197,10 @@ def cached_blocks(params: dict, x: jnp.ndarray, kv: dict,
     (x, kv, counts). ``counts`` is None for the dense kind; for the
     shortcut and the layer-by-layer kinds the expert layers' counts summed
     over the layers (``held`` and ``identity`` a token, (b*t,);
-    ``touched`` a scalar). The layer-by-layer kind's selection goes from
-    a full layer to the shared layers after it here; the hybrid's layers
-    each take their mixer's kind from ``cfg.layer_mixer``."""
+    ``touched`` and ``carried`` a scalar each). The layer-by-layer kind's
+    selection goes from a full layer to the shared layers after it here;
+    the hybrid's layers each take their mixer's kind from
+    ``cfg.layer_mixer``."""
     block = (_shortcut_cached_block if cfg.block == "shortcut"
              else _hybrid_cached_block if cfg.hybrid
              else _dense_cached_block)
@@ -1210,8 +1212,9 @@ def cached_blocks(params: dict, x: jnp.ndarray, kv: dict,
         else:
             x, kv, counts = block(layer, x, kv, i, cfg, ops)
         if counts is not None:
+            # ``carried`` is a plain number where no branch is built
             total = counts if total is None else jax.tree.map(
-                jnp.add, total, counts)
+                operator.add, total, counts)
     return x, kv, total
 
 

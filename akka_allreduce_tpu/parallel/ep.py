@@ -370,16 +370,58 @@ def dropless_route(h: jnp.ndarray, params: dict, cfg: ExpertShareConfig
 
 
 def _row_buffer(rows: int) -> int:
-    """Rows of the sorted-assignment buffer: every assignment could fall
-    on a held expert, so at least N x k of them, static; rounded up to an
-    ODD multiple of 128. The TPU compiler tiles its grouped matmul by the
+    """Rows of the WHOLE sorted-assignment buffer: every assignment could
+    fall on a held expert, so at least N x k of them, static; rounded up to
+    an ODD multiple of 128. The TPU compiler tiles its grouped matmul by the
     largest of 512, 256 and 128 rows that divides the buffer, and a share
     sees a few rows an expert: at 128 lanes x top-12 (1,536 rows, ~2 an
     expert) a 512-row tile spends four times the MXU time on padding and
     the decode step takes 36.2 ms where 1,664 rows take 29.7 (chip runs,
-    PR 26; tests/test_compile_for_chip.py pins the tile)."""
+    PR 26; tests/test_compile_for_chip.py pins the tile). It is the one
+    buffer of a decode step and the last branch of a prefill
+    (:func:`_row_prefixes`)."""
     tiles = -(-rows // 128)
     return 128 * (tiles + 1 - tiles % 2)
+
+
+# a short buffer is worth its branch (three more grouped matmuls a layer to
+# compile, a ``cond`` to run) where it leaves out this many rows: never
+# at a decode step's assignments (at most 128 lanes x top-12 in a cell)
+_WORTH_ROWS = 2048
+
+
+def _row_prefixes(rows: int, cfg: ExpertShareConfig) -> tuple[int, ...]:
+    """The lengths of the sorted-assignment buffer that ``rows`` = N x k
+    assignments may run through, shortest first, the whole buffer
+    (:func:`_row_buffer`) last; :func:`held_experts_ffn` takes the first
+    that holds the assignments on held experts. From what the code has
+    and nothing else: balanced routing sends this chip ``held_count /
+    n_outputs`` of the assignments, and the short buffer is that share
+    plus a margin (an eighth of it, 1,024 rows at least), as an odd
+    multiple of its tile so that the compiler takes that tile: 256 rows
+    where the share is 128 rows or more an expert, else the decode step's
+    128. Chip runs, PR 35, the layer alone (v5e, ms a grouped matmul, by
+    tile 128 / 256 / 512): Granite's chunk, 20,480 assignments of which
+    ~10,240 on 36 experts, ~285 each, 1.64 / 1.26 / 1.30 (1.80 over the
+    whole 20,608 rows); its 1,024 bucket, ~142 each, 1.06 / 0.91 / 1.04;
+    GLM-5.2's chunk, ~66 each, 0.98 / 1.00 / 1.42; LongCat's bucket of
+    512, ~8 each, 0.65 / 0.80 / 1.26. Rows past the last group cost the
+    grouped matmul next to nothing (GLM: 1.12 ms at 16,512 rows, 0.99 at
+    1,408); what the short buffer saves is the rows' way in and back.
+    With every expert held, or where the short buffer would leave out
+    fewer than :data:`_WORTH_ROWS`, there is the whole buffer alone: a
+    decode step's program has no branch."""
+    whole = _row_buffer(rows)
+    share = rows * cfg.held_count / cfg.n_outputs
+    tile = 256 if share >= 128 * cfg.held_count else 128
+    tiles = -(-int(share + max(share / 8, 1024)) // tile)
+    short = tile * (tiles + 1 - tiles % 2)
+    return (short, whole) if whole - short >= _WORTH_ROWS else (whole,)
+
+
+def _branch(prefixes: tuple[int, ...], live: jnp.ndarray) -> jnp.ndarray:
+    """Index of the shortest of ``prefixes`` that holds ``live`` rows."""
+    return jnp.sum(jnp.asarray(prefixes[:-1], jnp.int32) < live)
 
 
 def _on_held(pick: jnp.ndarray, cfg: ExpertShareConfig
@@ -391,30 +433,75 @@ def _on_held(pick: jnp.ndarray, cfg: ExpertShareConfig
 
 def held_experts_ffn(h: jnp.ndarray, pick: jnp.ndarray,
                      weight: jnp.ndarray, params: dict,
-                     cfg: ExpertShareConfig) -> jnp.ndarray:
+                     cfg: ExpertShareConfig,
+                     counted: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """sum over a token's picks that fall on HELD experts of weight x
     expert(h): (N, D) float32. The assignments are sorted by expert (those
     on no held expert last), one grouped matmul a weight stack runs over
     the groups - an expert's weights are read at most once, an expert
     with no row not at all - and the rows go back to their tokens by the
-    inverse permutation. Shapes are static in N and k alone."""
+    inverse permutation. Shapes are static in N and k alone.
+
+    The rows that matter are a prefix of the sorted order, and at a
+    chunk's assignments (:func:`_row_prefixes`) the gather of the rows,
+    the grouped matmuls and the way back run over the shortest static
+    prefix that holds them, chosen on the device; the whole buffer stays
+    the last branch, so no assignment is ever dropped and the result is
+    the same for any routing. There a token that is not ``counted``
+    (padding) is keyed as on no held expert: its rows leave the prefix and
+    its part is zero. Chip runs, PR 35, a layer of Granite's chunk alone
+    (2,048 x top-10, 36 of 72 held): 11.98 ms over the whole buffer - the
+    grouped matmuls 5.36, the rows' way in 1.76, convert and mask 0.77,
+    back as float32 through a (N, k, D) relayout 3.54 - and 5.28 over the
+    prefix of 11,520 (3.88, 0.10, 0.13, 0.54); GLM-5.2's chunk 9.35 ->
+    4.27, LongCat's bucket of 512 4.33 -> 2.42."""
     n, k = pick.shape
     local, held = _on_held(pick, cfg)
+    prefixes = _row_prefixes(n * k, cfg)
+    if counted is not None and len(prefixes) > 1:
+        held = held & counted[:, None]
     key = jnp.where(held, local, cfg.held_count).reshape(n * k)
     order = jnp.argsort(key, stable=True)
     sizes = jnp.zeros((cfg.held_count + 1,), jnp.int32).at[key].add(1)
     sizes = sizes[:cfg.held_count]
-    m = _row_buffer(n * k)
-    rows = jnp.pad(h[order // k], ((0, m - n * k), (0, 0)))
-    gate = lax.ragged_dot(rows, params["we1"], sizes)
-    up = lax.ragged_dot(rows, params["we3"], sizes)
-    out = lax.ragged_dot(jax.nn.silu(gate) * up, params["we2"],
-                         sizes).astype(jnp.float32)
-    # rows past the last group belong to no expert; the grouped matmul
-    # leaves them unwritten
-    out = jnp.where((jnp.arange(m) < sizes.sum())[:, None], out, 0.0)
-    back = out[jnp.argsort(order)].reshape(n, k, -1)
-    return jnp.einsum("nkd,nk->nd", back, jnp.where(held, weight, 0.0))
+
+    def experts(rows, sizes):
+        gate = lax.ragged_dot(rows, params["we1"], sizes)
+        up = lax.ragged_dot(rows, params["we3"], sizes)
+        return lax.ragged_dot(jax.nn.silu(gate) * up, params["we2"], sizes)
+
+    def whole(order, sizes):
+        m = prefixes[-1]
+        rows = jnp.pad(h[order // k], ((0, m - n * k), (0, 0)))
+        out = experts(rows, sizes).astype(jnp.float32)
+        # rows past the last group belong to no expert; the grouped matmul
+        # leaves them unwritten
+        out = jnp.where((jnp.arange(m) < sizes.sum())[:, None], out, 0.0)
+        back = out[jnp.argsort(order)].reshape(n, k, -1)
+        return jnp.einsum("nkd,nk->nd", back, jnp.where(held, weight, 0.0))
+
+    def prefix(m: int):
+        """The same over the first ``m`` rows of the sorted order. The rows
+        come back as they left the grouped matmul, bfloat16, laid (k, N, D)
+        so that a token's k rows add up without a relayout."""
+        def run(order, sizes):
+            out = experts(h[order[:m] // k], sizes)
+            out = jnp.where((jnp.arange(m) < sizes.sum())[:, None], out,
+                            jnp.zeros((), out.dtype))
+            # an assignment past the prefix is on no held expert: its
+            # weight is 0 and any finite row will do
+            at = jnp.minimum(jnp.argsort(order), m - 1).reshape(n, k).T
+            back, w = out[at], jnp.where(held, weight, 0.0).T[:, :, None]
+            # pick by pick: one pass that widens as it adds (as a product
+            # and a sum over k the compiler widens all N x k rows first)
+            return sum(back[j].astype(jnp.float32) * w[j] for j in range(k))
+        return run
+
+    if len(prefixes) == 1:
+        return whole(order, sizes)
+    return lax.switch(_branch(prefixes, sizes.sum()),
+                      [prefix(m) for m in prefixes[:-1]] + [whole],
+                      order, sizes)
 
 
 def shared_expert_ffn(h: jnp.ndarray, params: dict) -> jnp.ndarray:
@@ -434,14 +521,16 @@ def dropless_moe(h: jnp.ndarray, params: dict, cfg: ExpertShareConfig,
     deployment, plus the shared expert where there is one
     (:func:`shared_expert_ffn`). Returns (m (N, D) in h's dtype, counts):
     per token the assignments on ``held`` and on ``identity`` experts (the
-    rest of ``top_k`` are on absent experts), and ``touched``, how many held
-    experts got a row. A token that is not ``counted`` (N,) bool (default:
-    all are; padding and idle lanes are not) counts nowhere."""
+    rest of ``top_k`` are on absent experts), ``touched``, how many held
+    experts got a row, and ``carried``, the rows the grouped matmuls ran
+    over (a plain number where :func:`_row_prefixes` builds no branch). A
+    token that is not ``counted`` (N,) bool (default: all are; padding and
+    idle lanes are not) counts nowhere."""
     with jax.named_scope(SCOPE_MOE_ROUTER):
         pick, weight = dropless_route(h, params, cfg)
     on_identity = pick >= cfg.n_real
     with jax.named_scope(SCOPE_MOE_EXPERTS):
-        y = held_experts_ffn(h, pick, weight, params, cfg)
+        y = held_experts_ffn(h, pick, weight, params, cfg, counted)
         y = y + jnp.where(on_identity, weight, 0.0).sum(
             -1, keepdims=True) * h.astype(jnp.float32)
     if cfg.d_shared:
@@ -452,7 +541,11 @@ def dropless_moe(h: jnp.ndarray, params: dict, cfg: ExpertShareConfig,
         on_identity = on_identity & counted[:, None]
     rows = jnp.zeros((cfg.held_count + 1,), jnp.int32).at[
         jnp.where(on_held, local, cfg.held_count)].add(1)
+    prefixes = _row_prefixes(pick.size, cfg)
+    carried = prefixes[0] if len(prefixes) == 1 else jnp.asarray(
+        prefixes, jnp.int32)[_branch(prefixes, on_held.sum())]
     counts = {"held": on_held.sum(-1).astype(jnp.int32),
               "identity": on_identity.sum(-1).astype(jnp.int32),
-              "touched": (rows[:cfg.held_count] > 0).sum().astype(jnp.int32)}
+              "touched": (rows[:cfg.held_count] > 0).sum().astype(jnp.int32),
+              "carried": carried}
     return y.astype(h.dtype), counts

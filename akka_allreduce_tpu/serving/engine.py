@@ -377,6 +377,9 @@ _KV_KEYS = ("k", "v", "k_scale", "v_scale", "latent", "index_k",
 # how many numbers a token's route counts are (held, identity, absent),
 # and the rows the shortcut kind's step adds to its packed readback
 _ROUTE_KINDS = ("held", "identity", "absent")
+# what the prefills since the last step leave in ``route``: those, the held
+# experts that got a row, and the rows their grouped matmuls ran over
+_PREFILL_ROUTE = _ROUTE_KINDS + ("touched", "carried")
 
 
 def _slot_decode(params: dict, kv: dict, token: jnp.ndarray,
@@ -455,8 +458,8 @@ def _engine_step(params: dict, state: dict, pos: jnp.ndarray,
     # above, a row a lane of its assignments on held and on identity
     # experts over the layers (zero for an idle lane), then the held
     # experts touched by busy lanes, then what the prefills since the
-    # last step left in ``route`` (held, identity, absent, touched), which
-    # starts again from zero
+    # last step left in ``route`` (:data:`_PREFILL_ROUTE`), which starts
+    # again from zero
     packed = jnp.concatenate([
         packed.reshape(-1), counts["held"], counts["identity"],
         counts["touched"][None], state["route"]])
@@ -559,12 +562,13 @@ def _engine_prefill(params: dict, state: dict, prompt: jnp.ndarray,
 
 
 def _prefill_route(counts: dict, n, cfg: TransformerConfig) -> jnp.ndarray:
-    """(held, identity, absent, touched) of a prefill's ``n`` true
-    positions, from the expert layers' counts (which leave padding out)."""
+    """:data:`_PREFILL_ROUTE` of a prefill's ``n`` true positions, from the
+    expert layers' counts (which leave padding out; ``carried`` is the
+    rows the layers' grouped matmuls ran over, padding or not)."""
     held, identity = counts["held"].sum(), counts["identity"].sum()
     absent = n * cfg.experts.top_k * cfg.n_expert_layers - held - identity
-    return jnp.stack(
-        [held, identity, absent, counts["touched"]]).astype(jnp.int32)
+    return jnp.stack([held, identity, absent, counts["touched"],
+                      counts["carried"]]).astype(jnp.int32)
 
 
 @partial(jax.jit, static_argnames=("cfg",), donate_argnums=(1,))
@@ -1703,8 +1707,8 @@ class ServingEngine:
         del base["pos"]  # per-slot positions live host-side
         if self.cfg.experts is not None:
             # what the prefills since the last step routed where
-            # (held, identity, absent, touched); the step reads it out
-            base["route"] = jnp.zeros((len(_ROUTE_KINDS) + 1,), jnp.int32)
+            # (:data:`_PREFILL_ROUTE`); the step reads it out
+            base["route"] = jnp.zeros((len(_PREFILL_ROUTE),), jnp.int32)
         return {**base, "logits": jnp.zeros(
             (self.ecfg.num_slots, self.cfg.vocab_size), self.cfg.dtype)}
 
@@ -2314,7 +2318,7 @@ class ServingEngine:
         usual rows, and where the ``counted`` lanes this dispatch ran
         and the prefills since the last step sent their tokens, as
         ``{phase: {kind: n}}`` with the kinds of :data:`_ROUTE_KINDS`
-        and ``touched``."""
+        and ``touched``, and for the prefills ``carried`` besides."""
         n = self.num_slots
         # an idle lane (parked at position 0) counted nowhere on the device
         held = int(packed[2 * n:3 * n].sum())
@@ -2325,8 +2329,7 @@ class ServingEngine:
                             "touched": int(packed[4 * n])}}
         pre = packed[4 * n + 1:]
         if pre.any():
-            route["prefill"] = dict(zip(_ROUTE_KINDS + ("touched",),
-                                        map(int, pre)))
+            route["prefill"] = dict(zip(_PREFILL_ROUTE, map(int, pre)))
         return packed[:2 * n].reshape(2, n), route
 
     def _commit_single(self, flight: _Flight, packed: np.ndarray) -> tuple:

@@ -321,31 +321,37 @@ class ServingMetrics:
                      prompt_len=prompt_len)
 
     def on_route(self, phase: str, held: int, identity: int, absent: int,
-                 touched: int) -> None:
+                 touched: int, carried: int = 0) -> None:
         """Where an expert layer's router sent the tokens of one decode
         step (busy lanes) or of the prefills before it (true positions),
         summed over the layers: assignments on experts this chip HOLDS,
         on IDENTITY experts (no weights; the token's own chip adds them)
         and on experts held on ABSENT chips (their part is left out on a
-        chip that holds a share), and how many held experts got a row."""
+        chip that holds a share), and how many held experts got a row.
+        A prefill's record says besides how many rows its grouped
+        matmuls CARRIED (parallel/ep.py ``_row_prefixes``): ``held`` over
+        it is how full the chosen buffer was."""
         if self._route is None:
             self._route = {
                 kind: self.registry.counter(
                     "serve_route_assignments_total",
                     help="router assignments of decode steps' busy lanes "
                          "and prefills' true positions, by where the "
-                         "expert is",
+                         "expert is; carried: the rows the prefills' "
+                         "grouped matmuls ran over",
                     labels={**self.labels, "kind": kind})
-                for kind in ("held", "identity", "absent")}
+                for kind in ("held", "identity", "absent", "carried")}
             self._route["touched"] = self.registry.counter(
                 "serve_route_experts_touched_total",
                 help="held experts that got at least one row, summed "
                      "over layers and dispatches", labels=self.labels)
         for kind, n in (("held", held), ("identity", identity),
-                        ("absent", absent), ("touched", touched)):
+                        ("absent", absent), ("touched", touched),
+                        ("carried", carried)):
             self._route[kind].inc(n)
         self._record("serve_route", phase=phase, held=held,
-                     identity=identity, absent=absent, touched=touched)
+                     identity=identity, absent=absent, touched=touched,
+                     carried=carried)
 
     def on_lookahead(self, ahead: bool, discarded: int) -> None:
         """One decode step of the slot engine that launched the next

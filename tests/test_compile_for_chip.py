@@ -11,6 +11,7 @@ import re
 import jax
 import jax.numpy as jnp
 import pytest
+from jax import lax
 from jax.sharding import SingleDeviceSharding
 
 from akka_allreduce_tpu.models import generate as G
@@ -43,6 +44,18 @@ def one_chip():
 @pytest.fixture(scope="module")
 def cfg():
     return config_from_hf(HF, MAX_SEQ, jnp.bfloat16)
+
+
+def _engine_state(cfg, lanes):
+    """The slot engine's state for ``lanes`` lanes, as shapes."""
+    from akka_allreduce_tpu.serving.engine import _PREFILL_ROUTE
+
+    def state():
+        base = G.init_kv_cache(cfg, lanes)
+        del base["pos"]
+        return {**base, "route": jnp.zeros((len(_PREFILL_ROUTE),), jnp.int32),
+                "logits": jnp.zeros((lanes, cfg.vocab_size), cfg.dtype)}
+    return jax.eval_shape(state)
 
 
 def _on(sharding, tree):
@@ -172,13 +185,8 @@ def glm(one_chip):
     cfg = config_from_hf(GLM, GLM_MAX_SEQ, jnp.bfloat16)
     params = jax.eval_shape(lambda k: init_transformer(k, cfg),
                             jax.random.key(0))
-
-    def state():
-        base = G.init_kv_cache(cfg, GLM_LANES)
-        del base["pos"]
-        return {**base, "route": jnp.zeros((4,), jnp.int32),
-                "logits": jnp.zeros((GLM_LANES, cfg.vocab_size), cfg.dtype)}
-    return cfg, _on(one_chip, params), _on(one_chip, jax.eval_shape(state))
+    return (cfg, _on(one_chip, params),
+            _on(one_chip, _engine_state(cfg, GLM_LANES)))
 
 
 def _lower_off_cache(lowered):
@@ -303,13 +311,8 @@ def granite(one_chip):
     cfg = config_from_hf(GRANITE, GRN_MAX_SEQ, jnp.bfloat16)
     params = jax.eval_shape(lambda k: init_transformer(k, cfg),
                             jax.random.key(0))
-
-    def state():
-        base = G.init_kv_cache(cfg, GRN_LANES)
-        del base["pos"]
-        return {**base, "route": jnp.zeros((4,), jnp.int32),
-                "logits": jnp.zeros((GRN_LANES, cfg.vocab_size), cfg.dtype)}
-    return cfg, _on(one_chip, params), _on(one_chip, jax.eval_shape(state))
+    return (cfg, _on(one_chip, params),
+            _on(one_chip, _engine_state(cfg, GRN_LANES)))
 
 
 def test_the_hybrid_decode_step_fits_and_holds_one_copy_of_the_state(
@@ -365,3 +368,84 @@ def test_the_hybrid_chunk_program_fits_beside_weights_and_state(one_chip,
     layer = rf"f32\[{GRN_LANES},128,64,128\]"
     assert not re.search(rf"= {layer}\S* (copy|transpose)\(", hlo)
     assert G.ssm_scan_path(GRN_CHUNK, cfg.ssm_chunk) == 256
+
+
+# -- the expert share's buffer at a chunk's and a step's assignments -------
+
+# (short prefix, whole buffer) of Granite's chunk, the short one's tile and
+# the share's temporaries there (ep._row_prefixes; chip runs, PR 35)
+GRN_ROWS, GRN_TILE, GRN_SHARE_TEMP = (11520, 20608), "256", 900_000_000
+
+
+def test_a_chunks_expert_share_runs_a_prefix_tiled_for_its_rows(one_chip):
+    """Granite's chunk (2,048 tokens x top-10 over 36 of 72 experts, ~285
+    rows an expert): two branches, the short prefix of the sorted order at
+    the tile the chip chose for hundreds of rows an expert, the whole buffer
+    at the decode step's 128; no expert stack copied or widened on the way
+    into either, and the temporaries are the short branch's plus the
+    whole's, not every assignment's at float32 twice over."""
+    ex = config_from_hf(GRANITE, GRN_MAX_SEQ, jnp.bfloat16).experts
+    rows = GRN_CHUNK * ex.top_k
+    short, whole = ep._row_prefixes(rows, ex)
+    assert (short, whole) == GRN_ROWS and whole == ep._row_buffer(rows)
+    moe = jax.eval_shape(lambda k: ep.init_expert_share(
+        k, 4096, ex, jnp.bfloat16), jax.random.key(0))
+    h = jax.ShapeDtypeStruct((GRN_CHUNK, 4096), jnp.bfloat16)
+    compiled = _compile(lambda m, x: ep.dropless_moe(x, m, ex),
+                        _on(one_chip, moe), _on(one_chip, h))
+    hlo = compiled.as_text()
+    tiles = sorted(re.findall(r'ragged_dot_tiling="(\d+),', hlo))
+    assert tiles == sorted(["128"] * 3 + [GRN_TILE] * 3)
+    assert not re.search(r"= (bf16|f32)\[36,(4096,768|768,4096)\]\S* "
+                         r"(copy|convert)\(", hlo)
+    assert compiled.memory_analysis().temp_size_in_bytes < GRN_SHARE_TEMP
+
+
+def _one_buffer_ffn(h, pick, weight, params, cfg, counted=None):
+    """``held_experts_ffn`` as it stood before the rule (PRs 26-34), line
+    for line: one buffer of ``_row_buffer`` rows whatever the routing."""
+    del counted
+    n, k = pick.shape
+    local, held = ep._on_held(pick, cfg)
+    key = jnp.where(held, local, cfg.held_count).reshape(n * k)
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.zeros((cfg.held_count + 1,), jnp.int32).at[key].add(1)
+    sizes = sizes[:cfg.held_count]
+    m = ep._row_buffer(n * k)
+    rows = jnp.pad(h[order // k], ((0, m - n * k), (0, 0)))
+    gate = lax.ragged_dot(rows, params["we1"], sizes)
+    up = lax.ragged_dot(rows, params["we3"], sizes)
+    out = lax.ragged_dot(jax.nn.silu(gate) * up, params["we2"],
+                         sizes).astype(jnp.float32)
+    out = jnp.where((jnp.arange(m) < sizes.sum())[:, None], out, 0.0)
+    back = out[jnp.argsort(order)].reshape(n, k, -1)
+    return jnp.einsum("nkd,nk->nd", back, jnp.where(held, weight, 0.0))
+
+
+@pytest.mark.parametrize("which", ["longcat", "glm", "granite"])
+def test_the_decode_programs_are_the_one_buffer_programs(which, request,
+                                                         monkeypatch):
+    """The three expert cells' decode steps at their published widths and
+    lanes: with the rule the jaxpr is, to the letter, the one that the old
+    one-buffer function gives (no branch, no new operation; what differs
+    from PR 34's program is the route vector alone, five numbers for
+    four)."""
+    from akka_allreduce_tpu.models.transformer import init_transformer
+    from akka_allreduce_tpu.serving import engine as eng
+    if which == "longcat":
+        cfg = request.getfixturevalue("cfg")
+        params = jax.eval_shape(lambda k: init_transformer(k, cfg),
+                                jax.random.key(0))
+        state = _engine_state(cfg, LANES)
+    else:
+        cfg, params, state = request.getfixturevalue(which)
+    pos = jax.ShapeDtypeStruct((state["logits"].shape[0],), jnp.int32)
+
+    def text():
+        return str(jax.make_jaxpr(
+            lambda p, s, q: eng._engine_step.__wrapped__(p, s, q, cfg))(
+                params, state, pos))
+    mine = text()
+    monkeypatch.setattr(ep, "held_experts_ffn", _one_buffer_ffn)
+    assert "ragged_dot" in mine and "cond[" not in mine
+    assert mine == text()

@@ -32,6 +32,7 @@ from akka_allreduce_tpu.models.transformer import (
     rmsnorm,
     transformer_apply,
 )
+from akka_allreduce_tpu.parallel import ep
 from akka_allreduce_tpu.parallel.ep import (
     ExpertShareConfig,
     dropless_moe,
@@ -270,6 +271,9 @@ def test_engine_counts_where_routing_sent_the_tokens():
     pre = phases["prefill"]
     assert pre["held"] + pre["identity"] + pre["absent"] == 11 * k * layers
     _lg, want = ref.forward(params, prompt, cfg)
+    # the rows the grouped matmuls ran over, padding or not: the bucket's
+    # one buffer a layer (no branch at 16 x k assignments)
+    assert pre.pop("carried") == layers * ep._row_buffer(16 * k)
     assert pre == {n: int(want[n]) for n in pre}
     # decode: the one busy lane of four
     dec = phases["decode"]
@@ -286,9 +290,11 @@ def test_route_counts_reach_the_registry():
     m = ServingMetrics()
     assert "serve_route" not in m.registry.to_prometheus_text()
     m.on_route("decode", held=3, identity=4, absent=5, touched=2)
-    m.on_route("prefill", held=1, identity=0, absent=3, touched=1)
+    m.on_route("prefill", held=1, identity=0, absent=3, touched=1,
+               carried=128)
     text = m.registry.to_prometheus_text()
-    for kind, n in (("held", 4), ("identity", 4), ("absent", 8)):
+    for kind, n in (("held", 4), ("identity", 4), ("absent", 8),
+                    ("carried", 128)):
         assert f'serve_route_assignments_total{{kind="{kind}"}} {n}' in text
     assert "serve_route_experts_touched_total 3" in text
 
