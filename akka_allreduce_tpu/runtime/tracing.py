@@ -17,25 +17,40 @@ Usage::
     with tracer.span("bucket_sync", round=0):
         ...  # dispatch + block on the collective
     tracer.counters["round_start"]        # -> 1
-    tracer.round_latencies()              # round -> seconds
     tracer.write_jsonl("/tmp/trace.jsonl")
 
-Every protocol engine (worker/master) takes an optional ``tracer``; the
-default ``None`` keeps the hot path free of any tracing cost.
+Every protocol engine (worker/master) takes an optional ``tracer`` and
+records its point events only where one is given.
 
 :func:`span` is the program's one span primitive: every span site opens
 it. It always opens a ``jax.profiler.TraceAnnotation`` of the span's name,
 so whenever a profiler session runs (``--xprof-dir``) the span sits in the
 same ``.xplane.pb`` as the device's timeline, on the profiler's clock; and
-where a :class:`Tracer` is attached it records the JSONL event
-``Tracer.span`` records. With neither it costs one annotation's
-construction and a flag check. ``SPANS`` and ``SCOPES`` below are the one
-table of the names.
+it always records the event ``Tracer.span`` records: into the
+:class:`Tracer` the site was given, else into the process's own record,
+:func:`flight`. That record is a ``Tracer`` on ``time.perf_counter`` that
+keeps the NEWEST ``FLIGHT_EVENTS`` events (a constructed ``Tracer`` keeps
+the oldest ``max_events``), so after a slow step there is always
+something to look at: ``tracing.flight().write_jsonl(path)`` from a
+debugger or a handler, with no flag set beforehand. It stores an event as
+one tuple of atoms (read back as a :class:`TraceEvent`, its fields a
+mapping), which the collector untracks: a full record adds nothing for a
+full collection to walk. With it comes one ``gc.callbacks`` hook that records
+a ``host_gc`` span for every collection of the oldest generation and for
+any that lasts a millisecond. The cost is measured, not assumed: the six
+spans of a decode step take 16 us on the v5e's host, of which 3.7 are the
+annotations (PERF.md section 6, PR 36), beside a step of 16-30 ms on the
+device; tests/test_tracing.py pins it by count: two clock reads a span and
+no object left for the collector to track. ``SPANS`` and ``SCOPES`` below
+are the one table of the names.
 """
 
 from __future__ import annotations
 
+import collections
 import dataclasses
+import gc
+import itertools
 import json
 import threading
 import time
@@ -72,26 +87,89 @@ class TraceEvent:
         return d
 
 
+# The process's record stores an event as ONE tuple of atoms: ``(ts, kind,
+# duration_s, span_id, parent_id, names, *values)``, the fields' names a
+# tuple shared by every event of the same shape (this table), and seals
+# every ``_CHUNK`` of them into a tuple. A young collection untracks a tuple
+# of atoms, and then the tuple of such tuples, so what a full collection
+# meets of a full record is its few hundred chunks and not its events: a
+# record of ``TraceEvent`` s with a dict each lengthened a full collection
+# by 65 ms on the builder's CPU, and one deque of the flat tuples still by
+# 8-10 (the deque is walked, and each event's header touched); the chunks
+# add nothing that can be measured (PERF.md section 6). A record that causes
+# the pause it is there to find is the one way it could hurt.
+_FIELD_NAMES: dict = {}
+_CHUNK = 256
+
+
+def _unflat(stored: tuple) -> TraceEvent:
+    ts, kind, duration_s, span_id, parent_id, names = stored[:6]
+    return TraceEvent(ts, kind, dict(zip(names, stored[6:])), duration_s,
+                      span_id, parent_id)
+
+
 class Tracer:
     """Append-only event log + per-kind counters.
 
-    Not thread-safe by design: each host process traces its own protocol
-    engine (one mailbox, one thread — the same safety argument as the
-    reference's actor model, SURVEY.md §5.2). The open-span stack rides
-    that same rule: spans nest lexically in the tracing thread.
+    One thread traces a protocol engine (one mailbox, one thread — the
+    same safety argument as the reference's actor model, SURVEY.md §5.2);
+    the serving engine's watchdog executor is the second thread that
+    records, and what the two share holds for it: the open-span stack is
+    per thread, ids come from ``itertools.count`` and an append is atomic.
+
+    At ``max_events`` a tracer stops appending and so keeps the OLDEST
+    events (a trace file starts at the start). ``newest=True`` is the
+    process's own record (:func:`flight`): it drops its oldest events (a
+    sealed chunk at a time, never below the newest ``max_events``), stores
+    each flat (see ``_FIELD_NAMES``) and builds the :class:`TraceEvent` s
+    when ``events`` is read. Two threads that fill a chunk's last place at
+    once may lose one event between them.
     """
 
-    def __init__(self, clock=time.perf_counter, max_events: int = 1_000_000):
+    def __init__(self, clock=time.perf_counter, max_events: int = 1_000_000,
+                 newest: bool = False):
         self._clock = clock
         self._max_events = max_events
-        self.events: list[TraceEvent] = []
+        self._events: list[TraceEvent] = []
+        # the ring of the process's record (None in a constructed tracer):
+        # sealed chunks of ``_CHUNK`` stored events, oldest first, and the
+        # chunk being filled
+        self._chunks = collections.deque(
+            maxlen=-(-max_events // _CHUNK)) if newest else None
+        self._filling: list = []
         self.counters: dict[str, int] = defaultdict(int)
-        self._next_span_id = 1
+        self._ids = itertools.count(1)
         # the open-span stack is PER THREAD: background recorders (the
         # host sampler, a watchdog worker) must not have their events
         # parented to whatever span the main thread happens to have
         # open — cross-thread "nesting" would be a lie about structure
         self._tls = threading.local()
+
+    @property
+    def events(self):
+        """The events held, oldest first: the list itself of a constructed
+        tracer, a snapshot of the ring built into :class:`TraceEvent` s of
+        the process's record (its fields a mapping again)."""
+        if self._chunks is None:
+            return self._events
+        return self.newest(self._max_events)
+
+    def newest(self, n: int) -> list:
+        """The last ``n`` events, oldest first, without building the rest."""
+        if self._chunks is None:
+            return self._events[-n:]
+        return [_unflat(stored) for stored in self._stored(n)]
+
+    def _stored(self, n: int) -> list:
+        """The ring's newest ``n`` events as stored, oldest first."""
+        parts, held = [self._filling[:]], len(self._filling)
+        for chunk in reversed(tuple(self._chunks)):
+            if held >= n:
+                break
+            parts.append(chunk)
+            held += len(chunk)
+        stored = [ev for part in reversed(parts) for ev in part]
+        return stored[-n:] if held > n else stored
 
     @property
     def _span_stack(self) -> list:
@@ -106,13 +184,12 @@ class Tracer:
         stack = self._span_stack
         return stack[-1] if stack else None
 
-    def record(self, kind: str, **fields: Any) -> TraceEvent:
-        ev = TraceEvent(ts=self._clock(), kind=kind, fields=fields,
-                        parent_id=self.current_span_id)
-        self._append(ev)
-        return ev
+    def record(self, kind: str, **fields: Any) -> Optional[TraceEvent]:
+        return self._append(self._clock(), kind, fields, None, None,
+                            self.current_span_id)
 
-    def record_transition(self, t: str, **fields: Any) -> TraceEvent:
+    def record_transition(self, t: str,
+                          **fields: Any) -> Optional[TraceEvent]:
         """A fleet control-plane transition (graftcheck's dynamic
         twin): one ``fleet_transition`` event whose ``t`` field names
         a transition of analysis/fleet_model.py. The router,
@@ -123,19 +200,17 @@ class Tracer:
 
     def _open_span(self) -> tuple:
         """Push a new span on this thread's stack: (id, parent, start)."""
-        sid = self._next_span_id
-        self._next_span_id += 1
-        parent = self.current_span_id
-        self._span_stack.append(sid)
+        stack = self._span_stack
+        sid = next(self._ids)
+        parent = stack[-1] if stack else None
+        stack.append(sid)
         return sid, parent, self._clock()
 
     def _close_span(self, opened: tuple, kind: str, fields: dict) -> None:
         sid, parent, t0 = opened
-        t1 = self._clock()
+        duration = self._clock() - t0
         self._span_stack.pop()
-        self._append(TraceEvent(ts=t0, kind=kind, fields=fields,
-                                duration_s=t1 - t0, span_id=sid,
-                                parent_id=parent))
+        self._append(t0, kind, fields, duration, sid, parent)
 
     @contextmanager
     def span(self, kind: str, **fields: Any):
@@ -151,70 +226,43 @@ class Tracer:
             self._close_span(opened, kind, fields)
 
     def record_span(self, kind: str, ts: float, duration_s: float,
-                    **fields: Any) -> TraceEvent:
+                    **fields: Any) -> Optional[TraceEvent]:
         """Append an already-timed span (the device-span helper measures
         host/device splits itself and reports afterwards). Parented to
         the currently open span like any other event."""
-        sid = self._next_span_id
-        self._next_span_id += 1
-        ev = TraceEvent(ts=ts, kind=kind, fields=fields,
-                        duration_s=duration_s, span_id=sid,
-                        parent_id=self.current_span_id)
-        self._append(ev)
+        return self._append(ts, kind, fields, duration_s, next(self._ids),
+                            self.current_span_id)
+
+    def _append(self, ts, kind, fields, duration_s, span_id,
+                parent_id) -> Optional[TraceEvent]:
+        """Count and store one event: flat in the ring (nothing returned),
+        else the :class:`TraceEvent`, which is returned, while the tracer
+        is under its cap."""
+        self.counters[kind] += 1
+        if self._chunks is not None:
+            keys = tuple(fields)
+            filling = self._filling
+            filling.append((ts, kind, duration_s, span_id, parent_id,
+                            _FIELD_NAMES.setdefault(keys, keys),
+                            *fields.values()))
+            if len(filling) >= _CHUNK:
+                self._filling = []
+                self._chunks.append(tuple(filling))
+            return None
+        ev = TraceEvent(ts, kind, fields, duration_s, span_id, parent_id)
+        if len(self._events) < self._max_events:
+            self._events.append(ev)
         return ev
-
-    def _append(self, ev: TraceEvent) -> None:
-        self.counters[ev.kind] += 1
-        if len(self.events) < self._max_events:
-            self.events.append(ev)
-
-    # -- aggregation --------------------------------------------------------
-
-    def round_latencies(self, start_kind: str = "round_start",
-                        end_kind: str = "round_complete") -> dict[int, float]:
-        """Per-round wall latency: first ``start_kind`` to last ``end_kind``
-        carrying the same ``round`` field."""
-        starts: dict[int, float] = {}
-        ends: dict[int, float] = {}
-        for ev in self.events:
-            r = ev.fields.get("round")
-            if r is None:
-                continue
-            if ev.kind == start_kind:
-                starts.setdefault(r, ev.ts)
-            elif ev.kind == end_kind:
-                ends[r] = ev.ts
-        return {r: ends[r] - starts[r] for r in starts if r in ends
-                and ends[r] >= starts[r]}
-
-    def span_stats(self, kind: str) -> dict[str, float]:
-        """count / total / mean / max seconds across spans of ``kind``."""
-        ds = [ev.duration_s for ev in self.events
-              if ev.kind == kind and ev.duration_s is not None]
-        if not ds:
-            return {"count": 0, "total_s": 0.0, "mean_s": 0.0, "max_s": 0.0}
-        return {"count": len(ds), "total_s": sum(ds),
-                "mean_s": sum(ds) / len(ds), "max_s": max(ds)}
-
-    def summary(self) -> dict[str, Any]:
-        lat = self.round_latencies()
-        out: dict[str, Any] = {"counters": dict(self.counters),
-                               "events": len(self.events)}
-        if lat:
-            vals = list(lat.values())
-            out["rounds_traced"] = len(vals)
-            out["round_latency_mean_s"] = sum(vals) / len(vals)
-            out["round_latency_max_s"] = max(vals)
-        return out
 
     # -- export -------------------------------------------------------------
 
     def write_jsonl(self, path: str) -> int:
         """One JSON object per line; returns events written."""
+        events = self.events
         with open(path, "w") as f:
-            for ev in self.events:
+            for ev in events:
                 f.write(json.dumps(ev.as_dict()) + "\n")
-        return len(self.events)
+        return len(events)
 
     def to_chrome_trace(self) -> dict:
         """The SAME event stream as Perfetto-loadable Chrome-trace JSON
@@ -273,8 +321,11 @@ SERVE_PREFILL_CHUNK = "serve_prefill.chunk"
 SERVE_ADMIT_COMMIT = "serve_admit.commit"
 SCHED_POP_READY = "sched_pop_ready"
 TRAIN_ROUND = "train_round"
+HOST_GC = "host_gc"
 
-# ``serve_step`` carries ``occupied``, ``admitted`` and, from the slot
+# ``serve_step`` carries ``occupied`` beside ``lanes`` (the engine's
+# ``num_slots``: occupancy is a ratio of two numbers recorded in one place),
+# ``admitted`` (a tuple of ``(rid, positions dispatched)``) and, from the slot
 # engine's S=1 step, ``ahead`` (1 where the call launched a dispatch before
 # its readback: every lane was busy) and ``discarded`` (lane steps its
 # commit dropped: the lane's request had ended in the dispatch before). In
@@ -305,22 +356,38 @@ TRAIN_ROUND = "train_round"
 # positions the layers' scans counted and the padding they ran over and let
 # advance nothing, both times the layers (all four zero for every other
 # model). Fields of a span that belong to another layer than the span's own
-# have a row in ``SPAN_FIELDS``.
+# have a row in ``SPAN_FIELDS``. ``sched_pop_ready`` carries ``queue_depth``
+# and, when it returns a request, ``rid`` and ``waited_ms``: from the
+# request's ``arrival`` (the hand-over) to the pop's close on the tracer's
+# clock, so a request's spans share ``rid`` from the pop through
+# ``serve_admit`` and its prefill to the ``serve_step`` whose ``admitted``
+# lists it. ``host_gc`` (``generation``, ``collected``) is a collection of
+# the oldest generation, or any that lasted a millisecond, recorded by the
+# hook that comes with the process's record (:func:`flight`). A
+# ``serve_step`` far slower than its like makes the engine log one line
+# (serving/engine.py ``_watch_step``): ``flood_step_stall_ms_max`` and
+# ``chat_step_stall_ms_max`` read the same steps.
 _DECODE = "engine, decode step (serving/engine.py)"
 _PREFILL = "engine, prefill (serving/engine.py)"
 SPANS = {
-    SERVE_STEP: (_DECODE, "chat_step_idle_ms"),
+    SERVE_STEP: (_DECODE, "chat_step_idle_ms, chat_step_host_ms_p50, "
+                 "chat_step_over_device_ms_p50, chat_step_stall_ms_max, "
+                 "flood_step_stall_ms_max, engine_occupancy_pct"),
     SERVE_STEP_UPLOAD: (_DECODE, "flood_idle_launch_ms"),
     SERVE_STEP_DISPATCH: (_DECODE, "flood_idle_launch_ms"),
-    SERVE_STEP_READBACK: (_DECODE, "flood_idle_readback_ms"),
-    SERVE_STEP_COMMIT: (_DECODE, "flood_idle_commit_ms"),
+    SERVE_STEP_READBACK: (_DECODE, "flood_idle_readback_ms, "
+                          "chat_step_host_ms_p50"),
+    SERVE_STEP_COMMIT: (_DECODE, "flood_idle_commit_ms, "
+                        "admit_to_token_p50_ms"),
     SERVE_ADMIT: (_PREFILL, "chat_admit_idle_ms"),
     SERVE_PREFILL: (_PREFILL, "chat_admit_idle_ms"),
     SERVE_PREFILL_CHUNK: (_PREFILL, "-"),
     SERVE_ADMIT_COMMIT: (_PREFILL, "chat_admit_idle_ms"),
     SCHED_POP_READY: ("scheduler (serving/scheduler.py)",
-                      "flood_idle_outside_ms"),
+                      "flood_idle_outside_ms, sched_wait_p90_ms (waited_ms), "
+                      "admit_to_token_p50_ms"),
     TRAIN_ROUND: ("train loop (cli._cmd_train shape)", "-"),
+    HOST_GC: ("host runtime (python)", "flood_host_gc_ms_max"),
 }
 
 KV_BLOCKS_LIVE = "kv_blocks_live"
@@ -419,56 +486,108 @@ SERVING_SCOPES = frozenset({SCOPE_MLA_ATTENTION, SCOPE_DENSE_FFN,
                             SCOPE_SSM_MIXER, SCOPE_SSM_SCAN,
                             SCOPE_SSM_STEP})
 
-_annotation = None   # the annotation-only span's class, made at first use
+_annotation = None     # jax.profiler.TraceAnnotation, from the first span
+_flight = None         # the process's own record, from the first use
+
+# Events the process's record holds: one whole benchmark run with room to
+# spare (ramp, window and tail at ~58 steps/s x ~7 spans is at most ~50,000;
+# a run of ``serve-chat`` leaves 17,000); full, it is 26.6 MB resident
+# (PERF.md section 6).
+FLIGHT_EVENTS = 131_072
+# A collection shorter than this is recorded only if it was a full one.
+GC_SPAN_MIN_S = 1e-3
 
 
-def _annotation_only():
-    """``jax.profiler.TraceAnnotation`` with a ``set`` that drops its
-    fields: what :func:`span` hands out where no tracer is attached, so a
-    site with tracing off pays the annotation alone. Made at the first span,
-    so the protocol plane's processes that never trace never import jax."""
-    global _annotation
-    from jax.profiler import TraceAnnotation
-
-    class annotation(TraceAnnotation):
-        __slots__ = ()
-
-        def set(self, **fields: Any) -> None:
-            pass
-
-    _annotation = annotation
-    return annotation
+def flight() -> Tracer:
+    """The process's own record: the newest ``FLIGHT_EVENTS`` events of
+    every :func:`span` that was given no tracer, and the ``host_gc`` spans
+    of the hook installed here with it."""
+    global _flight
+    if _flight is None:
+        _flight = Tracer(max_events=FLIGHT_EVENTS, newest=True)
+        gc.callbacks.append(_on_gc)
+    return _flight
 
 
-class _TracedSpan:
-    """The annotation and the tracer's JSONL event, one inside the other."""
+_gc_t0 = 0.0     # collections do not nest: one slot
 
-    __slots__ = ("_ann", "_tracer", "_kind", "_fields", "_opened")
+
+def _on_gc(phase: str, info: dict) -> None:
+    """``gc.callbacks``: two clock reads a collection, and a ``host_gc``
+    span for a full one or one of ``GC_SPAN_MIN_S``. The record is read
+    through the global, so a test that swaps it sees its own."""
+    global _gc_t0
+    if phase == "start":
+        _gc_t0 = _flight._clock()
+        return
+    duration = _flight._clock() - _gc_t0
+    if info["generation"] == 2 or duration >= GC_SPAN_MIN_S:
+        _flight.record_span(HOST_GC, _gc_t0, duration,
+                            generation=info["generation"],
+                            collected=info["collected"])
+
+
+class _Span:
+    """The annotation and the tracer's event, one inside the other: what
+    ``Tracer.span`` records, with the open and the close written out here
+    (a decode step opens six of these). ``duration_s`` is there once the
+    span has closed."""
+
+    __slots__ = ("_ann", "_tracer", "_stack", "_parent", "kind", "fields",
+                 "span_id", "ts", "duration_s")
 
     def __init__(self, kind: str, tracer: Tracer, fields: dict):
-        self._ann = (_annotation or _annotation_only())(kind)
+        self._ann = _annotation(kind)
         self._tracer = tracer
-        self._kind = kind
-        self._fields = fields
+        self.kind = kind
+        self.fields = fields
 
     def set(self, **fields: Any) -> None:
-        self._fields.update(fields)
+        self.fields.update(fields)
 
-    def __enter__(self) -> "_TracedSpan":
+    def now(self) -> float:
+        """The tracer's clock: for a field that is a time up to the
+        span's close, read as the block's last statement."""
+        return self._tracer._clock()
+
+    def __enter__(self) -> "_Span":
         self._ann.__enter__()
-        self._opened = self._tracer._open_span()
+        tracer = self._tracer
+        try:
+            stack = tracer._tls.stack
+        except AttributeError:
+            stack = tracer._tls.stack = []
+        self._stack = stack
+        self.span_id = sid = next(tracer._ids)
+        self._parent = stack[-1] if stack else None
+        stack.append(sid)
+        self.ts = tracer._clock()
         return self
 
-    def __exit__(self, *exc) -> None:
-        self._tracer._close_span(self._opened, self._kind, self._fields)
-        self._ann.__exit__(*exc)
+    def __exit__(self, exc_type, exc, tb) -> None:
+        tracer = self._tracer
+        ts = self.ts
+        self.duration_s = duration = tracer._clock() - ts
+        self._stack.pop()
+        tracer._append(ts, self.kind, self.fields, duration, self.span_id,
+                       self._parent)
+        self._ann.__exit__(exc_type, exc, tb)
+
+
+def _first_span() -> None:
+    """At the first span, so the protocol plane's processes that open none
+    never import jax."""
+    global _annotation
+    from jax.profiler import TraceAnnotation
+    _annotation = TraceAnnotation
+    flight()
 
 
 def span(kind: str, tracer: Optional[Tracer] = None, **fields: Any):
     """``with span(SERVE_STEP, self.tracer, occupied=n) as sp:`` - the
-    program's one span primitive (see the module docstring).
-    ``sp.set(tokens=3)`` adds fields known only inside the block; fields
-    reach the JSONL event alone, so without a tracer they are dropped."""
-    if tracer is None:
-        return (_annotation or _annotation_only())(kind)
-    return _TracedSpan(kind, tracer, fields)
+    program's one span primitive (see the module docstring): recorded
+    into ``tracer``, else into the process's own record.
+    ``sp.set(tokens=3)`` adds fields known only inside the block."""
+    if _annotation is None:
+        _first_span()
+    return _Span(kind, tracer or _flight, fields)

@@ -104,8 +104,11 @@ that proves each path):
 from __future__ import annotations
 
 import bisect
+import collections
 import concurrent.futures
 import dataclasses
+import logging
+import statistics
 import time
 from functools import partial
 from typing import Optional
@@ -144,6 +147,7 @@ from akka_allreduce_tpu.parallel.ep import moe_ffn
 from akka_allreduce_tpu.parallel.ring_attention import NEG_INF
 from akka_allreduce_tpu.runtime.faults import InjectedFault, maybe_fail
 from akka_allreduce_tpu.runtime.tracing import (
+    HOST_GC,
     SERVE_ADMIT,
     SERVE_ADMIT_COMMIT,
     SERVE_PREFILL,
@@ -153,9 +157,21 @@ from akka_allreduce_tpu.runtime.tracing import (
     SERVE_STEP_DISPATCH,
     SERVE_STEP_READBACK,
     SERVE_STEP_UPLOAD,
+    flight,
     span,
 )
 from akka_allreduce_tpu.serving.scheduler import Request, RequestScheduler
+
+log = logging.getLogger(__name__)
+
+# The watch over slow steps (:meth:`ServingEngine._watch_step`): a step is
+# slow beyond this many medians of the last ``_WATCH_STEPS`` of its like,
+# the median taken anew every ``_WATCH_EVERY`` of them, and at most one
+# line a ``_WATCH_SAY_S``.
+_WATCH_STEPS = 256
+_WATCH_EVERY = 32
+_WATCH_FACTOR = 8.0
+_WATCH_SAY_S = 1.0
 
 
 class WatchdogTimeout(RuntimeError):
@@ -1574,6 +1590,18 @@ class ServingEngine:
         self._flight: Optional[_Flight] = None
         self.lookahead_dispatches = 0
         self.discarded_lane_steps = 0
+        # the watch over slow steps (:meth:`_watch_step`): this call's
+        # ``serve_step`` span, whether the call before admitted, and a kind
+        # of step (admitted in this call, in the call before) its like:
+        # [the durations of the last of them, how many there were, the
+        # duration beyond which one is slow (None until there are
+        # ``_WATCH_EVERY``)]; how many were slow, and when the last line
+        # went out
+        self._step_span = None
+        self._admitted_before = False
+        self._like: dict = {}
+        self.slow_steps = 0
+        self._slow_said = float("-inf")
         # where the last step's tokens were routed (the shortcut kind
         # only): {"decode": {held, identity, absent, touched}[, "prefill"]}
         self.last_route: Optional[dict] = None
@@ -2157,8 +2185,11 @@ class ServingEngine:
         (``discarded_lane_steps``). A lane whose BUDGET ends in the
         dispatch in flight is known, and parks (:meth:`_plan`)."""
         if self.ecfg.decode_steps > 1:
-            return self._step_block()
-        return self._step_single(launch=True)
+            finished = self._step_block()
+        else:
+            finished = self._step_single(launch=True)
+        self._watch_step()
+        return finished
 
     def harvest(self) -> list[tuple[int, Request, list, str]]:
         """Completions of what no :meth:`step` has returned yet: reads
@@ -2171,15 +2202,86 @@ class ServingEngine:
         again)."""
         if self._flight is None:
             return []
-        return self._step_single(launch=False)
+        finished = self._step_single(launch=False)
+        self._watch_step()
+        return finished
+
+    def _open_step(self, **fields):
+        """This call's ``serve_step`` span: ``occupied`` beside ``lanes``,
+        and who was admitted since the last; kept for :meth:`_watch_step`."""
+        self._step_span = sp = span(
+            SERVE_STEP, self.tracer, occupied=self.occupied,
+            lanes=self.num_slots, admitted=self._take_admitted(), **fields)
+        return sp
+
+    def _watch_step(self) -> None:
+        """The slow step says so itself. A step that lasted more than
+        ``_WATCH_FACTOR`` medians of the last ``_WATCH_STEPS`` of its like
+        is counted (``slow_steps``, ``serve_slow_steps_total``) and says
+        one line (:meth:`_say_slow`). Its like: the quiet steps (no
+        admission in this call or the one before), and apart from them,
+        each against its own, the steps that carry a prefill (an admission
+        in this call, in the call before, in both), whose durations are the
+        prefills': the first stall the record caught, 3,966 ms of readback
+        in ``serve-chat``, fell in a step with an admission (PERF.md
+        section 6, PR 36)."""
+        sp = self._step_span
+        admitted = bool(sp.fields["admitted"])
+        kind = (admitted, self._admitted_before)
+        self._admitted_before = admitted
+        like = self._like.get(kind)
+        if like is None:
+            like = self._like[kind] = [
+                collections.deque(maxlen=_WATCH_STEPS), 0, None]
+        durations, seen, over = like
+        if over is not None and sp.duration_s > over:
+            self.slow_steps += 1
+            if self.metrics is not None:
+                self.metrics.on_slow_step()
+            self._say_slow(sp, over / _WATCH_FACTOR)
+        durations.append(sp.duration_s)
+        like[1] = seen + 1
+        if like[1] % _WATCH_EVERY == 0:
+            like[2] = _WATCH_FACTOR * statistics.median(durations)
+
+    def _say_slow(self, sp, median_s: float) -> None:
+        """One warning line for the slow step ``sp``, at most one a
+        ``_WATCH_SAY_S``: its duration beside the median of its like, its
+        four phases in ms, ``ahead``, ``occupied`` and how many it
+        admitted, and every
+        ``host_gc`` of the process's record that overlaps it (the
+        collector's pauses are recorded there whatever tracer the engine
+        was given, on ``time.perf_counter``)."""
+        t0, t1 = sp.ts, sp.ts + sp.duration_s
+        if t1 - self._slow_said < _WATCH_SAY_S:
+            return
+        self._slow_said = t1
+        # its phases by the clock, not by parentage: with the watchdog
+        # armed the dispatch and the readback are roots on the executor's
+        # thread
+        recent = flight().newest(64)
+        own = recent if self.tracer is None else self.tracer.newest(64)
+        phases = {ev.kind[len(SERVE_STEP) + 1:]: ev.duration_s * 1e3
+                  for ev in own if ev.kind.startswith(SERVE_STEP + ".")
+                  and t0 <= ev.ts <= t1}
+        pauses = [f"gen{ev.fields['generation']} {ev.duration_s * 1e3:.1f} ms"
+                  for ev in recent if ev.kind == HOST_GC
+                  and ev.ts < t1 and ev.ts + ev.duration_s > t0]
+        log.warning(
+            "slow serve_step: %.1f ms, the median of its like %.1f: %s; "
+            "ahead=%s occupied=%s of %s admitted=%s; host_gc: %s",
+            sp.duration_s * 1e3, median_s * 1e3,
+            " ".join(f"{k} {v:.1f}" for k, v in phases.items()),
+            sp.fields.get("ahead", 0), sp.fields["occupied"],
+            sp.fields["lanes"], len(sp.fields["admitted"]),
+            ", ".join(pauses) or "none")
 
     def _step_single(self, launch: bool) -> list:
         """One S=1 call: launch what there is to launch (the dispatch
         this call reads back, unless one is in flight already; and with
         no lane free the one after it), then read back and commit the
         OLDER dispatch. ``launch=False`` is :meth:`harvest`."""
-        with span(SERVE_STEP, self.tracer, occupied=self.occupied,
-                  admitted=self._take_admitted()) as step_span:
+        with self._open_step() as step_span:
             older, following = self._flight, None
             launches = []
             with span(SERVE_STEP_UPLOAD, self.tracer):
@@ -2376,8 +2478,8 @@ class ServingEngine:
         self._evict_expired(finished)
         return finished, n_tokens, dropped
 
-    def _take_admitted(self) -> list:
-        admitted, self._admitted = self._admitted, []
+    def _take_admitted(self) -> tuple:
+        admitted, self._admitted = tuple(self._admitted), []
         return admitted
 
     def _prepare_writes(self) -> None:
@@ -2471,8 +2573,7 @@ class ServingEngine:
         steps as wasted."""
         s_steps = self.ecfg.decode_steps
         sampled = self.ecfg.sample is not None
-        with span(SERVE_STEP, self.tracer, occupied=self.occupied,
-                  decode_steps=s_steps, admitted=self._take_admitted()):
+        with self._open_step(decode_steps=s_steps):
             with span(SERVE_STEP_UPLOAD, self.tracer):
                 self._maybe_poison()
                 self._prepare_writes()
@@ -2679,7 +2780,9 @@ class _SpeculativeMixin:
         super()._free_slot(i)
 
     def step(self) -> list:
-        return self._step_spec()
+        finished = self._step_spec()
+        self._watch_step()
+        return finished
 
     def _launches_ahead(self) -> bool:
         """Never: how far a lane moves in a block is the number of
@@ -2694,8 +2797,7 @@ class _SpeculativeMixin:
         host replays the device latch token for token, then settles
         the draft ledger from what actually entered the stream."""
         k = self.ecfg.draft_steps
-        with span(SERVE_STEP, self.tracer, occupied=self.occupied,
-                  draft_steps=k, admitted=self._take_admitted()):
+        with self._open_step(draft_steps=k):
             with span(SERVE_STEP_UPLOAD, self.tracer):
                 self._maybe_poison()
                 self._prepare_writes()

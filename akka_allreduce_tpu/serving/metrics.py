@@ -88,6 +88,9 @@ class ServingMetrics:
         # the decode nor the wasted count)
         self.lookahead_steps = 0
         self.discarded_lane_steps = 0
+        # decode steps that lasted eight medians of their like
+        # (engine._watch_step: each also says a line, one a second)
+        self.slow_steps = 0
         # the latent decode kernel's key blocks (engine.step on the
         # latent cache, TPU): blocks the committed dispatches read, and
         # blocks of the buffer past their lanes' positions that they
@@ -212,6 +215,11 @@ class ServingMetrics:
              lambda: self.discarded_lane_steps,
              "lane steps a dispatch launched ahead computed for a "
              "request that had already ended (dropped, never emitted)"),
+            ("serve_slow_steps_total", lambda: self.slow_steps,
+             "decode steps that lasted eight medians of their like, quiet "
+             "steps and steps with a prefill each against their own (the "
+             "engine logs each, one line a second, with its phases and the "
+             "collector's pauses)"),
             ("serve_draft_proposed_total", lambda: self.draft_proposed,
              "draft tokens proposed by the speculative engine"),
             ("serve_draft_accepted_total", lambda: self.draft_accepted,
@@ -359,6 +367,11 @@ class ServingMetrics:
         dropped ``discarded`` lane steps of a dispatch so launched."""
         self.lookahead_steps += ahead
         self.discarded_lane_steps += discarded
+
+    def on_slow_step(self) -> None:
+        """One decode step far slower than its like
+        (engine._watch_step)."""
+        self.slow_steps += 1
 
     def _register_kinds(self, name: str, attr: str, kinds: tuple,
                         help: str) -> None:
@@ -667,6 +680,8 @@ class ServingMetrics:
             out["lookahead"] = {
                 "steps": self.lookahead_steps,
                 "discarded_lane_steps": self.discarded_lane_steps}
+        if self.slow_steps:
+            out["slow_steps"] = self.slow_steps
         for name in ("kv_blocks", "key_blocks"):
             live = getattr(self, name + "_live")
             skipped = getattr(self, name + "_skipped")

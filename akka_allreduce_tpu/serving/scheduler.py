@@ -350,6 +350,14 @@ class RequestScheduler:
         with span(SCHED_POP_READY, self.tracer) as sp:
             req = self._pop_ready(now, can_admit)
             sp.set(queue_depth=len(self._arrived))
+            if req is not None:
+                # the wait ends where the request leaves the queue: from
+                # its arrival (its submit, where it came with none) to
+                # this span's close, on the tracer's clock (which is the
+                # scheduler's wherever both are the process's monotonic
+                # clock, as in every runner)
+                since = req.arrival or req.submitted_at or 0.0
+                sp.set(rid=req.rid, waited_ms=(sp.now() - since) * 1e3)
         return req
 
     def _pop_ready(self, now: Optional[float],

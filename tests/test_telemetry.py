@@ -414,7 +414,7 @@ class TestDeviceTimer:
                 pass
         inner, outer_ev = tracer.events[-2:]
         assert inner.parent_id == outer_ev.span_id
-        assert outer._kind == SERVE_STEP
+        assert outer.kind == SERVE_STEP
 
     def test_tracer_span_recorded(self):
         tracer = Tracer()

@@ -46,8 +46,7 @@ class TestTracerCore:
         assert ev.kind == "work"
         assert ev.duration_s == 0.5
         assert ev.ts == 10.0
-        assert t.span_stats("work") == {
-            "count": 1, "total_s": 0.5, "mean_s": 0.5, "max_s": 0.5}
+        assert ev.fields == {"round": 3} and ev.span_id == 1
 
     def test_span_records_on_exception(self):
         t = Tracer()
@@ -58,22 +57,35 @@ class TestTracerCore:
             pass
         assert t.counters["boom"] == 1
 
-    def test_round_latency_pairs_start_to_last_complete(self):
-        ts = iter([0.0, 1.0, 2.0, 5.0])
-        t = Tracer(clock=lambda: next(ts))
-        t.record("round_start", round=0)
-        t.record("round_complete", round=0, worker=0)
-        t.record("round_start", round=1)
-        t.record("round_complete", round=1, worker=0)
-        lat = t.round_latencies()
-        assert lat == {0: 1.0, 1: 3.0}
-
     def test_max_events_cap_keeps_counters(self):
         t = Tracer(max_events=2)
         for i in range(5):
             t.record("e", i=i)
         assert len(t.events) == 2
         assert t.counters["e"] == 5
+
+    def test_a_constructed_tracer_keeps_the_oldest_the_record_the_newest(
+            self):
+        """A trace file starts at the start; the process's record is what
+        to look at after a slow step, so it drops its oldest."""
+        oldest = Tracer(max_events=3)
+        newest = Tracer(max_events=3, newest=True)
+        for t in (oldest, newest):
+            for i in range(7):
+                with t.span("s", i=i):
+                    t.record("point", i=i)
+        assert [(e.kind, e.fields["i"]) for e in oldest.events] == [
+            ("point", 0), ("s", 0), ("point", 1)]
+        assert [(e.kind, e.fields["i"]) for e in newest.events] == [
+            ("s", 5), ("point", 6), ("s", 6)]
+        assert [e.fields["i"] for e in newest.newest(2)] == [6, 6]
+        assert [e.fields["i"] for e in oldest.newest(2)] == [0, 1]
+        assert dict(oldest.counters) == dict(newest.counters) \
+            == {"s": 7, "point": 7}
+        assert len(newest.events) == 3
+        # same event either way: ids, parentage, a mapping of fields
+        point, s = newest.events[1:]
+        assert point.parent_id == s.span_id and s.fields == {"i": 6}
 
     def test_jsonl_round_trip(self, tmp_path):
         t = Tracer(clock=lambda: 1.25)
@@ -113,11 +125,12 @@ class TestClusterTracing:
         fired = [e for e in tracer.events if e.kind == "reduce_fired"]
         assert all(e.fields["contributors"] == n for e in fired)
 
-        lat = tracer.round_latencies()
-        assert set(range(rounds)) <= set(lat)
-        assert all(v >= 0 for v in lat.values())
-        summary = tracer.summary()
-        assert summary["rounds_traced"] >= rounds
+        # every paced round starts before its last completion
+        starts = {e.fields["round"]: e.ts for e in reversed(tracer.events)
+                  if e.kind == "round_start"}
+        for e in completes:
+            if e.fields["round"] < rounds:
+                assert e.ts >= starts[e.fields["round"]]
 
     def test_dead_worker_traced_via_deathwatch(self):
         tracer = Tracer()
@@ -149,6 +162,28 @@ def _fake_clock(step=0.25):
     return lambda: next(ticks)
 
 
+class _Clock:
+    """A clock that stands at ``t`` and moves ``tick`` on at each read."""
+
+    def __init__(self, t=0.0, tick=0.0):
+        self.t, self.tick, self.reads = t, tick, 0
+
+    def __call__(self):
+        self.reads += 1
+        self.t += self.tick
+        return self.t
+
+
+@pytest.fixture
+def record(monkeypatch):
+    """A process's record of the test's own, on a ticking clock of 1 ms
+    (``record._clock``), in place of :func:`tracing.flight`'s."""
+    T.flight()                      # the collector's hook is installed
+    rec = Tracer(clock=_Clock(tick=1e-3), max_events=4096, newest=True)
+    monkeypatch.setattr(T, "_flight", rec)
+    return rec
+
+
 class TestSpanPrimitive:
     def test_records_what_tracer_span_recorded(self):
         """Same kind, fields, ids, parentage and timing as the method."""
@@ -166,12 +201,91 @@ class TestSpanPrimitive:
         assert commit.parent_id == step.span_id and step.parent_id is None
         assert b.events[0].parent_id == commit.span_id
 
-    def test_without_a_tracer_it_appends_nothing(self):
-        t = Tracer()
-        with span(T.SERVE_STEP, None, occupied=3) as sp:
+    def test_without_a_tracer_it_lands_in_the_process_record(self, record):
+        """No site's fields are dropped anywhere: a span that is given no
+        tracer is recorded, whole, in :func:`tracing.flight`'s."""
+        assert T.flight() is record
+        with span(T.SERVE_STEP, None, occupied=3, admitted=((7, 4),)) as sp:
             sp.set(tokens=1)
-            assert t.current_span_id is None
-        assert t.events == [] and not t.counters
+            with span(T.SERVE_STEP_COMMIT) as inner:
+                assert record.current_span_id == inner.span_id
+        commit, step = record.events
+        assert (step.kind, step.fields) == (T.SERVE_STEP, {
+            "occupied": 3, "admitted": ((7, 4),), "tokens": 1})
+        assert commit.parent_id == step.span_id == sp.span_id
+        assert step.duration_s == pytest.approx(sp.duration_s) \
+            == pytest.approx(3e-3)
+        assert dict(record.counters) == {T.SERVE_STEP: 1,
+                                         T.SERVE_STEP_COMMIT: 1}
+        # a tracer that is given takes the span, and the record nothing
+        t = Tracer()
+        with span(T.SERVE_ADMIT, t, rid=1):
+            pass
+        assert [e.kind for e in t.events] == [T.SERVE_ADMIT]
+        assert len(record.events) == 2
+
+    def test_the_record_exports_like_any_tracer(self, record, tmp_path):
+        with span(T.SERVE_ADMIT, rid=3, slot=0):
+            record.record("point", x=1)
+        path = str(tmp_path / "flight.jsonl")
+        assert record.write_jsonl(path) == 2
+        rows = Tracer.read_jsonl(path)
+        assert rows[0]["kind"] == "point" and rows[0]["x"] == 1
+        assert rows[1]["kind"] == T.SERVE_ADMIT and rows[1]["rid"] == 3
+        assert rows[0]["parent_id"] == rows[1]["span_id"]
+        names = {e["name"] for e in record.to_chrome_trace()["traceEvents"]}
+        assert T.SERVE_ADMIT in names
+
+    def test_a_span_costs_two_clock_reads_and_no_tracked_object(self,
+                                                                record):
+        """What the record adds to a decode step is pinned by count, not
+        by a stopwatch (PERF.md section 6 has the chip host's time): two
+        reads of the clock a span, and an event that is one tuple of
+        atoms, which the first young collection untracks, so a full
+        record adds nothing for a full collection to walk."""
+        import gc
+        def six():
+            for _ in range(6):
+                with span(T.SERVE_STEP, occupied=3, lanes=4,
+                          admitted=()) as sp:
+                    sp.set(ahead=1, discarded=0)
+
+        six()
+        gc.collect()      # the tuple of the fields' names is an old one now
+        before = len(record.events)
+        reads = record._clock.reads
+        six()
+        assert record._clock.reads - reads == 12
+        gc.collect(0)     # a ``host_gc``, at a tick of 1 ms
+        steps = record._stored(7)[:6]
+        assert len(record.events) == before + 7
+        assert {stored[1] for stored in steps} == {T.SERVE_STEP}
+        for stored in steps:
+            assert type(stored) is tuple and not gc.is_tracked(stored)
+
+    def test_a_full_record_is_a_few_chunks_to_the_collector(self):
+        """Sealed chunks are tuples of untracked tuples, which a young
+        collection untracks in turn: a full collection meets the chunks,
+        not the events."""
+        import gc
+        rec = Tracer(max_events=4 * T._CHUNK, newest=True)
+        for i in range(6 * T._CHUNK + 5):
+            rec.record("e", i=i)
+            if i % 100 == 0:
+                gc.collect(0)
+        # the last chunk sealed holds events younger than any collection:
+        # one young pass untracks them, the next one the chunk
+        gc.collect(0)
+        gc.collect(1)
+        assert len(rec._chunks) == 4 and len(rec._filling) == 5
+        assert not any(gc.is_tracked(chunk) for chunk in rec._chunks)
+        events = rec.events
+        assert len(events) == 4 * T._CHUNK
+        assert [e.fields["i"] for e in events[:2] + events[-2:]] == [
+            2 * T._CHUNK + 5, 2 * T._CHUNK + 6,
+            6 * T._CHUNK + 3, 6 * T._CHUNK + 4]
+        assert [e.fields["i"] for e in rec.newest(7)] == list(
+            range(6 * T._CHUNK - 2, 6 * T._CHUNK + 5))
 
     def test_records_on_exception_and_unwinds_the_stack(self):
         t = Tracer()
@@ -183,12 +297,18 @@ class TestSpanPrimitive:
 
     def test_tables_name_every_constant(self):
         spans = {v for k, v in vars(T).items()
-                 if k.startswith(("SERVE_", "SCHED_", "TRAIN_"))}
+                 if k.startswith(("SERVE_", "SCHED_", "TRAIN_", "HOST_"))}
         scopes = {v for k, v in vars(T).items() if k.startswith("SCOPE_")}
         assert spans == set(T.SPANS) and scopes == set(T.SCOPES)
         for layer, metric in list(T.SPANS.values()) + list(
                 T.SCOPES.values()):
             assert layer and metric
+        # what the process's record added: the collector's pauses, the
+        # wait a request's pop ends, and the entries that read them
+        assert T.SPANS[T.HOST_GC] == ("host runtime (python)",
+                                      "flood_host_gc_ms_max")
+        assert "sched_wait_p90_ms" in T.SPANS[T.SCHED_POP_READY][1]
+        assert "engine_occupancy_pct" in T.SPANS[T.SERVE_STEP][1]
 
     def test_span_fields_of_another_layer_have_their_rows(self):
         assert set(T.SPAN_FIELDS) <= set(T.SPANS)
@@ -329,13 +449,230 @@ def test_engine_step_is_four_phases_and_admit_holds_its_prefill(kind):
     engine.close()
 
 
-def test_engine_without_a_tracer_traces_nothing():
+def test_engine_without_a_tracer_leaves_its_step_in_the_record(record):
     from akka_allreduce_tpu.serving import Request
     engine = _toy_engine("slot", None)
     engine.admit(Request(rid=1, prompt=(1, 2, 3), max_new_tokens=2,
                          submitted_at=0.0))
     engine.step()
     assert engine._device_timer().tracer is None
+    (step,) = [e for e in record.events if e.kind == T.SERVE_STEP]
+    assert step.fields["admitted"] == ((1, 4),)
+    assert (step.fields["occupied"], step.fields["lanes"]) == (1, 3)
+    assert [k.kind for k in sorted(_children(record, step),
+                                   key=lambda e: e.ts)] == [
+        T.SERVE_STEP_UPLOAD, T.SERVE_STEP_DISPATCH, T.SERVE_STEP_READBACK,
+        T.SERVE_STEP_COMMIT]
+    # the request's spans share its rid from the admission on
+    (admit,) = [e for e in record.events if e.kind == T.SERVE_ADMIT]
+    (prefill,) = [e for e in record.events if e.kind == T.SERVE_PREFILL]
+    assert admit.fields["rid"] == prefill.fields["rid"] == 1
+    engine.close()
+
+
+def test_a_stall_in_a_step_with_an_admission_is_held_to_its_like(
+        record, monkeypatch, caplog):
+    """The first stall the record caught fell in a step with an admission:
+    such steps are watched too, against the median of their own like (a
+    prefill's duration is in them), not against the quiet steps'."""
+    import logging
+    from akka_allreduce_tpu.serving import Request
+    from akka_allreduce_tpu.serving import engine as eng
+    monkeypatch.setattr(eng, "_WATCH_EVERY", 4)
+    engine = _toy_engine("slot", None)
+    clock, real = record._clock, engine._dispatch_single
+    stall = []
+
+    def dispatch(*args):
+        if stall:
+            clock.t += stall.pop()
+        return real(*args)
+
+    monkeypatch.setattr(engine, "_dispatch_single", dispatch)
+    with caplog.at_level(logging.WARNING, logger=eng.__name__):
+        for rid in range(6):
+            engine.admit(Request(rid=rid, prompt=(1, 2, 3),
+                                 max_new_tokens=2, submitted_at=0.0))
+            if rid == 5:
+                stall.append(4.0)
+            engine.step()           # with the admission
+            assert engine.step()    # the one after it ends the request
+    assert engine._like[(True, False)][1] == 6
+    assert engine.slow_steps == 1
+    (line,) = [r.getMessage() for r in caplog.records]
+    assert re.search(r"^slow serve_step: 40\d\d\.\d ms", line), line
+    assert "occupied=1 of 3 admitted=1;" in line
+    engine.close()
+
+
+def test_the_step_that_gives_a_request_its_first_token(record):
+    """What ``admit_to_token_p50_ms`` (benchmark/readers/program_spans.py)
+    rests on: a request's first token is committed by the step whose
+    ``admitted`` lists it, unless a dispatch launched ahead of it was in
+    the air at its admission (the step before says ``ahead`` 1), which
+    that step commits without it; then by the next."""
+    from akka_allreduce_tpu.serving import Request
+    from akka_allreduce_tpu.serving import engine as eng
+
+    class Sink:
+        registry = None
+        call = 0
+
+        def __init__(self):
+            self.first = {}
+
+        def on_token(self, rid, _at):
+            self.first.setdefault(rid, self.call)
+
+        def __getattr__(self, name):
+            if name.startswith("on_"):
+                return lambda *a, **k: None
+            raise AttributeError(name)
+
+    cfg, params = _toy()
+    sink = Sink()
+    engine = eng.ServingEngine(params, cfg, eng.EngineConfig(
+        num_slots=3, prefill_buckets=(4, 8)), metrics=sink)
+    budgets = iter([3, 9, 5, 4, 6, 3, 2])
+    rid = 0
+    for call in range(16):
+        sink.call = call
+        # a lane stays free for the first calls (nothing launched ahead),
+        # then every free lane is filled before each call
+        while engine.free_slot_count > (1 if call < 3 else 0):
+            budget = next(budgets, None)
+            if budget is None:
+                break
+            engine.admit(Request(rid=rid, prompt=(1, 2, 3),
+                                 max_new_tokens=budget, submitted_at=0.0))
+            rid += 1
+        if engine.occupied:
+            engine.step()
+    steps = [e for e in record.events if e.kind == T.SERVE_STEP]
+    moved = []
+    for i, step in enumerate(steps):
+        in_the_air = steps[i - 1].fields["ahead"] if i else 0
+        for r, _n in step.fields["admitted"]:
+            assert sink.first[r] == i + in_the_air, (r, i, in_the_air)
+            moved.append(in_the_air)
+    assert len(moved) == 7 and 0 in moved and 1 in moved
+    engine.close()
+
+
+def test_pop_ready_carries_the_wait_of_the_request_it_returns():
+    from akka_allreduce_tpu.serving import (Request, RequestScheduler,
+                                            SchedulerConfig)
+    clock = _Clock(t=10.0)
+    tracer = Tracer(clock=clock)
+    sched = RequestScheduler(SchedulerConfig(max_queue_depth=8),
+                             num_slots=2, clock=clock, tracer=tracer)
+    assert sched.pop_ready() is None
+    # handed over at 10.0 with no arrival of its own; and due at 10.5
+    sched.submit(Request(rid=4, prompt=(1, 2), max_new_tokens=2))
+    sched.submit(Request(rid=5, prompt=(1, 2), max_new_tokens=2,
+                         arrival=10.5))
+    clock.t = 10.25
+    assert sched.pop_ready().rid == 4
+    assert sched.pop_ready() is None          # 5 is not due yet
+    clock.t = 10.75
+    assert sched.pop_ready().rid == 5
+    pops = [e.fields for e in tracer.events if e.kind == T.SCHED_POP_READY]
+    assert pops == [{"queue_depth": 0},
+                    {"queue_depth": 0, "rid": 4, "waited_ms": 250.0},
+                    {"queue_depth": 0},
+                    {"queue_depth": 0, "rid": 5, "waited_ms": 250.0}]
+
+
+def test_host_gc_is_a_full_collection_or_a_long_one(record):
+    import gc
+    gc.collect()
+    (full,) = [e for e in record.events if e.kind == T.HOST_GC]
+    assert full.fields["generation"] == 2 and "collected" in full.fields
+    assert full.duration_s == pytest.approx(1e-3)      # one tick
+    assert full.span_id is not None and full.parent_id is None
+    # a young collection that lasts under GC_SPAN_MIN_S records nothing
+    record._clock.tick = T.GC_SPAN_MIN_S / 4
+    gc.collect(0)
+    assert record.counters[T.HOST_GC] == 1
+    # ... and one that lasts it is recorded, under the span that was open
+    record._clock.tick = T.GC_SPAN_MIN_S
+    with span(T.SERVE_STEP_COMMIT) as sp:
+        gc.collect(0)
+    young = [e for e in record.events if e.kind == T.HOST_GC][-1]
+    assert young.fields["generation"] == 0
+    assert young.parent_id == sp.span_id
+
+
+def test_the_slow_step_says_so_itself_once_a_second(record, monkeypatch,
+                                                    caplog):
+    """A quiet step of eight medians of its like is counted and logged
+    with its phases and the collector's pause inside it; a second within
+    the second is counted and not logged."""
+    import gc
+    import logging
+    from akka_allreduce_tpu.serving import Request
+    from akka_allreduce_tpu.serving import engine as eng
+
+    class Sink:
+        slow = 0
+
+        def on_slow_step(self):
+            self.slow += 1
+
+        def __getattr__(self, name):
+            if name.startswith("on_"):
+                return lambda *a, **k: None
+            raise AttributeError(name)
+
+    monkeypatch.setattr(eng, "_WATCH_EVERY", 4)
+    cfg, params = _toy()
+    engine = eng.ServingEngine(params, cfg, eng.EngineConfig(
+        num_slots=3, prefill_buckets=(4, 8)), metrics=Sink())
+    engine.metrics.registry = None
+    engine.admit(Request(rid=1, prompt=(1, 2, 3), max_new_tokens=28,
+                         submitted_at=0.0))
+    clock, real = record._clock, engine._dispatch_single
+    stall = {}
+
+    def dispatch(*args):
+        if stall:
+            clock.t += stall["s"]
+            if stall.pop("gc", False):
+                gc.collect()
+            del stall["s"]
+        return real(*args)
+
+    monkeypatch.setattr(engine, "_dispatch_single", dispatch)
+    with caplog.at_level(logging.WARNING, logger=eng.__name__):
+        # the step of the admission and the one after it are not quiet:
+        # a stall there is a prefill's, and says nothing
+        stall.update(s=2.0)
+        engine.step()
+        for _ in range(7):
+            engine.step()
+        assert engine._like[(False, False)][2] is not None
+        assert engine.slow_steps == 0
+        stall.update(s=2.0, gc=True)
+        engine.step()
+        assert engine.slow_steps == 1
+        stall.update(s=0.5)           # within the second: counted only
+        engine.step()
+        assert engine.slow_steps == 2
+        clock.t += 1.0
+        stall.update(s=0.5)
+        engine.step()
+    assert engine.slow_steps == engine.metrics.slow == 3
+    lines = [r.getMessage() for r in caplog.records
+             if r.getMessage().startswith("slow serve_step")]
+    assert len(lines) == 2, lines
+    first = lines[0]
+    for phase in ("upload", "dispatch", "readback", "commit"):
+        assert f"{phase} " in first
+    assert re.search(r"^slow serve_step: 20\d\d\.\d ms", first), first
+    assert re.search(r"dispatch 200\d\.\d", first), first
+    assert "ahead=0 occupied=1 of 3 admitted=0;" in first
+    assert re.search(r"host_gc: gen2 1\.0 ms$", first), first
+    assert lines[1].endswith("host_gc: none")
     engine.close()
 
 
