@@ -494,6 +494,15 @@ def _add_train(sub: argparse._SubParsersAction) -> None:
     p.add_argument("--tie-embeddings", action="store_true",
                    help="output head reuses the input embedding "
                         "(GPT-2-style weight tying)")
+    p.add_argument("--model-config", default=None, metavar="FILE",
+                   help="the model from a published config.json (HF-style "
+                        "keys; models/transformer.py config_from_hf) instead "
+                        "of the --d-model/... flags: model_type "
+                        "glm4_moe_lite trains latent attention over every "
+                        "position, the dropless expert share (experts_held "
+                        "in the file) and its multi-token-prediction "
+                        "module, on dp alone; float32 master weights, "
+                        "--bf16 for the compute")
     p.add_argument("--batch", type=int, default=0,
                    help="global batch (0 = 2 per dp rank)")
     p.add_argument("--seq", type=int, default=0,
@@ -974,8 +983,10 @@ def _build_model_config(args: argparse.Namespace, max_seq: int):
         tie_embeddings=args.tie_embeddings)
 
 
-def _model_config_from_file(args: argparse.Namespace, max_seq: int):
-    """``serve --model-config FILE`` -> TransformerConfig; ``args.vocab``
+def _model_config_from_file(args: argparse.Namespace, max_seq: int,
+                            dtype=None):
+    """``serve|train --model-config FILE`` -> TransformerConfig (``dtype``:
+    the weights', default the file's ``torch_dtype``); ``args.vocab``
     follows the file, so the synthetic load draws its ids from it."""
     import json
 
@@ -984,8 +995,9 @@ def _model_config_from_file(args: argparse.Namespace, max_seq: int):
     from akka_allreduce_tpu.models.transformer import config_from_hf
     with open(args.model_config) as f:
         hf = json.load(f)
-    dtype = jnp.bfloat16 if hf.get("torch_dtype") == "bfloat16" \
-        else jnp.float32
+    if dtype is None:
+        dtype = jnp.bfloat16 if hf.get("torch_dtype") == "bfloat16" \
+            else jnp.float32
     mcfg = config_from_hf(hf, max_seq, dtype)
     args.vocab = mcfg.vocab_size
     return mcfg
@@ -1437,7 +1449,25 @@ def _cmd_train(args: argparse.Namespace) -> int:
             print(f"note: raising --vocab {args.vocab} -> {needed} to "
                   f"cover the corpus (largest token id {needed - 1})")
             args.vocab = needed
-    mcfg = _build_model_config(args, t)
+    if args.model_config:
+        if model_par > 1 or args.grad_accum > 1 or args.moe_experts:
+            print("error: --model-config trains on dp alone (no --tp, --sp, "
+                  "--pp, --ep, --grad-accum or --moe-experts)",
+                  file=sys.stderr)
+            return 2
+        try:
+            mcfg = _model_config_from_file(args, t, jnp.float32)
+        except (ValueError, KeyError) as exc:
+            print(f"error: --model-config {args.model_config}: {exc!r}",
+                  file=sys.stderr)
+            return 2
+        if mcfg.new_kind is not None and not mcfg.full_latent:
+            print(f"error: --model-config {args.model_config}: "
+                  f"{mcfg.new_kind} does not train (serve it)",
+                  file=sys.stderr)
+            return 2
+    else:
+        mcfg = _build_model_config(args, t)
     cfg = TrainConfig(model=mcfg, learning_rate=args.lr,
                       bucket_elems=args.bucket_elems, microbatches=micro,
                       pp_schedule=args.pp_schedule,

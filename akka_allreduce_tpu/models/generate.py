@@ -39,6 +39,7 @@ from akka_allreduce_tpu.models.transformer import (
     apply_rope,
     embed_tokens,
     lm_logits,
+    mla_project,
     rmsnorm,
 )
 from akka_allreduce_tpu.ops.pallas_kernels.attention import (
@@ -580,16 +581,9 @@ def _mla_cached_attention(p: dict, x: jnp.ndarray, kv: dict, a: int,
     b, t, _ = x.shape
     heads, rank = cfg.n_heads, cfg.kv_lora_rank
     nope, vd = cfg.qk_nope_head_dim, cfg.v_head_dim
-    s_q, s_kv = cfg.mla_scales
     kv = dict(kv)
-    h = rmsnorm(x, p["ln"], cfg.norm_eps)
-    c_q = rmsnorm(h @ p["wq_a"], p["q_norm"], cfg.norm_eps)
-    q = ((c_q @ p["wq_b"]) * s_q).reshape(b, t, heads, -1)
-    q_nope = q[..., :nope]
-    q_rope = ops.rope(q[..., nope:], cfg.rope_theta)
-    down = h @ p["wkv_a"]
-    c_kv = rmsnorm(down[..., :rank], p["kv_norm"], cfg.norm_eps) * s_kv
-    k_rope = ops.rope(down[:, :, None, rank:], cfg.rope_theta)
+    _h, _c_q, q_nope, q_rope, c_kv, k_rope = mla_project(
+        p, x, cfg, lambda v: ops.rope(v, cfg.rope_theta))
     latent = jnp.concatenate([c_kv, k_rope[:, :, 0]], axis=-1)
     kv["latent"] = ops.write(kv["latent"], a,
                              latent.astype(kv["latent"].dtype))
@@ -940,7 +934,6 @@ def _layerwise_cached_block(layer: dict, x: jnp.ndarray, kv: dict, i: int,
     p = layer["mla"]
     heads, rank = cfg.n_heads, cfg.kv_lora_rank
     nope, vd = cfg.qk_nope_head_dim, cfg.v_head_dim
-    s_q, s_kv = cfg.mla_scales
     positions = ops.positions(b, t)
     lanes = (jnp.arange(b) if ops.lane is None
              else jnp.reshape(ops.lane, (1,)))
@@ -949,13 +942,8 @@ def _layerwise_cached_block(layer: dict, x: jnp.ndarray, kv: dict, i: int,
     blk = selected_attention_path(t, top, n_seq, ops.lane is not None)
     kv = dict(kv)
     with jax.named_scope(SCOPE_MLA_ATTENTION):
-        h = rmsnorm(x, p["ln"], cfg.norm_eps)
-        c_q = rmsnorm(h @ p["wq_a"], p["q_norm"], cfg.norm_eps)
-        q = ((c_q @ p["wq_b"]) * s_q).reshape(b, t, heads, -1)
-        q_rope = ops.rope(q[..., nope:], cfg.rope_theta)
-        down = h @ p["wkv_a"]
-        c_kv = rmsnorm(down[..., :rank], p["kv_norm"], cfg.norm_eps) * s_kv
-        k_rope = ops.rope(down[:, :, None, rank:], cfg.rope_theta)
+        h, c_q, q_nope, q_rope, c_kv, k_rope = mla_project(
+            p, x, cfg, lambda v: ops.rope(v, cfg.rope_theta))
         row = jnp.concatenate([c_kv, k_rope[:, :, 0]], axis=-1)
         row = jnp.pad(row, ((0, 0), (0, 0),
                             (0, kv["latent"].shape[-1] - row.shape[-1])))
@@ -971,7 +959,7 @@ def _layerwise_cached_block(layer: dict, x: jnp.ndarray, kv: dict, i: int,
             chosen = _chosen_mask(chosen, positions, n_seq)
         up = p["wkv_b"].reshape(rank, heads, nope + vd)
         q_lat = jnp.concatenate(
-            [jnp.einsum("bthn,rhn->bthr", q[..., :nope], up[..., :nope]),
+            [jnp.einsum("bthn,rhn->bthr", q_nope, up[..., :nope]),
              q_rope], axis=-1)
         scale = (nope + q_rope.shape[-1]) ** -0.5
         if blk is None:
@@ -1201,6 +1189,10 @@ def cached_blocks(params: dict, x: jnp.ndarray, kv: dict,
     selection goes from a full layer to the shared layers after it here;
     the hybrid's layers each take their mixer's kind from
     ``cfg.layer_mixer``."""
+    if cfg.full_latent:
+        raise NotImplementedError(
+            f"{cfg.new_kind}: no cached block runs it (it trains: "
+            f"models/transformer.py `latent_block`)")
     block = (_shortcut_cached_block if cfg.block == "shortcut"
              else _hybrid_cached_block if cfg.hybrid
              else _dense_cached_block)

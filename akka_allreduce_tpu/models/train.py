@@ -36,6 +36,7 @@ import optax
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from akka_allreduce_tpu.models.mtp import MTP_WEIGHT, mtp_loss_sums
 from akka_allreduce_tpu.models.transformer import (
     TransformerConfig,
     init_transformer,
@@ -46,6 +47,7 @@ from akka_allreduce_tpu.models.transformer import (
     weighted_ce,
 )
 from akka_allreduce_tpu.parallel.dp import GradSyncConfig, allreduce_gradients
+from akka_allreduce_tpu.parallel.ep import selection_bias_update
 from akka_allreduce_tpu.parallel.mesh import place_tree
 from akka_allreduce_tpu.parallel.pp import (
     gpipe_apply,
@@ -253,6 +255,10 @@ def param_specs(cfg: TransformerConfig, pp: int = 1) -> dict:
     pipeline rank owns its contiguous slice of layers; non-layer leaves
     stay replicated over pp (their grads psum over it in make_grad_step).
     """
+    if cfg.full_latent:
+        # dp alone: every leaf replicated (make_grad_step refuses the rest)
+        return jax.tree.map(lambda _: P(), jax.eval_shape(
+            lambda: init_transformer(jax.random.key(0), cfg)))
     attn, dense_ff, moe_ff = _uniform_layer_spec(cfg)
     top = {"embed": P(), "out_norm": P()}
     if not cfg.tie_embeddings:
@@ -546,7 +552,8 @@ def _local_attention_impl(cfg: TrainConfig):
         interpret = jax.default_backend() != "tpu"
 
         def flash_or_fallback(q, k, v):
-            want = cfg.attn_block_size or default_flash_block(q.dtype)
+            want = cfg.attn_block_size or default_flash_block(
+                q.dtype, q.shape[-1])
             # block choice needs T, known only at trace time; "auto" falls
             # back to the pure-JAX paths for untileable lengths instead of
             # failing lengths that worked before the kernel existed — and
@@ -626,7 +633,8 @@ def select_ring_attention(cfg: TrainConfig):
             interp = jax.default_backend() != "tpu"
 
             def flash_or_fallback(q, k, v):
-                want = cfg.attn_block_size or default_flash_block(q.dtype)
+                want = cfg.attn_block_size or default_flash_block(
+                    q.dtype, q.shape[-1])
                 blk = pick_flash_block(q.shape[1], want)
                 if blk is None:
                     if impl == "flash":
@@ -665,7 +673,8 @@ def select_ring_attention(cfg: TrainConfig):
     interpret = jax.default_backend() != "tpu"
 
     def ring_or_fallback(q, k, v):
-        want = cfg.attn_block_size or default_flash_block(q.dtype)
+        want = cfg.attn_block_size or default_flash_block(q.dtype,
+                                                          q.shape[-1])
         blk = pick_flash_block(q.shape[1], want)
         if blk is None:
             if not auto:
@@ -760,7 +769,13 @@ def make_grad_step(cfg: TrainConfig, mesh: Mesh,
         weights = weights.at[:, -1].set(1.0 - is_last)
         return targets, weights, positions
 
-    if has_sp:
+    if mcfg.full_latent:
+        _refuse_latent_mesh(cfg, mesh)
+        # the kernel's calls sit under ``mla_attention`` alone (a reader
+        # that takes the first scope it finds would file them under
+        # ``attention``)
+        attn = _local_attention_impl(cfg)
+    elif has_sp:
         attn = select_ring_attention(cfg)
     else:
         attn = select_local_attention(cfg)
@@ -849,7 +864,7 @@ def make_grad_step(cfg: TrainConfig, mesh: Mesh,
         return res.grads, res.bucket_counts.min(), res.residual
 
     def make_metrics(loss, aux, total_count, min_count):
-        return {
+        metrics = {
             "loss": psum_all(loss, metric_axes),
             "tokens": total_count,
             "min_bucket_count": min_count,
@@ -858,6 +873,13 @@ def make_grad_step(cfg: TrainConfig, mesh: Mesh,
             "dispatch_fraction": psum_all(aux["dispatch_fraction"],
                                           metric_axes) / disp_norm,
         }
+        if mcfg.full_latent:
+            # the expert layers' route counts over the whole batch, and
+            # the module's loss alone (models/mtp.py)
+            for k in ("route_held", "route_touched", "route_load",
+                      "mtp_loss"):
+                metrics[k] = psum_all(aux[k], metric_axes)
+        return metrics
 
     def sync_and_metrics(loss, aux, grads, total_count, quant_key,
                          valid=None, ef=None):
@@ -986,6 +1008,39 @@ def make_grad_step(cfg: TrainConfig, mesh: Mesh,
                 body, zeros, (tok_m, tgt_m, w_m))
             # aux terms are per-microbatch diagnostics: report the mean
             aux = jax.tree.map(lambda x: x / accum, aux)
+        return sync_and_metrics(loss, aux, grads, total_count,
+                                derive_quant_key(quant_seed),
+                                valid=valid, ef=ef)
+
+    def grad_local_latent(params, tokens, quant_seed, valid=None, ef=None):
+        """Latent attention over every position: the main loss plus
+        models/mtp.py ``MTP_WEIGHT`` x the multi-token-prediction module's (where the
+        model has one), each a mean over its own positions; the aux
+        carries the expert layers' route counts."""
+        targets, weights, positions = targets_and_weights(tokens)
+        # t_{i+2}: the last two positions have none in the row
+        mtp_targets = jnp.roll(targets, -1, axis=1)
+        mtp_weights = weights.at[:, -2].set(0.0)
+        total_count = psum_all(weights.sum(), dense_axes)
+        mtp_count = psum_all(mtp_weights.sum(), dense_axes)
+
+        def loss_fn(p):
+            p = cast_compute(p)
+            if mcfg.mtp_layers:
+                main, mtp, aux = mtp_loss_sums(
+                    p, tokens, mcfg, targets, weights, mtp_targets,
+                    mtp_weights, positions, attn, cfg.remat)
+            else:
+                main, _, aux = next_token_loss_and_aux(
+                    p, tokens, mcfg, positions, attn, targets=targets,
+                    weights=weights, remat=cfg.remat)
+                mtp = jnp.zeros((), jnp.float32)
+            aux = dict(aux, mtp_loss=mtp / mtp_count)
+            return (main / total_count
+                    + MTP_WEIGHT * aux["mtp_loss"]), aux
+
+        (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+            params)
         return sync_and_metrics(loss, aux, grads, total_count,
                                 derive_quant_key(quant_seed),
                                 valid=valid, ef=ef)
@@ -1119,6 +1174,8 @@ def make_grad_step(cfg: TrainConfig, mesh: Mesh,
                 "fused backward has no aux-loss channel) — use gpipe "
                 "for MoE pipelines")
         local_fn = grad_local_1f1b
+    elif mcfg.full_latent:
+        local_fn = grad_local_latent
     else:
         local_fn = grad_local_pp if has_pp else grad_local
     # the ef8 residual is explicit rank-varying state: one
@@ -1219,6 +1276,39 @@ def make_grad_step(cfg: TrainConfig, mesh: Mesh,
         return mapped(params, tokens, seed)
 
     return grad_step
+
+
+def _refuse_latent_mesh(cfg: TrainConfig, mesh: Mesh) -> None:
+    """Latent attention over every position trains on dp alone, the
+    sequence whole, one microbatch: the parts of the step it does not go
+    through are refused by name."""
+    wide = [f"{a}={mesh.shape[a]}" for a in ("tp", "sp", "pp", "ep")
+            if mesh.shape.get(a, 1) > 1]
+    if cfg.grad_accum > 1:
+        wide.append(f"grad_accum={cfg.grad_accum}")
+    if wide:
+        raise ValueError(f"{cfg.model.new_kind} trains on dp alone with "
+                         f"grad_accum 1; got {', '.join(wide)}")
+
+
+def update_selection_biases(params: dict, load: jnp.ndarray) -> dict:
+    """Each expert layer's selection bias after a step
+    (parallel/ep.py ``selection_bias_update``), ``load`` (expert layers,
+    router outputs) in the order of models/mtp.py ``mtp_loss_sums``: the
+    main stack's expert layers, then the module's."""
+    rows = iter(range(load.shape[0]))
+
+    def layer(lyr):
+        if "moe" not in lyr:
+            return lyr
+        moe = dict(lyr["moe"])
+        moe["bias"] = selection_bias_update(moe["bias"], load[next(rows)])
+        return dict(lyr, moe=moe)
+
+    out = dict(params, layers=[layer(lyr) for lyr in params["layers"]])
+    if "mtp" in params:
+        out["mtp"] = dict(params["mtp"], layer=layer(params["mtp"]["layer"]))
+    return out
 
 
 def _data_axes(cfg: TrainConfig, mesh: Mesh) -> tuple:
@@ -1329,10 +1419,18 @@ def make_train_step(cfg: TrainConfig, mesh: Mesh,
                 "optimizer with make_optimizer (or chain step_counter())")
         return state.count
 
-    def apply_optimizer(grads, opt_state, params):
+    def apply_optimizer(grads, opt_state, params, metrics):
+        """The optimizer's update, then (latent attention over every
+        position) each expert layer's selection bias, which has no
+        gradient and which Adam leaves where it is."""
         with jax.named_scope(SCOPE_OPTIMIZER):
             updates, opt_state = opt.update(grads, opt_state, params)
-            return optax.apply_updates(params, updates), opt_state
+            params = optax.apply_updates(params, updates)
+            if cfg.model.full_latent:
+                metrics = dict(metrics)
+                params = update_selection_biases(
+                    params, metrics.pop("route_load"))
+            return params, opt_state, metrics
 
     @partial(jax.jit, donate_argnums=donate_args)
     def step(params, opt_state, tokens):
@@ -1340,7 +1438,8 @@ def make_train_step(cfg: TrainConfig, mesh: Mesh,
         # noise, so every round draws fresh bits even on repeated batches
         count = step_count(opt_state)
         grads, metrics = grad_step(params, tokens, quant_seed=count)
-        params, opt_state = apply_optimizer(grads, opt_state, params)
+        params, opt_state, metrics = apply_optimizer(grads, opt_state,
+                                                     params, metrics)
         return params, opt_state, metrics
 
     @partial(jax.jit, donate_argnums=donate_args)
@@ -1348,7 +1447,8 @@ def make_train_step(cfg: TrainConfig, mesh: Mesh,
         count = step_count(opt_state)
         grads, metrics = grad_step(params, tokens, quant_seed=count,
                                    valid=valid)
-        params, opt_state = apply_optimizer(grads, opt_state, params)
+        params, opt_state, metrics = apply_optimizer(grads, opt_state,
+                                                     params, metrics)
         return params, opt_state, metrics
 
     # ef8 steps: the error-feedback residual is a fourth state item the
@@ -1360,7 +1460,8 @@ def make_train_step(cfg: TrainConfig, mesh: Mesh,
         grads, metrics, ef_state = grad_step(params, tokens,
                                              quant_seed=count,
                                              ef_state=ef_state)
-        params, opt_state = apply_optimizer(grads, opt_state, params)
+        params, opt_state, metrics = apply_optimizer(grads, opt_state,
+                                                     params, metrics)
         return params, opt_state, metrics, ef_state
 
     @partial(jax.jit, donate_argnums=donate_args_ef)
@@ -1370,7 +1471,8 @@ def make_train_step(cfg: TrainConfig, mesh: Mesh,
                                              quant_seed=count,
                                              valid=valid,
                                              ef_state=ef_state)
-        params, opt_state = apply_optimizer(grads, opt_state, params)
+        params, opt_state, metrics = apply_optimizer(grads, opt_state,
+                                                     params, metrics)
         return params, opt_state, metrics, ef_state
 
     if use_ef:

@@ -27,12 +27,17 @@ import jax.numpy as jnp
 from akka_allreduce_tpu.parallel.ep import (
     ExpertShareConfig,
     MoEConfig,
+    dropless_moe,
     init_expert_share,
     init_moe_layer,
     moe_ffn,
 )
 from akka_allreduce_tpu.parallel.ring_attention import local_causal_attention
-from akka_allreduce_tpu.runtime.tracing import SCOPE_HEAD_LOSS
+from akka_allreduce_tpu.runtime.tracing import (
+    SCOPE_DENSE_FFN,
+    SCOPE_HEAD_LOSS,
+    SCOPE_MLA_ATTENTION,
+)
 from akka_allreduce_tpu.parallel.tp import column_parallel_dense, \
     row_parallel_dense, tp_grad_boundary
 
@@ -116,6 +121,12 @@ class TransformerConfig:
     #   ``embed_scale`` multiplies the embedding, ``residual_scale`` every
     #   branch before it joins the residual, ``logit_divisor`` divides the
     #   head's output (all 1 for every other kind).
+    # * block = "standard" with attention = "mla" and NO ``layer_indexer``
+    #   is latent attention over every position, layer by layer
+    #   (``layer_ffn`` as above): the kind the full forward and the train
+    #   step run (:func:`latent_block`), which no serving path runs yet.
+    #   ``mtp_layers`` 1 gives it a multi-token-prediction module
+    #   (models/mtp.py), trained as a second objective.
     attention: str = "gqa"
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
@@ -140,6 +151,7 @@ class TransformerConfig:
     embed_scale: float = 1.0
     residual_scale: float = 1.0
     logit_divisor: float = 1.0
+    mtp_layers: int = 0
 
     @property
     def head_dim(self) -> int:
@@ -192,7 +204,15 @@ class TransformerConfig:
     def indexed(self) -> bool:
         """Latent attention over an indexer's selection in a standard
         block (``layer_ffn`` / ``layer_indexer``)."""
-        return self.block == "standard" and self.attention == "mla"
+        return self.block == "standard" and self.attention == "mla" \
+            and bool(self.layer_indexer)
+
+    @property
+    def full_latent(self) -> bool:
+        """Latent attention over every position in a standard block,
+        layer by layer (``layer_ffn``, no ``layer_indexer``)."""
+        return self.block == "standard" and self.attention == "mla" \
+            and not self.layer_indexer
 
     @property
     def layerwise(self) -> bool:
@@ -241,8 +261,9 @@ class TransformerConfig:
 
     @property
     def n_expert_layers(self) -> int:
-        """Expert layers (``experts``) a token passes."""
-        if self.layerwise:
+        """Expert layers (``experts``) a token passes (the main stack's;
+        the multi-token-prediction module has one more)."""
+        if self.layerwise or self.full_latent:
             return sum(kind == "sparse" for kind in self.layer_ffn)
         return self.n_layers if self.experts is not None else 0
 
@@ -315,7 +336,7 @@ class TransformerConfig:
             raise ValueError(
                 "ssm_* / attn_scale / embed_scale / residual_scale / "
                 "logit_divisor describe the hybrid of `layer_mixer`")
-        elif self.indexed:
+        elif self.indexed or self.full_latent:
             self._check_layerwise()
         elif any(described) or not self.mla_lora_scales:
             raise ValueError(
@@ -329,21 +350,33 @@ class TransformerConfig:
             raise ValueError(
                 "the shortcut double layer has swiglu dense FFNs and an "
                 "untied head, and its expert layer is `experts`, not `moe`")
+        if self.mtp_layers not in (0, 1) or self.mtp_layers and not (
+                self.full_latent and self.experts is not None):
+            raise ValueError(
+                f"mtp_layers={self.mtp_layers}: one multi-token-prediction "
+                f"module (a sparse layer) follows latent attention over "
+                f"every position with an `experts` share")
 
     def _check_layerwise(self) -> None:
         n = self.n_layers
-        if len(self.layer_ffn) != n or len(self.layer_indexer) != n \
+        if len(self.layer_ffn) != n \
+                or len(self.layer_indexer) not in (0, n) \
                 or set(self.layer_ffn) - {"dense", "sparse"} \
                 or set(self.layer_indexer) - {"full", "shared"}:
             raise ValueError(
                 f"latent attention in a standard block is described layer "
-                f"by layer: layer_ffn ('dense' | 'sparse') and "
-                f"layer_indexer ('full' | 'shared'), {n} entries each, got "
-                f"{self.layer_ffn} and {self.layer_indexer}")
-        if self.layer_indexer[0] != "full":
+                f"by layer: layer_ffn ('dense' | 'sparse') and, where it "
+                f"has an indexer, layer_indexer ('full' | 'shared'), {n} "
+                f"entries each, got {self.layer_ffn} and "
+                f"{self.layer_indexer}")
+        if self.full_latent:
+            if self.index_n_heads or self.index_head_dim or self.index_topk:
+                raise ValueError("index_* size an indexer, and no layer "
+                                 "has one (layer_indexer is empty)")
+        elif self.layer_indexer[0] != "full":
             raise ValueError("layer 0 shares an indexer's choice and no "
                              "layer before it has one")
-        if min(self.index_n_heads, self.index_topk) < 1 \
+        elif min(self.index_n_heads, self.index_topk) < 1 \
                 or self.index_head_dim < self.qk_rope_head_dim:
             raise ValueError(
                 f"the indexer needs index_n_heads, index_topk >= 1 and "
@@ -399,6 +432,9 @@ class TransformerConfig:
         if self.indexed:
             return ("latent attention over an indexer's selection, layer "
                     "by layer (block='standard', attention='mla')")
+        if self.full_latent:
+            return ("latent attention over every position, layer by layer "
+                    "(block='standard', attention='mla', no layer_indexer)")
         return None
 
 
@@ -551,6 +587,27 @@ def init_mla(key: jax.Array, cfg: TransformerConfig) -> dict:
     }
 
 
+def mla_project(p: dict, x: jnp.ndarray, cfg: TransformerConfig,
+                rope: Callable[[jnp.ndarray], jnp.ndarray]) -> tuple:
+    """A latent attention's projections of x (b, t, d), the same in every
+    path that runs one: (``h``, the normed input; ``c_q``, the query's
+    normed bottleneck; ``q_nope`` (b, t, heads, qk_nope_head_dim);
+    ``q_rope`` (b, t, heads, qk_rope_head_dim) after ``rope``; ``c_kv``
+    (b, t, kv_lora_rank), the normed and scaled latent; ``k_rope`` (b, t,
+    1, qk_rope_head_dim), the one rotary key all heads share, after
+    ``rope``). ``rope`` rotates (b, t, h, d) at the block's positions."""
+    b, t, _ = x.shape
+    nope, rank = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    s_q, s_kv = cfg.mla_scales
+    h = rmsnorm(x, p["ln"], cfg.norm_eps)
+    c_q = rmsnorm(h @ p["wq_a"], p["q_norm"], cfg.norm_eps)
+    q = ((c_q @ p["wq_b"]) * s_q).reshape(b, t, cfg.n_heads, -1)
+    q_nope, q_rope = q[..., :nope], rope(q[..., nope:])
+    down = h @ p["wkv_a"]
+    c_kv = rmsnorm(down[..., :rank], p["kv_norm"], cfg.norm_eps) * s_kv
+    return h, c_q, q_nope, q_rope, c_kv, rope(down[:, :, None, rank:])
+
+
 def init_indexer(key: jax.Array, cfg: TransformerConfig) -> dict:
     """A full layer's indexer: ``wq_b`` (the query's bottleneck ->
     ``index_n_heads`` heads of ``index_head_dim``), ``wk`` (hidden -> the
@@ -630,7 +687,10 @@ def _init_new_kind(key: jax.Array, cfg: TransformerConfig) -> dict:
     norm) and ``moe`` (parallel/ep.py ``init_expert_share``). Of the
     layer-by-layer model: ``mla`` (one), ``indexer`` in a full layer,
     ``ln2`` and either the dense FFN's ``w1`` / ``w3`` / ``w2`` or
-    ``moe``."""
+    ``moe``; with ``mtp_layers``, ``mtp`` holds the multi-token-prediction
+    module: ``enorm`` / ``hnorm`` (the embedding's and the hidden state's
+    norms), ``eh_proj`` (2 x hidden -> hidden), ``layer`` (a sparse layer)
+    and ``norm`` (ahead of the shared head)."""
     if cfg.hybrid:
         return _init_hybrid(key, cfg)
     d, dt = cfg.d_model, cfg.dtype
@@ -647,23 +707,33 @@ def _init_new_kind(key: jax.Array, cfg: TransformerConfig) -> dict:
               * d ** -0.5,
               "lm_head": _normal(next(kg), (d, cfg.vocab_size), dt),
               "out_norm": jnp.ones((d,), dt), "layers": []}
+    def sparse(layer):
+        layer["ln2"] = jnp.ones((d,), dt)
+        layer["moe"] = init_expert_share(next(kg), d, cfg.experts, dt)
+        return layer
+
     for i in range(cfg.n_layers):
-        if not cfg.indexed:
+        if cfg.block == "shortcut":
             params["layers"].append({
                 "mla": [init_mla(next(kg), cfg) for _ in range(2)],
                 "ffn": [ffn() for _ in range(2)],
                 "moe": init_expert_share(next(kg), d, cfg.experts, dt)})
             continue
         layer = {"mla": init_mla(next(kg), cfg)}
-        if cfg.layer_indexer[i] == "full":
+        if cfg.indexed and cfg.layer_indexer[i] == "full":
             layer["indexer"] = init_indexer(next(kg), cfg)
         if cfg.layer_ffn[i] == "sparse":
-            layer["ln2"] = jnp.ones((d,), dt)
-            layer["moe"] = init_expert_share(next(kg), d, cfg.experts, dt)
+            sparse(layer)
         else:
             dense = ffn()
             layer.update(ln2=dense.pop("ln"), **dense)
         params["layers"].append(layer)
+    if cfg.mtp_layers:
+        params["mtp"] = {
+            "enorm": jnp.ones((d,), dt), "hnorm": jnp.ones((d,), dt),
+            "eh_proj": _normal(next(kg), (2 * d, d), dt),
+            "layer": sparse({"mla": init_mla(next(kg), cfg)}),
+            "norm": jnp.ones((d,), dt)}
     return params
 
 
@@ -680,11 +750,16 @@ def config_from_hf(hf: dict, max_seq: int, dtype=jnp.bfloat16,
     is the hybrid of state-space mixers and attention without positions
     (:func:`_config_from_granitemoehybrid`); ``attention_method`` "MLA" with
     ``zero_expert_num`` is the shortcut double layer with latent
-    attention. ``experts_held`` = (offset, count) of the real experts that
+    attention; ``model_type`` "glm4_moe_lite" is latent attention over
+    every position with sigmoid routing, a shared expert and a
+    multi-token-prediction module (:func:`_config_from_glm4_moe_lite`).
+    ``experts_held`` = (offset, count) of the real experts that
     this chip holds (default: the key ``experts_held`` of ``hf``, the one
     key that no ``config.json`` has; else all of them)."""
     if hf.get("model_type") == "glm_moe_dsa":
         return _config_from_glm_moe_dsa(hf, max_seq, dtype, experts_held)
+    if hf.get("model_type") == "glm4_moe_lite":
+        return _config_from_glm4_moe_lite(hf, max_seq, dtype, experts_held)
     if hf.get("model_type") == "granitemoehybrid":
         return _config_from_granitemoehybrid(hf, max_seq, dtype,
                                              experts_held)
@@ -721,6 +796,73 @@ def config_from_hf(hf: dict, max_seq: int, dtype=jnp.bfloat16,
             held_offset=int(offset), held_count=int(count)))
 
 
+_GLM_CAN = (("n_group", 1), ("topk_group", 1), ("topk_method", "noaux_tc"),
+            ("scoring_func", "sigmoid"), ("hidden_act", "silu"),
+            ("attention_bias", False), ("tie_word_embeddings", False))
+
+
+def _refuse_by_key(hf: dict, family: str, more: tuple = ()) -> None:
+    """Raise, naming the key, where ``hf`` asks for what the GLM families'
+    routing and block do not implement (a missing key is what is
+    implemented)."""
+    for key, can in _GLM_CAN + more:
+        if hf.get(key, can) != can:
+            raise ValueError(f"{key} {hf[key]!r}: only {can!r} is "
+                             f"implemented for {family}")
+
+
+def _glm_experts(hf: dict, experts_held) -> ExpertShareConfig:
+    """The GLM families' expert share: ``n_routed_experts`` sigmoid
+    outputs, top ``num_experts_per_tok`` by score + bias, renormalised
+    over the picked and scaled, ``n_shared_experts`` shared experts of the
+    routed width each; this chip holds ``experts_held``."""
+    n_real = hf["n_routed_experts"]
+    offset, count = experts_held or hf.get("experts_held", (0, n_real))
+    return ExpertShareConfig(
+        n_outputs=n_real, top_k=hf["num_experts_per_tok"],
+        scale=float(hf["routed_scaling_factor"]),
+        d_ff=hf["moe_intermediate_size"], scoring="sigmoid",
+        renormalise=bool(hf.get("norm_topk_prob", True)),
+        d_shared=hf.get("n_shared_experts", 0) * hf["moe_intermediate_size"],
+        held_offset=int(offset), held_count=int(count))
+
+
+def _config_from_glm4_moe_lite(hf: dict, max_seq: int, dtype,
+                               experts_held) -> TransformerConfig:
+    """``model_type`` "glm4_moe_lite": latent attention over every position
+    in every layer (no indexer, no lora scale), the first
+    ``first_k_dense_replace`` layers a dense SwiGLU of
+    ``intermediate_size``, every later one the expert share of
+    :func:`_glm_experts`, top-level ``rope_theta``, and
+    ``num_nextn_predict_layers`` (0 or 1) multi-token-prediction modules.
+    What cannot run is refused by the name of its key."""
+    n = hf["num_hidden_layers"]
+    _refuse_by_key(hf, "glm4_moe_lite", (("partial_rotary_factor", 1),
+                                         ("rope_scaling", None)))
+    mtp = hf.get("num_nextn_predict_layers", 0)
+    if mtp not in (0, 1):
+        raise ValueError(f"num_nextn_predict_layers {mtp!r}: one "
+                         f"multi-token-prediction module at most")
+    dense = hf.get("first_k_dense_replace", 0)
+    if not 0 <= dense <= n:
+        raise ValueError(f"first_k_dense_replace {dense!r} of "
+                         f"num_hidden_layers {n}")
+    return TransformerConfig(
+        vocab_size=hf["vocab_size"], d_model=hf["hidden_size"],
+        n_heads=hf["num_attention_heads"], n_layers=n,
+        d_ff=hf["intermediate_size"], max_seq=max_seq, dtype=dtype,
+        rope=True, rope_theta=float(hf["rope_theta"]), ffn="swiglu",
+        norm_eps=float(hf.get("rms_norm_eps", 1e-6)),
+        attention="mla", q_lora_rank=hf["q_lora_rank"],
+        kv_lora_rank=hf["kv_lora_rank"],
+        qk_nope_head_dim=hf["qk_nope_head_dim"],
+        qk_rope_head_dim=hf["qk_rope_head_dim"],
+        v_head_dim=hf["v_head_dim"], block="standard",
+        layer_ffn=("dense",) * dense + ("sparse",) * (n - dense),
+        mla_lora_scales=False, mtp_layers=mtp,
+        experts=_glm_experts(hf, experts_held) if dense < n else None)
+
+
 def _config_from_glm_moe_dsa(hf: dict, max_seq: int, dtype,
                              experts_held) -> TransformerConfig:
     """``model_type`` "glm_moe_dsa": the two per-layer lists
@@ -730,14 +872,7 @@ def _config_from_glm_moe_dsa(hf: dict, max_seq: int, dtype,
     no ``mla_scale_*`` key (scale 1). ``n_routed_experts`` is the router's
     width. What cannot run is refused by the name of its key."""
     n = hf["num_hidden_layers"]
-    for key, can in (("n_group", 1), ("topk_group", 1),
-                     ("topk_method", "noaux_tc"),
-                     ("scoring_func", "sigmoid"), ("hidden_act", "silu"),
-                     ("attention_bias", False),
-                     ("tie_word_embeddings", False)):
-        if hf.get(key, can) != can:
-            raise ValueError(f"{key} {hf[key]!r}: only {can!r} is "
-                             f"implemented for glm_moe_dsa")
+    _refuse_by_key(hf, "glm_moe_dsa")
     if hf.get("num_nextn_predict_layers", 0):
         raise ValueError(
             "num_nextn_predict_layers > 0: the multi-token-prediction "
@@ -746,8 +881,6 @@ def _config_from_glm_moe_dsa(hf: dict, max_seq: int, dtype,
         if len(hf.get(key, ())) != n:
             raise ValueError(f"{key} needs one entry a layer "
                              f"(num_hidden_layers {n})")
-    n_real = hf["n_routed_experts"]
-    offset, count = experts_held or hf.get("experts_held", (0, n_real))
     sparse = "sparse" in hf["mlp_layer_types"]
     return TransformerConfig(
         vocab_size=hf["vocab_size"], d_model=hf["hidden_size"],
@@ -765,16 +898,7 @@ def _config_from_glm_moe_dsa(hf: dict, max_seq: int, dtype,
         index_n_heads=hf["index_n_heads"],
         index_head_dim=hf["index_head_dim"], index_topk=hf["index_topk"],
         mla_lora_scales=False,
-        experts=ExpertShareConfig(
-            n_outputs=n_real, top_k=hf["num_experts_per_tok"],
-            scale=float(hf["routed_scaling_factor"]),
-            d_ff=hf["moe_intermediate_size"],
-            scoring="sigmoid",
-            renormalise=bool(hf.get("norm_topk_prob", True)),
-            d_shared=hf.get("n_shared_experts", 0)
-            * hf["moe_intermediate_size"],
-            held_offset=int(offset), held_count=int(count))
-        if sparse else None)
+        experts=_glm_experts(hf, experts_held) if sparse else None)
 
 
 def _config_from_granitemoehybrid(hf: dict, max_seq: int, dtype,
@@ -856,7 +980,13 @@ def transformer_block(layer: dict, x: jnp.ndarray, cfg: TransformerConfig,
     GQA K/V carry cfg.kv_heads heads; ``attn_fn`` receives the narrow K/V
     (the flash kernel consumes them natively, the pure-JAX paths expand).
     MoE layers keep their own expert FF (ffn="swiglu" shapes dense layers
-    only)."""
+    only). Latent attention over every position (``cfg.full_latent``) is
+    :func:`latent_block`, whose aux is its expert layer's counts."""
+    if cfg.full_latent:
+        if tp_axis is not None or ep_axis is not None:
+            raise NotImplementedError(f"{cfg.new_kind} runs without tp "
+                                      f"or ep")
+        return latent_block(layer, x, cfg, attn_fn, positions)
     if cfg.new_kind is not None:
         raise NotImplementedError(
             f"{cfg.new_kind} runs on the serving slot path only "
@@ -914,6 +1044,80 @@ def transformer_block(layer: dict, x: jnp.ndarray, cfg: TransformerConfig,
         else:
             x = x + hh @ layer["w2"]
     return x, aux
+
+
+def latent_attention(p: dict, x: jnp.ndarray, cfg: TransformerConfig,
+                     attn_fn: Optional[AttnFn] = None,
+                     positions: Optional[jnp.ndarray] = None
+                     ) -> jnp.ndarray:
+    """Latent attention over every position of x (b, t, d), causal, under
+    ``mla_attention``: :func:`mla_project`, then each head's key
+    [nope | the shared rotary key] and value expanded from the latent by
+    ``wkv_b``, ``attn_fn`` over q, k, v (one head size for all three, which
+    the flash kernel takes, and the score scale 1 / sqrt of it), and
+    ``wo``. Returns what joins the residual."""
+    b, t, _ = x.shape
+    heads, rank = cfg.n_heads, cfg.kv_lora_rank
+    nope, vd = cfg.qk_nope_head_dim, cfg.v_head_dim
+    if nope + cfg.qk_rope_head_dim != vd:
+        raise ValueError(
+            f"latent attention over every position runs q.k heads of "
+            f"{nope} + {cfg.qk_rope_head_dim} and v heads of {vd}: the "
+            f"attention takes one head size for q, k and v")
+    if positions is None:
+        positions = jnp.arange(t)
+    if attn_fn is None:
+        attn_fn = local_causal_attention
+    with jax.named_scope(SCOPE_MLA_ATTENTION):
+        _h, _c_q, q_nope, q_rope, c_kv, k_rope = mla_project(
+            p, x, cfg, lambda v: apply_rope(v, positions, cfg.rope_theta))
+        kv = jnp.einsum("btr,rhe->bthe", c_kv,
+                        p["wkv_b"].reshape(rank, heads, nope + vd))
+        k = jnp.concatenate(
+            [kv[..., :nope],
+             jnp.broadcast_to(k_rope, (b, t, heads, k_rope.shape[-1]))],
+            axis=-1)
+        out = attn_fn(jnp.concatenate([q_nope, q_rope], axis=-1), k,
+                      kv[..., nope:])
+        return out.reshape(b, t, heads * vd) @ p["wo"]
+
+
+def latent_block(layer: dict, x: jnp.ndarray, cfg: TransformerConfig,
+                 attn_fn: Optional[AttnFn] = None,
+                 positions: Optional[jnp.ndarray] = None
+                 ) -> tuple[jnp.ndarray, dict]:
+    """One layer of latent attention over every position
+    (:func:`latent_attention`) and, after ``ln2``, a dense SwiGLU or the
+    expert share with its shared expert (parallel/ep.py ``dropless_moe``,
+    its train-step call): what the full forward and the train step both
+    run. Returns (x, counts): {} for a dense layer, else the layer's
+    ``held`` assignments and ``touched`` experts (scalars) and ``load``,
+    the assignments of each router output."""
+    b, t, d = x.shape
+    x = x + latent_attention(layer["mla"], x, cfg, attn_fn, positions)
+    h = rmsnorm(x, layer["ln2"], cfg.norm_eps)
+    if "moe" not in layer:
+        with jax.named_scope(SCOPE_DENSE_FFN):
+            return x + (jax.nn.silu(h @ layer["w1"])
+                        * (h @ layer["w3"])) @ layer["w2"], {}
+    m, counts = dropless_moe(h.reshape(b * t, d), layer["moe"], cfg.experts,
+                             train=True)
+    return x + m.reshape(b, t, d), {"held": counts["held"].sum(),
+                                    "touched": counts["touched"],
+                                    "load": counts["load"]}
+
+
+def route_aux(counts: list) -> dict:
+    """The aux of a stack of :func:`latent_block` layers (no load-balance
+    loss, nothing dropped): ``route_held`` / ``route_touched`` summed over
+    its expert layers, ``route_load`` (expert layers, router outputs)."""
+    zero = jnp.asarray(0, jnp.int32)
+    return {"aux_loss": jnp.asarray(0.0, jnp.float32),
+            "dispatch_fraction": jnp.asarray(1.0, jnp.float32),
+            "route_held": sum((c["held"] for c in counts), zero),
+            "route_touched": sum((c["touched"] for c in counts), zero),
+            "route_load": jnp.stack([c["load"] for c in counts])
+            if counts else jnp.zeros((0, 1), jnp.int32)}
 
 
 def _merge_aux(total: dict, aux: dict) -> dict:
@@ -994,12 +1198,17 @@ def transformer_hidden_with_aux(params: dict, tokens: jnp.ndarray,
         block = jax.checkpoint(block)
 
     aux_total: dict = {}
+    routed = []
     for layer in params["layers"]:
         x, aux = block(layer, x)
-        aux_total = _merge_aux(aux_total, aux)
+        if cfg.full_latent:
+            routed += [aux] if aux else []
+        else:
+            aux_total = _merge_aux(aux_total, aux)
 
     return (rmsnorm(x, params["out_norm"], cfg.norm_eps),
-            _finalize_aux(aux_total))
+            route_aux(routed) if cfg.full_latent
+            else _finalize_aux(aux_total))
 
 
 def transformer_apply(params: dict, tokens: jnp.ndarray,

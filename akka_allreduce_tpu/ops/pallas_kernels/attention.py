@@ -437,12 +437,16 @@ def flash_causal_attention(q, k, v, block_q=128, block_k=128,
                            window)
 
 
-def default_flash_block(dtype) -> int:
+def default_flash_block(dtype, head_dim: int = 128) -> int:
     """The swept-optimal flash block per dtype: bf16 tiles fit the 16M
     scoped VMEM at 1024 (the T=2048 sweep optimum: 256 -> 19.8 ms,
     512 -> 10.8 ms, 1024 -> 9.0 ms fwd+bwd); f32 tiles are 2x and OOM
-    there, so full precision halves to 512."""
-    return 1024 if dtype == jnp.bfloat16 else 512
+    there, so full precision halves to 512. Past a head size of 128 the
+    tiles grow with it and the block shrinks in step: at 256 in bf16 the
+    dk/dv kernel's tiles of 1024 need 16.31M of the 16M (the v5e's
+    compiler), 512 fit."""
+    return (1024 if dtype == jnp.bfloat16 else 512) * 128 // max(head_dim,
+                                                                  128)
 
 
 # -- paged decode attention (the serving engine's KV-pool read path) ----

@@ -461,7 +461,8 @@ def held_experts_path(h: jnp.ndarray, params: dict
 def held_experts_ffn(h: jnp.ndarray, pick: jnp.ndarray,
                      weight: jnp.ndarray, params: dict,
                      cfg: ExpertShareConfig,
-                     counted: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+                     counted: Optional[jnp.ndarray] = None,
+                     train: bool = False) -> jnp.ndarray:
     """sum over a token's picks that fall on HELD experts of weight x
     expert(h): (N, D) float32. Where :func:`held_experts_path` engages the
     streaming kernel, every touched expert's matrices are read once and
@@ -486,10 +487,16 @@ def held_experts_ffn(h: jnp.ndarray, pick: jnp.ndarray,
     grouped matmuls 5.36, the rows' way in 1.76, convert and mask 0.77,
     back as float32 through a (N, k, D) relayout 3.54 - and 5.28 over the
     prefix of 11,520 (3.88, 0.10, 0.13, 0.54); GLM-5.2's chunk 9.35 ->
-    4.27, LongCat's bucket of 512 4.33 -> 2.42."""
+    4.27, LongCat's bucket of 512 4.33 -> 2.42.
+
+    ``train``: the train step's call, differentiated. It runs the whole
+    buffer alone and no kernel: under autodiff a branch between buffers
+    keeps what every branch needs for the backward pass (8,192 tokens x
+    top-4 over 8 of 64 held at hidden 2,048, compiled for the v5e: 3.2 GB
+    of temporaries a layer with the branch, 1.0 GB without)."""
     n, k = pick.shape
     local, held = _on_held(pick, cfg)
-    path = held_experts_path(h, params)
+    path = None if train else held_experts_path(h, params)
     detail = f"h={n}x{h.shape[1]} dtype={h.dtype} held={cfg.held_count}"
     if path is not None:
         interpret, f_block = path
@@ -501,6 +508,8 @@ def held_experts_ffn(h: jnp.ndarray, pick: jnp.ndarray,
             h, jnp.where(held, local, cfg.held_count), weight,
             params["we1"], params["we3"], params["we2"], f_block, interpret)
     prefixes = _row_prefixes(n * k, cfg)
+    if train:
+        prefixes = prefixes[-1:]
     say(f"experts[held]: reference:ragged_dot {detail} "
         f"rows={','.join(map(str, prefixes))}")
     if counted is not None and len(prefixes) > 1:
@@ -518,6 +527,13 @@ def held_experts_ffn(h: jnp.ndarray, pick: jnp.ndarray,
     def whole(order, sizes):
         m = prefixes[-1]
         rows = jnp.pad(h[order // k], ((0, m - n * k), (0, 0)))
+        if train:
+            # the grouped matmul's transpose leaves the rows past the last
+            # group unwritten too (on the TPU: garbage, and the first
+            # gradient of a chip run read 1e7 x the reference's), so no
+            # gradient goes back through them
+            rows = jnp.where((jnp.arange(m) < sizes.sum())[:, None], rows,
+                             jnp.zeros((), rows.dtype))
         out = experts(rows, sizes).astype(jnp.float32)
         # rows past the last group belong to no expert; the grouped matmul
         # leaves them unwritten
@@ -558,8 +574,8 @@ def shared_expert_ffn(h: jnp.ndarray, params: dict) -> jnp.ndarray:
 
 
 def dropless_moe(h: jnp.ndarray, params: dict, cfg: ExpertShareConfig,
-                 counted: Optional[jnp.ndarray] = None
-                 ) -> tuple[jnp.ndarray, dict]:
+                 counted: Optional[jnp.ndarray] = None,
+                 train: bool = False) -> tuple[jnp.ndarray, dict]:
     """This chip's share of the expert layer for tokens h (N, D): the held
     experts' part plus the identity part, (sum of the weights of the
     picked identity experts) x h, which the token's own chip computes in a
@@ -573,12 +589,15 @@ def dropless_moe(h: jnp.ndarray, params: dict, cfg: ExpertShareConfig,
     rows it multiplied: N x the experts it streamed, which are the
     ``touched`` ones. A
     token that is not ``counted`` (N,) bool (default: all are; padding and
-    idle lanes are not) counts nowhere."""
+    idle lanes are not) counts nowhere. ``train`` is the train step's call
+    (:func:`held_experts_ffn`); its counts add ``load``, the assignments
+    each of the ``n_outputs`` took over all the tokens, which
+    :func:`selection_bias_update` reads."""
     with jax.named_scope(SCOPE_MOE_ROUTER):
         pick, weight = dropless_route(h, params, cfg)
     on_identity = pick >= cfg.n_real
     with jax.named_scope(SCOPE_MOE_EXPERTS):
-        y = held_experts_ffn(h, pick, weight, params, cfg, counted)
+        y = held_experts_ffn(h, pick, weight, params, cfg, counted, train)
         y = y + jnp.where(on_identity, weight, 0.0).sum(
             -1, keepdims=True) * h.astype(jnp.float32)
     if cfg.d_shared:
@@ -591,7 +610,9 @@ def dropless_moe(h: jnp.ndarray, params: dict, cfg: ExpertShareConfig,
         jnp.where(on_held, local, cfg.held_count)].add(1)
     touched = (rows[:cfg.held_count] > 0).sum().astype(jnp.int32)
     prefixes = _row_prefixes(pick.size, cfg)
-    if held_experts_path(h, params) is not None:
+    if train:
+        carried = prefixes[-1]
+    elif held_experts_path(h, params) is not None:
         carried = h.shape[0] * touched
     elif len(prefixes) == 1:
         carried = prefixes[0]
@@ -601,4 +622,26 @@ def dropless_moe(h: jnp.ndarray, params: dict, cfg: ExpertShareConfig,
     counts = {"held": on_held.sum(-1).astype(jnp.int32),
               "identity": on_identity.sum(-1).astype(jnp.int32),
               "touched": touched, "carried": carried}
+    if train:
+        counts["load"] = jnp.zeros((cfg.n_outputs,), jnp.int32).at[
+            pick].add(1)
     return y.astype(h.dtype), counts
+
+
+# The step of each router output's selection bias towards balance after
+# every training step (DeepSeek-V3, arXiv:2412.19437 section 2.1.2: 0.001).
+BIAS_UPDATE_RATE = 1e-3
+
+
+def selection_bias_update(bias: jnp.ndarray,
+                          load: jnp.ndarray) -> jnp.ndarray:
+    """The auxiliary-loss-free balance of ``noaux_tc`` routing (DeepSeek-V3,
+    arXiv:2412.19437 section 2.1.2), between steps: each router output's
+    selection bias moves by ``BIAS_UPDATE_RATE`` towards the mean load, up
+    where its ``load`` (assignments over the step's tokens, every output's,
+    so known to every chip that routes) is under the mean, down where it is
+    over.
+    Not a gradient: the bias enters the choice and no product."""
+    load = load.astype(jnp.float32)
+    return bias + BIAS_UPDATE_RATE * jnp.sign(
+        load.mean(-1, keepdims=True) - load)

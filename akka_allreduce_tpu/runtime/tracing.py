@@ -446,11 +446,18 @@ SCOPE_MOE_SHARED = "moe_shared"
 SCOPE_SSM_MIXER = "ssm_mixer"
 SCOPE_SSM_SCAN = "ssm_scan"
 SCOPE_SSM_STEP = "ssm_step"
+SCOPE_MTP = "mtp"
 
 _SYNC = "gradient sync (parallel/dp.py, ops/collectives.py)"
 _STEP = "train step (models/train.py)"
 _EXPERTS = "expert layer (parallel/ep.py)"
 SCOPES = {
+    # the multi-token-prediction module of the train step (models/mtp.py),
+    # an OUTER scope listed first: a reader that takes the first name it
+    # finds in an op's path files everything the module runs under it, its
+    # latent attention, expert layer, head and loss included
+    SCOPE_MTP: ("multi-token prediction (models/mtp.py)",
+                "gfl_mtp_device_pct"),
     SCOPE_SYNC_PACK: (_SYNC, "sync_device_pct, sync_staging_ms"),
     SCOPE_SYNC_REDUCE: (_SYNC, "sync_device_pct"),
     SCOPE_SYNC_UNPACK: (_SYNC, "sync_device_pct, sync_staging_ms"),
@@ -458,17 +465,19 @@ SCOPES = {
     SCOPE_OPTIMIZER: (_STEP, "-"),
     SCOPE_ATTENTION: ("attention kernels (ops/pallas_kernels/attention.py)",
                       "-"),
-    SCOPE_MLA_ATTENTION: (_LATENT, "lcr_mla_device_pct, glm_mla_device_pct"),
+    SCOPE_MLA_ATTENTION: (_LATENT, "lcr_mla_device_pct, glm_mla_device_pct, "
+                          "gfl_mla_device_pct"),
     SCOPE_DENSE_FFN: ("engine, decode step (serving/engine.py)", "-"),
     SCOPE_MOE_ROUTER: (_EXPERTS,
                        "lcr_experts_device_pct, glm_experts_device_pct, "
-                       "grn_experts_device_pct"),
+                       "grn_experts_device_pct, gfl_experts_device_pct"),
     SCOPE_MOE_EXPERTS: (_EXPERTS,
                         "lcr_experts_device_pct, glm_experts_device_pct, "
-                        "grn_experts_device_pct"),
+                        "grn_experts_device_pct, gfl_experts_device_pct"),
     SCOPE_SPARSE_INDEXER: (_INDEXER, "glm_indexer_device_pct"),
     SCOPE_MOE_SHARED: (_EXPERTS,
-                       "glm_experts_device_pct, grn_experts_device_pct"),
+                       "glm_experts_device_pct, grn_experts_device_pct, "
+                       "gfl_experts_device_pct"),
     # the two inner scopes ahead of the mixer's: a reader that takes the
     # first name it finds in an op's path (benchmark/program_trace.py
     # ``scope_of``) then files an op under the innermost

@@ -494,3 +494,69 @@ def test_the_decode_programs_stream_their_held_experts(which, f_block,
     moe = next(lyr["moe"] for lyr in params["layers"] if "moe" in lyr)
     h = jax.ShapeDtypeStruct((lanes, cfg.d_model), cfg.dtype)
     assert ep.held_experts_path(h, moe) == (False, f_block)
+
+
+# GLM-4.7-Flash's widths (config.json), one dense and one sparse layer of
+# the cell's five, and its multi-token-prediction module
+FLASH = dict(
+    model_type="glm4_moe_lite", vocab_size=19360, hidden_size=2048,
+    intermediate_size=10240, moe_intermediate_size=1536, num_hidden_layers=2,
+    first_k_dense_replace=1, num_attention_heads=20, q_lora_rank=768,
+    kv_lora_rank=512, qk_nope_head_dim=192, qk_rope_head_dim=64,
+    v_head_dim=256, n_routed_experts=64, n_shared_experts=1,
+    num_experts_per_tok=4, routed_scaling_factor=1.8, rms_norm_eps=1e-5,
+    rope_theta=1e6, num_nextn_predict_layers=1, experts_held=[0, 8])
+FLASH_SEQ = 2048
+
+
+def test_the_latent_train_step_compiles_with_the_kernel_at_head_size_256(
+        one_chip, monkeypatch):
+    """The train step of latent attention over every position at published
+    widths, bf16 compute over float32 masters: the flash kernel runs
+    forward and backward at head size 256 with the default block (512: at
+    1,024 its dk/dv tiles exceed the 16M of scoped VMEM) and its custom
+    calls sit under ``mla_attention`` (the module's under ``mtp`` too);
+    the experts go through the grouped matmul, never the decode step's
+    streaming kernel."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from akka_allreduce_tpu.models.train import (TrainConfig, make_optimizer,
+                                                 make_train_step)
+    from akka_allreduce_tpu.models.transformer import init_transformer
+    from akka_allreduce_tpu.ops.pallas_kernels.attention import (
+        default_flash_block)
+    from akka_allreduce_tpu.parallel.mesh import MeshSpec, make_device_mesh
+    assert default_flash_block(jnp.bfloat16, 256) == 512
+    assert default_flash_block(jnp.bfloat16, 128) == 1024
+    cfg = config_from_hf(FLASH, FLASH_SEQ, jnp.float32)
+    tc = TrainConfig(model=cfg, compute_dtype="bf16", attn_impl="flash",
+                     bucket_elems=1 << 22)
+    mesh = make_device_mesh(MeshSpec(dp=1), devices=list(one_chip.device_set))
+    rep = NamedSharding(mesh, P())
+    params = jax.eval_shape(lambda: init_transformer(jax.random.key(0), cfg))
+    opt = make_optimizer(tc)
+    state = jax.eval_shape(opt.init, params)
+    with monkeypatch.context() as m:
+        m.setattr(jax, "default_backend", lambda: "tpu")
+        step = make_train_step(tc, mesh, opt, donate=True)
+        lowered = step.lower(_on(rep, params), _on(rep, state),
+                             jax.ShapeDtypeStruct((1, FLASH_SEQ), jnp.int32,
+                                                  sharding=rep))
+    hlo = _lower_off_cache(lowered).as_text()
+    # the Pallas kernels' custom calls, their op_name's path with JAX's
+    # transform wrappers (jvp(...), transpose(...)) taken off
+    kernels = ["/" + re.sub(r"[\w.-]+\(|\)", "", k) + "/"
+               for k in re.findall(r'custom_call_target="tpu_custom_call".*?'
+                                   r'op_name="([^"]*pallas_call[^"]*)"', hlo)]
+    # forward, dq and dk/dv of each of the three attentions
+    assert len(kernels) >= 9
+    assert all("/mla_attention/" in k for k in kernels), kernels
+    assert sum("/mtp/" in k for k in kernels) >= 3
+    # the compiler names the kernel's calls after the scope, and the
+    # grouped matmuls' ragged-dot-*: benchmark/metrics/gfl_attn_roofline.json
+    # tells the two apart by that name
+    names = re.findall(r'^\s*%(\S+) = .*custom_call_target="tpu_custom_call"'
+                       r'.*op_name="[^"]*pallas_call', hlo, re.M)
+    assert len(names) == len(kernels)
+    assert all("mla_attention" in n for n in names), names
+    assert "ragged-dot-none" in hlo
+    assert "held_experts_stream" not in hlo

@@ -753,8 +753,9 @@ def test_train_step_scopes_are_in_the_hlo(dp, masked):
     # layout "leaves"): f32 leaves on the f32 wire with a mean factor of
     # exactly 1.0 leave nothing to pack or unpack
     empty = () if masked else (T.SCOPE_SYNC_PACK, T.SCOPE_SYNC_UNPACK)
-    for sc in set(T.SCOPES) - T.SERVING_SCOPES:
+    for sc in set(T.SCOPES) - T.SERVING_SCOPES - {T.SCOPE_MTP}:
         assert any(under(p, sc) for _l, p in named) == (sc not in empty), sc
+    assert not any(under(p, T.SCOPE_MTP) for _l, p in named)
     matrix = rf"f32\[({_TOY_BUCKETS},1024|{_TOY_BUCKETS * 1024})\]"
     # every collective that carries gradients is the sync's wire; the
     # scalar sums of the loss and its metrics are not the sync
@@ -788,6 +789,52 @@ def test_train_step_scopes_are_in_the_hlo(dp, masked):
         assert under(path, T.SCOPE_SYNC_PACK) \
             or under(path, T.SCOPE_SYNC_UNPACK) \
             or under(path, T.SCOPE_SYNC_REDUCE), line[:200]
+
+
+def test_latent_train_step_scopes_are_in_the_hlo():
+    """The train step of latent attention over every position with an
+    expert share and a multi-token-prediction module: its attention, the
+    expert layer's three parts, the heads' loss and the module each carry
+    their scope (the attention's under ``mla_attention`` alone, so that a
+    reader filing an op under the first scope it finds does not take it
+    for ``attention``), the module's ops under ``mtp``; the step returns
+    its route counts with the loss."""
+    from akka_allreduce_tpu.models.train import (TrainConfig,
+                                                 make_train_state,
+                                                 make_train_step)
+    from akka_allreduce_tpu.models.transformer import config_from_hf
+    from akka_allreduce_tpu.parallel.mesh import MeshSpec, make_device_mesh
+    hf = dict(model_type="glm4_moe_lite", vocab_size=64, hidden_size=32,
+              intermediate_size=64, moe_intermediate_size=16,
+              num_hidden_layers=2, first_k_dense_replace=1,
+              num_attention_heads=2, q_lora_rank=16, kv_lora_rank=8,
+              qk_nope_head_dim=12, qk_rope_head_dim=4, v_head_dim=16,
+              n_routed_experts=8, n_shared_experts=1, num_experts_per_tok=2,
+              routed_scaling_factor=1.8, rms_norm_eps=1e-5, rope_theta=1e6,
+              num_nextn_predict_layers=1, experts_held=[0, 4])
+    cfg = TrainConfig(model=config_from_hf(hf, 16, jnp.float32),
+                      bucket_elems=1024)
+    mesh = make_device_mesh(MeshSpec(dp=1), devices=jax.devices()[:1])
+    params, opt_state, opt = make_train_state(jax.random.key(0), cfg, mesh)
+    step = make_train_step(cfg, mesh, opt)
+    tokens = jnp.zeros((2, 16), jnp.int32)
+    with _no_compile_cache():
+        hlo = step.lower(params, opt_state, tokens).compile().as_text()
+    named = _op_names(hlo)
+    for sc in (T.SCOPE_MLA_ATTENTION, T.SCOPE_MOE_ROUTER,
+               T.SCOPE_MOE_EXPERTS, T.SCOPE_MOE_SHARED, T.SCOPE_HEAD_LOSS,
+               T.SCOPE_MTP, T.SCOPE_OPTIMIZER, T.SCOPE_DENSE_FFN):
+        assert any(f"/{sc}/" in p for _l, p in named), sc
+    assert not any(f"/{T.SCOPE_ATTENTION}/" in p for _l, p in named)
+    # the module's attention and experts nest inside it
+    assert any(f"/{T.SCOPE_MTP}/" in p and f"/{T.SCOPE_MLA_ATTENTION}/" in p
+               for _l, p in named)
+    assert any(f"/{T.SCOPE_MTP}/" in p and f"/{T.SCOPE_MOE_EXPERTS}/" in p
+               for _l, p in named)
+    _p, _o, metrics = step(params, opt_state, tokens)
+    assert {"loss", "route_held", "route_touched", "mtp_loss"} <= set(
+        metrics) and "route_load" not in metrics
+    assert int(metrics["route_held"]) > 0
 
 
 def test_masked_sync_counts_ride_the_reduce_scope():
@@ -857,10 +904,13 @@ def test_names_the_benchmark_reads_are_pinned():
         assert readers.get(name), f"no metric file matches {name}"
     assert set(T.SCOPES) - T.SERVING_SCOPES == {
         "grad_sync/pack", "grad_sync/reduce", "grad_sync/unpack",
-        "lm_head_loss", "optimizer", "attention"}, (
+        "lm_head_loss", "optimizer", "attention", "mtp"}, (
         "benchmark/program_trace.py (sync_device_pct, sync_staging_ms, "
-        "head_loss_device_pct) reads these scope names; a rename goes "
-        "with a `benchmark` PR")
+        "head_loss_device_pct) and benchmark/metrics/gfl_mtp_device_pct."
+        "json read these scope names; a rename goes with a `benchmark` PR")
+    # the one outer scope, ahead of every inner one: a reader that takes
+    # the first name it finds files the module's ops under it
+    assert next(iter(T.SCOPES)) == T.SCOPE_MTP
     assert T.SERVING_SCOPES == {
         "mla_attention", "dense_ffn", "moe_router", "moe_experts",
         "sparse_indexer", "moe_shared", "ssm_mixer", "ssm_scan",
