@@ -59,6 +59,7 @@ from akka_allreduce_tpu.parallel.pp import (
 from akka_allreduce_tpu.ops.pallas_kernels.attention import (
     default_flash_block,
     flash_causal_attention,
+    flash_tile_counts,
     pick_flash_block,
 )
 from akka_allreduce_tpu.ops.pallas_kernels.dispatch import (say_attention,
@@ -560,8 +561,11 @@ def _local_attention_impl(cfg: TrainConfig):
             # says so (the reference is several times slower on the chip)
             blk = pick_flash_block(q.shape[1], want)
             if blk is not None:
+                live, grid, masked = flash_tile_counts(
+                    q.shape[1], k.shape[1], blk, blk, window=window)
                 say_attention("local", "flash", q, interpret=interpret,
-                              block=blk, window=window)
+                              block=blk, window=window,
+                              tiles=f"{live}/{grid}", masked=masked)
                 return flash_causal_attention(q, k, v, block_q=blk,
                                               block_k=blk,
                                               interpret=interpret,

@@ -22,13 +22,25 @@ statistics and accumulators are f32 (the flash rule: low-precision MXU
 matmuls, full-precision running stats); log-sum-exp is saved as (B, H, T, 1)
 f32 for the backward pass.
 
-Grid iteration relies on TPU Pallas executing the grid sequentially with
-the LAST dimension minormost: the key-block axis is innermost, so VMEM
-scratch carries (m, l, acc) across the key loop of one query block.
+The grid holds the tiles the mask leaves, and no others. Each (query
+block, key block) tile is classified at trace time from the static causal
+geometry (:func:`_tile_kind`): DEAD (every pair masked), WHOLE (every pair
+visible) or MASKED (it straddles the diagonal or a window's edge). A
+kernel's innermost grid axis walks a plan of its live tiles
+(:func:`_tile_plan`), handed over as a scalar-prefetch operand that the
+block index maps read, so a dead tile has no grid step: no copy into VMEM
+and no fixed cost. A whole tile runs without a mask; only a straddling one
+builds and applies it. Grid iteration relies on TPU Pallas executing the
+grid sequentially with the LAST dimension minormost: one row's tiles (a
+query block's key blocks, or for dk/dv a key block's query blocks) are
+consecutive in the plan, in the order a full grid would visit them, so
+VMEM scratch carries (m, l, acc) across the row, and o, lse, dq, dk and dv
+are what the full grid computes, bit for bit.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
@@ -56,24 +68,134 @@ def _causal_mask(iq, ik, blk_q, blk_k, q_off=0, k_off=0, window=None):
     return mask
 
 
-def _tile_live_local(iq, ik, blk_q, blk_k, causal, window=None,
-                     q_off=0, k_off=0):
-    """Tile has at least one potentially-unmasked score: not entirely in
-    the queries' future (causal) and not entirely fallen out of the
-    sliding window. Skipped tiles cost nothing (~half the grid for plain
-    causal; all but ~window/blk_k tiles per query row under a window).
-    Offsets shift into the same frame _causal_mask uses (rectangular
-    attention: Tq != Tk with the query block starting at q_off)."""
+DEAD, WHOLE, MASKED = 0, 1, 2
+
+
+def _tile_kind(iq, ik, blk_q, blk_k, causal, window=None, q_off=0,
+               k_off=0) -> int:
+    """What tile (iq, ik) needs, from the static geometry (plain ints):
+    DEAD where :func:`_causal_mask` has no true entry, WHOLE where it has
+    no false one, MASKED otherwise. A tile's query-minus-key distances
+    are every integer in [q0 - k1, q1 - k0], and a pair is visible where
+    its distance lies in [0, window) (window None: [0, inf)). Offsets
+    shift into the frame _causal_mask uses (rectangular attention: Tq !=
+    Tk with the query block starting at q_off).
+
+    A DEAD tile is left out of the kernels' grids, so it costs nothing:
+    with no grid step there is no block to copy into VMEM and no step's
+    fixed cost (plain causal at 16 blocks a side: 120 of 256 tiles; under
+    a window all but ~window / blk_k + 1 tiles of a query block's row). A
+    WHOLE tile skips building and applying the mask."""
     if not causal:
-        return True
-    live = ik * blk_k + k_off <= iq * blk_q + q_off + blk_q - 1
-    if window is not None:
-        # newest key in the tile must still be inside the OLDEST query's
-        # window: max(k_pos) > min(q_pos) - window. & not `and`: the grid
-        # indices are traced scalars inside the kernel.
-        live = live & (ik * blk_k + k_off + blk_k - 1
-                       > iq * blk_q + q_off - window)
-    return live
+        return WHOLE
+    q0, k0 = q_off + iq * blk_q, k_off + ik * blk_k
+    near, far = q0 - (k0 + blk_k - 1), q0 + blk_q - 1 - k0
+    if far < 0 or (window is not None and near >= window):
+        return DEAD
+    if near >= 0 and (window is None or far < window):
+        return WHOLE
+    return MASKED
+
+
+# A plan entry's four flags, in its low bits
+_COMPUTE, _MASK, _FIRST, _LAST = 1, 2, 4, 8
+_FLAG_BITS = 4
+
+
+def _bits(n: int) -> int:
+    """Bits that hold every index below ``n``."""
+    return (n - 1).bit_length()
+
+
+@dataclasses.dataclass(frozen=True)
+class _TilePlan:
+    """The steps of a kernel's innermost grid axis, one int32 word a step:
+    bit fields, high to low, of the tile's row, the query head in the GQA
+    group (dk/dv; 0 elsewhere), its column, and four flags. A row is what
+    a kernel accumulates into its scratch: a query block (forward, dq;
+    column = key block) or a key block (dk/dv; column = query block).
+    Fields and not ``row * cols + col``: the index maps and the kernel
+    decode a word with shifts and masks alone."""
+
+    words: "tuple[int, ...]"
+    heads: int
+    cols: int
+
+    def at(self, word):
+        """(row, head, col) of a plan entry (traced, in a kernel or an
+        index map)."""
+        hb, cb = _bits(self.heads), _bits(self.cols)
+        x = word >> _FLAG_BITS
+        return x >> (hb + cb), (x >> cb) & ((1 << hb) - 1), \
+            x & ((1 << cb) - 1)
+
+    def count(self, flag: int) -> int:
+        return sum(bool(w & flag) for w in self.words)
+
+
+def _tile_plan(rows: int, heads: int, cols: int, kind) -> _TilePlan:
+    """For each row in order, its live (head, column) pairs ascending
+    (``kind(row, head, col)`` not DEAD), flagged _COMPUTE, with _MASK
+    where MASKED, _FIRST on the row's first entry (scratch init) and
+    _LAST on its last (emit): the order a full grid visits them, less
+    the dead. A row with no live tile keeps one entry at (0, 0) without
+    _COMPUTE, so its output is still written."""
+    hb, cb = _bits(heads), _bits(cols)
+    if _bits(rows) + hb + cb + _FLAG_BITS > 31:
+        raise ValueError(f"a tile plan of {rows} x {heads} x {cols} "
+                         "does not fit an int32 word")
+    words = []
+    for r in range(rows):
+        live = [(hd, c, k) for hd in range(heads) for c in range(cols)
+                if (k := kind(r, hd, c)) != DEAD] or [(0, 0, DEAD)]
+        for i, (hd, c, k) in enumerate(live):
+            flags = ((_COMPUTE if k != DEAD else 0)
+                     | (_MASK if k == MASKED else 0)
+                     | (_FIRST if i == 0 else 0)
+                     | (_LAST if i == len(live) - 1 else 0))
+            words.append((((r << hb | hd) << cb | c) << _FLAG_BITS)
+                         | flags)
+    return _TilePlan(tuple(words), heads, cols)
+
+
+def _query_plan(nq, nk, blk_q, blk_k, causal, window, q_off, k_off):
+    """The forward's and dq's plan: a row a query block, its live key
+    blocks."""
+    return _tile_plan(nq, 1, nk, lambda iq, _, ik: _tile_kind(
+        iq, ik, blk_q, blk_k, causal, window, q_off, k_off))
+
+
+def _key_plan(nq, nk, g, blk_q, blk_k, causal, window, q_off, k_off):
+    """dk/dv's plan: a row a key block, its live (query head in the GQA
+    group, query block) pairs, so one scratch sums the whole group."""
+    return _tile_plan(nk, g, nq, lambda ik, _, iq: _tile_kind(
+        iq, ik, blk_q, blk_k, causal, window, q_off, k_off))
+
+
+def _has(word, flag):
+    return (word & flag) != 0
+
+
+def _run_tile(word, plan: _TilePlan, tile, mask):
+    """``tile(None)`` on a whole tile's step, ``tile(mask())`` on a
+    straddling one's, nothing on a dead row's placeholder. A branch the
+    static plan never takes is not traced."""
+    if any((w & (_COMPUTE | _MASK)) == _COMPUTE for w in plan.words):
+        pl.when((word & (_COMPUTE | _MASK)) == _COMPUTE)(lambda: tile(None))
+    if plan.count(_MASK):
+        pl.when(_has(word, _MASK))(lambda: tile(mask()))
+
+
+def flash_tile_counts(tq: int, tk: int, block_q: int, block_k: int,
+                      causal: bool = True, window=None
+                      ) -> "tuple[int, int, int]":
+    """(live tiles, full grid, masked tiles) a head of
+    :func:`flash_attention`'s forward runs at these static shapes: what
+    the training dispatch line reports (``tiles=live/grid masked=n``)."""
+    blk_q, blk_k = _block_sizes(tq, tk, block_q, block_k)
+    nq, nk = tq // blk_q, tk // blk_k
+    plan = _query_plan(nq, nk, blk_q, blk_k, causal, window, 0, 0)
+    return plan.count(_COMPUTE), nq * nk, plan.count(_MASK)
 
 
 def _softmax_tile(q, k, v, m_prev, l_prev, acc_prev, mask, scale,
@@ -116,29 +238,20 @@ def _bwd_tile(q, k, v, do, lse, delta, mask, scale):
     return p, p * (dp - delta) * scale
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                *, scale, blk_q, blk_k, causal, window, q_off=0,
-                k_off=0):
-    iq, ik = pl.program_id(2), pl.program_id(3)
-    nk = pl.num_programs(3)
+def _fwd_kernel(plan_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr,
+                l_scr, acc_scr, *, scale, blk_q, blk_k, window, plan,
+                q_off=0, k_off=0):
+    # one step a live tile of this query block's row of the plan
+    word = plan_ref[pl.program_id(2)]
+    iq, _, ik = plan.at(word)
 
-    @pl.when(ik == 0)
+    @pl.when(_has(word, _FIRST))
     def _init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    # Causal skip: key block entirely in the queries' future — every score
-    # masked, nothing to accumulate (same early-out as the ring/blockwise
-    # paths; ~half the inner iterations vanish).
-    live = _tile_live_local(iq, ik, blk_q, blk_k, causal, window,
-                            q_off, k_off)
-
-    @pl.when(live)
-    def _step():
-        mask = _causal_mask(iq, ik, blk_q, blk_k, q_off, k_off,
-                            window=window) \
-            if causal else None
+    def _step(mask):
         m_new, l_new, acc_new = _softmax_tile(
             q_ref[0, 0, :, :], k_ref[0, 0, :, :], v_ref[0, 0, :, :],
             m_scr[:, 0:1], l_scr[:, 0:1], acc_scr[:], mask, scale)
@@ -146,7 +259,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
 
-    @pl.when(ik == nk - 1)
+    _run_tile(word, plan, _step, lambda: _causal_mask(
+        iq, ik, blk_q, blk_k, q_off, k_off, window=window))
+
+    @pl.when(_has(word, _LAST))
     def _emit():
         m = m_scr[:, 0:1]
         l = l_scr[:, 0:1]
@@ -156,25 +272,18 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         lse_ref[0, 0, :, :] = m + jnp.log(l)
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               dq_scr, *, scale, blk_q, blk_k, causal, window,
+def _dq_kernel(plan_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+               dq_ref, dq_scr, *, scale, blk_q, blk_k, window, plan,
                q_off=0, k_off=0):
-    iq, ik = pl.program_id(2), pl.program_id(3)
-    nk = pl.num_programs(3)
+    word = plan_ref[pl.program_id(2)]
+    iq, _, ik = plan.at(word)
 
-    @pl.when(ik == 0)
+    @pl.when(_has(word, _FIRST))
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    live = _tile_live_local(iq, ik, blk_q, blk_k, causal, window,
-                            q_off, k_off)
-
-    @pl.when(live)
-    def _step():
+    def _step(mask):
         k = k_ref[0, 0, :, :]
-        mask = _causal_mask(iq, ik, blk_q, blk_k, q_off, k_off,
-                            window=window) \
-            if causal else None
         _, ds = _bwd_tile(q_ref[0, 0, :, :], k, v_ref[0, 0, :, :],
                           do_ref[0, 0, :, :], lse_ref[0, 0, :, :],
                           delta_ref[0, 0, :, :], mask, scale)
@@ -182,40 +291,32 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(ik == nk - 1)
+    _run_tile(word, plan, _step, lambda: _causal_mask(
+        iq, ik, blk_q, blk_k, q_off, k_off, window=window))
+
+    @pl.when(_has(word, _LAST))
     def _emit():
         dq_ref[0, 0, :, :] = dq_scr[:].astype(dq_ref.dtype)
 
 
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+def _dkv_kernel(plan_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dk_scr, dv_scr,
-                *, scale, blk_q, blk_k, causal, nq, window,
-                q_off=0, k_off=0):
-    # Swapped grid: (B, KV head, key-block, inner) where the innermost axis
-    # enumerates (query head within the GQA group) x (query block),
-    # jj = qh_local * nq + iq — scratch accumulates dk/dv across the whole
-    # group (see _bwd for why a plain per-q-head grid would be wrong).
-    ik, jj = pl.program_id(2), pl.program_id(3)
-    n_inner = pl.num_programs(3)
-    iq = jj % nq
+                *, scale, blk_q, blk_k, window, plan, q_off=0, k_off=0):
+    # Swapped grid: (B, KV head, plan) where a row of the plan is a key
+    # block and its steps run over (query head within the GQA group) x
+    # (query block): scratch accumulates dk/dv across the whole group
+    # (see _bwd for why a plain per-q-head grid would be wrong).
+    word = plan_ref[pl.program_id(2)]
+    ik, _, iq = plan.at(word)
 
-    @pl.when(jj == 0)
+    @pl.when(_has(word, _FIRST))
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    # Skip query blocks entirely BEFORE this key block (they never attend
-    # to it under causality).
-    live = _tile_live_local(iq, ik, blk_q, blk_k, causal, window,
-                            q_off, k_off)
-
-    @pl.when(live)
-    def _step():
+    def _step(mask):
         q = q_ref[0, 0, :, :]
         do = do_ref[0, 0, :, :]
-        mask = _causal_mask(iq, ik, blk_q, blk_k, q_off, k_off,
-                            window=window) \
-            if causal else None
         p, ds = _bwd_tile(q, k_ref[0, 0, :, :], v_ref[0, 0, :, :], do,
                           lse_ref[0, 0, :, :], delta_ref[0, 0, :, :],
                           mask, scale)
@@ -227,7 +328,10 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(jj == n_inner - 1)
+    _run_tile(word, plan, _step, lambda: _causal_mask(
+        iq, ik, blk_q, blk_k, q_off, k_off, window=window))
+
+    @pl.when(_has(word, _LAST))
     def _emit():
         dk_ref[0, 0, :, :] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0, 0, :, :] = dv_scr[:].astype(dv_ref.dtype)
@@ -257,40 +361,39 @@ def _fwd(q, k, v, causal, block_q, block_k, interpret, window=None,
     blk_q, blk_k = _block_sizes(t, tk, block_q, block_k)
     nq, nk = t // blk_q, tk // blk_k
     scale = d ** -0.5
+    plan = _query_plan(nq, nk, blk_q, blk_k, causal, window, q_off, k_off)
 
-    def qspec():
-        return pl.BlockSpec((1, 1, blk_q, d),
-                            lambda b_, h_, i, j: (b_, h_, i, 0),
+    def qspec(cols):
+        return pl.BlockSpec((1, 1, blk_q, cols),
+                            lambda b_, h_, s, p: (
+                                b_, h_, plan.at(p[s])[0], 0),
                             memory_space=pltpu.VMEM)
 
-    def kspec():
-        return pl.BlockSpec((1, 1, blk_k, d),
-                            lambda b_, h_, i, j: (b_, h_ // g, j, 0),
-                            memory_space=pltpu.VMEM)
+    kspec = pl.BlockSpec((1, 1, blk_k, d),
+                         lambda b_, h_, s, p: (
+                             b_, h_ // g, plan.at(p[s])[2], 0),
+                         memory_space=pltpu.VMEM)
 
     o, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, blk_q=blk_q,
-                          blk_k=blk_k, causal=causal, window=window,
+                          blk_k=blk_k, window=window, plan=plan,
                           q_off=q_off, k_off=k_off),
-        grid=(b, h, nq, nk),
-        in_specs=[qspec(), kspec(), kspec()],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, h, len(plan.words)),
+            in_specs=[qspec(d), kspec, kspec],
+            out_specs=(qspec(d), qspec(1)),
+            scratch_shapes=[
+                pltpu.VMEM((blk_q, 128), jnp.float32),  # running max
+                pltpu.VMEM((blk_q, 128), jnp.float32),  # running sum
+                pltpu.VMEM((blk_q, d), jnp.float32),    # output accumulator
+            ]),
         out_shape=(
             jax.ShapeDtypeStruct(q.shape, q.dtype),
             jax.ShapeDtypeStruct((b, h, t, 1), jnp.float32),
         ),
-        out_specs=(
-            qspec(),
-            pl.BlockSpec((1, 1, blk_q, 1),
-                         lambda b_, h_, i, j: (b_, h_, i, 0),
-                         memory_space=pltpu.VMEM),
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((blk_q, 128), jnp.float32),  # running max
-            pltpu.VMEM((blk_q, 128), jnp.float32),  # running sum
-            pltpu.VMEM((blk_q, d), jnp.float32),    # output accumulator
-        ],
         interpret=interpret,
-    )(q, k, v)
+    )(jnp.asarray(plan.words, jnp.int32), q, k, v)
     return o, lse
 
 
@@ -310,59 +413,64 @@ def _bwd(q, k, v, o, lse, do, causal, block_q, block_k, interpret,
     delta = jnp.einsum("bhtd,bhtd->bht", do.astype(jnp.float32),
                        o.astype(jnp.float32))[..., None]  # (B,H,T,1)
 
-    def tspec(blk, which):
+    def tspec(blk, which, cols=d):
         # q-addressed or k-addressed (B, H, T, D) blocks per grid layout
-        return pl.BlockSpec((1, 1, blk, d),
+        return pl.BlockSpec((1, 1, blk, cols),
                             memory_space=pltpu.VMEM,
                             index_map=which)
 
-    q_by_i = lambda b_, h_, i, j: (b_, h_, i, 0)
-    k_by_j = lambda b_, h_, i, j: (b_, h_ // g, j, 0)
-    row_by_i = pl.BlockSpec((1, 1, blk_q, 1),
-                            lambda b_, h_, i, j: (b_, h_, i, 0),
-                            memory_space=pltpu.VMEM)
+    # dq: the forward's plan, a row a query block
+    plan = _query_plan(nq, nk, blk_q, blk_k, causal, window, q_off, k_off)
+    q_by_i = lambda b_, h_, s, p: (b_, h_, plan.at(p[s])[0], 0)
+    k_by_j = lambda b_, h_, s, p: (b_, h_ // g, plan.at(p[s])[2], 0)
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, blk_q=blk_q,
-                          blk_k=blk_k, causal=causal, window=window,
+                          blk_k=blk_k, window=window, plan=plan,
                           q_off=q_off, k_off=k_off),
-        grid=(b, h, nq, nk),
-        in_specs=[tspec(blk_q, q_by_i), tspec(blk_k, k_by_j),
-                  tspec(blk_k, k_by_j), tspec(blk_q, q_by_i),
-                  row_by_i, row_by_i],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, h, len(plan.words)),
+            in_specs=[tspec(blk_q, q_by_i), tspec(blk_k, k_by_j),
+                      tspec(blk_k, k_by_j), tspec(blk_q, q_by_i),
+                      tspec(blk_q, q_by_i, 1), tspec(blk_q, q_by_i, 1)],
+            out_specs=tspec(blk_q, q_by_i),
+            scratch_shapes=[pltpu.VMEM((blk_q, d), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        out_specs=tspec(blk_q, q_by_i),
-        scratch_shapes=[pltpu.VMEM((blk_q, d), jnp.float32)],
         interpret=interpret,
-    )(q, k, v, do, lse, delta)
+    )(jnp.asarray(plan.words, jnp.int32), q, k, v, do, lse, delta)
 
-    # Swapped grid for dk/dv: (batch, KV head, key block, inner), with the
-    # inner axis running over (query head in group) x (query block) —
-    # jj = qh_local * nq + iq — so the scratch accumulates each KV head's
+    # Swapped plan for dk/dv: a row a key block, its steps (query head in
+    # group) x (query block), so the scratch accumulates each KV head's
     # gradient across its WHOLE query group before the single emit (with
     # plain per-q-head grids a g-headed group would overwrite the shared
     # dk/dv block g times, keeping only the last group's member).
-    q_by_jj = lambda b_, hk, i, jj: (b_, hk * g + jj // nq, jj % nq, 0)
-    k_by_i = lambda b_, hk, i, jj: (b_, hk, i, 0)
-    row_by_jj = pl.BlockSpec(
-        (1, 1, blk_q, 1),
-        lambda b_, hk, i, jj: (b_, hk * g + jj // nq, jj % nq, 0),
-        memory_space=pltpu.VMEM)
+    kv_plan = _key_plan(nq, nk, g, blk_q, blk_k, causal, window, q_off,
+                        k_off)
+
+    def q_by_head(b_, hk, s, p):
+        _, qh, iq = kv_plan.at(p[s])
+        return b_, hk * g + qh, iq, 0
+
+    k_by_i = lambda b_, hk, s, p: (b_, hk, kv_plan.at(p[s])[0], 0)
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, blk_q=blk_q,
-                          blk_k=blk_k, causal=causal, nq=nq,
-                          window=window, q_off=q_off, k_off=k_off),
-        grid=(b, h_kv, nk, g * nq),
-        in_specs=[tspec(blk_q, q_by_jj), tspec(blk_k, k_by_i),
-                  tspec(blk_k, k_by_i), tspec(blk_q, q_by_jj),
-                  row_by_jj, row_by_jj],
+                          blk_k=blk_k, window=window, plan=kv_plan,
+                          q_off=q_off, k_off=k_off),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, h_kv, len(kv_plan.words)),
+            in_specs=[tspec(blk_q, q_by_head), tspec(blk_k, k_by_i),
+                      tspec(blk_k, k_by_i), tspec(blk_q, q_by_head),
+                      tspec(blk_q, q_by_head, 1),
+                      tspec(blk_q, q_by_head, 1)],
+            out_specs=(tspec(blk_k, k_by_i), tspec(blk_k, k_by_i)),
+            scratch_shapes=[pltpu.VMEM((blk_k, d), jnp.float32),
+                            pltpu.VMEM((blk_k, d), jnp.float32)]),
         out_shape=(jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)),
-        out_specs=(tspec(blk_k, k_by_i), tspec(blk_k, k_by_i)),
-        scratch_shapes=[pltpu.VMEM((blk_k, d), jnp.float32),
-                        pltpu.VMEM((blk_k, d), jnp.float32)],
         interpret=interpret,
-    )(q, k, v, do, lse, delta)
+    )(jnp.asarray(kv_plan.words, jnp.int32), q, k, v, do, lse, delta)
     return dq, dk, dv
 
 
@@ -389,13 +497,13 @@ def flash_attention(q, k, v, causal=True, block_q=128, block_k=128,
     ``interpret`` runs the kernels in Pallas interpreter mode
     (CPU-testable). ``window`` (causal only, >= 1): sliding-window
     attention — each query sees itself plus the window-1 preceding
-    positions; tiles entirely outside the band are skipped, so compute
-    is O(T * window). ``q_off``/``k_off`` (static ints) shift the
-    query/key positions into a common frame for the causal and window
-    masks: query i sits at ``q_off + i``, key j at ``k_off + j`` —
-    offsets change MASKING only, so the caller owns making the geometry
-    meaningful (flash_windowed_sp_attention's front-pad layout is the
-    worked example).
+    positions; tiles entirely outside the band have no grid step, so
+    compute and copies are O(T * window). ``q_off``/``k_off`` (static
+    ints) shift the query/key positions into a common frame for the
+    causal and window masks: query i sits at ``q_off + i``, key j at
+    ``k_off + j`` — offsets change MASKING only, so the caller owns
+    making the geometry meaningful (flash_windowed_sp_attention's
+    front-pad layout is the worked example).
     """
     if window is not None and (not causal or window < 1):
         raise ValueError("window needs causal=True and window >= 1")
@@ -467,7 +575,7 @@ def default_flash_block(dtype, head_dim: int = 128) -> int:
 #   map reads the table), and an online softmax accumulates across the
 #   page axis — no gathered copy of the KV ever exists, HBM reads are
 #   exactly the pages the lane owns, and pages past the lane's position
-#   are skipped the way the causal flash grid skips future tiles.
+#   skip their arithmetic (their grid step and its copy remain).
 #   Online softmax reassociates the reduction, so this path is
 #   allclose- (not bitwise-) equal to the gather path — the engine
 #   defaults to gather and offers the kernel as the TPU throughput

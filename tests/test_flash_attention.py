@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from akka_allreduce_tpu.ops.pallas_kernels import attention as A
 from akka_allreduce_tpu.ops.pallas_kernels.attention import (
     flash_attention,
     flash_causal_attention,
@@ -249,7 +250,7 @@ class TestBlockSelection:
 def test_causal_gradients_fast_tier():
     # small causal backward pin that stays in the fast tier (the larger
     # parametrised grad tests are marked slow): exercises _causal_mask and
-    # the live-skip predicates in both backward kernels
+    # the tile plans of both backward kernels
     q, k, v = _qkv(jax.random.key(9), b=1, t=64, h=1, d=32)
 
     def loss(attn, q, k, v):
@@ -318,3 +319,170 @@ print("FULL-OPTS PARITY OK")
                        capture_output=True, text=True, timeout=300)
     assert r.returncode == 0, r.stdout + r.stderr
     assert "FULL-OPTS PARITY OK" in r.stdout
+
+
+# -- the tile plan: which tiles a kernel's grid holds, and which it masks --
+
+def _sp_layout(t, window, blk_k):
+    """(tk, q_off) as flash_windowed_sp_attention lays out its keys:
+    [front pad ++ the previous rank's tail ++ local], the queries after
+    the pad and the tail."""
+    tail = window - 1
+    pad = (-(t + tail)) % blk_k
+    return pad + tail + t, pad + tail
+
+
+def _geometries():
+    """(tq, tk, blk_q, blk_k, window, q_off): plain causal, windows of 1,
+    a block and three blocks, the windowed-SP offsets, and keys past every
+    query (a key block no query sees)."""
+    out = []
+    for blk_q, blk_k in ((16, 16), (32, 16), (16, 32)):
+        for window in (None, 1, blk_k, 3 * blk_k):
+            out.append((96, 96, blk_q, blk_k, window, 0))
+            if window is not None:
+                tk, q_off = _sp_layout(96, window, blk_k)
+                out.append((96, tk, blk_q, blk_k, window, q_off))
+        out.append((32, 96, blk_q, blk_k, None, 0))
+    return out
+
+
+def _tile_mask(iq, ik, blk_q, blk_k, window, q_off):
+    q_pos = q_off + iq * blk_q + np.arange(blk_q)[:, None]
+    k_pos = ik * blk_k + np.arange(blk_k)[None, :]
+    mask = q_pos >= k_pos
+    if window is not None:
+        mask &= q_pos - k_pos < window
+    return mask
+
+
+def _walk(plan):
+    """[(row, head, col, flags)] of a plan's words."""
+    return [(*plan.at(w), w & ((1 << A._FLAG_BITS) - 1))
+            for w in plan.words]
+
+
+def _check_plan(plan, rows, live, masked):
+    """``live``: the (row, head, col) a brute force found live; the plan
+    holds exactly those, in the full grid's order, each row at least once,
+    flagged first / last / masked as its kernel needs."""
+    steps = _walk(plan)
+    assert [s[:3] for s in steps] == sorted(s[:3] for s in steps)
+    ran = [s[:3] for s in steps if s[3] & A._COMPUTE]
+    assert ran == sorted(live)
+    assert {s[:3] for s in steps if s[3] & A._MASK} == masked
+    for r in range(rows):
+        mine = [s for s in steps if s[0] == r]
+        assert mine, f"row {r} has no step"
+        assert [bool(s[3] & A._FIRST) for s in mine] == \
+            [True] + [False] * (len(mine) - 1)
+        assert [bool(s[3] & A._LAST) for s in mine] == \
+            [False] * (len(mine) - 1) + [True]
+        if not any(s[3] & A._COMPUTE for s in mine):
+            assert len(mine) == 1 and not mine[0][3] & A._MASK
+
+
+@pytest.mark.parametrize("geom", _geometries(), ids=str)
+def test_tile_kinds_and_plans_match_the_mask(geom):
+    tq, tk, blk_q, blk_k, window, q_off = geom
+    nq, nk, g = tq // blk_q, tk // blk_k, 2
+    kinds = {}
+    for iq in range(nq):
+        for ik in range(nk):
+            mask = _tile_mask(iq, ik, blk_q, blk_k, window, q_off)
+            kind = A._tile_kind(iq, ik, blk_q, blk_k, True, window, q_off)
+            assert kind == (A.DEAD if not mask.any() else
+                            A.WHOLE if mask.all() else A.MASKED), (iq, ik)
+            kinds[iq, ik] = kind
+    live = {t for t, k in kinds.items() if k != A.DEAD}
+    masked = {t for t, k in kinds.items() if k == A.MASKED}
+    _check_plan(A._query_plan(nq, nk, blk_q, blk_k, True, window, q_off, 0),
+                nq, {(iq, 0, ik) for iq, ik in live},
+                {(iq, 0, ik) for iq, ik in masked})
+    _check_plan(A._key_plan(nq, nk, g, blk_q, blk_k, True, window, q_off, 0),
+                nk, {(ik, h, iq) for iq, ik in live for h in range(g)},
+                {(ik, h, iq) for iq, ik in masked for h in range(g)})
+
+
+def test_a_noncausal_plan_is_the_whole_grid_unmasked():
+    assert A.flash_tile_counts(64, 128, 16, 32, causal=False) == (16, 16, 0)
+    plan = A._key_plan(4, 4, 3, 16, 32, False, None, 0, 0)
+    assert [s[:3] for s in _walk(plan)] == [
+        (ik, h, iq) for ik in range(4) for h in range(3) for iq in range(4)]
+
+
+def _offset_oracle(q, k, v, window=None, q_off=0):
+    """Masked softmax attention with query i at ``q_off + i`` and key j at
+    ``j``: the kernel's frame, GQA by expansion."""
+    from akka_allreduce_tpu.parallel.ring_attention import (NEG_INF,
+                                                            expand_kv_heads)
+    k, v = expand_kv_heads(q, k, v)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                   preferred_element_type=jnp.float32) * q.shape[-1] ** -0.5
+    dist = q_off + jnp.arange(q.shape[1])[:, None] - jnp.arange(k.shape[1])
+    mask = dist >= 0
+    if window is not None:
+        mask &= dist < window
+    p = jax.nn.softmax(jnp.where(mask[None, None], s, NEG_INF), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v,
+                      preferred_element_type=jnp.float32).astype(q.dtype)
+
+
+_SP = _sp_layout(64, 24, 16)
+
+
+@pytest.mark.parametrize("tq,tk,blk_q,blk_k,window,q_off", [
+    (64, 64, 32, 16, None, 0),              # causal, uneven blocks
+    (64, 64, 16, 32, 1, 0),                 # a window of one position
+    (64, 64, 16, 16, 16, 0),                # a window of one block
+    (64, 64, 32, 16, 48, 0),                # a window of three blocks
+    (64, _SP[0], 32, 16, 24, _SP[1]),       # windowed-SP offsets
+    (32, 64, 16, 16, None, 0),              # keys past every query
+])
+def test_planned_grid_matches_the_oracle_with_gqa(tq, tk, blk_q, blk_k,
+                                                  window, q_off):
+    ks = jax.random.split(jax.random.key(11), 3)
+    q = jax.random.normal(ks[0], (1, tq, 4, 16))
+    k = jax.random.normal(ks[1], (1, tk, 2, 16))
+    v = jax.random.normal(ks[2], (1, tk, 2, 16))
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, True, blk_q, blk_k, True, window,
+                               q_off, 0)
+
+    def oracle(q, k, v):
+        return _offset_oracle(q, k, v, window, q_off)
+
+    np.testing.assert_allclose(flash(q, k, v), oracle(q, k, v), atol=2e-5,
+                               rtol=2e-5)
+
+    def loss(attn, q, k, v):
+        return jnp.sum(jnp.sin(attn(q, k, v).astype(jnp.float32)))
+
+    g_flash = jax.grad(lambda *a: loss(flash, *a), argnums=(0, 1, 2))(q, k, v)
+    g_oracle = jax.grad(lambda *a: loss(oracle, *a),
+                        argnums=(0, 1, 2))(q, k, v)
+    for gf, go, name in zip(g_flash, g_oracle, "qkv"):
+        np.testing.assert_allclose(gf, go, atol=5e-5, rtol=5e-5,
+                                   err_msg=f"d{name} mismatch")
+
+
+@pytest.mark.parametrize("t,block,heads,line", [
+    (8192, 512, (20, 20), "block=512 tiles=136/256 masked=16"),   # GLM cell
+    (4096, 1024, (16, 8), "block=1024 tiles=10/16 masked=4"),     # 1chip
+])
+def test_dispatch_line_reports_the_tiles_it_runs(t, block, heads, line,
+                                                  monkeypatch, capsys):
+    from akka_allreduce_tpu.models.train import (TrainConfig,
+                                                 select_local_attention)
+    from akka_allreduce_tpu.models.transformer import TransformerConfig
+    from akka_allreduce_tpu.ops.pallas_kernels import dispatch
+    monkeypatch.setattr(dispatch, "_said", set())
+    cfg = TrainConfig(model=TransformerConfig(), attn_impl="flash",
+                      attn_block_size=block)
+    q = jax.ShapeDtypeStruct((1, t, heads[0], 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, t, heads[1], 128), jnp.bfloat16)
+    jax.eval_shape(select_local_attention(cfg), q, kv, kv)
+    said = [s for s in capsys.readouterr().err.splitlines()
+            if s.startswith("attention[local]: interpret:flash ")]
+    assert len(said) == 1 and said[0].endswith(line), said
