@@ -56,6 +56,9 @@ from akka_allreduce_tpu.parallel.ring_attention import (
 from akka_allreduce_tpu.runtime.tracing import (
     SCOPE_ATTENTION,
     SCOPE_DENSE_FFN,
+    SCOPE_KDA_CHUNK,
+    SCOPE_KDA_MIXER,
+    SCOPE_KDA_STEP,
     SCOPE_MLA_ATTENTION,
     SCOPE_SPARSE_INDEXER,
     SCOPE_SSM_MIXER,
@@ -95,6 +98,31 @@ def init_kv_cache(cfg: TransformerConfig, batch: int,
     Scales ride in ``k_scale``/``v_scale`` entries; every cache consumer
     (decode_step / prefill / extend / the serving engine) branches on
     their presence, so the pytree structure IS the format switch."""
+    if cfg.kda_layers:
+        # the delta-rule hybrid: a "kda" layer holds its matrix state a head
+        # (float32, head size squared, ``kda_state[j]`` layer
+        # ``kda_layers[j]``'s) and the last ``kda_conv - 1`` inputs of its
+        # q, k and v convolutions (``kda_conv``), a buffer a layer, both
+        # OVERWRITTEN as the lane advances (:func:`init_kv_cache`'s hybrid
+        # below says why a buffer a layer); a "latent" layer a latent a
+        # position, as the latent kinds hold it
+        if kv_dtype is not None:
+            raise NotImplementedError(
+                f"kv_dtype={kv_dtype!r}: the delta-rule hybrid's cache has no "
+                f"quantized format (missing: a scale a cached latent; the "
+                f"recurrent state stays float32)")
+        hd = cfg.kda_head_dim
+        return {
+            "kda_state": tuple(
+                jnp.zeros((batch, cfg.kda_heads, hd, hd), jnp.float32)
+                for _ in cfg.kda_layers),
+            "kda_conv": tuple(
+                jnp.zeros((batch, cfg.kda_conv - 1, 3 * cfg.kda_dim),
+                          cfg.dtype) for _ in cfg.kda_layers),
+            "latent": jnp.zeros((cfg.n_attentions, batch, cfg.max_seq,
+                                 cfg.latent_row), cfg.dtype),
+            "pos": jnp.zeros((), jnp.int32),
+        }
     if cfg.attention == "mla":
         # the latent cache is a pytree key of its own: per attention (two
         # a double layer) and position the normed, scaled latent and the
@@ -576,19 +604,27 @@ def _mla_cached_attention(p: dict, x: jnp.ndarray, kv: dict, a: int,
     (``attention[latent_decode]``): the fused kernel over each lane's live
     key blocks in the slot engine's step on the TPU, the pure-JAX formula
     over the whole buffer everywhere else; projections, rotary phases and
-    the cache write are the same on both. Returns (the attention's output
-    through ``wo``, kv)."""
+    the cache write are the same on both. A chunk through the cache (``ops``
+    with a ``lane``, the delta-rule hybrid's prefill) attends the lane's
+    rows through :func:`_masked_latent_attention`. Where ``p`` has a
+    ``w_gate`` each head's output is gated by a sigmoid of the normed
+    input (the delta-rule hybrid's latent layers), and ``cfg.rope_interleave`` rotates
+    the interleaved pairs. Returns (the attention's output through ``wo``,
+    kv)."""
     b, t, _ = x.shape
     heads, rank = cfg.n_heads, cfg.kv_lora_rank
     nope, vd = cfg.qk_nope_head_dim, cfg.v_head_dim
     kv = dict(kv)
-    _h, _c_q, q_nope, q_rope, c_kv, k_rope = mla_project(
-        p, x, cfg, lambda v: ops.rope(v, cfg.rope_theta))
+    def rope(v):
+        if cfg.rope_interleave:
+            v = _pairs_apart(v)
+        return ops.rope(v, cfg.rope_theta)
+    h, _c_q, q_nope, q_rope, c_kv, k_rope = mla_project(p, x, cfg, rope)
     latent = jnp.concatenate([c_kv, k_rope[:, :, 0]], axis=-1)
     kv["latent"] = ops.write(kv["latent"], a,
                              latent.astype(kv["latent"].dtype))
     up = p["wkv_b"].reshape(rank, heads, nope + vd)
-    if ops.pos is None:
+    if ops.pos is None and ops.lane is None:
         kv_heads = jnp.einsum("btr,rhe->bthe", c_kv, up)
         k = jnp.concatenate(
             [kv_heads[..., :nope],
@@ -602,8 +638,22 @@ def _mla_cached_attention(p: dict, x: jnp.ndarray, kv: dict, a: int,
             [jnp.einsum("bthn,rhn->bthr", q_nope, up[..., :nope]), q_rope],
             axis=-1)
         scale = (nope + q_rope.shape[-1]) ** -0.5
-        path = latent_decode_path(ops.pos, kv["latent"])
-        if path is None:
+        if ops.pos is None:
+            # a chunk through the cache: its queries attend the lane's rows
+            # at or before their own positions, those the chunks before it
+            # wrote and its own: GLM-5.2's masked pass with causality for
+            # membership
+            positions = ops.positions(b, t)
+            n_seq = kv["latent"].shape[2]
+            blk = math.gcd(n_seq, KEY_ROWS)
+            say_attention("latent_chunk",
+                          "reference:_masked_latent_attention", q_lat,
+                          key_block=blk)
+            out_lat = _masked_latent_attention(
+                q_lat, kv["latent"], a, ops.lane,
+                jnp.arange(n_seq)[None, None, :] <= positions[..., None],
+                positions, rank, scale, blk)
+        elif (path := latent_decode_path(ops.pos, kv["latent"])) is None:
             say_attention("latent_decode", "reference:_latent_attention",
                           q_lat)
             out_lat = _latent_attention(q_lat, kv["latent"][a], ops.pos,
@@ -619,7 +669,18 @@ def _mla_cached_attention(p: dict, x: jnp.ndarray, kv: dict, a: int,
                 q_lat[:, 0], kv["latent"], a, ops.pos, rank, scale,
                 tiling=tiling, interpret=interpret)[:, None]
         out = jnp.einsum("bthr,rhv->bthv", out_lat, up[..., nope:])
+    if "w_gate" in p:
+        # the head-wise output gate
+        out = out * jax.nn.sigmoid(h @ p["w_gate"])[..., None]
     return out.reshape(b, t, heads * vd) @ p["wo"], kv
+
+
+def _pairs_apart(x: jnp.ndarray) -> jnp.ndarray:
+    """x's last axis with its interleaved rotary pairs (x[2i], x[2i+1])
+    moved to the places (x[i], x[i + d/2]) that :func:`apply_rope` rotates
+    together: queries and keys alike, so that every q . k is the
+    interleaved rotation's."""
+    return jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
 
 
 def _shortcut_cached_block(layer: dict, x: jnp.ndarray, kv: dict, i: int,
@@ -1142,19 +1203,223 @@ def _ssm_mixer(p: dict, u: jnp.ndarray, kv: dict, j: int,
     return rmsnorm(y, p["norm"], cfg.norm_eps) @ p["w_out"], kv
 
 
+# the block length of the delta rule's chunked form (no key of a config.json
+# states one): the public KDA kernel's
+KDA_CHUNK = 64
+# positions whose decays share one reference point inside such a block: 16 x
+# a log-decay of at least -5 keeps every factor within exp(+-80), inside
+# float32's range
+KDA_SUB = 16
+
+
+def kda_chunk_path(t: int) -> "int | None":
+    """How a delta-rule mixer runs ``t`` tokens a sequence, from the shape
+    alone: None for ONE step of the recurrence (a decode step), else the
+    block length of the chunked form (:func:`_kda_chunked`):
+    :data:`KDA_CHUNK`, or all ``t`` tokens where they are fewer. Same
+    numbers either way. No option chooses."""
+    return None if t == 1 else min(t, KDA_CHUNK)
+
+
+def _kda_chunked(q, k, v, g, beta, s0, blk: int):
+    """The gated delta rule ``S_t = (I - beta_t k_t k_t^T) Diag(exp g_t)
+    S_{t-1} + beta_t k_t v_t^T``, ``o_t = S_t^T q_t``, over q, k (b, t,
+    heads, dk), v (b, t, heads, dv), the log-decay g (b, t, heads, dk) <= 0
+    and beta (b, t, heads), all float32 (g and beta 0 where a token is
+    padding: it neither decays the state nor adds to it), from s0 (b,
+    heads, dk, dv) float32, in the chunked (WY) form: a ``lax.scan`` over
+    blocks of ``blk`` tokens that carries the state. In a block, with
+    ``G_r`` the decay summed from the block's start to token r (a channel),
+    the new values u solve ``(I + A) u = beta (v - exp(G) k S)`` with ``A_rs
+    = beta_r (k_r exp(G_r - G_s)) . k_s`` below the diagonal, the outputs
+    are ``exp(G) q S + P u`` with ``P_rs = (q_r exp(G_r - G_s)) . k_s`` at
+    and below it, and the state at the block's end is ``exp(G_end) S +
+    (k exp(G_end - G))^T u``. The pairwise decays are never exp(G_r) x
+    exp(-G_s) over a block (exp(-G) overflows by 64 x 5): row r takes
+    exp(G_r - G_ref), key s exp(G_ref - G_s), with G_ref where the
+    :data:`KDA_SUB` rows around r begin, and a key after those rows 0.
+    Every product is float32 at the highest precision. Returns (o (b, t,
+    heads, dv), the state (b, heads, dk, dv))."""
+    b, t, heads, dk = q.shape
+    blocks = -(-t // blk)
+    pad = blocks * blk - t
+    sub = math.gcd(blk, KDA_SUB)
+    n_sub = blk // sub
+    hi = lax.Precision.HIGHEST
+
+    def split(x):
+        # (b, t, heads, ...) -> (blocks, b, heads, blk, ...)
+        x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+        x = x.reshape((b, blocks, blk) + x.shape[2:])
+        return jnp.moveaxis(jnp.moveaxis(x, 1, 0), 3, 2)
+
+    at = jnp.arange(blk)
+    # key s is seen by the rows of sub-block j where s ends no later
+    seen = at[None, :] < ((jnp.arange(n_sub) + 1) * sub)[:, None]
+    below = at[:, None] > at[None, :]
+
+    def block(s, args):
+        qb, kb, vb, gb, bb = args
+        cum = jnp.cumsum(gb, axis=2)                      # (b, heads, blk, dk)
+        ref = jnp.concatenate([jnp.zeros_like(cum[:, :, :1]),
+                               cum[:, :, sub - 1:blk - 1:sub]], axis=2)
+        rows_up = jnp.exp(cum - jnp.repeat(ref, sub, axis=2))
+        # masked BEFORE the exp: past its sub-block a key would overflow
+        keys_down = kb[:, :, None] * jnp.exp(jnp.where(
+            seen[None, None, :, :, None],
+            ref[:, :, :, None] - cum[:, :, None], -jnp.inf))
+
+        def pairs(x):
+            x = (x * rows_up).reshape(b, heads, n_sub, sub, dk)
+            return jnp.einsum("bhjrd,bhjsd->bhjrs", x, keys_down,
+                              precision=hi).reshape(b, heads, blk, blk)
+
+        a = jnp.where(below, pairs(kb), 0.0) * bb[..., None]
+        p = jnp.where(below | (at[:, None] == at[None, :]), pairs(qb), 0.0)
+        decay = jnp.exp(cum)
+        wu = jax.scipy.linalg.solve_triangular(
+            a, bb[..., None] * jnp.concatenate([kb * decay, vb], axis=-1),
+            lower=True, unit_diagonal=True)
+        u = wu[..., dk:] - jnp.einsum("bhck,bhkv->bhcv", wu[..., :dk], s,
+                                      precision=hi)
+        o = jnp.einsum("bhck,bhkv->bhcv", qb * decay, s, precision=hi) \
+            + jnp.einsum("bhcs,bhsv->bhcv", p, u, precision=hi)
+        end = cum[:, :, -1]
+        s = s * jnp.exp(end)[..., None] + jnp.einsum(
+            "bhck,bhcv->bhkv", kb * jnp.exp(end[:, :, None] - cum), u,
+            precision=hi)
+        return s, o
+
+    s, os_ = lax.scan(block, s0, (split(q), split(k), split(v), split(g),
+                                  split(beta[..., None])[..., 0]))
+    # (blocks, b, heads, blk, dv) -> (b, t, heads, dv)
+    o = jnp.moveaxis(jnp.moveaxis(os_, 0, 1), 3, 2)
+    return o.reshape(b, blocks * blk, heads, -1)[:, :t], s
+
+
+def _kda_mixer(p: dict, u: jnp.ndarray, kv: dict, j: int,
+               cfg: TransformerConfig, ops: CacheOps):
+    """Delta-rule mixer ``j`` (Kimi delta attention) over its normed input
+    u (b, t, d) through ``kv["kda_state"][j]`` and ``kv["kda_conv"][j]`` (a
+    buffer a layer, OVERWRITTEN and not appended to): ``[q | k | v | f | g
+    | beta] = u w_in``; a causal depthwise convolution of width
+    ``kda_conv`` over q, k and v, continued from the lane's tail, then
+    SiLU; q and k L2-normed a head, q times ``kda_head_dim ** -0.5``;
+    ``beta = sigmoid(beta)`` a head; the log-decay a channel ``g = lower x
+    sigmoid(exp(a_log) (f + dt_bias))``, in (``kda_lower_bound``, 0); the
+    gated delta rule of :func:`_kda_chunked` from the lane's state (a chunk
+    at ``offset`` > 0) or from zeros; ``o <- rmsnorm(o) x sigmoid(g)``
+    (one group over all channels); ``w_out``. A decode step (t = 1) is one
+    step of the recurrence for every row of the batch, ``S <- exp(g) S; S
+    <- S + beta k (v - S^T k)^T; o = S^T q`` (float32, elementwise); which
+    form runs
+    :func:`kda_chunk_path` says once on stderr (``attention[kda_chunk]``).
+    Tokens that are not ``ops.counted`` leave state and tail as the last
+    counted token left them. Returns (the mixer's output (b, t, d), kv)."""
+    b, t, _ = u.shape
+    heads, hd, kd, width = (cfg.kda_heads, cfg.kda_head_dim, cfg.kda_dim,
+                            cfg.kda_conv)
+    f32 = jnp.float32
+    kv = dict(kv)
+    # the projections kept in float32: the decay's input is multiplied by
+    # exp(a_log) (up to 16) inside a sigmoid, and q, k, v feed a state that
+    # sums thousands of tokens
+    proj = jnp.matmul(u, p["w_in"], preferred_element_type=f32)
+    qkv, f, gate, beta = (proj[..., :3 * kd], proj[..., 3 * kd:4 * kd],
+                          proj[..., 4 * kd:5 * kd], proj[..., 5 * kd:])
+    tail, s0 = kv["kda_conv"][j], kv["kda_state"][j]
+    if ops.lane is not None:
+        tail = lax.dynamic_index_in_dim(tail, ops.lane, 0)
+        s0 = lax.dynamic_index_in_dim(s0, ops.lane, 0)
+    if ops.pos is None:
+        # what a lane held belongs to its last request
+        fresh = True if ops.offset is None else ops.offset == 0
+        tail = jnp.where(fresh, jnp.zeros_like(tail), tail)
+        s0 = jnp.where(fresh, jnp.zeros_like(s0), s0)
+    counted = (jnp.ones((b, t), bool) if ops.counted is None or t == 1
+               else ops.counted.reshape(b, t))
+    seq = jnp.concatenate([tail, qkv], axis=1)       # (b, width - 1 + t, c)
+    conv = sum(seq[:, i:i + t].astype(f32) * p["conv_w"][:, i].astype(f32)
+               for i in range(width))
+    if t == 1:
+        new_tail = seq[:, 1:]
+    else:
+        n_valid = counted.sum(axis=1)
+        new_tail = jax.vmap(lambda x, at: lax.dynamic_slice_in_dim(
+            x, at, width - 1, axis=0))(seq, n_valid)
+    q, k, v = (x.reshape(b, t, heads, hd)
+               for x in jnp.split(jax.nn.silu(conv), 3, axis=-1))
+
+    def l2norm(x):
+        return x * lax.rsqrt(jnp.sum(jnp.square(x), -1, keepdims=True)
+                             + 1e-6)
+    q, k = l2norm(q) * hd ** -0.5, l2norm(k)
+    g = cfg.kda_lower_bound * jax.nn.sigmoid(
+        jnp.exp(p["a_log"].astype(f32))[:, None]
+        * (f.astype(f32) + p["dt_bias"].astype(f32)).reshape(b, t, heads, hd))
+    beta = jax.nn.sigmoid(beta.astype(f32))
+    g = jnp.where(counted[..., None, None], g, 0.0)
+    beta = jnp.where(counted[..., None], beta, 0.0)
+    blk = kda_chunk_path(t)
+    if blk is None:
+        say_attention("kda_chunk", "reference:delta_rule_step", q)
+        with jax.named_scope(SCOPE_KDA_STEP):
+            # S^T k and S^T q from ONE pass over the decayed state, and the
+            # read-out of the new state from them: S'^T q = S^T q + beta
+            # (k . q) (v - S^T k), so the state is read twice (the sums,
+            # the update) and written once
+            k1, q1, b1 = k[:, 0], q[:, 0], beta[:, 0, :, None]
+            s = s0 * jnp.exp(g[:, 0])[..., None]
+            sk = jnp.sum(s * k1[..., None], axis=2)
+            sq = jnp.sum(s * q1[..., None], axis=2)
+            err = v[:, 0] - sk
+            o = (sq + b1 * jnp.sum(k1 * q1, -1, keepdims=True) * err)[:, None]
+            s = s + (b1 * k1)[..., None] * err[:, :, None]
+    else:
+        say_attention("kda_chunk", "reference:_kda_chunked", q, block=blk,
+                      blocks=-(-t // blk))
+        with jax.named_scope(SCOPE_KDA_CHUNK):
+            o, s = _kda_chunked(q, k, v, g, beta, s0, blk)
+    new_tail = new_tail.astype(kv["kda_conv"][j].dtype)
+    if ops.lane is not None:
+        s = lax.dynamic_update_slice(kv["kda_state"][j], s,
+                                     (ops.lane, 0, 0, 0))
+        new_tail = lax.dynamic_update_slice(kv["kda_conv"][j], new_tail,
+                                            (ops.lane, 0, 0))
+    for name, new in (("kda_state", s), ("kda_conv", new_tail)):
+        kv[name] = kv[name][:j] + (new,) + kv[name][j + 1:]
+    o = rmsnorm(o.reshape(b, t, kd), p["norm"].astype(f32), cfg.norm_eps)
+    o = o * jax.nn.sigmoid(gate.astype(f32))
+    return o.astype(u.dtype) @ p["w_out"], kv
+
+
 def _hybrid_cached_block(layer: dict, x: jnp.ndarray, kv: dict, i: int,
                          cfg: TransformerConfig, ops: CacheOps):
-    """One layer of the hybrid: its mixer - a state-space recurrence
-    (:func:`_ssm_mixer`) or GQA attention WITHOUT any position signal, at
-    the stated score scale, through the ``k`` / ``v`` cache as the dense
-    block has it (:func:`_slot_cached_attention` over the lane's rows, the
-    fresh ones written first: a decode step's query, a prefill's or a
-    chunk's queries alike) - then the expert share
-    with its shared expert; each branch joins the residual times
-    ``residual_scale``. Returns (x, kv, the expert layer's counts)."""
+    """One layer of a hybrid: its mixer - a state-space recurrence
+    (:func:`_ssm_mixer`), a delta rule (:func:`_kda_mixer`), latent
+    attention (:func:`_mla_cached_attention`, its own norm ahead of it) or
+    GQA attention WITHOUT any position signal, at the stated score scale,
+    through the ``k`` / ``v`` cache as the dense block has it
+    (:func:`_slot_cached_attention` over the lane's rows, the fresh ones
+    written first: a decode step's query, a prefill's or a chunk's queries
+    alike) - then the expert share with its shared expert, or a dense
+    SwiGLU; each branch joins the residual times ``residual_scale``.
+    Returns (x, kv, the expert layer's counts or None)."""
     b, t, d = x.shape
     scale = jnp.asarray(cfg.residual_scale, x.dtype)
+    if "mla" in layer:
+        with jax.named_scope(SCOPE_MLA_ATTENTION):
+            out, kv = _mla_cached_attention(
+                layer["mla"], x, kv, cfg.latent_layers.index(i), cfg, ops)
+        x = x + out * scale
+        return _hybrid_ffn(layer, x, cfg, ops, scale, kv)
     h = rmsnorm(x, layer["ln1"], cfg.norm_eps)
+    if "kda" in layer:
+        with jax.named_scope(SCOPE_KDA_MIXER):
+            out, kv = _kda_mixer(layer["kda"], h, kv,
+                                 cfg.kda_layers.index(i), cfg, ops)
+        x = x + out * scale
+        return _hybrid_ffn(layer, x, cfg, ops, scale, kv)
     if "ssm" in layer:
         with jax.named_scope(SCOPE_SSM_MIXER):
             out, kv = _ssm_mixer(layer["ssm"], h, kv,
@@ -1173,7 +1438,20 @@ def _hybrid_cached_block(layer: dict, x: jnp.ndarray, kv: dict, i: int,
                 ops.positions(b, t), scale=cfg.attn_scale)
             out = attn.reshape(b, t, -1) @ layer["wo"]
     x = x + out * scale
+    return _hybrid_ffn(layer, x, cfg, ops, scale, kv)
+
+
+def _hybrid_ffn(layer: dict, x: jnp.ndarray, cfg: TransformerConfig,
+                ops: CacheOps, scale: jnp.ndarray, kv: dict):
+    """A hybrid layer's second half: the expert share (``moe``) or a dense
+    SwiGLU after ``ln2``. Returns (x, kv, the expert layer's counts or
+    None)."""
+    b, t, d = x.shape
     h = rmsnorm(x, layer["ln2"], cfg.norm_eps)
+    if "moe" not in layer:
+        with jax.named_scope(SCOPE_DENSE_FFN):
+            return x + (jax.nn.silu(h @ layer["w1"]) * (h @ layer["w3"])) \
+                @ layer["w2"] * scale, kv, None
     m, counts = dropless_moe(h.reshape(b * t, d), layer["moe"], cfg.experts,
                              ops.counted)
     return x + m.reshape(b, t, d) * scale, kv, counts

@@ -127,6 +127,21 @@ class TransformerConfig:
     #   step run (:func:`latent_block`), which no serving path runs yet.
     #   ``mtp_layers`` 1 gives it a multi-token-prediction module
     #   (models/mtp.py), trained as a second objective.
+    # * block = "standard" with ``layer_mixer`` "kda" | "latent" is the
+    #   hybrid of delta-rule linear attention and latent attention (Ling's
+    #   ``bailing_hybrid``): a "kda" layer mixes its tokens with Kimi delta
+    #   attention, ``kda_heads`` heads of ``kda_head_dim`` for q, k and v,
+    #   each through a causal depthwise convolution of width ``kda_conv``,
+    #   over a matrix state a head (float32, ``kda_head_dim`` squared) that
+    #   decays a channel at a time, its log-decay bounded below by
+    #   ``kda_lower_bound``. A "latent" layer is latent attention (the ``mla``
+    #   sizes; ``q_lora_rank`` 0 is a full-rank query normed a head) with
+    #   a sigmoid gate a head on its output (``w_gate``).
+    #   ``layer_ffn`` says which layers end in a dense SwiGLU and which in
+    #   the ``experts`` share. The cache holds a state and a convolution
+    #   tail a lane a "kda" layer (OVERWRITTEN each step) and a latent a
+    #   position a "latent" layer. ``rope_interleave`` rotates the pairs
+    #   (x[2i], x[2i+1]) where the other kinds rotate (x[i], x[i + d/2]).
     attention: str = "gqa"
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
@@ -152,6 +167,11 @@ class TransformerConfig:
     residual_scale: float = 1.0
     logit_divisor: float = 1.0
     mtp_layers: int = 0
+    kda_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv: int = 0
+    kda_lower_bound: float = 0.0
+    rope_interleave: bool = False
 
     @property
     def head_dim(self) -> int:
@@ -210,9 +230,10 @@ class TransformerConfig:
     @property
     def full_latent(self) -> bool:
         """Latent attention over every position in a standard block,
-        layer by layer (``layer_ffn``, no ``layer_indexer``)."""
+        layer by layer (``layer_ffn``, no ``layer_indexer``, no
+        ``layer_mixer``)."""
         return self.block == "standard" and self.attention == "mla" \
-            and not self.layer_indexer
+            and not self.layer_indexer and not self.layer_mixer
 
     @property
     def layerwise(self) -> bool:
@@ -237,6 +258,26 @@ class TransformerConfig:
                      if kind == "attention")
 
     @property
+    def kda_layers(self) -> tuple:
+        """The layers whose mixer is Kimi delta attention, in order: layer
+        ``kda_layers[j]`` owns state and tail entry ``j``."""
+        return tuple(i for i, kind in enumerate(self.layer_mixer)
+                     if kind == "kda")
+
+    @property
+    def latent_layers(self) -> tuple:
+        """The hybrid's latent attentions, in order: layer
+        ``latent_layers[a]`` owns latent cache entry ``a``."""
+        return tuple(i for i, kind in enumerate(self.layer_mixer)
+                     if kind == "latent")
+
+    @property
+    def kda_dim(self) -> int:
+        """Channels of a delta-rule mixer's q, k and v (heads x head
+        size)."""
+        return self.kda_heads * self.kda_head_dim
+
+    @property
     def ssm_inner(self) -> int:
         """Channels of a state-space mixer (heads x head size)."""
         return self.ssm_heads * self.ssm_head_dim
@@ -249,6 +290,8 @@ class TransformerConfig:
     @property
     def n_attentions(self) -> int:
         """Latent attentions, each with a cache entry of its own."""
+        if self.hybrid:
+            return len(self.latent_layers)
         return 2 * self.n_layers if self.block == "shortcut" \
             else self.n_layers
 
@@ -303,9 +346,11 @@ class TransformerConfig:
             sizes = (self.q_lora_rank, self.kv_lora_rank,
                      self.qk_nope_head_dim, self.qk_rope_head_dim,
                      self.v_head_dim)
-            if min(sizes) < 1 or self.qk_rope_head_dim % 2:
+            if min(sizes[1:]) < 1 or self.qk_rope_head_dim % 2 or (
+                    self.q_lora_rank < (1 if self.mla_lora_scales else 0)):
                 raise ValueError(
-                    f"mla needs its five sizes (q_lora_rank, kv_lora_rank, "
+                    f"mla needs its five sizes (q_lora_rank, 0 only for a "
+                    f"full-rank query without lora scales, kv_lora_rank, "
                     f"qk_nope_head_dim, qk_rope_head_dim even, v_head_dim), "
                     f"got {sizes}")
             if not self.rope or self.attn_window is not None \
@@ -328,7 +373,15 @@ class TransformerConfig:
                      self.index_head_dim, self.index_topk)
         hybrid_sizes = (self.ssm_heads, self.ssm_head_dim, self.ssm_state,
                         self.ssm_conv, self.ssm_chunk)
-        if self.hybrid:
+        kda_sizes = (self.kda_heads, self.kda_head_dim, self.kda_conv)
+        if self.hybrid and "kda" in self.layer_mixer:
+            self._check_kda_hybrid(hybrid_sizes, kda_sizes)
+        elif any(kda_sizes) or self.kda_lower_bound \
+                or self.rope_interleave:
+            raise ValueError(
+                "kda_* / rope_interleave describe the hybrid of "
+                "`layer_mixer` 'kda' | 'latent'")
+        elif self.hybrid:
             self._check_hybrid(hybrid_sizes)
         elif any(hybrid_sizes) or self.attn_scale is not None or (
                 self.embed_scale, self.residual_scale,
@@ -418,6 +471,44 @@ class TransformerConfig:
                 "every layer of the hybrid ends in the `experts` share "
                 "(layer_ffn all 'sparse', no indexer, no `moe`)")
 
+    def _check_kda_hybrid(self, ssm_sizes: tuple, sizes: tuple) -> None:
+        n = self.n_layers
+        if len(self.layer_mixer) != n \
+                or set(self.layer_mixer) - {"kda", "latent"}:
+            raise ValueError(
+                f"layer_mixer says 'kda' | 'latent' for each of the {n} "
+                f"layers, got {self.layer_mixer}")
+        if min(sizes) < 1 or self.kda_lower_bound >= 0:
+            raise ValueError(
+                f"the delta-rule mixer needs its three sizes (kda_heads, "
+                f"kda_head_dim, kda_conv) and a negative "
+                f"kda_lower_bound, got {sizes}, {self.kda_lower_bound}")
+        if any(ssm_sizes) or self.attn_scale is not None or (
+                self.embed_scale, self.residual_scale,
+                self.logit_divisor) != (1.0, 1.0, 1.0):
+            raise ValueError("ssm_* / attn_scale / embed_scale / "
+                             "residual_scale / logit_divisor describe the "
+                             "hybrid of 'ssm' | 'attention' layers")
+        if self.attention != "mla" or self.block != "standard" \
+                or self.mla_lora_scales:
+            raise ValueError(
+                "the delta-rule hybrid's attention is latent attention in a "
+                "standard block, without lora scales")
+        if len(self.layer_ffn) != n \
+                or set(self.layer_ffn) - {"dense", "sparse"} \
+                or self.layer_indexer or self.index_n_heads \
+                or self.index_head_dim or self.index_topk \
+                or ("sparse" in self.layer_ffn) != (self.experts is not None):
+            raise ValueError(
+                "each layer of the delta-rule hybrid ends in a 'dense' "
+                "SwiGLU or the 'sparse' `experts` share (layer_ffn, one "
+                "entry a layer; no indexer)")
+        if self.ffn != "swiglu" or self.moe is not None \
+                or self.tie_embeddings or self.mtp_layers:
+            raise ValueError(
+                "the delta-rule hybrid has swiglu dense FFNs, an untied head, "
+                "`experts` as its expert layers and no mtp_layers")
+
     @property
     def new_kind(self) -> Optional[str]:
         """None for the Llama-family block every path runs; else what a
@@ -425,6 +516,10 @@ class TransformerConfig:
         if self.block == "shortcut":
             return ("the shortcut double layer with latent attention "
                     "(block='shortcut', attention='mla')")
+        if self.kda_layers:
+            return ("delta-rule linear attention beside latent attention, "
+                    "layer by layer (block='standard', layer_mixer 'kda' | "
+                    "'latent')")
         if self.hybrid:
             return ("state-space mixers beside attention without "
                     "positions, layer by layer (block='standard', "
@@ -574,17 +669,26 @@ def init_mla(key: jax.Array, cfg: TransformerConfig) -> dict:
         # without the scales: fan-in, like every other matrix
         std = (d if cfg.mla_lora_scales else rank) ** -0.5
         return jax.random.normal(key, (rank, width), dt) * std
-    return {
+    out = {
         "ln": jnp.ones((d,), dt),
-        "wq_a": _normal(k[0], (d, cfg.q_lora_rank), dt),
-        "q_norm": jnp.ones((cfg.q_lora_rank,), dt),
-        "wq_b": up(k[1], cfg.q_lora_rank, h * qk),
         "wkv_a": _normal(k[2], (d, cfg.latent_dim), dt),
         "kv_norm": jnp.ones((cfg.kv_lora_rank,), dt),
         "wkv_b": up(k[3], cfg.kv_lora_rank,
                     h * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
         "wo": _normal(k[4], (h * cfg.v_head_dim, d), dt),
     }
+    if not cfg.q_lora_rank:
+        # a full-rank query, normed a head (``q_norm`` over its qk numbers),
+        # and in the delta-rule hybrid a gate a head on the output
+        out.update(wq=_normal(k[0], (d, h * qk), dt),
+                   q_norm=jnp.ones((qk,), dt))
+        if cfg.kda_layers:
+            out["w_gate"] = _normal(k[1], (d, h), dt)
+        return out
+    out.update(wq_a=_normal(k[0], (d, cfg.q_lora_rank), dt),
+               q_norm=jnp.ones((cfg.q_lora_rank,), dt),
+               wq_b=up(k[1], cfg.q_lora_rank, h * qk))
+    return out
 
 
 def mla_project(p: dict, x: jnp.ndarray, cfg: TransformerConfig,
@@ -600,8 +704,13 @@ def mla_project(p: dict, x: jnp.ndarray, cfg: TransformerConfig,
     nope, rank = cfg.qk_nope_head_dim, cfg.kv_lora_rank
     s_q, s_kv = cfg.mla_scales
     h = rmsnorm(x, p["ln"], cfg.norm_eps)
-    c_q = rmsnorm(h @ p["wq_a"], p["q_norm"], cfg.norm_eps)
-    q = ((c_q @ p["wq_b"]) * s_q).reshape(b, t, cfg.n_heads, -1)
+    if cfg.q_lora_rank:
+        c_q = rmsnorm(h @ p["wq_a"], p["q_norm"], cfg.norm_eps)
+        q = ((c_q @ p["wq_b"]) * s_q).reshape(b, t, cfg.n_heads, -1)
+    else:
+        c_q = None
+        q = rmsnorm((h @ p["wq"]).reshape(b, t, cfg.n_heads, -1),
+                    p["q_norm"], cfg.norm_eps)
     q_nope, q_rope = q[..., :nope], rope(q[..., nope:])
     down = h @ p["wkv_a"]
     c_kv = rmsnorm(down[..., :rank], p["kv_norm"], cfg.norm_eps) * s_kv
@@ -654,6 +763,63 @@ def init_ssm_mixer(key: jax.Array, cfg: TransformerConfig) -> dict:
     }
 
 
+def init_kda_mixer(key: jax.Array, cfg: TransformerConfig) -> dict:
+    """One delta-rule mixer's leaves (Kimi delta attention, full-rank
+    gates): ``w_in`` (hidden -> q | k | v | f, the decay's input, a
+    channel | g, the output gate, a channel | beta, a head), the depthwise
+    convolution ``conv_w`` (the 3 x ``kda_dim`` channels of q, k and v,
+    width; no bias), ``a_log`` (a head) and ``dt_bias`` (a channel), both
+    float32, the output norm's gain ``norm`` (one group over all channels)
+    and ``w_out``. The decay's constants take the Mamba-2 initialisation
+    that the public KDA layer keeps: ``exp(a_log)`` uniform in 1-16,
+    ``softplus(dt_bias)`` log-uniform in 0.001-0.1, so that a channel
+    remembers a few to thousands of tokens."""
+    d, dt, kd = cfg.d_model, cfg.dtype, cfg.kda_dim
+    k = jax.random.split(key, 5)
+    step = jnp.exp(jax.random.uniform(
+        k[3], (kd,), jnp.float32, math.log(1e-3), math.log(1e-1)))
+    return {
+        "w_in": _normal(k[0], (d, 5 * kd + cfg.kda_heads), dt),
+        "conv_w": jax.random.normal(k[1], (3 * kd, cfg.kda_conv), dt)
+        * cfg.kda_conv ** -0.5,
+        "a_log": jnp.log(jax.random.uniform(k[4], (cfg.kda_heads,),
+                                            jnp.float32, 1.0, 16.0)),
+        # the inverse of softplus
+        "dt_bias": step + jnp.log(-jnp.expm1(-step)),
+        "norm": jnp.ones((kd,), dt),
+        "w_out": _normal(k[2], (kd, d), dt),
+    }
+
+
+def _init_kda_hybrid(key: jax.Array, cfg: TransformerConfig) -> dict:
+    """The delta-rule hybrid's tree: ``layers[i]`` holds either ``ln1`` and
+    ``kda`` (:func:`init_kda_mixer`) or ``mla`` (:func:`init_mla`, its
+    ``ln`` the layer's first norm), then ``ln2`` and either the dense
+    SwiGLU's ``w1`` / ``w3`` / ``w2`` or ``moe``; an untied head."""
+    d, dt = cfg.d_model, cfg.dtype
+    kg = iter(jax.random.split(key, 2 + 2 * cfg.n_layers))
+    params = {"embed": jax.random.normal(next(kg), (cfg.vocab_size, d), dt)
+              * d ** -0.5,
+              "lm_head": _normal(next(kg), (d, cfg.vocab_size), dt),
+              "out_norm": jnp.ones((d,), dt), "layers": []}
+    for kind, ffn in zip(cfg.layer_mixer, cfg.layer_ffn):
+        if kind == "kda":
+            layer = {"ln1": jnp.ones((d,), dt),
+                     "kda": init_kda_mixer(next(kg), cfg)}
+        else:
+            layer = {"mla": init_mla(next(kg), cfg)}
+        layer["ln2"] = jnp.ones((d,), dt)
+        if ffn == "sparse":
+            layer["moe"] = init_expert_share(next(kg), d, cfg.experts, dt)
+        else:
+            k1, k2, k3 = jax.random.split(next(kg), 3)
+            layer.update(w1=_normal(k1, (d, cfg.d_ff), dt),
+                         w3=_normal(k2, (d, cfg.d_ff), dt),
+                         w2=_normal(k3, (cfg.d_ff, d), dt))
+        params["layers"].append(layer)
+    return params
+
+
 def _init_hybrid(key: jax.Array, cfg: TransformerConfig) -> dict:
     """The hybrid's tree: ``layers[i]`` holds ``ln1``, either ``ssm``
     (:func:`init_ssm_mixer`) or the attention's ``wq`` / ``wk`` / ``wv`` /
@@ -691,6 +857,8 @@ def _init_new_kind(key: jax.Array, cfg: TransformerConfig) -> dict:
     module: ``enorm`` / ``hnorm`` (the embedding's and the hidden state's
     norms), ``eh_proj`` (2 x hidden -> hidden), ``layer`` (a sparse layer)
     and ``norm`` (ahead of the shared head)."""
+    if cfg.kda_layers:
+        return _init_kda_hybrid(key, cfg)
     if cfg.hybrid:
         return _init_hybrid(key, cfg)
     d, dt = cfg.d_model, cfg.dtype
@@ -752,10 +920,13 @@ def config_from_hf(hf: dict, max_seq: int, dtype=jnp.bfloat16,
     ``zero_expert_num`` is the shortcut double layer with latent
     attention; ``model_type`` "glm4_moe_lite" is latent attention over
     every position with sigmoid routing, a shared expert and a
-    multi-token-prediction module (:func:`_config_from_glm4_moe_lite`).
-    ``experts_held`` = (offset, count) of the real experts that
-    this chip holds (default: the key ``experts_held`` of ``hf``, the one
-    key that no ``config.json`` has; else all of them)."""
+    multi-token-prediction module (:func:`_config_from_glm4_moe_lite`);
+    ``model_type`` "bailing_hybrid" is the hybrid of delta-rule linear
+    attention and latent attention with group-limited sigmoid routing
+    (:func:`_config_from_bailing_hybrid`). ``experts_held`` = (offset,
+    count) of the real experts that this chip holds (default: the key
+    ``experts_held`` of ``hf``, the one key that no ``config.json`` has;
+    else all of them)."""
     if hf.get("model_type") == "glm_moe_dsa":
         return _config_from_glm_moe_dsa(hf, max_seq, dtype, experts_held)
     if hf.get("model_type") == "glm4_moe_lite":
@@ -763,12 +934,15 @@ def config_from_hf(hf: dict, max_seq: int, dtype=jnp.bfloat16,
     if hf.get("model_type") == "granitemoehybrid":
         return _config_from_granitemoehybrid(hf, max_seq, dtype,
                                              experts_held)
+    if hf.get("model_type") == "bailing_hybrid":
+        return _config_from_bailing_hybrid(hf, max_seq, dtype, experts_held)
     eps = float(hf.get("rms_norm_eps", 1e-6))
     if hf.get("attention_method") != "MLA":
         raise ValueError(
             f"attention_method {hf.get('attention_method')!r}, model_type "
             f"{hf.get('model_type')!r}: only the MLA shortcut double layer, "
-            f"glm_moe_dsa and granitemoehybrid are read from a config.json")
+            f"glm_moe_dsa, glm4_moe_lite, granitemoehybrid and "
+            f"bailing_hybrid are read from a config.json")
     for key in ("mla_scale_q_lora", "mla_scale_kv_lora"):
         if not hf.get(key, False):
             raise ValueError(f"{key} false: latent attention without its "
@@ -958,6 +1132,91 @@ def _config_from_granitemoehybrid(hf: dict, max_seq: int, dtype,
             d_ff=hf["intermediate_size"], scoring="softmax",
             renormalise=True, d_shared=hf.get("shared_intermediate_size", 0),
             held_offset=int(offset), held_count=int(count)))
+
+
+# what the bailing_hybrid reader implements, a key at a time (a missing key
+# is what is implemented)
+_BAILING_CAN = (
+    ("score_function", "sigmoid"), ("scoring_func", "sigmoid"),
+    ("topk_method", "noaux_tc"), ("hidden_act", "silu"),
+    ("use_bias", False), ("use_qkv_bias", False),
+    ("tie_word_embeddings", False), ("rope_scaling", None),
+    ("q_lora_rank", None), ("use_kda_lora", False), ("no_kda_lora", True),
+    ("kda_safe_gate", True), ("linear_silu", True), ("use_qk_norm", True),
+    ("num_kv_heads_for_linear_attn", 0), ("use_mla_nope", False),
+    ("use_nGPT", False), ("group_norm_size", 1),
+    ("gated_attention_proj_granularity_type", "head_wise"),
+    ("scale_router_input", False), ("up_proj_norm", False),
+    ("value_norm", False), ("num_nextn_predict_layers", 0))
+
+
+def _config_from_bailing_hybrid(hf: dict, max_seq: int, dtype,
+                                experts_held) -> TransformerConfig:
+    """``model_type`` "bailing_hybrid" (Ling): layer i mixes with latent
+    attention where ``(i + 1) % layer_group_size == 0`` and with Kimi delta
+    attention elsewhere (``num_attention_heads`` heads of ``head_dim`` for
+    q, k and v, a convolution of ``short_conv_kernel_size`` on each, a
+    full-rank decay a channel bounded by ``kda_lower_bound``, a full-rank
+    output gate, one norm group); the latent layers have a full-rank query
+    normed a head (``q_lora_rank`` null, ``use_qk_norm``), interleaved
+    rotary phases on ``qk_rope_head_dim`` and a sigmoid gate a head on
+    their output. The first ``first_k_dense_replace`` layers end in a
+    dense SwiGLU of ``intermediate_size``, the others in ``num_experts``
+    sigmoid outputs with a selection bias, ``n_group`` groups of which the
+    ``topk_group`` best (by the sum of each group's two best biased scores)
+    hold the ``num_experts_per_tok`` picks, renormalised and scaled by
+    ``routed_scaling_factor``, beside ``num_shared_experts`` shared experts
+    of ``moe_shared_expert_intermediate_size``. ``num_experts`` is the
+    router's width; ``experts_held`` says which this chip holds. What
+    cannot run is refused by the name of its key."""
+    n = hf["num_hidden_layers"]
+    for key, can in _BAILING_CAN:
+        if hf.get(key, can) != can:
+            raise ValueError(f"{key} {hf[key]!r}: only {can!r} is "
+                             f"implemented for bailing_hybrid")
+    for key in ("expert_swiglu_limit_list", "share_expert_swiglu_limit_list"):
+        if any(hf.get(key, ())):
+            raise ValueError(f"{key} has a nonzero entry: the clamp of the "
+                             f"SwiGLU is not implemented for bailing_hybrid")
+    rotary = hf.get("partial_rotary_factor", 1.0) * hf["head_dim"]
+    if rotary != hf["qk_rope_head_dim"]:
+        raise ValueError(f"partial_rotary_factor x head_dim = {rotary}: only "
+                         f"qk_rope_head_dim {hf['qk_rope_head_dim']} rotary "
+                         f"numbers are implemented for bailing_hybrid")
+    group, dense = hf["layer_group_size"], hf.get("first_k_dense_replace", 0)
+    if not 0 <= dense <= n:
+        raise ValueError(f"first_k_dense_replace {dense!r} of "
+                         f"num_hidden_layers {n}")
+    n_real = hf["num_experts"]
+    offset, count = experts_held or hf.get("experts_held", (0, n_real))
+    experts = ExpertShareConfig(
+        n_outputs=n_real, top_k=hf["num_experts_per_tok"],
+        scale=float(hf["routed_scaling_factor"]),
+        d_ff=hf["moe_intermediate_size"], scoring="sigmoid",
+        renormalise=bool(hf.get("norm_topk_prob", True)),
+        d_shared=hf.get("num_shared_experts", 0)
+        * hf["moe_shared_expert_intermediate_size"],
+        n_group=hf.get("n_group", 1), topk_group=hf.get("topk_group", 1),
+        held_offset=int(offset), held_count=int(count))
+    return TransformerConfig(
+        vocab_size=hf["vocab_size"], d_model=hf["hidden_size"],
+        n_heads=hf["num_attention_heads"], n_layers=n,
+        d_ff=hf["intermediate_size"], max_seq=max_seq, dtype=dtype,
+        rope=True, rope_theta=float(hf["rope_theta"]), ffn="swiglu",
+        norm_eps=float(hf.get("rms_norm_eps", 1e-6)), attention="mla",
+        q_lora_rank=0, kv_lora_rank=hf["kv_lora_rank"],
+        qk_nope_head_dim=hf["qk_nope_head_dim"],
+        qk_rope_head_dim=hf["qk_rope_head_dim"],
+        v_head_dim=hf["v_head_dim"], block="standard",
+        mla_lora_scales=False,
+        layer_mixer=tuple("latent" if (i + 1) % group == 0 else "kda"
+                          for i in range(n)),
+        layer_ffn=("dense",) * dense + ("sparse",) * (n - dense),
+        kda_heads=hf["num_attention_heads"], kda_head_dim=hf["head_dim"],
+        kda_conv=hf["short_conv_kernel_size"],
+        kda_lower_bound=float(hf["kda_lower_bound"]),
+        rope_interleave=bool(hf.get("rope_interleave", False)),
+        experts=experts if dense < n else None)
 
 
 AttnFn = Callable[[jnp.ndarray, jnp.ndarray, jnp.ndarray], jnp.ndarray]

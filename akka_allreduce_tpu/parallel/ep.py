@@ -290,10 +290,14 @@ class ExpertShareConfig:
     """``n_outputs`` is the router's width: ``n_outputs - n_identity``
     experts with weights (SwiGLU, width ``d_ff``), then ``n_identity``
     identity experts. A token takes its ``top_k`` outputs by score + bias
-    and weighs each by ``scale`` x its score alone. ``scoring`` is what
-    turns the router's outputs into scores, a "softmax" over all of them
-    or a "sigmoid" of each; with ``renormalise`` the picked scores are
-    divided by their sum before the scale. ``d_shared`` > 0 is a shared
+    and weighs each by ``scale`` x its score alone. With ``n_group`` > 1
+    the outputs fall in that many equal groups, a group's worth is the sum
+    of its two best scores + bias, and the picks come from the
+    ``topk_group`` best groups alone (DeepSeek-V3's group limit).
+    ``scoring`` is what turns the router's outputs into scores, a
+    "softmax" over all of them or a "sigmoid" of each; with
+    ``renormalise`` the picked scores are divided by their sum before the
+    scale. ``d_shared`` > 0 is a shared
     expert of that width beside the routed ones: a SwiGLU every token
     takes at weight 1, which every chip of a deployment computes alike.
     This chip holds the real experts ``[held_offset, held_offset +
@@ -309,6 +313,8 @@ class ExpertShareConfig:
     scoring: str = "softmax"
     renormalise: bool = False
     d_shared: int = 0
+    n_group: int = 1
+    topk_group: int = 1
 
     @property
     def n_real(self) -> int:
@@ -324,6 +330,17 @@ class ExpertShareConfig:
             raise ValueError(f"unknown scoring {self.scoring!r}")
         if self.d_shared < 0:
             raise ValueError(f"d_shared={self.d_shared}")
+        if self.n_group < 1 or self.n_outputs % self.n_group \
+                or not 1 <= self.topk_group <= self.n_group \
+                or self.n_group > 1 and (
+                    self.n_identity or self.n_outputs // self.n_group < 2
+                    or self.top_k > self.topk_group * self.n_outputs
+                    // self.n_group):
+            raise ValueError(
+                f"n_group={self.n_group}, topk_group={self.topk_group}: the "
+                f"groups divide the {self.n_outputs} outputs, two or more "
+                f"each (no identity expert among them), and the kept ones "
+                f"hold top_k={self.top_k}")
         if self.held_count < 1 or self.held_offset < 0 \
                 or self.held_offset + self.held_count > self.n_real:
             raise ValueError(
@@ -360,7 +377,9 @@ def dropless_route(h: jnp.ndarray, params: dict, cfg: ExpertShareConfig
     """h (N, D) -> (pick (N, k) int32, weight (N, k) f32). Scores are in
     float32 over all ``n_outputs``, a softmax or a sigmoid each
     (``cfg.scoring``); the bias enters the choice only; the picked scores
-    are renormalised to sum to 1 only under ``cfg.renormalise``."""
+    are renormalised to sum to 1 only under ``cfg.renormalise``; with
+    ``cfg.n_group`` > 1 the choice is limited to the best groups
+    (:func:`_group_limited`)."""
     logits = jnp.matmul(h.astype(jnp.float32),
                         params["router"].astype(jnp.float32),
                         precision=lax.Precision.HIGHEST)
@@ -368,11 +387,28 @@ def dropless_route(h: jnp.ndarray, params: dict, cfg: ExpertShareConfig
         scores = jax.nn.sigmoid(logits)
     else:
         scores = jax.nn.softmax(logits, axis=-1)
-    _, pick = lax.top_k(scores + params["bias"], cfg.top_k)
+    choice = scores + params["bias"]
+    if cfg.n_group > 1:
+        choice = _group_limited(choice, cfg)
+    _, pick = lax.top_k(choice, cfg.top_k)
     weight = jnp.take_along_axis(scores, pick, axis=-1)
     if cfg.renormalise:
         weight = weight / (weight.sum(-1, keepdims=True) + 1e-20)
     return pick.astype(jnp.int32), weight * cfg.scale
+
+
+def _group_limited(choice: jnp.ndarray, cfg: ExpertShareConfig
+                   ) -> jnp.ndarray:
+    """``choice`` (N, n_outputs), the biased scores, with every output of a
+    group outside a token's ``topk_group`` best at -inf: a group's worth is
+    the sum of its two best biased scores."""
+    n = choice.shape[0]
+    grouped = choice.reshape(n, cfg.n_group, -1)
+    worth = lax.top_k(grouped, 2)[0].sum(-1)               # (N, groups)
+    _, best = lax.top_k(worth, cfg.topk_group)
+    keep = jnp.zeros((n, cfg.n_group), bool).at[
+        jnp.arange(n)[:, None], best].set(True)
+    return jnp.where(keep[:, :, None], grouped, -jnp.inf).reshape(n, -1)
 
 
 def _row_buffer(rows: int) -> int:

@@ -355,7 +355,10 @@ HOST_GC = "host_gc"
 # ``serve_prefill.chunk`` carries ``scan_tokens`` and ``scan_padded``: the
 # positions the layers' scans counted and the padding they ran over and let
 # advance nothing, both times the layers (all four zero for every other
-# model). Fields of a span that belong to another layer than the span's own
+# model). Of a model whose layers carry a delta rule's state the same four
+# are ``kda_lanes`` / ``kda_idle_lanes`` and ``kda_tokens`` / ``kda_padded``,
+# over its "kda" layers (zero for every other model). Fields of a span that
+# belong to another layer than the span's own
 # have a row in ``SPAN_FIELDS``. ``sched_pop_ready`` carries ``queue_depth``
 # and, when it returns a request, ``rid`` and ``waited_ms``: from the
 # request's ``arrival`` (the hand-over) to the pop's close on the tracer's
@@ -400,9 +403,14 @@ SSM_LANES = "ssm_lanes"
 SSM_IDLE_LANES = "ssm_idle_lanes"
 SCAN_TOKENS = "scan_tokens"
 SCAN_PADDED = "scan_padded"
+KDA_LANES = "kda_lanes"
+KDA_IDLE_LANES = "kda_idle_lanes"
+KDA_TOKENS = "kda_tokens"
+KDA_PADDED = "kda_padded"
 _LATENT = "latent attention (models/generate.py)"
 _INDEXER = "sparse attention indexer (models/generate.py)"
 _SSM = "state-space mixer (models/generate.py)"
+_KDA = "linear attention (models/generate.py)"
 # span -> field -> (layer, the quantity that reads it)
 SPAN_FIELDS = {
     SERVE_STEP: {KV_BLOCKS_LIVE: (_LATENT, "-"),
@@ -411,12 +419,16 @@ SPAN_FIELDS = {
                  INDEX_SELECTED: (_INDEXER,
                                   "glm_selected_pct, glm_decode_roofline"),
                  SSM_LANES: (_SSM, "grn_decode_roofline"),
-                 SSM_IDLE_LANES: (_SSM, "-")},
+                 SSM_IDLE_LANES: (_SSM, "-"),
+                 KDA_LANES: (_KDA, "lng_decode_roofline"),
+                 KDA_IDLE_LANES: (_KDA, "-")},
     SERVE_PREFILL_CHUNK: {KEY_BLOCKS_LIVE: (_LATENT, "-"),
                           KEY_BLOCKS_SKIPPED: (_LATENT, "-"),
                           SCAN_TOKENS: (_SSM, "grn_scan_roofline, "
                                         "grn_scan_padded_pct"),
-                          SCAN_PADDED: (_SSM, "grn_scan_padded_pct")},
+                          SCAN_PADDED: (_SSM, "grn_scan_padded_pct"),
+                          KDA_TOKENS: (_KDA, "lng_kda_chunk_roofline"),
+                          KDA_PADDED: (_KDA, "-")},
 }
 
 # ``jax.named_scope`` names inside the jitted train step and the serving
@@ -431,6 +443,9 @@ SPAN_FIELDS = {
 # ``ssm_scan`` is a prefill's scan (everything between the convolution and
 # the gated norm) and ``ssm_step`` a decode step's state update and
 # read-out. The hybrid's one attention layer goes under ``attention``.
+# ``kda_mixer`` holds a delta-rule mixer from ``w_in`` to ``w_out``, and
+# inside it ``kda_chunk`` the chunked form (between the convolution and the
+# gated norm) and ``kda_step`` a decode step's state update and read-out.
 SCOPE_SYNC_PACK = "grad_sync/pack"
 SCOPE_SYNC_REDUCE = "grad_sync/reduce"
 SCOPE_SYNC_UNPACK = "grad_sync/unpack"
@@ -446,6 +461,9 @@ SCOPE_MOE_SHARED = "moe_shared"
 SCOPE_SSM_MIXER = "ssm_mixer"
 SCOPE_SSM_SCAN = "ssm_scan"
 SCOPE_SSM_STEP = "ssm_step"
+SCOPE_KDA_MIXER = "kda_mixer"
+SCOPE_KDA_CHUNK = "kda_chunk"
+SCOPE_KDA_STEP = "kda_step"
 SCOPE_MTP = "mtp"
 
 _SYNC = "gradient sync (parallel/dp.py, ops/collectives.py)"
@@ -484,6 +502,9 @@ SCOPES = {
     SCOPE_SSM_SCAN: (_SSM, "grn_ssm_device_pct, grn_scan_roofline"),
     SCOPE_SSM_STEP: (_SSM, "grn_ssm_device_pct"),
     SCOPE_SSM_MIXER: (_SSM, "grn_ssm_device_pct"),
+    SCOPE_KDA_CHUNK: (_KDA, "lng_kda_device_pct, lng_kda_chunk_roofline"),
+    SCOPE_KDA_STEP: (_KDA, "lng_kda_device_pct"),
+    SCOPE_KDA_MIXER: (_KDA, "lng_kda_device_pct"),
 }
 
 # the scopes of the cached-block functions: in the serving programs only
@@ -493,7 +514,8 @@ SERVING_SCOPES = frozenset({SCOPE_MLA_ATTENTION, SCOPE_DENSE_FFN,
                             SCOPE_MOE_ROUTER, SCOPE_MOE_EXPERTS,
                             SCOPE_SPARSE_INDEXER, SCOPE_MOE_SHARED,
                             SCOPE_SSM_MIXER, SCOPE_SSM_SCAN,
-                            SCOPE_SSM_STEP})
+                            SCOPE_SSM_STEP, SCOPE_KDA_MIXER,
+                            SCOPE_KDA_CHUNK, SCOPE_KDA_STEP})
 
 _annotation = None     # jax.profiler.TraceAnnotation, from the first span
 _flight = None         # the process's own record, from the first use

@@ -387,7 +387,7 @@ class PagedEngineConfig(EngineConfig):
 
 
 _KV_KEYS = ("k", "v", "k_scale", "v_scale", "latent", "index_k",
-            "ssm_state", "conv_state")
+            "ssm_state", "conv_state", "kda_state", "kda_conv")
 
 
 # how many numbers a token's route counts are (held, identity, absent),
@@ -1871,10 +1871,17 @@ class ServingEngine:
                 padded_out = n_ssm * length - scanned
                 if scanned and self.metrics is not None:
                     self.metrics.on_scan(scanned, padded_out)
+                # the same for the delta rule's chunked form
+                n_kda = len(self.cfg.kda_layers)
+                kda_tokens = n_kda * min(length, n_full - offset)
+                kda_padded = n_kda * length - kda_tokens
+                if kda_tokens and self.metrics is not None:
+                    self.metrics.on_kda("chunk", kda_tokens, kda_padded)
                 with span(SERVE_PREFILL_CHUNK, self.tracer, rid=req.rid,
                           offset=offset, key_blocks_live=live,
                           key_blocks_skipped=skipped, scan_tokens=scanned,
-                          scan_padded=padded_out):
+                          scan_padded=padded_out, kda_tokens=kda_tokens,
+                          kda_padded=kda_padded):
                     self._state = _engine_prefill_chunk(
                         self.params, self._state,
                         jnp.asarray(padded[None, offset:offset + length]),
@@ -2335,10 +2342,14 @@ class ServingEngine:
             n_ssm = len(self.cfg.ssm_layers)
             ssm_busy = n_ssm * (len(older.lanes) - dropped)
             ssm_idle = n_ssm * self.num_slots - ssm_busy
+            n_kda = len(self.cfg.kda_layers)
+            kda_busy = n_kda * (len(older.lanes) - dropped)
+            kda_idle = n_kda * self.num_slots - kda_busy
             step_span.set(ahead=int(ahead), discarded=dropped,
                           kv_blocks_live=live, kv_blocks_skipped=skipped,
                           index_scanned=scanned, index_selected=selected,
-                          ssm_lanes=ssm_busy, ssm_idle_lanes=ssm_idle)
+                          ssm_lanes=ssm_busy, ssm_idle_lanes=ssm_idle,
+                          kda_lanes=kda_busy, kda_idle_lanes=kda_idle)
             if self.metrics is not None:
                 if ahead or dropped:
                     self.metrics.on_lookahead(ahead, dropped)
@@ -2348,6 +2359,8 @@ class ServingEngine:
                     self.metrics.on_index(scanned, selected)
                 if n_ssm:
                     self.metrics.on_ssm(ssm_busy, ssm_idle)
+                if n_kda:
+                    self.metrics.on_kda("step", kda_busy, kda_idle)
             return finished
 
     def _launches_ahead(self) -> bool:

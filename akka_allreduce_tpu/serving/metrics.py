@@ -114,6 +114,11 @@ class ServingMetrics:
         self.ssm_idle_lanes = 0
         self.scan_tokens = 0
         self.scan_padded = 0
+        # the same for a model whose layers carry a delta rule's state
+        self.kda_lanes = 0
+        self.kda_idle_lanes = 0
+        self.kda_tokens = 0
+        self.kda_padded = 0
         self.requests_submitted = 0
         self.requests_completed = 0
         self.requests_rejected = 0
@@ -463,6 +468,34 @@ class ServingMetrics:
         self.scan_tokens += tokens
         self.scan_padded += padded
 
+    def on_kda(self, phase: str, done: int, idle: int) -> None:
+        """A dispatch of a model whose layers carry a delta rule's state:
+        at ``phase`` "step" a committed decode dispatch, the lane-layers
+        whose state it advanced for a request (``done``: busy lanes x
+        delta-rule layers; 2 MB read and written each at the published
+        sizes) and those it stepped for no one (``idle``); at "chunk" a
+        prefill program's dispatch, the positions its chunked forms counted
+        (``done``, x the layers) and the padding they let advance nothing
+        (``idle``)."""
+        if phase == "step":
+            if not self.kda_lanes + self.kda_idle_lanes:
+                self._register_kinds(
+                    "serve_kda_lane_steps_total", "kda_",
+                    ("lanes", "idle_lanes"),
+                    "lane-layers whose delta-rule state a decode step "
+                    "advanced for a request (lanes) and for no one "
+                    "(idle_lanes)")
+            self.kda_lanes += done
+            self.kda_idle_lanes += idle
+            return
+        if not self.kda_tokens:
+            self._register_kinds(
+                "serve_kda_positions_total", "kda_", ("tokens", "padded"),
+                "positions a prefill's delta-rule chunks counted (tokens) "
+                "and ran over as padding (padded)")
+        self.kda_tokens += done
+        self.kda_padded += idle
+
     def on_token(self, rid: int, submitted_at: float) -> None:
         """Called per emitted token; the first emission banks TTFT."""
         self.on_block_tokens(rid, submitted_at, 1)
@@ -698,6 +731,11 @@ class ServingMetrics:
                           "idle_lanes": self.ssm_idle_lanes,
                           "scan_tokens": self.scan_tokens,
                           "scan_padded": self.scan_padded}
+        if self.kda_lanes + self.kda_idle_lanes:
+            out["kda"] = {"lanes": self.kda_lanes,
+                          "idle_lanes": self.kda_idle_lanes,
+                          "tokens": self.kda_tokens,
+                          "padded": self.kda_padded}
         if self.draft_proposed:
             # the speculation story (speculative engines only): the
             # same cells the serve_draft_* collectors read

@@ -560,3 +560,93 @@ def test_the_latent_train_step_compiles_with_the_kernel_at_head_size_256(
     assert all("mla_attention" in n for n in names), names
     assert "ragged-dot-none" in hlo
     assert "held_experts_stream" not in hlo
+
+
+# -- the hybrid of delta-rule and latent attention at the published widths --
+
+LING = dict(
+    model_type="bailing_hybrid", vocab_size=39296, hidden_size=2560,
+    num_hidden_layers=8, layer_group_size=6, first_k_dense_replace=2,
+    num_attention_heads=32, head_dim=128, short_conv_kernel_size=4,
+    kda_lower_bound=-5, kv_lora_rank=512, qk_nope_head_dim=128,
+    qk_rope_head_dim=64, v_head_dim=128, partial_rotary_factor=0.5,
+    rope_theta=6000000, rope_interleave=True, q_lora_rank=None,
+    intermediate_size=6144, moe_intermediate_size=768,
+    moe_shared_expert_intermediate_size=768, num_shared_experts=1,
+    num_experts=512, num_experts_per_tok=8, n_group=8, topk_group=4,
+    routed_scaling_factor=2.5, norm_topk_prob=True, rms_norm_eps=1e-6,
+    experts_held=[0, 128])
+LNG_LANES, LNG_MAX_SEQ, LNG_CHUNK = 64, 24576, 2048
+LNG_STATE = 7 * LNG_LANES * 32 * 128 * 128 * 4
+LNG_LATENT = LNG_LANES * LNG_MAX_SEQ * 576 * 2
+
+
+@pytest.fixture(scope="module")
+def ling(one_chip):
+    """(cfg, params, engine state, both on the described chip as shapes)."""
+    from akka_allreduce_tpu.models.transformer import init_transformer
+    cfg = config_from_hf(LING, LNG_MAX_SEQ, jnp.bfloat16)
+    params = jax.eval_shape(lambda k: init_transformer(k, cfg),
+                            jax.random.key(0))
+    return (cfg, _on(one_chip, params),
+            _on(one_chip, _engine_state(cfg, LNG_LANES)))
+
+
+def test_the_delta_rule_decode_step_fits_and_updates_its_state_in_place(
+        one_chip, ling, chip_rule, monkeypatch):
+    """64 lanes x 24,576 beside 10.68 GB of weights: the step's arguments
+    are 80% of the chip, each layer's state is donated and updated in
+    place (no second copy of 0.94 GB), the held experts stream and the
+    latent layer's decode is the fused kernel over the cache as it lies."""
+    from akka_allreduce_tpu.ops.pallas_kernels.attention import (
+        pick_latent_tiling)
+    cfg, params, state = ling
+    assert sum(x.size for x in state["kda_state"]) * 4 == LNG_STATE \
+        == 939_524_096
+    assert state["latent"].size * 2 == LNG_LATENT == 1_811_939_328
+    weights = sum(x.size * x.dtype.itemsize
+                  for x in jax.tree.leaves(params))
+    # 5,342,058,912 parameters in bf16, the float32 ones twice over: the
+    # six selection biases and seven layers' A_log and dt_bias
+    assert weights == 2 * 5_342_058_912 + 2 * (6 * 512 + 7 * (32 + 4096))
+    tiling = pick_latent_tiling(LNG_LANES, LNG_MAX_SEQ, 576, jnp.bfloat16)
+    assert tiling == (4, 256)
+    monkeypatch.setattr(G, "latent_decode_path",
+                        lambda pos, latent: (False, tiling))
+    pos = jax.ShapeDtypeStruct((LNG_LANES,), jnp.int32, sharding=one_chip)
+    compiled = _step_on_chip(params, state, pos, cfg)
+    m = compiled.memory_analysis()
+    assert _device_bytes(compiled) <= CHIP_BYTES
+    assert m.argument_size_in_bytes > 13.4e9
+    assert m.alias_size_in_bytes >= LNG_STATE + LNG_LATENT
+    assert m.temp_size_in_bytes < 100_000_000
+    hlo = compiled.as_text()
+    assert len(_streams(hlo)) == 6 and "ragged-dot" not in hlo
+    entry = hlo[hlo.index("ENTRY "):]
+    layer = rf"f32\[{LNG_LANES},32,128,128\]"
+    assert not re.search(rf"= {layer}\S* (copy|transpose)\(", entry)
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"',
+                          entry)) == 7
+
+
+def test_the_delta_rule_chunk_program_fits_beside_weights_and_state(
+        one_chip, ling):
+    from akka_allreduce_tpu.serving import engine as eng
+    cfg, params, state = ling
+    i32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    tokens = jax.ShapeDtypeStruct((1, LNG_CHUNK), jnp.int32,
+                                  sharding=one_chip)
+    compiled = _lower_off_cache(eng._engine_prefill_chunk.lower(
+        params, state, tokens, i32, i32, i32, cfg))
+    m = compiled.memory_analysis()
+    assert _device_bytes(compiled) <= CHIP_BYTES
+    # the chunk's temporaries: the latent layer's lane (28 MB) and a block
+    # of 128 queries' scores against a key block, a block of 64 positions'
+    # pairwise decays in the delta rule, the expert share's buffer (0.51 GB
+    # compiled for the described v5e)
+    assert m.temp_size_in_bytes < 700_000_000
+    assert m.alias_size_in_bytes >= LNG_STATE + LNG_LATENT
+    hlo = compiled.as_text()
+    layer = rf"f32\[{LNG_LANES},32,128,128\]"
+    assert not re.search(rf"= {layer}\S* (copy|transpose)\(", hlo)
+    assert G.kda_chunk_path(LNG_CHUNK) == 64

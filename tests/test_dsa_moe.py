@@ -751,7 +751,9 @@ def test_the_scopes_are_in_the_decode_and_the_chunk_programs():
     for hlo in (step, chunk):
         names = " ".join(re.findall(r'op_name="([^"]*)"', hlo))
         for sc in T.SERVING_SCOPES - {T.SCOPE_SSM_MIXER, T.SCOPE_SSM_SCAN,
-                                      T.SCOPE_SSM_STEP}:
+                                      T.SCOPE_SSM_STEP,
+                                      T.SCOPE_KDA_MIXER, T.SCOPE_KDA_CHUNK,
+                                      T.SCOPE_KDA_STEP}:
             assert f"/{sc}/" in names, sc
         assert "/attention/" not in names and "/ssm_mixer/" not in names
         # the choice (a top-k beside the router's) is the indexer's; in

@@ -614,7 +614,9 @@ def test_the_new_scopes_are_in_the_decode_and_prefill_programs():
         # state-space mixer
         for sc in T.SERVING_SCOPES - {T.SCOPE_SPARSE_INDEXER,
                                       T.SCOPE_MOE_SHARED, T.SCOPE_SSM_MIXER,
-                                      T.SCOPE_SSM_SCAN, T.SCOPE_SSM_STEP}:
+                                      T.SCOPE_SSM_SCAN, T.SCOPE_SSM_STEP,
+                                      T.SCOPE_KDA_MIXER, T.SCOPE_KDA_CHUNK,
+                                      T.SCOPE_KDA_STEP}:
             assert f"/{sc}/" in names, sc
         assert "/attention/" not in names
         assert "sparse_indexer" not in names and "moe_shared" not in names

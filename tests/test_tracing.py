@@ -324,6 +324,8 @@ class TestSpanPrimitive:
         indexer = T.SCOPES[T.SCOPE_SPARSE_INDEXER][0]
         ssm = T.SCOPES[T.SCOPE_SSM_MIXER][0]
         assert ssm == "state-space mixer (models/generate.py)"
+        kda = T.SCOPES[T.SCOPE_KDA_MIXER][0]
+        assert kda == "linear attention (models/generate.py)"
         assert T.SPAN_FIELDS[T.SERVE_STEP] == {
             "kv_blocks_live": (T.SCOPES[T.SCOPE_MLA_ATTENTION][0], "-"),
             "kv_blocks_skipped": (T.SCOPES[T.SCOPE_MLA_ATTENTION][0], "-"),
@@ -333,7 +335,10 @@ class TestSpanPrimitive:
             # a model whose layers carry a recurrent state: lane-layers
             # the committed dispatch advanced for a request, and for no one
             "ssm_lanes": (ssm, "grn_decode_roofline"),
-            "ssm_idle_lanes": (ssm, "-")}
+            "ssm_idle_lanes": (ssm, "-"),
+            # the same of a model whose layers carry a delta rule's state
+            "kda_lanes": (kda, "lng_decode_roofline"),
+            "kda_idle_lanes": (kda, "-")}
         # a prefill chunk's masked attentions: the lane's key blocks they
         # scored and left unscored; its state-space scans: the positions
         # they counted and the padding they ran over
@@ -342,7 +347,9 @@ class TestSpanPrimitive:
             "key_blocks_skipped": (T.SCOPES[T.SCOPE_MLA_ATTENTION][0],
                                    "-"),
             "scan_tokens": (ssm, "grn_scan_roofline, grn_scan_padded_pct"),
-            "scan_padded": (ssm, "grn_scan_padded_pct")}
+            "scan_padded": (ssm, "grn_scan_padded_pct"),
+            "kda_tokens": (kda, "lng_kda_chunk_roofline"),
+            "kda_padded": (kda, "-")}
         assert (T.KEY_BLOCKS_LIVE, T.KEY_BLOCKS_SKIPPED) == (
             "key_blocks_live", "key_blocks_skipped")
         assert (T.SSM_LANES, T.SSM_IDLE_LANES, T.SCAN_TOKENS,
@@ -914,9 +921,11 @@ def test_names_the_benchmark_reads_are_pinned():
     assert T.SERVING_SCOPES == {
         "mla_attention", "dense_ffn", "moe_router", "moe_experts",
         "sparse_indexer", "moe_shared", "ssm_mixer", "ssm_scan",
-        "ssm_step"}, (
+        "ssm_step", "kda_mixer", "kda_chunk", "kda_step"}, (
         "benchmark/readers/latent_moe.py (lcr_experts_device_pct, "
         "lcr_mla_device_pct, glm_indexer_device_pct, glm_mla_device_pct, "
         "glm_experts_device_pct, grn_ssm_device_pct, "
-        "grn_experts_device_pct) and benchmark/readers/hybrid_ssm.py "
-        "(grn_scan_roofline) read these scope names")
+        "grn_experts_device_pct, lng_kda_device_pct, lng_mla_device_pct, "
+        "lng_experts_device_pct), benchmark/readers/hybrid_ssm.py "
+        "(grn_scan_roofline) and benchmark/readers/hybrid_kda.py "
+        "(lng_kda_chunk_roofline) read these scope names")
